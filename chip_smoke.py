@@ -1,0 +1,318 @@
+"""Bring-up proof: serve Qwen3-1.7B on one TPU chip through the entry
+points a user calls, and check what comes out.
+
+    python chip_smoke.py              # one chip; fails where there is none
+    python chip_smoke.py --chips 4    # the TP=4 phase only (four-chip host)
+    JAX_PLATFORMS=cpu python chip_smoke.py --rehearse   # tiny model, CPU
+
+One process, top to bottom: `initialize_distributed()` ->
+`AutoLLM.from_config(qwen3_1p7b())` -> `Engine` -> `TokenServer(paged=True,
+prefix_cache=True)` -> `request_stream` clients on threads. Two phases:
+
+1. engine differential: `Engine(backend="flash")` (the Pallas kernels)
+   against `Engine(backend="xla")` on the same weights and prompts —
+   prefill logits, then every step of a 16-step decode through the
+   cache, teacher-forced by the reference's greedy token;
+2. server: six concurrent clients, three sharing a 64-token prefix;
+   every stream must end `done` with the asked count and no error, the
+   prefix cache must hit, and the page pool must balance at the end.
+
+Any failed check or exception ends the run non-zero; nothing is caught
+and downgraded. Earlier output lines are logs of this run (one JSON
+object each), not measurements. The LAST line is the verdict the driver
+reads: {"ok": true, "device": {"platform", "kind", "count"}}.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import sys
+import threading
+import time
+
+# --- what the run is held to -------------------------------------------
+BATCH, PAGE, N_CLIENTS, N_SHARING = 8, 16, 6, 3
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    prompt_len: int      # engine differential: prompt tokens per row
+    decode_steps: int    # ... and decode steps through the cache
+    max_seq: int         # cache capacity per slot
+    gen_len: int         # server: tokens asked per client
+
+
+ON_CHIP = Sizes(prompt_len=128, decode_steps=16, max_seq=512, gen_len=32)
+# the CPU rehearsal interprets every Pallas kernel; same control flow,
+# fewer positions, so it fits a tier-1 test
+REHEARSAL = Sizes(prompt_len=32, decode_steps=4, max_seq=128, gen_len=8)
+
+# Logit tolerance, as a fraction of the reference's largest |logit|.
+# The two backends run the same equations with different accumulation
+# orders (online-softmax tiles vs one softmax; bf16 rounding between
+# kernels), so they agree to a few units of the activation dtype's
+# epsilon (bf16 2^-8, f32 2^-23) compounded through the layer stack —
+# not bitwise. A wrong mask, offset or page mapping moves logits by
+# the logits' own scale, two orders above either bound.
+TOL_REL = {"bfloat16": 4e-2, "float32": 1e-4}
+
+
+class SmokeFailure(RuntimeError):
+    """A check of this script did not hold."""
+
+
+def _log(**fields) -> None:
+    print(json.dumps(fields), flush=True)
+
+
+def _require(cond: bool, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(what)
+
+
+def _timed(fn):
+    """(result, wall seconds) with the device work finished inside."""
+    import jax
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn())
+    return out, round(time.perf_counter() - t0, 3)
+
+
+def _versions() -> dict:
+    import importlib.metadata as md
+    import jax
+    import jaxlib
+    try:
+        libtpu = md.version("libtpu")
+    except md.PackageNotFoundError:
+        libtpu = None
+    return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "libtpu": libtpu}
+
+
+def _peak_bytes(device):
+    stats = device.memory_stats()          # None on backends without it
+    return None if stats is None else stats.get("peak_bytes_in_use")
+
+
+def _compare(ref, got, tol_rel: float) -> dict:
+    """Max logit error of `got` against `ref` ([B, V] float32), and
+    greedy-token agreement wherever the reference's top-2 margin is
+    wider than the tolerance (random-init logits are nearly flat, so a
+    bare token equality would fail on rounding, not on bugs)."""
+    import numpy as np
+    ref = np.asarray(ref, np.float32)
+    got = np.asarray(got, np.float32)
+    _require(ref.shape == got.shape, f"shapes {ref.shape} {got.shape}")
+    _require(bool(np.isfinite(got).all()), "non-finite logits")
+    tol = tol_rel * float(np.abs(ref).max())
+    err = float(np.abs(got - ref).max())
+    top2 = np.sort(ref, axis=-1)[:, -2:]
+    decided = (top2[:, 1] - top2[:, 0]) > 2 * tol
+    agree = ref.argmax(-1) == got.argmax(-1)
+    return {"max_err": err, "tol": tol,
+            "rows_decided": int(decided.sum()),
+            "rows_disagree": int((decided & ~agree).sum())}
+
+
+def _differential(ref_eng, eng, ids, steps: int, tol_rel: float,
+                  label: str) -> None:
+    """`eng` against `ref_eng` (same model object): prefill, then
+    `steps` single-token decode scans through each engine's own cache.
+    Both are fed the reference's logits, so both take the reference's
+    greedy token and one early near-tie cannot void the later steps."""
+    model = eng.model
+    (lx, cx), ref_first = _timed(lambda: ref_eng.prefill(ids))
+    (lf, cf), first = _timed(lambda: eng.prefill(ids))
+    _, second = _timed(lambda: eng.prefill(ids))
+    worst = _compare(lx, lf, tol_rel)
+    _log(phase=f"{label}.prefill", backend=eng.backend,
+         ref_backend=ref_eng.backend, first_call_s=first,
+         second_call_s=second, ref_first_call_s=ref_first, **worst)
+    _require(worst["max_err"] <= worst["tol"]
+             and not worst["rows_disagree"], f"{label} prefill: {worst}")
+    step_s = []
+    for t in range(steps):
+        (_, lx_next, cx), _ = _timed(lambda: ref_eng._decode_scan(
+            model, lx, cx, gen_len=1))
+        (_, lf, cf), dt = _timed(lambda: eng._decode_scan(
+            model, lx, cf, gen_len=1))
+        lx = lx_next
+        step_s.append(dt)
+        cmp = _compare(lx, lf, tol_rel)
+        _require(cmp["max_err"] <= cmp["tol"]
+                 and not cmp["rows_disagree"],
+                 f"{label} decode step {t}: {cmp}")
+        if cmp["max_err"] >= worst["max_err"]:
+            worst = cmp
+    _log(phase=f"{label}.decode", steps=steps, first_call_s=step_s[0],
+         second_call_s=step_s[1], last_step=cmp, worst_step=worst)
+
+
+def _serve(eng, vocab: int, gen_len: int, seed: int) -> None:
+    """TokenServer on an ephemeral port, served from a thread; the
+    clients are threads of this process too (a chip belongs to one
+    process)."""
+    import numpy as np
+    from triton_dist_tpu.serving import (ByteTokenizer, TokenServer,
+                                         request_stream)
+    rng = np.random.RandomState(seed)
+
+    def text(n):                            # n printable ASCII bytes
+        return bytes(rng.randint(32, 127, size=n).tolist()).decode()
+
+    # 64 shared bytes = four whole pages; every prompt is 80 tokens, so
+    # admission compiles two suffix buckets (80 cold, 16 after a hit)
+    shared = text(4 * PAGE)
+    prompts = [shared + text(PAGE) for _ in range(N_SHARING)] + \
+        [text(5 * PAGE) for _ in range(N_CLIENTS - N_SHARING)]
+
+    srv = TokenServer(eng, ByteTokenizer(vocab), batch=BATCH, paged=True,
+                      prefix_cache=True, page=PAGE)
+    failures: list = []
+
+    def guarded(fn, *args):
+        try:
+            fn(*args)
+        except BaseException as e:           # re-raised on the main thread
+            failures.append(e)
+
+    results: dict = {}
+
+    def client(i):
+        toks, done = [], None
+        for msg in request_stream(srv.host, srv.port, prompts[i],
+                                  gen_len=gen_len, timeout=900.0):
+            if msg.get("done"):
+                done = msg
+                break
+            toks.extend(msg["token_ids"])
+        results[i] = (toks, done)
+
+    server = threading.Thread(
+        target=guarded, name="smoke-server",
+        args=(lambda: srv.serve_forever(max_requests=N_CLIENTS),))
+    clients = [threading.Thread(target=guarded, args=(client, i),
+                                name=f"smoke-client-{i}")
+               for i in range(N_CLIENTS)]
+    t0 = time.perf_counter()
+    server.start()
+    for c in clients:
+        c.start()
+    for c in clients:
+        c.join(timeout=1000)
+    srv.stop()
+    server.join(timeout=60)
+    wall = round(time.perf_counter() - t0, 3)
+    if failures:
+        raise failures[0]
+    alive = [t.name for t in [server, *clients] if t.is_alive()]
+    _require(not alive, f"threads still running: {alive}")
+
+    for i in range(N_CLIENTS):
+        toks, done = results.get(i, ([], None))
+        _require(done is not None, f"client {i}: no done message")
+        _require("error" not in done, f"client {i}: {done}")
+        _require(len(toks) == gen_len == done["n_tokens"],
+                 f"client {i}: {len(toks)} tokens, done={done}")
+        _require(all(0 <= t < vocab for t in toks),
+                 f"client {i}: token out of vocab")
+    st = srv.stats()
+    pool = srv.sched.slots.prefix.pool
+    keep = ("admissions", "hits", "hit_rate", "prompt_tokens",
+            "prefill_tokens_skipped", "prefill_skip_frac", "evictions",
+            "pages_in_use", "pages_free", "pages_outstanding",
+            "host_ms_per_poll", "tokens_generated", "polls")
+    _log(phase="server", wall_s=wall, clients=N_CLIENTS,
+         gen_len=gen_len, pool_pages=pool.num_pages,
+         stats={k: st[k] for k in keep if k in st})
+    _require(st["hits"] >= 1 and st["prefill_tokens_skipped"] > 0,
+             f"prefix cache never hit: {st['hits']} hits")
+    _require(pool.available + pool.outstanding == pool.num_pages,
+             f"page pool leaks: {pool.available} + {pool.outstanding} "
+             f"!= {pool.num_pages}")
+
+
+def _tune_stores_absent() -> None:
+    """A populated tune store under the user's home would change block
+    shapes from outside git; this run must not be steered by one."""
+    import os
+    from triton_dist_tpu.tools import sweep, tune
+    for path in (sweep.default_store_path(), tune.default_cache_path()):
+        exists = os.path.exists(path)
+        _log(tune_store=path, exists=exists)
+        _require(not exists, f"tune store {path} exists: this run's "
+                 "block shapes would come from outside the checkout")
+
+
+def _one_chip(device, cfg, sz: Sizes, seed: int) -> None:
+    import jax
+    import numpy as np
+    from triton_dist_tpu.models import AutoLLM, Engine
+    from triton_dist_tpu.runtime import initialize_distributed
+
+    ctx = initialize_distributed({"tp": 1}, devices=[device])
+    model, init_s = _timed(
+        lambda: AutoLLM.from_config(cfg, ctx.mesh, seed=seed))
+    weight_bytes = sum(x.nbytes for x in jax.tree.leaves(model)
+                       if hasattr(x, "nbytes"))
+    _log(phase="init", seconds=init_s, weight_bytes=weight_bytes,
+         layers=cfg.num_layers, hidden=cfg.hidden_size,
+         vocab=cfg.vocab_size, dtype=cfg.dtype,
+         compile_cache_dir=jax.config.jax_compilation_cache_dir)
+
+    flash = Engine(model, max_seq=sz.max_seq, backend="flash")
+    xla = Engine(model, max_seq=sz.max_seq, backend="xla")
+    ids = np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, size=(BATCH, sz.prompt_len)).astype(np.int32)
+    _differential(xla, flash, ids, sz.decode_steps, TOL_REL[cfg.dtype],
+                  "engine")
+    _log(phase="engine.memory", peak_bytes_in_use=_peak_bytes(device))
+
+    _serve(flash, cfg.vocab_size, sz.gen_len, seed)
+    _log(phase="server.memory", peak_bytes_in_use=_peak_bytes(device))
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="run the same code at tiny_qwen3(1) on the CPU "
+                         "(last line then says platform cpu)")
+    args = ap.parse_args(argv)
+
+    import jax
+    devices = jax.devices()
+    dev = devices[0]
+    if dev.platform != "tpu" and not args.rehearse:
+        print(f"chip_smoke: no TPU (jax.devices()[0] is {dev.platform} "
+              f"{dev.device_kind!r}); this run needs the chip",
+              file=sys.stderr)
+        return 1
+
+    from triton_dist_tpu.models.config import qwen3_1p7b, tiny_qwen3
+    from triton_dist_tpu.runtime import interpret_mode
+    _log(**_versions(), platform=dev.platform, kind=dev.device_kind,
+         devices=len(devices), rehearse=args.rehearse, seed=args.seed)
+    if dev.platform == "tpu":
+        _require(interpret_mode() is False,
+                 "Pallas kernels would be interpreted on the chip")
+    _tune_stores_absent()
+
+    cfg, sz = ((tiny_qwen3(1), REHEARSAL) if args.rehearse
+               else (qwen3_1p7b(), ON_CHIP))
+    t0 = time.perf_counter()
+    _one_chip(dev, cfg, sz, args.seed)
+    count = 1
+    _log(phase="total", seconds=round(time.perf_counter() - t0, 3))
+
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": count}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
