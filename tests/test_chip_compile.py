@@ -1,0 +1,153 @@
+"""The main serving path's Pallas kernels, compiled by the TPU's own
+compiler for a DESCRIBED v5e chip at Qwen3-1.7B widths.
+
+No chip is attached and nothing runs: these catch what interpret mode
+cannot — a slice not aligned to the tiling, a kernel over the VMEM
+limit, a shape Mosaic refuses — at no chip time. Shapes are the ones
+`chip_smoke.py` drives (B=8, Hq=16, Hkv=8, d=128, max_seq 512, page 16,
+FFN 6144, bf16).
+
+The topology is described inside a module-scoped fixture and nowhere at
+import: the TPU library admits one process at a time, xdist workers all
+import this file, and only the worker that RUNS it may load the library.
+Keep every such test in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+B, HQ, HKV, D, T, PAGE = 8, 16, 8, 128, 512, 16
+HIDDEN, FFN = 2048, 6144
+MAXP = T // PAGE
+NUM_PAGES = B * HKV * MAXP + 1
+BF16, I32 = jnp.bfloat16, jnp.int32
+
+
+def _machine_cpus() -> int:
+    with open("/proc/cpuinfo") as f:
+        return sum(line.startswith("processor") for line in f)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        if "fakecpus" in os.environ.get("LD_PRELOAD", ""):
+            # conftest's CPU-substrate shim inflates the CPU count libc
+            # reports; the TPU library checks that count against the
+            # machine's topology as it loads and ABORTS the process on
+            # a mismatch. The shim reads FAKE_NPROC on every call, so
+            # tell the truth for as long as the load takes.
+            mp.setenv("FAKE_NPROC", str(_machine_cpus()))
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def for_chip(monkeypatch):
+    """Kernels take their compiled (not interpreted) form, and the
+    persistent compile cache stays out of it: an entry written for a
+    described device cannot be read back without one, and would warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from triton_dist_tpu.runtime import bootstrap
+    monkeypatch.setattr(bootstrap, "on_tpu", lambda: True)
+    assert bootstrap.interpret_mode() is False
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _flash_decode(q, k, v, kv_len):
+    from triton_dist_tpu.kernels.flash_attn import flash_decode
+    return flash_decode(q, k, v, kv_len)
+
+
+def _flash_decode_slots(q, k, v, kv_lens):
+    from triton_dist_tpu.kernels.flash_attn import flash_decode
+    return flash_decode(q, k, v, jnp.max(kv_lens), kv_lens=kv_lens)
+
+
+def _flash_decode_paged(q, pk, pv, table, kv_lens):
+    from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
+    return flash_decode_paged(q, pk, pv, table, jnp.max(kv_lens),
+                              kv_lens=kv_lens)
+
+
+def _kv_update(cache, new, tile_pos):
+    from triton_dist_tpu.kernels.flash_attn import kv_update
+    return kv_update(cache, new, tile_pos)
+
+
+def _swiglu(x2):
+    from triton_dist_tpu.kernels.swiglu import swiglu
+    return swiglu(x2)
+
+
+def _q(b, s):
+    return ((b, s, HQ, D), BF16)
+
+
+def _kv(b, t):
+    return ((b, HKV, t, D), BF16)
+
+
+_POOL = ((NUM_PAGES, PAGE, D), BF16)
+
+# name -> (function, [(shape, dtype), ...]); () is a traced scalar
+CASES = {
+    # Engine.prefill: B=8 prompts of 128 into the contiguous cache
+    "flash_prefill_b8_s128": (
+        _flash_decode, [_q(B, 128), _kv(B, T), _kv(B, T), ((), I32)]),
+    # paged admission: one 80-token prompt through the 1-row scratch
+    # (slot capacity + one 8-row bucket), and the 16-token suffix left
+    # after a 64-token prefix hit
+    "flash_admit_s80": (
+        _flash_decode, [_q(1, 80), _kv(1, T + 8), _kv(1, T + 8),
+                        ((), I32)]),
+    "flash_admit_s16": (
+        _flash_decode, [_q(1, 16), _kv(1, T + 8), _kv(1, T + 8),
+                        ((), I32)]),
+    # Engine.decode: one token per row over the contiguous cache
+    "flash_decode_b8": (
+        _flash_decode, [_q(B, 1), _kv(B, T), _kv(B, T), ((), I32)]),
+    "flash_decode_slots_b8": (
+        _flash_decode_slots, [_q(B, 1), _kv(B, T), _kv(B, T),
+                              ((B,), I32)]),
+    # the serving tick: per-slot lengths through the page table
+    "flash_decode_paged_b8_page16": (
+        _flash_decode_paged, [_q(B, 1), _POOL, _POOL,
+                              ((B * HKV, MAXP), I32), ((B,), I32)]),
+    # cache row insert: a whole prompt, and one 8-row decode tile
+    "kv_update_prefill_s128": (
+        _kv_update, [_kv(B, T), _kv(B, 128), ((), I32)]),
+    "kv_update_tile_s8": (
+        _kv_update, [_kv(B, T), _kv(B, 8), ((), I32)]),
+    # fused SwiGLU on the packed [gate | up] projection
+    "swiglu_prefill_m1024": (_swiglu, [((B * 128, 2 * FFN), BF16)]),
+    "swiglu_decode_m8": (_swiglu, [((B, 2 * FFN), BF16)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip, for_chip):
+    fn, args = CASES[name]
+    shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+              for s, dt in args]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{name}: no Mosaic kernel in the compiled program")
