@@ -51,12 +51,16 @@ REHEARSAL = Sizes(prompt_len=32, decode_steps=4, max_seq=128, gen_len=8)
 
 # Logit tolerance, as a fraction of the reference's largest |logit|.
 # The two backends run the same equations with different accumulation
-# orders (online-softmax tiles vs one softmax; bf16 rounding between
-# kernels), so they agree to a few units of the activation dtype's
-# epsilon (bf16 2^-8, f32 2^-23) compounded through the layer stack —
-# not bitwise. A wrong mask, offset or page mapping moves logits by
-# the logits' own scale, two orders above either bound.
-TOL_REL = {"bfloat16": 4e-2, "float32": 1e-4}
+# orders (online-softmax tiles against one softmax, bf16 rounding
+# between kernels), so they agree to the activation dtype's epsilon
+# (bf16 2^-8, f32 2^-23) compounded through the layer stack, not
+# bitwise. At these widths in bf16 the CPU interpreter shows 1.0% of
+# the largest logit after 2 layers and 1.7% after 8 (growing like the
+# root of the depth, so ~3% at 28). A wrong mask, offset or page
+# mapping replaces logits by unrelated ones: an error of the logits'
+# own spread, which over a [8, 151936] block peaks above the largest
+# logit itself. 8% sits between the two.
+TOL_REL = {"bfloat16": 8e-2, "float32": 1e-4}
 
 
 class SmokeFailure(RuntimeError):
@@ -80,6 +84,46 @@ def _timed(fn):
     return out, round(time.perf_counter() - t0, 3)
 
 
+class CompileLog:
+    """What JAX reports about compilation, per phase: how many programs
+    went to the backend compiler and for how long, and what the
+    persistent cache did. take() returns the tally and starts anew."""
+
+    _EVENTS = {
+        "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+        "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+        "/jax/compilation_cache/cache_hits": "cache_hits",
+        "/jax/compilation_cache/cache_misses": "cache_misses",
+    }
+
+    def __init__(self):
+        import jax.monitoring
+        self._lock = threading.Lock()
+        self._tally: dict = {}
+        jax.monitoring.register_event_listener(
+            lambda event, **kw: self._add(event, 1))
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda event, secs, **kw: self._add(event, secs))
+
+    def _add(self, event: str, amount: float) -> None:
+        key = self._EVENTS.get(event)
+        if key is None:
+            return
+        with self._lock:                 # the server compiles on a thread
+            self._tally[key] = self._tally.get(key, 0) + amount
+            if key == "backend_compile_s":
+                self._tally["programs"] = self._tally.get("programs", 0) + 1
+                if amount >= 1.0:
+                    self._tally["programs_over_1s"] = \
+                        self._tally.get("programs_over_1s", 0) + 1
+
+    def take(self) -> dict:
+        with self._lock:
+            out, self._tally = self._tally, {}
+        return {k: round(v, 3) for k, v in sorted(out.items())}
+
+
 def _versions() -> dict:
     import importlib.metadata as md
     import jax
@@ -99,9 +143,10 @@ def _peak_bytes(device):
 
 def _compare(ref, got, tol_rel: float) -> dict:
     """Max logit error of `got` against `ref` ([B, V] float32), and
-    greedy-token agreement wherever the reference's top-2 margin is
-    wider than the tolerance (random-init logits are nearly flat, so a
-    bare token equality would fail on rounding, not on bugs)."""
+    greedy-token agreement in every row whose top-2 margin in the
+    reference is wider than the tolerance (random-init logits are
+    nearly flat, so a bare token equality would fail on rounding, not
+    on bugs)."""
     import numpy as np
     ref = np.asarray(ref, np.float32)
     got = np.asarray(got, np.float32)
@@ -110,15 +155,18 @@ def _compare(ref, got, tol_rel: float) -> dict:
     tol = tol_rel * float(np.abs(ref).max())
     err = float(np.abs(got - ref).max())
     top2 = np.sort(ref, axis=-1)[:, -2:]
-    decided = (top2[:, 1] - top2[:, 0]) > 2 * tol
+    decided = (top2[:, 1] - top2[:, 0]) > tol
     agree = ref.argmax(-1) == got.argmax(-1)
     return {"max_err": err, "tol": tol,
+            "rms_err": float(np.sqrt(np.mean((got - ref) ** 2))),
+            "ref_std": float(ref.std()),
+            "rows_same_token": int(agree.sum()),
             "rows_decided": int(decided.sum()),
             "rows_disagree": int((decided & ~agree).sum())}
 
 
 def _differential(ref_eng, eng, ids, steps: int, tol_rel: float,
-                  label: str) -> None:
+                  label: str, compiles: CompileLog) -> None:
     """`eng` against `ref_eng` (same model object): prefill, then
     `steps` single-token decode scans through each engine's own cache.
     Both are fed the reference's logits, so both take the reference's
@@ -130,7 +178,8 @@ def _differential(ref_eng, eng, ids, steps: int, tol_rel: float,
     worst = _compare(lx, lf, tol_rel)
     _log(phase=f"{label}.prefill", backend=eng.backend,
          ref_backend=ref_eng.backend, first_call_s=first,
-         second_call_s=second, ref_first_call_s=ref_first, **worst)
+         second_call_s=second, ref_first_call_s=ref_first,
+         compile=compiles.take(), **worst)
     _require(worst["max_err"] <= worst["tol"]
              and not worst["rows_disagree"], f"{label} prefill: {worst}")
     step_s = []
@@ -148,10 +197,12 @@ def _differential(ref_eng, eng, ids, steps: int, tol_rel: float,
         if cmp["max_err"] >= worst["max_err"]:
             worst = cmp
     _log(phase=f"{label}.decode", steps=steps, first_call_s=step_s[0],
-         second_call_s=step_s[1], last_step=cmp, worst_step=worst)
+         second_call_s=step_s[1], compile=compiles.take(),
+         last_step=cmp, worst_step=worst)
 
 
-def _serve(eng, vocab: int, gen_len: int, seed: int) -> None:
+def _serve(eng, vocab: int, gen_len: int, seed: int,
+           compiles: CompileLog) -> None:
     """TokenServer on an ephemeral port, served from a thread; the
     clients are threads of this process too (a chip belongs to one
     process)."""
@@ -227,6 +278,7 @@ def _serve(eng, vocab: int, gen_len: int, seed: int) -> None:
             "host_ms_per_poll", "tokens_generated", "polls")
     _log(phase="server", wall_s=wall, clients=N_CLIENTS,
          gen_len=gen_len, pool_pages=pool.num_pages,
+         compile=compiles.take(),
          stats={k: st[k] for k in keep if k in st})
     _require(st["hits"] >= 1 and st["prefill_tokens_skipped"] > 0,
              f"prefix cache never hit: {st['hits']} hits")
@@ -253,6 +305,7 @@ def _one_chip(device, cfg, sz: Sizes, seed: int) -> None:
     from triton_dist_tpu.models import AutoLLM, Engine
     from triton_dist_tpu.runtime import initialize_distributed
 
+    compiles = CompileLog()
     ctx = initialize_distributed({"tp": 1}, devices=[device])
     model, init_s = _timed(
         lambda: AutoLLM.from_config(cfg, ctx.mesh, seed=seed))
@@ -260,7 +313,7 @@ def _one_chip(device, cfg, sz: Sizes, seed: int) -> None:
                        if hasattr(x, "nbytes"))
     _log(phase="init", seconds=init_s, weight_bytes=weight_bytes,
          layers=cfg.num_layers, hidden=cfg.hidden_size,
-         vocab=cfg.vocab_size, dtype=cfg.dtype,
+         vocab=cfg.vocab_size, dtype=cfg.dtype, compile=compiles.take(),
          compile_cache_dir=jax.config.jax_compilation_cache_dir)
 
     flash = Engine(model, max_seq=sz.max_seq, backend="flash")
@@ -268,10 +321,10 @@ def _one_chip(device, cfg, sz: Sizes, seed: int) -> None:
     ids = np.random.RandomState(seed).randint(
         0, cfg.vocab_size, size=(BATCH, sz.prompt_len)).astype(np.int32)
     _differential(xla, flash, ids, sz.decode_steps, TOL_REL[cfg.dtype],
-                  "engine")
+                  "engine", compiles)
     _log(phase="engine.memory", peak_bytes_in_use=_peak_bytes(device))
 
-    _serve(flash, cfg.vocab_size, sz.gen_len, seed)
+    _serve(flash, cfg.vocab_size, sz.gen_len, seed, compiles)
     _log(phase="server.memory", peak_bytes_in_use=_peak_bytes(device))
 
 

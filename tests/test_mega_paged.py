@@ -26,6 +26,7 @@ gate); `tools/mega_smoke.sh` is the focused full-matrix loop.
 import functools
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -283,9 +284,9 @@ def _count_prims(jaxpr, counts):
         for v in eqn.params.values():
             vs = v if isinstance(v, (list, tuple)) else (v,)
             for u in vs:
-                if isinstance(u, jax.core.ClosedJaxpr):
+                if isinstance(u, jax.extend.core.ClosedJaxpr):
                     _count_prims(u.jaxpr, counts)
-                elif isinstance(u, jax.core.Jaxpr):
+                elif isinstance(u, jax.extend.core.Jaxpr):
                     _count_prims(u, counts)
     return counts
 

@@ -19,10 +19,6 @@ Layer map (mirrors reference SURVEY.md section 1, re-targeted to TPU):
 
 __version__ = "0.1.0"
 
-from triton_dist_tpu import compat as _compat
-
-_compat.install()   # map modern jax spellings onto older installs
-
 from triton_dist_tpu.runtime.bootstrap import (  # noqa: F401
     initialize_distributed,
     finalize_distributed,

@@ -105,7 +105,7 @@ class Report:
 def iter_jaxprs(jaxpr):
     """Yield every (sub)jaxpr reachable from `jaxpr` (pjit/scan/while/
     cond/shard_map/custom_* bodies), outermost first."""
-    import jax.core as jc
+    import jax.extend.core as jc
     seen = set()
     stack = [jaxpr]
     while stack:
@@ -133,14 +133,24 @@ def iter_eqns(jaxpr, primitive: str = None):
                 yield eqn
 
 
+def _kernel_debug_info(eqn):
+    """debug_info of a pallas_call eqn's kernel body (None otherwise)."""
+    body = eqn.params.get("jaxpr") if eqn.primitive.name == "pallas_call" \
+        else None
+    return getattr(body, "debug_info", None)
+
+
+def pallas_kernel_name(eqn) -> str:
+    """A pallas_call eqn's `name=`, else its kernel function's name."""
+    return eqn.params["name"] or _kernel_debug_info(eqn).func_name
+
+
 def eqn_src(eqn) -> str:
     """Best-effort file:line of an eqn (the user frame of its source
-    info; pallas_call eqns prefer their kernel's src note)."""
-    nsi = eqn.params.get("name_and_src_info")
-    if nsi is not None and getattr(nsi, "src_info", ""):
-        # "at /path/file.py:123" -> "/path/file.py:123"
-        s = str(nsi.src_info)
-        return s[3:] if s.startswith("at ") else s
+    info; pallas_call eqns prefer their kernel's definition site)."""
+    di = _kernel_debug_info(eqn)
+    if di is not None and di.func_filename:
+        return f"{di.func_filename}:{di.func_lineno}"
     try:
         from jax._src import source_info_util as siu
         fr = siu.user_frame(eqn.source_info)

@@ -20,9 +20,9 @@ trace, nothing executes — and checks, per pallas_call:
   dropped alias silently doubles the buffer's HBM traffic and
   allocation.
 
-Every diagnostic carries the pallas_call's file:line (its
-name_and_src_info), so a finding lands in the kernel source, not in
-the analyzer.
+Every diagnostic carries the pallas_call kernel's file:line (its
+debug_info), so a finding lands in the kernel source, not in the
+analyzer.
 """
 
 from __future__ import annotations
@@ -30,7 +30,8 @@ from __future__ import annotations
 import math
 from typing import Optional
 
-from triton_dist_tpu.analysis import Report, eqn_src, iter_eqns
+from triton_dist_tpu.analysis import (Report, eqn_src, iter_eqns,
+                                      pallas_kernel_name)
 
 # ~16 MiB/core (pallas_guide "VMEM ~16 MB/core"); the estimate below
 # is deliberately conservative (counts double buffering) so a kernel
@@ -46,11 +47,16 @@ def _dtype_size(dt) -> int:
         return 4
 
 
+def _block_dims(bm) -> tuple:
+    """A block mapping's per-dimension block sizes (pl.Blocked) as
+    ints; a pl.Squeezed dimension has no block_size and reports None."""
+    return tuple(getattr(d, "block_size", None) for d in bm.block_shape)
+
+
 def _block_bytes(block_shape, dtype) -> int:
     n = 1
     for d in block_shape:
-        # older jax spells "no block axis" as None; newer as pl.Squeezed
-        n *= int(d) if isinstance(d, int) else 1
+        n *= 1 if d is None else int(d)    # None: squeezed, one row
     return n * _dtype_size(dtype)
 
 
@@ -82,7 +88,7 @@ def eqn_vmem(eqn) -> int:
     for bm, var in zip(gm.block_mappings, io_vars):
         if _unpipelined(var):
             continue
-        bb = _block_bytes(bm.block_shape, bm.array_shape_dtype.dtype)
+        bb = _block_bytes(_block_dims(bm), bm.array_aval.dtype)
         # Pallas double-buffers pipelined blocks (grid>1): 2x per operand
         vmem += bb * (2 if nsteps > 1 else 1)
     for var in scratch_vars:
@@ -112,7 +118,7 @@ def analyze_pallas_eqn(eqn, report: Report, kernel_name: str,
     (aliases may live on ANY pallas_call of a kernel's trace)."""
     gm = eqn.params["grid_mapping"]
     src = eqn_src(eqn)
-    body_name = eqn.params["name_and_src_info"].name
+    body_name = pallas_kernel_name(eqn)
     subject = f"{kernel_name}/{body_name}"
 
     io_vars, _ = _io_and_scratch_vars(eqn)
@@ -121,20 +127,21 @@ def analyze_pallas_eqn(eqn, report: Report, kernel_name: str,
     blocks = []
     for bm, var in zip(gm.block_mappings, io_vars):
         space = str(getattr(var.aval, "memory_space", None)).lower()
-        arr = bm.array_shape_dtype
-        rec = dict(block=tuple(bm.block_shape), array=tuple(arr.shape),
+        arr = bm.array_aval
+        dims = _block_dims(bm)
+        rec = dict(block=dims, array=tuple(arr.shape),
                    dtype=str(arr.dtype), space=space)
         blocks.append(rec)
         if _unpipelined(var):
             continue
         pipelined += 1
-        for bdim, adim in zip(bm.block_shape, arr.shape):
-            if not isinstance(bdim, int):
+        for bdim, adim in zip(dims, arr.shape):
+            if bdim is None:
                 continue
             if bdim > int(adim) or int(adim) % bdim:
                 report.add(
                     "error", src, subject,
-                    f"block shape {tuple(bm.block_shape)} does not "
+                    f"block shape {dims} does not "
                     f"divide array shape {tuple(arr.shape)} "
                     f"(dim {bdim} vs {int(adim)}): Mosaic pads the "
                     f"trailing block and unmasked reductions read "

@@ -193,7 +193,7 @@ def _subjaxprs_with_mapping(eqn):
     """(closed_jaxpr, invar_map) pairs for call-like eqns: invar_map[i]
     = index into eqn.invars feeding body invar i (None = no direct
     operand, e.g. scan's per-step slice keeps the same position)."""
-    import jax.core as jc
+    import jax.extend.core as jc
     prim = eqn.primitive.name
     out = []
     if prim in ("pjit", "closed_call", "core_call", "xla_call",
@@ -228,7 +228,7 @@ def _taint_jaxpr(jaxpr, table_in: set, buf_in: set, findings: list,
     INDICES tainted on entry. Returns (table_out, buf_out) outvar index
     sets. Appends (src, message) findings for table-bypassing pool
     writes."""
-    from jax.core import Literal
+    from jax.extend.core import Literal
     table_t = {jaxpr.invars[i] for i in table_in if i < len(jaxpr.invars)}
     buf_t = {jaxpr.invars[i] for i in buf_in if i < len(jaxpr.invars)}
 

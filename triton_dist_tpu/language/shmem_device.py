@@ -164,19 +164,6 @@ def _device_id(pe, axis: Optional[str]):
     """
     if axis is None:
         return pe, pltpu.DeviceIdType.LOGICAL
-    from triton_dist_tpu.compat import has_tpu_interpreter
-    if not has_tpu_interpreter():
-        # pre-TPU-interpreter jax: the interpret discharge rule for
-        # remote DMA addresses MESH peers as a bare scalar coordinate
-        # (one per mesh axis), not by the {axis: pe} dict — correct
-        # only on 1-D meshes, which is all that substrate can simulate
-        # anyway. The peer must reach the discharge rule as a TRACED
-        # scalar: a constant folds to a 0-d numpy literal which that
-        # rule can neither isinstance(jax.Array) nor len() — anchoring
-        # on axis_index (free inside the kernel) keeps it symbolic.
-        if not isinstance(pe, jax.core.Tracer):
-            pe = jax.lax.axis_index(axis) * 0 + jnp.int32(pe)
-        return pe, pltpu.DeviceIdType.MESH
     return {axis: pe}, pltpu.DeviceIdType.MESH
 
 
@@ -328,12 +315,7 @@ def barrier_all(axis: str, barrier_sem=None) -> None:
 
 def _static_axis_size(axis: str) -> int:
     """Axis size as a Python int (sizes are static under shard_map)."""
-    size = jax.lax.axis_size(axis)
-    try:
-        return int(size)
-    except Exception:  # pragma: no cover - should not happen under shard_map
-        import jax.core as jc
-        return int(jc.get_aval(size).val)
+    return int(jax.lax.axis_size(axis))
 
 
 def sem_value(sem) -> jax.Array:

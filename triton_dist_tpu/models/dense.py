@@ -16,11 +16,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from triton_dist_tpu.layers import TP_Attn, TP_MLP, precompute_rope, rms_norm
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.kv_cache import KVCache
+from triton_dist_tpu.runtime import auto_mesh
 
 
 @jax.tree_util.register_dataclass
@@ -78,6 +79,7 @@ class DenseLLM:
         long-context layout — weights replicate over it, only the
         paged pool shards; build the mesh as e.g.
         jax.make_mesh((1, 4), ("tp", "sp")) and pass sp_axis="sp")."""
+        mesh = auto_mesh(mesh)
         key = jax.random.key(seed)
         D, I = cfg.hidden_size, cfg.intermediate_size
         Hq, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -121,6 +123,7 @@ class DenseLLM:
         models/dense.py:150-168). Requires a local checkpoint dir."""
         from safetensors import safe_open
 
+        mesh = auto_mesh(mesh)
         cfg = ModelConfig.from_hf_config(path)
         D, Hq, Hkv, hd = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
                           cfg.head_dim)
@@ -383,14 +386,6 @@ class DenseLLM:
         impl = "flash" if mode == "train" else "ref"
         mlp_impl = "dist" if mode == "train" else "xla"
         x = self.embed[ids].reshape(B * S, self.config.hidden_size)
-        from jax.sharding import AxisType
-        if any(t == AxisType.Explicit
-               for t in (self.mesh.axis_types or ())):
-            # pin the embed-gather cotangent to replicated: its transpose
-            # is a scatter-add into the (replicated) table, which
-            # explicit-sharding mode rejects for a tp-sharded cotangent
-            x = jax.sharding.reshard(
-                x, NamedSharding(self.mesh, P(None, None)))
         for layer in self.layers:
             h = rms_norm(x, layer.ln_attn, self.config.rms_norm_eps)
             x = x + layer.attn.fwd_train(h, self.cos, self.sin, B, impl)

@@ -2203,23 +2203,8 @@ def _mega_scan_decode_fn(model, logits0, cache, *, gen_len: int):
             w_gu=mlp.w_gate_up.astype(bf),
             w_d=mlp.w_down.astype(bf),
         ))
-    from jax.sharding import AxisType, NamedSharding, PartitionSpec as _P
-
-    def _replicate(a):
-        # the cache arrives head-sharded over the (size-1) tp axis; the
-        # megakernel outputs are replicated — pin the scan carry to one
-        # consistent (replicated) type under explicit-sharding meshes
-        # (axis_types is None on jax 0.4.x meshes — treat as non-explicit)
-        if any(t == AxisType.Explicit
-               for t in (model.mesh.axis_types or ())):
-            return jax.sharding.reshard(a, NamedSharding(model.mesh, _P()))
-        return a
-
     ks = tuple(jnp.transpose(k, (1, 0, 2, 3)) for k in cache.k)
     vs = tuple(jnp.transpose(v, (1, 0, 2, 3)) for v in cache.v)
-    if n_mega == 1:
-        ks = tuple(_replicate(k) for k in ks)
-        vs = tuple(_replicate(v) for v in vs)
 
     # pallas_call needs Manual mesh axes: run each layer's megakernel
     # under a shard_map, with every array an ARGUMENT (closures over

@@ -61,6 +61,7 @@ from triton_dist_tpu.layers.ep_moe import EP_MoE
 from triton_dist_tpu.layers.tp_moe import TP_MoE
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.kv_cache import KVCache
+from triton_dist_tpu.runtime import auto_mesh
 
 
 @jax.tree_util.register_dataclass
@@ -115,6 +116,7 @@ class Qwen3MoE:
                     seed: int = 0, moe_impl: str = "tp",
                     moe_axis: str = None,
                     capacity_factor=2.0) -> "Qwen3MoE":
+        mesh = auto_mesh(mesh)
         key = jax.random.key(seed)
         D, I = cfg.hidden_size, cfg.moe_intermediate_size
         E, k = cfg.num_experts, cfg.num_experts_per_tok
@@ -162,6 +164,7 @@ class Qwen3MoE:
         (reference: models/qwen_moe.py HF loading + TP shard at load)."""
         from safetensors import safe_open
 
+        mesh = auto_mesh(mesh)
         cfg = ModelConfig.from_hf_config(path)
         Hq, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         dt = cfg.jax_dtype
@@ -431,13 +434,6 @@ class Qwen3MoE:
         impl = "flash" if mode == "train" else "ref"
         moe_mode = "train" if mode == "train" else "xla"
         x = self.embed[ids].reshape(B * S, self.config.hidden_size)
-        from jax.sharding import AxisType, NamedSharding
-        if any(t == AxisType.Explicit
-               for t in (self.mesh.axis_types or ())):
-            # pin the embed-gather cotangent replicated (see
-            # models/dense.py::forward_train)
-            x = jax.sharding.reshard(
-                x, NamedSharding(self.mesh, P(None, None)))
         for layer in self.layers:
             h = rms_norm(x, layer.ln_attn, self.config.rms_norm_eps)
             x = x + layer.attn.fwd_train(h, self.cos, self.sin, B, impl)

@@ -6,6 +6,8 @@ from triton_dist_tpu.runtime.bootstrap import (  # noqa: F401
     interpret_mode,
     shmem_compiler_params,
     make_mesh,
+    auto_mesh,
+    place_compile_cache,
     on_tpu,
     next_collective_id,
 )

@@ -73,11 +73,8 @@ def _maybe_init_multihost() -> None:
         autodetection);
       - otherwise single-host, do nothing.
     """
-    try:
-        if jax.distributed.is_initialized():
-            return
-    except AttributeError:  # older jax
-        pass
+    if jax.distributed.is_initialized():
+        return
     coord = os.environ.get("JAX_COORDINATOR_ADDRESS") or os.environ.get(
         "COORDINATOR_ADDRESS")
     nprocs = os.environ.get("JAX_NUM_PROCESSES")
@@ -114,6 +111,33 @@ def make_mesh(mesh_shape: Optional[dict] = None,
     return Mesh(dev_array, tuple(names))
 
 
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """`mesh` with every axis in Auto mode — the one mode this package's
+    programs are written for (sharding carried by shard_map specs and
+    NamedSharding placement, never by explicit-sharding types).
+    `make_mesh` above already builds such a mesh; `jax.make_mesh` builds
+    Explicit axes, so a model normalises the mesh it is handed once."""
+    return Mesh(mesh.devices, mesh.axis_names)
+
+
+# <checkout>/.jax_cache: fixed and derived from this file's own place,
+# because the directory is part of every cache key's lookup — a path
+# made from a temp name, a pid or the time never hits twice.
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+COMPILE_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def place_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a place, once, for every
+    program of the process. Where JAX_COMPILATION_CACHE_DIR is set, JAX
+    has already read it and nothing is touched; otherwise the cache goes
+    to COMPILE_CACHE_DIR. Returns the directory in use."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
+
+
 def initialize_distributed(mesh_shape: Optional[dict] = None,
                            seed: int = 42,
                            devices: Optional[Sequence[jax.Device]] = None,
@@ -121,6 +145,7 @@ def initialize_distributed(mesh_shape: Optional[dict] = None,
     """Bootstrap (reference: utils.py:302). Idempotent per mesh shape."""
     global _CONTEXT
     _maybe_init_multihost()
+    place_compile_cache()
     mesh = make_mesh(mesh_shape, devices)
     _CONTEXT = DistContext(mesh=mesh, axes=tuple(mesh.axis_names), seed=seed)
     return _CONTEXT
@@ -198,16 +223,7 @@ def interpret_mode():
         return False
     from jax.experimental.pallas import tpu as pltpu
     from triton_dist_tpu.utils import env_flag
-    params = getattr(pltpu, "InterpretParams", None) or getattr(
-        pltpu, "TPUInterpretParams", None)
-    if params is None:
-        # jax predates the Pallas TPU interpreter: fall back to the
-        # generic interpreter — single-buffer kernels (flash decode,
-        # paged walk, grouped GEMM) still run; comm kernels that need
-        # simulated semaphores/remote DMA raise and their tests skip
-        # (compat.has_tpu_interpreter gates them).
-        return True
-    return params(
+    return pltpu.InterpretParams(
         detect_races=env_flag("TDTPU_DETECT_RACES", False),
         dma_execution_mode="on_wait",
     )

@@ -173,6 +173,10 @@ class AOTProgramCache:
         # warm cache serves the same purpose); we only claim the
         # process-global knob when nobody else has, and remember what
         # we displaced so release_compilation_cache() can undo it.
+        # On the main path this claim never fires:
+        # initialize_distributed() has placed the cache already
+        # (runtime/bootstrap.py::place_compile_cache). It is left for
+        # a process that builds an AOT cache without a context.
         self._prev_cache_cfg: Tuple | None = None
         try:
             if not getattr(jax.config, "jax_compilation_cache_dir",
