@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
-# Regenerate every on-chip artifact in one command, the moment the chip
-# returns (VERDICT r4 next #2). Safe to re-run; each step is
-# independent and failures don't stop the rest.
+# Regenerate every on-chip artifact in one command, on a machine with
+# a chip. Safe to re-run; each step is independent and failures don't
+# stop the rest.
 #
 #   bash tools/onchip_regen.sh
 #
@@ -9,7 +9,7 @@
 #   tune cache (TDTPU_TUNE_CACHE / ~/.triton_dist_tpu/tune_cache.json)
 #   PERF_OPS_tpu.json            per-op SOL report (git+date stamped)
 #   PROFILE_<kernel>.json/.trace.json   ablation profiles x4
-#   BENCH_local.json             bench line (driver writes BENCH_rNN)
+#   BENCH_local.json             bench rows
 set -u
 cd "$(dirname "$0")/.."
 
