@@ -328,9 +328,65 @@ def _one_chip(device, cfg, sz: Sizes, seed: int) -> None:
     _log(phase="server.memory", peak_bytes_in_use=_peak_bytes(device))
 
 
+def _log_placement(model, devices) -> None:
+    """Where each weight lives: code that has only seen one chip may put
+    everything on the first. Every array must reach all the devices,
+    and the TP projections must be split, not copied."""
+    import jax
+    want = sorted(d.id for d in devices)
+    split = 0
+    for path, x in jax.tree_util.tree_leaves_with_path(model):
+        if not isinstance(x, jax.Array):
+            continue
+        name = jax.tree_util.keystr(path)
+        shards = x.addressable_shards
+        ids = sorted(s.device.id for s in shards)
+        _require(ids == want, f"{name} lives on devices {ids}, "
+                 f"not on {want}")
+        shard_shape = tuple(shards[0].data.shape)
+        split += shard_shape != tuple(x.shape)
+        if ".layers[" not in name or ".layers[0]" in name:
+            _log(weight=name, shape=list(x.shape),
+                 shard_shape=list(shard_shape), device_ids=ids,
+                 spec=str(x.sharding.spec))
+    per_layer = 4                  # w_qkv, w_o, w_gate_up, w_down
+    _require(split >= per_layer * model.config.num_layers,
+             f"only {split} arrays are split across the mesh")
+
+
+def _four_chips(devices, cfg, sz: Sizes, seed: int) -> None:
+    """TP=4: the fused GEMM+allreduce comm kernels against XLA's own
+    collectives, same mesh, same weights. Fails loudly if the fused
+    kernels fail; there is no switch to `xla`."""
+    import numpy as np
+    from triton_dist_tpu.models import AutoLLM, Engine
+    from triton_dist_tpu.runtime import initialize_distributed
+
+    compiles = CompileLog()
+    ctx = initialize_distributed({"tp": 4}, devices=devices)
+    model, init_s = _timed(
+        lambda: AutoLLM.from_config(cfg, ctx.mesh, seed=seed))
+    _log(phase="tp4.init", seconds=init_s, layers=cfg.num_layers,
+         hidden=cfg.hidden_size, dtype=cfg.dtype,
+         compile=compiles.take())
+    _log_placement(model, devices)
+
+    fused = Engine(model, max_seq=sz.max_seq, backend="gemm_ar")
+    xla = Engine(model, max_seq=sz.max_seq, backend="xla")
+    ids = np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, size=(BATCH, sz.prompt_len)).astype(np.int32)
+    _differential(xla, fused, ids, sz.decode_steps, TOL_REL[cfg.dtype],
+                  "tp4", compiles)
+    _log(phase="tp4.memory",
+         peak_bytes_in_use=[_peak_bytes(d) for d in devices])
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run ONLY the TP=4 phase (gemm_ar against "
+                         "xla on one four-device mesh)")
     ap.add_argument("--rehearse", action="store_true",
                     help="run the same code at tiny_qwen3(1) on the CPU "
                          "(last line then says platform cpu)")
@@ -354,16 +410,20 @@ def main(argv=None) -> int:
                  "Pallas kernels would be interpreted on the chip")
     _tune_stores_absent()
 
-    cfg, sz = ((tiny_qwen3(1), REHEARSAL) if args.rehearse
+    _require(len(devices) >= args.chips,
+             f"--chips {args.chips} on a host with {len(devices)}")
+    cfg, sz = ((tiny_qwen3(args.chips), REHEARSAL) if args.rehearse
                else (qwen3_1p7b(), ON_CHIP))
     t0 = time.perf_counter()
-    _one_chip(dev, cfg, sz, args.seed)
-    count = 1
+    if args.chips == 1:
+        _one_chip(dev, cfg, sz, args.seed)
+    else:
+        _four_chips(devices[:4], cfg, sz, args.seed)
     _log(phase="total", seconds=round(time.perf_counter() - t0, 3))
 
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
-        "count": count}}), flush=True)
+        "count": args.chips}}), flush=True)
     return 0
 
 

@@ -17,8 +17,10 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 B, HQ, HKV, D, T, PAGE = 8, 16, 8, 128, 512, 16
 HIDDEN, FFN = 2048, 6144
@@ -53,6 +55,11 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices), ("tp",))
 
 
 @pytest.fixture()
@@ -151,3 +158,39 @@ def test_kernel_compiles_for_v5e(name, one_chip, for_chip):
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{name}: no Mosaic kernel in the compiled program")
+
+
+# --- the TP=4 comm kernels (chip_smoke.py --chips 4 runs gemm_ar; the
+# "dist" backend runs the other two), MLP projections at 1.7B widths.
+# name -> (kernel, M, A [M, K] spec, B [K, N] spec, K, N)
+COMM_CASES = {
+    # down projection, decode rows and prefill rows: C replicated
+    "gemm_allreduce_m8": ("gemm_allreduce", B, P(None, "tp"),
+                          P("tp", None), FFN, HIDDEN),
+    "gemm_allreduce_m1024": ("gemm_allreduce", B * 128, P(None, "tp"),
+                             P("tp", None), FFN, HIDDEN),
+    # gate|up projection from row-sharded activations
+    "ag_gemm_m1024": ("ag_gemm", B * 128, P("tp", None), P(None, "tp"),
+                      HIDDEN, 2 * FFN),
+    # down projection back to row-sharded activations
+    "gemm_rs_m1024": ("gemm_rs", B * 128, P(None, "tp"), P("tp", None),
+                      FFN, HIDDEN),
+}
+
+
+@pytest.mark.parametrize("name", sorted(COMM_CASES))
+def test_comm_kernel_compiles_for_four_v5e(name, four_chips, for_chip):
+    from triton_dist_tpu import kernels
+    kernel, m, a_spec, b_spec, k, n = COMM_CASES[name]
+    fn = getattr(kernels, kernel)
+    a = jax.ShapeDtypeStruct((m, k), BF16,
+                             sharding=NamedSharding(four_chips, a_spec))
+    b = jax.ShapeDtypeStruct((k, n), BF16,
+                             sharding=NamedSharding(four_chips, b_spec))
+    compiled = jax.jit(
+        lambda a, b: fn(a, b, mesh=four_chips)).lower(a, b).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel"
+    # the collective lives INSIDE the kernel: XLA adds none of its own
+    assert "all-reduce(" not in text and "all-gather(" not in text \
+        and "reduce-scatter(" not in text, f"{name}: XLA collective"

@@ -21,6 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from triton_dist_tpu.layers import TP_Attn, TP_MLP, precompute_rope, rms_norm
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.kv_cache import KVCache
+from triton_dist_tpu.models.utils import place_replicated
 from triton_dist_tpu.runtime import auto_mesh
 
 
@@ -107,13 +108,14 @@ class DenseLLM:
         cos, sin = precompute_rope(hd, cfg.max_position_embeddings,
                                    cfg.rope_theta)
         embed = w(cfg.vocab_size, D, scale=0.02)
-        return DenseLLM(
+        model = DenseLLM(
             embed=embed, layers=tuple(layers),
             final_norm=jnp.ones((D,), dt),
             lm_head=(embed.T if cfg.tie_word_embeddings
                      else w(D, cfg.vocab_size, scale=0.02)),
             cos=cos, sin=sin, config=cfg, mesh=mesh, axis=axis,
             sp_axis=sp_axis, sp_combine=sp_combine)
+        return place_replicated(model, mesh)
 
     @staticmethod
     def from_hf(path: str, mesh: Mesh, axis: str = "tp",
@@ -164,11 +166,12 @@ class DenseLLM:
         embed = t("model.embed_tokens.weight")
         lm_head = (embed.T if cfg.tie_word_embeddings
                    else t("lm_head.weight").T)
-        return DenseLLM(embed=embed, layers=tuple(layers),
-                        final_norm=t("model.norm.weight"),
-                        lm_head=lm_head, cos=cos, sin=sin, config=cfg,
-                        mesh=mesh, axis=axis, sp_axis=sp_axis,
-                        sp_combine=sp_combine)
+        model = DenseLLM(embed=embed, layers=tuple(layers),
+                         final_norm=t("model.norm.weight"),
+                         lm_head=lm_head, cos=cos, sin=sin, config=cfg,
+                         mesh=mesh, axis=axis, sp_axis=sp_axis,
+                         sp_combine=sp_combine)
+        return place_replicated(model, mesh)
 
     def quantize_int8(self) -> "DenseLLM":
         """Weight-only int8 copy for the bandwidth-bound decode regime

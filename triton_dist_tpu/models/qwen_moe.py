@@ -61,6 +61,7 @@ from triton_dist_tpu.layers.ep_moe import EP_MoE
 from triton_dist_tpu.layers.tp_moe import TP_MoE
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.kv_cache import KVCache
+from triton_dist_tpu.models.utils import place_replicated
 from triton_dist_tpu.runtime import auto_mesh
 
 
@@ -148,13 +149,14 @@ class Qwen3MoE:
         cos, sin = precompute_rope(hd, cfg.max_position_embeddings,
                                    cfg.rope_theta)
         embed = w(cfg.vocab_size, D, scale=0.02)
-        return Qwen3MoE(
+        model = Qwen3MoE(
             embed=embed, layers=tuple(layers),
             final_norm=jnp.ones((D,), dt),
             lm_head=(embed.T if cfg.tie_word_embeddings
                      else w(D, cfg.vocab_size, scale=0.02)),
             cos=cos, sin=sin, config=cfg, mesh=mesh, axis=axis,
             moe_impl=moe_impl, moe_axis=moe_axis)
+        return place_replicated(model, mesh)
 
     @staticmethod
     def from_hf(path: str, mesh: Mesh, axis: str = "tp",
@@ -212,13 +214,14 @@ class Qwen3MoE:
         cos, sin = precompute_rope(hd, cfg.max_position_embeddings,
                                    cfg.rope_theta)
         embed = t("model.embed_tokens.weight")
-        return Qwen3MoE(
+        model = Qwen3MoE(
             embed=embed, layers=tuple(layers),
             final_norm=t("model.norm.weight"),
             lm_head=(embed.T if cfg.tie_word_embeddings
                      else t("lm_head.weight").T),
             cos=cos, sin=sin, config=cfg, mesh=mesh, axis=axis,
             moe_impl=moe_impl, moe_axis=moe_axis)
+        return place_replicated(model, mesh)
 
     # ------------------------------------------------------------------
     # forward (mirrors DenseLLM.forward_tokens)

@@ -16,6 +16,26 @@ if not logger.handlers:
     logger.setLevel(logging.INFO)
 
 
+def place_replicated(tree, mesh):
+    """Commit every array of `tree` that is not yet placed over `mesh`
+    to it, replicated. The TP layers shard their own projections; what
+    a constructor makes beside them (embedding, LM head, norms, rope
+    tables) is otherwise an uncommitted array on the first device —
+    one chip holds it all, and every program call copies it out again
+    to the others. (Under a trace, as in jax.eval_shape, there is
+    nothing to place.)"""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    def put(x):
+        if isinstance(x, jax.Array) and \
+                not isinstance(x, jax.core.Tracer) and \
+                getattr(x.sharding, "mesh", None) != mesh:
+            return jax.device_put(x, NamedSharding(mesh, PartitionSpec()))
+        return x
+
+    return jax.tree.map(put, tree)
+
+
 def sample_greedy(logits):
     return jnp.argmax(logits, axis=-1)
 
