@@ -53,9 +53,7 @@ _DRIVER = textwrap.dedent("""
     # reference launcher's world-size argument
     mesh = jax.make_mesh((int(ndev),), ("tp",))
     t0 = time.perf_counter()
-    # jax 0.4.x spells the mesh context as `with mesh:` (no set_mesh)
-    ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-    with ctx:
+    with jax.set_mesh(mesh):
         out = exported.call(*args)
     logits = np.asarray(out[0])
     first_call_s = time.perf_counter() - t0

@@ -67,7 +67,10 @@ class KVCache:
                 jax.device_put(jnp.zeros(shape[:3], jnp.float32), s_shd)
                 for _ in range(num_layers))
             ks, vs = mk(), mk()
-        return KVCache(k=k, v=v, offset=jnp.int32(0), ks=ks, vs=vs)
+        # placed like what a program hands back, so the second call of
+        # a program that carries the cache is not compiled again
+        offset = jax.device_put(jnp.int32(0), NamedSharding(mesh, P()))
+        return KVCache(k=k, v=v, offset=offset, ks=ks, vs=vs)
 
     @property
     def quantized(self) -> bool:

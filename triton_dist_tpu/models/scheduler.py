@@ -387,9 +387,16 @@ class DecodeSlots:
         self.tele = telemetry if telemetry is not None else Telemetry()
         V = engine.model.config.vocab_size
         self.cache = self._make_cache()
-        self.logits = jnp.zeros((batch, V), jnp.float32)
-        self.pos = jnp.zeros((batch,), jnp.int32)
-        self.active = jnp.zeros((batch,), bool)
+        # carried state starts out placed over the model's mesh: what a
+        # tick returns carries the mesh in its type, and a first tick
+        # fed bare host-made arrays would be compiled again for the
+        # second
+        from jax.sharding import NamedSharding, PartitionSpec
+        rep = NamedSharding(engine.model.mesh, PartitionSpec())
+        self.logits = jax.device_put(jnp.zeros((batch, V), jnp.float32),
+                                     rep)
+        self.pos = jax.device_put(jnp.zeros((batch,), jnp.int32), rep)
+        self.active = jax.device_put(jnp.zeros((batch,), bool), rep)
         self.keys = (None if engine.sampling == "greedy"
                      else jax.random.split(jax.random.key(0), batch))
         # host mirrors (scheduling is host-side; the model never syncs)

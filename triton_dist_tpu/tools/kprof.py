@@ -25,8 +25,9 @@ Per-step device timestamps (the VERDICT r4 #7 investigation): Mosaic
 exposes NO device clock readable from a kernel — the full pltpu surface
 was enumerated (r5): no %globaltimer analog, no cycle counter;
 pltpu.trace_value only tags profiler scopes. What IS exposed is
-`pltpu.semaphore_read` — sampling a semaphore's state without consuming it — so the implementable slice of
-the reference's per-step timeline is per-ring-step ARRIVAL-STATE
+`pltpu.semaphore_read` — sampling a semaphore's state without
+consuming it — so the implementable slice of the reference's per-step
+timeline is per-ring-step ARRIVAL-STATE
 stamps: ag_gemm(progress_trace=True) records, at each ring step,
 whether the next chunk had already landed when the step's compute
 finished (and the send-semaphore state), per rank. That answers "which

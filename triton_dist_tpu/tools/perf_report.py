@@ -65,8 +65,9 @@ def _time(step, x0, *, k1=None, k2=None, reps=3, slopes=3):
     constant dispatch/readback overhead.
     `step(x) -> x_like` must thread a data dependence.
 
-    A chip behind a shared host showed +-30% run-to-run noise, so take the MIN over `slopes` interleaved slope estimates —
-    the best pair is the least-contended measurement of the same
+    A chip behind a shared host showed +-30% run-to-run noise, so take
+    the MIN over `slopes` interleaved slope estimates — the best pair
+    is the least-contended measurement of the same
     program. Off-chip (the interpreter smoke, where per-iteration cost
     is ~1000x and the numbers only guard against breakage) the loop
     counts shrink so the full report stays runnable."""
