@@ -299,30 +299,43 @@ def _tune_stores_absent() -> None:
                  "block shapes would come from outside the checkout")
 
 
-def _one_chip(device, cfg, sz: Sizes, seed: int) -> None:
+def _engines_differ(devices, cfg, sz: Sizes, seed: int, backend: str,
+                    label: str, compiles: CompileLog):
+    """The model over a TP mesh of `devices`, and its `backend` engine
+    held to its `xla` engine on one seeded prompt batch. Returns the
+    `backend` engine."""
     import jax
     import numpy as np
     from triton_dist_tpu.models import AutoLLM, Engine
     from triton_dist_tpu.runtime import initialize_distributed
 
-    compiles = CompileLog()
-    ctx = initialize_distributed({"tp": 1}, devices=[device])
+    ctx = initialize_distributed({"tp": len(devices)}, devices=devices)
     model, init_s = _timed(
         lambda: AutoLLM.from_config(cfg, ctx.mesh, seed=seed))
     weight_bytes = sum(x.nbytes for x in jax.tree.leaves(model)
                        if hasattr(x, "nbytes"))
-    _log(phase="init", seconds=init_s, weight_bytes=weight_bytes,
+    _log(phase=f"{label}.init", seconds=init_s, weight_bytes=weight_bytes,
          layers=cfg.num_layers, hidden=cfg.hidden_size,
          vocab=cfg.vocab_size, dtype=cfg.dtype, compile=compiles.take(),
          compile_cache_dir=jax.config.jax_compilation_cache_dir)
+    if len(devices) > 1:
+        _log_placement(model, devices)
 
-    flash = Engine(model, max_seq=sz.max_seq, backend="flash")
+    eng = Engine(model, max_seq=sz.max_seq, backend=backend)
     xla = Engine(model, max_seq=sz.max_seq, backend="xla")
     ids = np.random.RandomState(seed).randint(
         0, cfg.vocab_size, size=(BATCH, sz.prompt_len)).astype(np.int32)
-    _differential(xla, flash, ids, sz.decode_steps, TOL_REL[cfg.dtype],
-                  "engine", compiles)
-    _log(phase="engine.memory", peak_bytes_in_use=_peak_bytes(device))
+    _differential(xla, eng, ids, sz.decode_steps, TOL_REL[cfg.dtype],
+                  label, compiles)
+    _log(phase=f"{label}.memory",
+         peak_bytes_in_use=[_peak_bytes(d) for d in devices])
+    return eng
+
+
+def _one_chip(device, cfg, sz: Sizes, seed: int) -> None:
+    compiles = CompileLog()
+    flash = _engines_differ([device], cfg, sz, seed, "flash", "engine",
+                            compiles)
 
     _serve(flash, cfg.vocab_size, sz.gen_len, seed, compiles)
     _log(phase="server.memory", peak_bytes_in_use=_peak_bytes(device))
@@ -358,27 +371,8 @@ def _four_chips(devices, cfg, sz: Sizes, seed: int) -> None:
     """TP=4: the fused GEMM+allreduce comm kernels against XLA's own
     collectives, same mesh, same weights. Fails loudly if the fused
     kernels fail; there is no switch to `xla`."""
-    import numpy as np
-    from triton_dist_tpu.models import AutoLLM, Engine
-    from triton_dist_tpu.runtime import initialize_distributed
-
-    compiles = CompileLog()
-    ctx = initialize_distributed({"tp": 4}, devices=devices)
-    model, init_s = _timed(
-        lambda: AutoLLM.from_config(cfg, ctx.mesh, seed=seed))
-    _log(phase="tp4.init", seconds=init_s, layers=cfg.num_layers,
-         hidden=cfg.hidden_size, dtype=cfg.dtype,
-         compile=compiles.take())
-    _log_placement(model, devices)
-
-    fused = Engine(model, max_seq=sz.max_seq, backend="gemm_ar")
-    xla = Engine(model, max_seq=sz.max_seq, backend="xla")
-    ids = np.random.RandomState(seed).randint(
-        0, cfg.vocab_size, size=(BATCH, sz.prompt_len)).astype(np.int32)
-    _differential(xla, fused, ids, sz.decode_steps, TOL_REL[cfg.dtype],
-                  "tp4", compiles)
-    _log(phase="tp4.memory",
-         peak_bytes_in_use=[_peak_bytes(d) for d in devices])
+    _engines_differ(devices, cfg, sz, seed, "gemm_ar", "tp4",
+                    CompileLog())
 
 
 def main(argv=None) -> int:

@@ -45,7 +45,7 @@ def test_rehearsal_runs_every_phase(chip_smoke, capsys,
     assert chip_smoke.main(["--rehearse"]) == 0
     lines = _json_lines(capsys.readouterr().out)
     by_phase = {ln["phase"]: ln for ln in lines if "phase" in ln}
-    assert set(by_phase) >= {"init", "engine.prefill", "engine.decode",
+    assert set(by_phase) >= {"engine.init", "engine.prefill", "engine.decode",
                              "server", "total"}, sorted(by_phase)
 
     # the differential compared something and stayed inside its bound
@@ -66,7 +66,7 @@ def test_rehearsal_runs_every_phase(chip_smoke, capsys,
     assert lines[-1] == {"ok": True, "device": {
         "platform": "cpu", "kind": jax.devices()[0].device_kind,
         "count": 1}}
-    assert by_phase["init"]["compile_cache_dir"] == \
+    assert by_phase["engine.init"]["compile_cache_dir"] == \
         jax.config.jax_compilation_cache_dir
 
 

@@ -392,11 +392,10 @@ class DecodeSlots:
         # fed bare host-made arrays would be compiled again for the
         # second
         from jax.sharding import NamedSharding, PartitionSpec
-        rep = NamedSharding(engine.model.mesh, PartitionSpec())
-        self.logits = jax.device_put(jnp.zeros((batch, V), jnp.float32),
-                                     rep)
-        self.pos = jax.device_put(jnp.zeros((batch,), jnp.int32), rep)
-        self.active = jax.device_put(jnp.zeros((batch,), bool), rep)
+        self.logits, self.pos, self.active = jax.device_put(
+            (jnp.zeros((batch, V), jnp.float32),
+             jnp.zeros((batch,), jnp.int32), jnp.zeros((batch,), bool)),
+            NamedSharding(engine.model.mesh, PartitionSpec()))
         self.keys = (None if engine.sampling == "greedy"
                      else jax.random.split(jax.random.key(0), batch))
         # host mirrors (scheduling is host-side; the model never syncs)
