@@ -332,15 +332,6 @@ def _engines_differ(devices, cfg, sz: Sizes, seed: int, backend: str,
     return eng
 
 
-def _one_chip(device, cfg, sz: Sizes, seed: int) -> None:
-    compiles = CompileLog()
-    flash = _engines_differ([device], cfg, sz, seed, "flash", "engine",
-                            compiles)
-
-    _serve(flash, cfg.vocab_size, sz.gen_len, seed, compiles)
-    _log(phase="server.memory", peak_bytes_in_use=_peak_bytes(device))
-
-
 def _log_placement(model, devices) -> None:
     """Where each weight lives: code that has only seen one chip may put
     everything on the first. Every array must reach all the devices,
@@ -365,14 +356,6 @@ def _log_placement(model, devices) -> None:
     per_layer = 4                  # w_qkv, w_o, w_gate_up, w_down
     _require(split >= per_layer * model.config.num_layers,
              f"only {split} arrays are split across the mesh")
-
-
-def _four_chips(devices, cfg, sz: Sizes, seed: int) -> None:
-    """TP=4: the fused GEMM+allreduce comm kernels against XLA's own
-    collectives, same mesh, same weights. Fails loudly if the fused
-    kernels fail; there is no switch to `xla`."""
-    _engines_differ(devices, cfg, sz, seed, "gemm_ar", "tp4",
-                    CompileLog())
 
 
 def main(argv=None) -> int:
@@ -409,10 +392,18 @@ def main(argv=None) -> int:
     cfg, sz = ((tiny_qwen3(args.chips), REHEARSAL) if args.rehearse
                else (qwen3_1p7b(), ON_CHIP))
     t0 = time.perf_counter()
+    compiles = CompileLog()
     if args.chips == 1:
-        _one_chip(dev, cfg, sz, args.seed)
+        flash = _engines_differ([dev], cfg, sz, args.seed, "flash",
+                                "engine", compiles)
+        _serve(flash, cfg.vocab_size, sz.gen_len, args.seed, compiles)
+        _log(phase="server.memory", peak_bytes_in_use=_peak_bytes(dev))
     else:
-        _four_chips(devices[:4], cfg, sz, args.seed)
+        # TP=4 only: the fused GEMM+allreduce comm kernels against
+        # XLA's own collectives, same mesh, same weights. If the fused
+        # kernels fail the run fails; there is no switch to `xla`.
+        _engines_differ(devices[:4], cfg, sz, args.seed, "gemm_ar", "tp4",
+                        compiles)
     _log(phase="total", seconds=round(time.perf_counter() - t0, 3))
 
     print(json.dumps({"ok": True, "device": {
