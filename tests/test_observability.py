@@ -623,3 +623,45 @@ def test_disagg_trace_bitwise_with_slo():
     ref, got = run(False), run(True)
     for rid in ref:
         np.testing.assert_array_equal(got[rid], ref[rid])
+
+
+def test_profiler_trace_holds_the_host_phases(tmp_path):
+    """An operator's `jax.profiler` session round a live TokenServer
+    (trace off) sees the serve loop's and the scheduler's phases as
+    `serve:` / `sched:` annotations on a host line, i.e. on the clock
+    the device's planes are on."""
+    import glob
+    from jax.profiler import ProfileData
+    from triton_dist_tpu.serving import (ByteTokenizer, TokenServer,
+                                         request_stream)
+    cfg, eng = _engine()
+    srv = TokenServer(eng, ByteTokenizer(cfg.vocab_size), batch=2,
+                      chunk=4, paged=True, page=8)
+    th = threading.Thread(target=srv.serve_forever,
+                          kwargs=dict(max_requests=2), daemon=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        th.start()
+        for p in ("first prompt", "second"):
+            toks = [t for msg in request_stream(srv.host, srv.port, p,
+                                                gen_len=8)
+                    for t in msg.get("token_ids", [])]
+            assert len(toks) == 8
+        th.join(timeout=120)
+    finally:
+        jax.profiler.stop_trace()
+        srv.stop()
+    (xplane,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                              / "*.xplane.pb"))
+    want = {"serve:loop", "serve:accept_wait", "serve:poll",
+            "serve:wire_write", "sched:poll", "sched:admit",
+            "sched:step", "sched:device_wait"}
+    lines = [{e.name for e in line.events}
+             for plane in ProfileData.from_file(xplane).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines]
+    assert any(want <= names for names in lines), \
+        [sorted(n for n in names if ":" in n) for names in lines]

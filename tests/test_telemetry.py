@@ -325,6 +325,19 @@ def test_streams_bitwise_trace_on_off(mode, kind):
             err_msg=f"{mode}/{kind}: rid={rid} diverged trace-on vs off")
 
 
+class _CompileCounter(logging.Handler):
+    """Names of the programs jax logs as compiled (jax_log_compiles)."""
+
+    def __init__(self):
+        super().__init__()
+        self.names = []
+
+    def emit(self, record):
+        msg = record.getMessage()
+        if msg.startswith("Compiling "):
+            self.names.append(msg.split()[1])
+
+
 def test_trace_no_new_programs():
     """Jit-cache-churn guard: tracing is host-side only, so a traced
     mixed refill/chunked-prefill soak must compile ZERO programs the
@@ -336,16 +349,6 @@ def test_trace_no_new_programs():
                                     page=8, prefill_budget=3,
                                     overlap=True, trace=trace)
         return sched.run(_mixed_requests(cfg, seed=4)), sched
-
-    class _CompileCounter(logging.Handler):
-        def __init__(self):
-            super().__init__()
-            self.names = []
-
-        def emit(self, record):
-            msg = record.getMessage()
-            if msg.startswith("Compiling "):
-                self.names.append(msg.split()[1])
 
     counter = _CompileCounter()
     logger = logging.getLogger("jax._src.interpreters.pxla")
@@ -515,8 +518,8 @@ def test_token_server_telemetry_surfacing(tmp_path, monkeypatch):
     assert len(dump["requests"]) == 3
     for req in dump["requests"].values():
         kinds = [e[1] for e in req["events"]]
-        assert kinds[0] == "queued" and "first_token" in kinds \
-            and kinds[-1] == "retired"
+        assert kinds[:2] == ["accepted", "queued"] \
+            and "first_token" in kinds and kinds[-1] == "retired"
         assert req["ttft_ms"] is not None
     assert dump["metrics"]["ttft_ms"]["count"] == 3
 
@@ -530,3 +533,136 @@ def test_token_server_telemetry_surfacing(tmp_path, monkeypatch):
     spec.loader.exec_module(tv)
     text = tv.summarize(dump, top_k=3)
     assert "poll" in text and "ttft" in text.lower()
+
+
+# ----------------------------------------------------------------------
+# the host path's phases (always on) and the lifecycle from accept() to
+# the wire, over one served batch with tracing off and one with it on
+# ----------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def served_pair():
+    """The same five prompts through a TokenServer with trace off,
+    then on (compiles logged round the second): per run the streams,
+    the final stats(), the telemetry export, every phase opened and
+    the scheduler's device_wait_s."""
+    from triton_dist_tpu.serving import ByteTokenizer, TokenServer, \
+        request_stream
+
+    cfg, eng = _engine("greedy")
+    tok = ByteTokenizer(cfg.vocab_size)
+    # gen_len 3 < chunk: retired inside its first chunk, before the wire
+    work = [("alpha prompt", 12), ("second one!", 9), ("third", 3),
+            ("and a fourth one", 10), ("fifth", 6)]
+
+    def serve(trace):
+        opened = []
+        srv = TokenServer(eng, tok, batch=3, chunk=4, paged=True,
+                          page=8, trace=trace)
+        tele = srv.sched.tele
+        phase = tele.phase
+
+        def spy(name):
+            ph = phase(name)
+            opened.append((name, ph))
+            return ph
+        tele.phase = spy
+        th = threading.Thread(target=srv.serve_forever,
+                              kwargs=dict(max_requests=len(work)),
+                              daemon=True)
+        th.start()
+        streams = {}
+
+        def client(i):
+            prompt, gen_len = work[i]
+            streams[i] = [t for msg in request_stream(
+                "127.0.0.1", srv.port, prompt, gen_len=gen_len)
+                for t in msg.get("token_ids", [])]
+
+        cts = [threading.Thread(target=client, args=(i,))
+               for i in range(len(work))]
+        for t in cts:
+            t.start()
+        for t in cts:
+            t.join(timeout=600)
+        th.join(timeout=120)
+        assert not th.is_alive()
+        srv.stop()
+        return dict(streams=streams, stats=srv.stats(),
+                    export=tele.export(), opened=opened,
+                    device_wait_s=srv.sched.slots.device_wait_s)
+
+    off = serve(False)
+    handler = _CompileCounter()
+    logger = logging.getLogger("jax._src.interpreters.pxla")
+    logger.addHandler(handler)
+    prev = jax.config.jax_log_compiles
+    jax.config.update("jax_log_compiles", True)
+    try:
+        on = serve(True)
+    finally:
+        jax.config.update("jax_log_compiles", prev)
+        logger.removeHandler(handler)
+    return dict(off=off, on=on, compiled=handler.names,
+                gen_lens=[g for _, g in work])
+
+
+def test_phase_self_times_partition_the_serve_loop(served_pair):
+    """Trace off: the per-phase self times sum to the summed durations
+    of the `serve:loop` roots (each phase hands its duration to its
+    parent, so this holds by construction), `device_wait` is the very
+    seconds `device_wait_s` counts, and the loop's counter counts its
+    roots."""
+    run = served_pair["off"]
+    st = run["stats"]
+    phases = st["host_phase_s"]
+    from triton_dist_tpu.runtime.telemetry import HOST_PHASES
+    assert set(phases) == set(HOST_PHASES)
+    roots = [ph.dt for name, ph in run["opened"] if name == "loop"]
+    assert len(roots) == st["serve_loop_iterations"] \
+        == st["host_phase_n{phase=loop}"] > 0
+    assert sum(phases.values()) == pytest.approx(sum(roots), rel=1e-9)
+    assert phases["device_wait"] == run["device_wait_s"] > 0
+    # the labeled series are the same totals, and every phase the
+    # served path ran through has exits
+    for name in ("accept_wait", "poll", "wire_write", "probe",
+                 "sched_poll", "bookkeep", "admit", "step", "retire",
+                 "device_wait"):
+        assert st[f"host_phase_s{{phase={name}}}"] == phases[name] > 0
+        assert st[f"host_phase_n{{phase={name}}}"] > 0
+    json.dumps(st)
+    assert run["export"]["requests"] == {}      # trace off: no ring
+    assert not any(e.get("ph") == "X"
+                   for e in run["export"]["traceEvents"])
+
+
+def test_lifecycle_from_accept_to_the_wire(served_pair):
+    """Trace on: every request's stamps run accepted <= queued <=
+    admitted <= first_token <= wire_first, the one that finished inside
+    its first chunk included."""
+    reqs = served_pair["on"]["export"]["requests"]
+    assert len(reqs) == len(served_pair["gen_lens"])
+    for rid, req in reqs.items():
+        at = {}
+        for ms, name, _ in req["events"]:
+            at.setdefault(name, ms)
+        order = [at[k] for k in ("accepted", "queued", "admitted",
+                                 "first_token", "wire_first")]
+        assert order == sorted(order), (rid, req["events"])
+        assert [e[1] for e in req["events"]].count("wire_first") == 1
+    # ... and the Chrome ring has the serve loop's spans beside the
+    # scheduler's (bare names, as tools/trace_view.py reads them)
+    names = {e.get("name") for e in
+             served_pair["on"]["export"]["traceEvents"]}
+    assert {"serve:loop", "serve:accept_wait", "serve:poll",
+            "serve:wire_write", "serve:probe", "poll", "bookkeep",
+            "admit", "step", "device_wait", "retire"} <= names
+
+
+def test_served_streams_and_programs_same_trace_on_off(served_pair):
+    off, on = served_pair["off"], served_pair["on"]
+    assert on["streams"] == off["streams"]
+    assert [len(off["streams"][i]) for i in range(5)] \
+        == served_pair["gen_lens"]
+    assert not served_pair["compiled"], (
+        f"the traced server compiled {served_pair['compiled']}")

@@ -247,6 +247,9 @@ class Request:
     resume: Optional[ResumeState] = None
     n: int = 1                     # parallel samples (KV fork fan-out)
     grammar: object = None         # structured.GrammarSpec (optional)
+    # time.monotonic() at which the serving layer's accept() returned
+    # the request's connection (the traced lifecycle's first event)
+    accepted_at: Optional[float] = None
 
 
 class _TokenLog:
@@ -776,16 +779,16 @@ class DecodeSlots:
         import jax
         moe_load = (self.engine.pop_moe_load()
                     if land and self._moe_family else None)
-        t0 = time.perf_counter()
-        if moe_load is not None:
-            # the landed tick's routing-load vector rides the SAME
-            # coalesced readback (its outputs are computed by now —
-            # this is a d2h copy, not a sync)
-            out = jax.device_get(arrs + (moe_load,))
-            out, moe_load = out[:-1], out[-1]
-        else:
-            out = jax.device_get(arrs)
-        dt = time.perf_counter() - t0
+        with self.tele.phase("device_wait") as wait:
+            if moe_load is not None:
+                # the landed tick's routing-load vector rides the SAME
+                # coalesced readback (its outputs are computed by now —
+                # this is a d2h copy, not a sync)
+                out = jax.device_get(arrs + (moe_load,))
+                out, moe_load = out[:-1], out[-1]
+            else:
+                out = jax.device_get(arrs)
+        dt = wait.dt
         self.device_wait_s += dt
         if moe_load is not None:
             self._note_moe_load(moe_load)
@@ -2063,8 +2066,11 @@ class ContinuousScheduler:
         self._c_busy_rejections = reg.counter(
             "busy_rejections", "submits refused at max_queue")
         self._g_host_ms = reg.gauge(
-            "host_ms_per_poll", "dispatch-to-dispatch host time minus "
-                                "device wait (EMA)")
+            "host_ms_per_poll",
+            "EMA of the wall time from one tick's dispatch to the next "
+            "minus the device wait between them: scheduling, drafting, "
+            "admission, the serve loop's accept() wait and socket "
+            "writes, and any compile an admission meets")
         # TP topology + live throughput (multi-chip serving — ROADMAP
         # open item 1): ONE scheduler drives the whole TP mesh, so
         # multi-chip runs must report both aggregate and per-chip
@@ -2138,7 +2144,8 @@ class ContinuousScheduler:
             # lifecycle stamp INSIDE the lock: the driver may admit
             # (and emit for) this request the instant it is visible in
             # the queue, and emit/retire need the record to exist
-            self.tele.queued(req.rid, slo=req.slo)
+            self.tele.queued(req.rid, slo=req.slo,
+                             accepted_at=req.accepted_at)
             self._queue.append(req)
         return True
 
@@ -2286,6 +2293,7 @@ class ContinuousScheduler:
                                      else round(self._host_ms_ema, 3)),
                 "device_wait_s": round(self.slots.device_wait_s, 4),
                 "device_wait_s_by_kind": by_kind,
+                "host_phase_s": self.tele.phase_seconds(),
                 "slo_classes": {
                     name: {"ttft_target_ms": c.ttft_target_ms,
                            "itl_target_ms": c.itl_target_ms,
@@ -2690,10 +2698,10 @@ class ContinuousScheduler:
         phases running under the device's compute instead of after
         its readback.
 
-        Every poll rides a telemetry span (poll_ms histogram always;
-        a timeline span + nested host-phase spans when tracing), and
-        delivered tokens drive the live ttft_ms / inter_token_ms
-        histograms."""
+        Every poll is a `sched:poll` phase (poll_ms histogram and
+        self-time totals always, its own phases nested under it; the
+        Chrome ring's spans when tracing), and delivered tokens drive
+        the live ttft_ms / inter_token_ms histograms."""
         with self.tele.poll_span():
             if self.overlap:
                 if self._grammar_sync_needed():
@@ -2734,7 +2742,8 @@ class ContinuousScheduler:
             # chunked prefill (prefill_budget) removes that hold time,
             # since admit_chunked runs no forward at all
             self._expire_deadlines(done)
-            self._admit(done)
+            with self.tele.phase("admit"):
+                self._admit(done)
         if not self.slots.occupied:
             # idle poll, nothing dispatched: drop the stamp so the idle
             # gap is not charged as host time at the next burst's first
@@ -2864,7 +2873,8 @@ class ContinuousScheduler:
                 self._staged = []
             with self._lock, tele.phase("bookkeep"):
                 self._expire_overlap(out_acc, done)
-                self._admit(done, out_acc)
+                with tele.phase("admit"):
+                    self._admit(done, out_acc)
             with tele.phase("land"):
                 out, finished = self._land_watchdog()
             rid_of = slots.rids
@@ -2882,7 +2892,8 @@ class ContinuousScheduler:
         else:
             with self._lock, tele.phase("bookkeep"):
                 self._expire_overlap(out_acc, done)
-                self._admit(done, out_acc)
+                with tele.phase("admit"):
+                    self._admit(done, out_acc)
             with tele.phase("land"):
                 out, finished = self._land_watchdog()
             rid_of = slots.rids

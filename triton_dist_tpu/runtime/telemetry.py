@@ -20,19 +20,39 @@ its observability substrate:
   holds process-wide counters (e.g. Engine dispatch counts) that are
   not per-scheduler.
 
-- REQUEST LIFECYCLE TRACES: `queued → admitted → prefill_chunk*N →
-  first_token → tokens → preempt/resume → retired/cancelled/expired`,
-  monotonic-stamped per request. The always-on half is two derived
+- REQUEST LIFECYCLE TRACES: `accepted → queued → admitted →
+  prefill_chunk*N → first_token → wire_first → tokens →
+  preempt/resume → retired/cancelled/expired`, monotonic-stamped per
+  request (`accepted` is the return of the serving layer's accept(),
+  `wire_first` the flush of the stream's first message; a scheduler
+  driven without a server has neither). The always-on half is two derived
   histograms — `ttft_ms` (queued → first token, the Sarathi-Serve
   TTFT) and `inter_token_ms` (gap between consecutive deliveries of a
   stream, the stall a client actually sees) — which previously
   existed only as offline bench rows. The full event ring (bounded,
   oldest-retired-first) is kept only when tracing is ON.
 
-- POLL-LOOP TIMELINE: Chrome trace-event JSON (perfetto-loadable —
-  `ui.perfetto.dev`, or `chrome://tracing`) with one track for HOST
-  phases (bookkeep/admit/dispatch/drafter/land/retire nested under
-  each poll span) and one for DEVICE occupancy (dispatch →
+- HOST PHASES (always on): `Telemetry.phase(name)` is the one span
+  of the host path, used by TokenServer.serve_forever (`serve:loop`
+  per iteration over accept_wait / poll / wire_write / probe /
+  idle_sleep) and the scheduler (`sched:poll` over bookkeep / admit /
+  step / dispatch / land / retire / drafter / device_wait) alike —
+  HOST_PHASES is the whole, fixed set. On exit a phase adds its SELF
+  time (its duration less what its child phases covered, so the
+  phases of one thread partition its wall time) to the registry's
+  `host_phase_s{phase=...}` and bumps `host_phase_n{phase=...}`;
+  stats() carries the totals as one flat dict, `host_phase_s`. For
+  its extent it is a `jax.profiler.TraceAnnotation` under its
+  `serve:` / `sched:` name: an operator who attaches jax.profiler to
+  a live TokenServer (start_trace / stop_trace, or the profiler
+  server) sees the phases on the serve thread's line, on the clock
+  of the device's planes, beside the device's operations.
+
+- POLL-LOOP TIMELINE (tracing on): Chrome trace-event JSON
+  (perfetto-loadable — `ui.perfetto.dev`, or `chrome://tracing`)
+  with one track for the HOST phases above (the scheduler's under
+  their bare names, nested under each poll span; the serve loop's as
+  `serve:*`) and one for DEVICE occupancy (dispatch →
   `DecodeSlots._fetch` landing), plus instants for watchdog fires,
   preemptions, drains, and KV demote/promote. This makes the PR-7
   overlap pipeline VISIBLE: the dispatch-ahead bubble structure and
@@ -63,17 +83,21 @@ its observability substrate:
   planes in one merged trace).
 
 - DEVICE-TIME ATTRIBUTION: `mark_dispatch(kind)` always remembers the
-  LAST dispatched program kind (one attribute write — trace-off stays
-  a no-op for streams), so the scheduler's coalesced readback can
+  LAST dispatched program kind (one attribute write), so the
+  scheduler's coalesced readback can
   attribute its blocking wait per program kind
   (DecodeSlots.device_wait_by_kind: decode/verify/mixed/admit, plus
   the disagg plane's prefill/transfer buckets).
 
-Tracing OFF (the default) is a true no-op: every trace entry point
-early-outs on `self.trace` before touching a ring or stamping a
-span. Tracing ON is host-side only — no jax call anywhere in this
-module — so token streams stay BITWISE identical and zero new XLA
-programs compile (asserted by tests/test_telemetry.py). Enable with
+ALWAYS ON: the registry, the derived latency histograms and the host
+phases (per phase two clock reads, two counter adds and one
+TraceAnnotation, which costs under a microsecond while no profiler
+session is open). `trace=True` ADDS the request event lists and the
+Chrome ring; every entry point of those early-outs on `self.trace`.
+Either way this module runs no device computation (its one jax call
+is the profiler's annotation), so token streams stay BITWISE
+identical and zero new XLA programs compile (asserted by
+tests/test_telemetry.py). Enable tracing with
 `ContinuousScheduler(trace=True)` / `TokenServer(trace=True)` or by
 setting `TDTPU_TRACE=path` (the TokenServer also dumps the trace to
 that path on exit); summarize dumps with `tools/trace_view.py`
@@ -92,6 +116,7 @@ from collections import deque
 from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 def labeled_name(name: str, labels: Optional[Dict[str, str]]) -> str:
@@ -107,10 +132,12 @@ def labeled_name(name: str, labels: Optional[Dict[str, str]]) -> str:
 
 
 class Counter:
-    """Monotonic event counter. `inc()` is a plain int add (GIL-atomic
+    """Monotonic event counter. `inc()` is a plain add (GIL-atomic
     enough for the single-writer driver thread; cross-thread writers
     — e.g. busy rejections from reader threads — tolerate the same
-    best-effort semantics the raw-int counters always had)."""
+    best-effort semantics the raw-int counters always had). A total of
+    seconds (`host_phase_s`) grows by floats; every other counter by
+    ints."""
 
     __slots__ = ("name", "help", "labels", "_v")
 
@@ -121,11 +148,11 @@ class Counter:
         self.labels = labels
         self._v = 0
 
-    def inc(self, n: int = 1) -> None:
+    def inc(self, n: float = 1) -> None:
         self._v += n
 
     @property
-    def value(self) -> int:
+    def value(self) -> float:
         return self._v
 
     def snapshot(self):
@@ -458,80 +485,122 @@ class _Req:
         self.itl_max = 0.0
 
 
-class _NullSpan:
-    """The tracing-off phase context: literally nothing."""
+# The host path's phases: name -> the name of its annotation in a
+# profiler trace. `serve:` phases are TokenServer.serve_forever's (the
+# root `loop` and its children), `sched:` the scheduler's (`sched_poll`
+# is one ContinuousScheduler.poll() and the parent of the rest). The
+# set is FIXED: Telemetry seeds a total per phase at construction, so
+# cross-thread stats() readers never see the dict resize, and a phase
+# not listed here is a KeyError at its first use.
+HOST_PHASES = {
+    "loop": "serve:loop",
+    "accept_wait": "serve:accept_wait",
+    "poll": "serve:poll",
+    "wire_write": "serve:wire_write",
+    "probe": "serve:probe",
+    "idle_sleep": "serve:idle_sleep",
+    "sched_poll": "sched:poll",
+    "bookkeep": "sched:bookkeep",
+    "admit": "sched:admit",
+    "step": "sched:step",
+    "dispatch": "sched:dispatch",
+    "land": "sched:land",
+    "retire": "sched:retire",
+    "drafter": "sched:drafter",
+    "device_wait": "sched:device_wait",
+}
 
-    __slots__ = ()
 
-    def __enter__(self):
-        return self
+class _Phase:
+    """One open phase of the host path (Telemetry.phase). Always on:
+    two clock reads, a `TraceAnnotation` for its extent, and on exit
+    its SELF time (its duration minus what its child phases covered)
+    added to `host_phase_s{phase=...}`. With tracing on it also lands
+    in the Chrome ring, whole. `dt` is the whole duration, readable
+    after exit (DecodeSlots._fetch charges device_wait_s with it)."""
 
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
-class _Span:
-    """One host-track phase span (emitted as a Chrome 'X' complete
-    event on exit; nests visually under the enclosing poll span)."""
-
-    __slots__ = ("_tele", "_name", "_t0")
+    __slots__ = ("_tele", "_name", "_ann", "_t0", "_child", "dt")
 
     def __init__(self, tele: "Telemetry", name: str):
         self._tele = tele
         self._name = name
 
     def __enter__(self):
+        tele = self._tele
+        self._child = 0.0
+        self._ann = TraceAnnotation(HOST_PHASES[self._name])
+        self._ann.__enter__()
+        tele._open.append(self)
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        self._tele._span(self._name, self._t0, time.monotonic(),
-                         tid=0)
+        t1 = time.monotonic()
+        tele = self._tele
+        self.dt = dt = t1 - self._t0
+        open_ = tele._open
+        if open_ and open_[-1] is self:
+            open_.pop()
+        elif self in open_:
+            # a child was abandoned open (a watchdogged step that hung
+            # runs on a thread of its own): unwind past it
+            del open_[open_.index(self):]
+        if open_:
+            open_[-1]._child += dt
+        seconds, count = tele._phase_totals[self._name]
+        seconds.inc(dt - self._child)
+        count.inc()
+        if tele.trace:
+            self._ring(t1)
+        self._ann.__exit__(*exc)
         return False
 
+    def _ring(self, t1: float) -> None:
+        # the scheduler's phases keep their bare names in the ring
+        # (tools/trace_view.py's phase table reads them)
+        self._tele._span(HOST_PHASES[self._name].removeprefix("sched:"),
+                         self._t0, t1, tid=0)
 
-class _PollSpan:
-    """Wraps one scheduler poll: records the `poll_ms` histogram
-    (always — it is the live twin of the host_ms_per_poll EMA) and,
-    when tracing, the poll's timeline span with its sequence number
-    (tools/trace_view.py ranks these for the top-k slowest polls)."""
 
-    __slots__ = ("_tele", "_t0")
+class _PollPhase(_Phase):
+    """The `sched_poll` phase of one scheduler poll: besides what
+    every phase does it records the `poll_ms` histogram (the live twin
+    of the host_ms_per_poll EMA) and gives its ring span the poll's
+    sequence number (tools/trace_view.py ranks these for the top-k
+    slowest polls)."""
+
+    __slots__ = ()
 
     def __init__(self, tele: "Telemetry"):
-        self._tele = tele
-
-    def __enter__(self):
-        self._t0 = time.monotonic()
-        return self
+        super().__init__(tele, "sched_poll")
 
     def __exit__(self, *exc):
-        tele = self._tele
-        t1 = time.monotonic()
-        tele.h_poll.record((t1 - self._t0) * 1e3)
-        if tele.trace:
-            tele._poll_seq += 1
-            tele._span("poll", self._t0, t1, tid=0,
-                       args={"seq": tele._poll_seq})
+        super().__exit__(*exc)
+        self._tele.h_poll.record(self.dt * 1e3)
         return False
+
+    def _ring(self, t1: float) -> None:
+        tele = self._tele
+        tele._poll_seq += 1
+        tele._span("poll", self._t0, t1, tid=0,
+                   args={"seq": tele._poll_seq})
 
 
 class Telemetry:
     """One scheduler's telemetry bundle: registry + request lifecycle
     + poll timeline (module docstring). The ALWAYS-ON half is the
-    registry and the derived latency histograms (`ttft_ms`,
-    `inter_token_ms`, `request_latency_ms`, `poll_ms`) — they are the
-    stats() surface and cost what the hand-rolled counters cost. The
-    TRACE half (event rings, timeline spans/instants) is gated on
-    `self.trace` with guarded early-outs: trace-off is a true no-op.
+    registry, the derived latency histograms (`ttft_ms`,
+    `inter_token_ms`, `request_latency_ms`, `poll_ms`) and the host
+    path's phases (`phase()`: per-phase self-time totals and a
+    profiler annotation each) — they are the stats() surface. The
+    TRACE half (request event rings, the Chrome timeline's spans and
+    instants) is gated on `self.trace` with guarded early-outs.
 
-    Thread contract: histogram/counter records come from the driver
-    thread; `queued`/`retire` (which resize the live-request dict)
-    and `export` take the small internal lock so cross-thread
-    submit() and stats dumps never iterate a resizing dict."""
+    Thread contract: histogram/counter records and phases come from
+    the driver thread (the serve loop's, which is the one that polls);
+    `queued`/`retire` (which resize the live-request dict) and
+    `export` take the small internal lock so cross-thread submit()
+    and stats dumps never iterate a resizing dict."""
 
     # retired statuses get their own counters, predeclared so the
     # retire path never takes the registry lock
@@ -557,6 +626,17 @@ class Telemetry:
             "poll_ms", "scheduler poll duration")
         self._c_status = {s: r.counter("requests_" + s)
                           for s in self._STATUSES}
+        # per-phase (self seconds, exits) of the host path, seeded for
+        # every phase there is; _open is the stack of phases open now
+        self._phase_totals = {
+            name: (r.counter("host_phase_s", "self time of a phase of "
+                             "the serve loop or the scheduler: a "
+                             "thread's phases partition its wall time",
+                             labels={"phase": name}),
+                   r.counter("host_phase_n", "exits of that phase",
+                             labels={"phase": name}))
+            for name in HOST_PHASES}
+        self._open: List[_Phase] = []
         self._live: Dict[object, _Req] = {}
         self._retired: deque = deque(maxlen=max_retired)
         self._events: deque = deque(maxlen=max_events)
@@ -608,7 +688,11 @@ class Telemetry:
                 str(slo), {}, self.registry)
         return cls
 
-    def queued(self, rid, slo=None) -> None:
+    def queued(self, rid, slo=None, accepted_at=None) -> None:
+        """rid entered the waiting line. accepted_at: the monotonic
+        stamp at which the serving layer's accept() returned its
+        connection, so a traced lifecycle starts at the program's
+        first sight of the request; ttft_ms still runs from here."""
         t = time.monotonic()
         with self._lock:
             rec = self._live.get(rid)
@@ -616,6 +700,8 @@ class Telemetry:
                 rec = self._live[rid] = _Req(t, self.trace,
                                              self._slo_of(slo))
         if rec.ev is not None:
+            if accepted_at is not None:
+                rec.ev.append([self._ms(accepted_at), "accepted", None])
             rec.ev.append([self._ms(t), "queued",
                            rec.slo.name if rec.slo else None])
 
@@ -629,6 +715,28 @@ class Telemetry:
         if rec is None or rec.ev is None:
             return
         rec.ev.append([self._ms(time.monotonic()), name, detail])
+
+    def wire_first(self, rid, n: int) -> None:
+        """Trace-only: the serving layer has flushed a message of n
+        tokens to rid's socket; stamped `wire_first` where it is the
+        stream's first (everything emit() has counted is in it). The
+        scheduler retires a request that finishes inside its first
+        chunk before the wire sees it, so the retired ring is read
+        too."""
+        if not self.trace or not n:
+            return
+        ev = [self._ms(time.monotonic()), "wire_first", int(n)]
+        rec = self._live.get(rid)
+        if rec is not None:
+            if rec.n == n and rec.ev is not None:
+                rec.ev.append(ev)
+            return
+        with self._lock:
+            for r, summary in reversed(self._retired):
+                if r == rid:
+                    if summary["tokens"] == n:
+                        summary["events"].append(ev)
+                    return
 
     def emit(self, rid, n: int) -> None:
         """One delivery of n tokens to rid's stream: derives ttft_ms
@@ -695,7 +803,8 @@ class Telemetry:
                            "ttft_ms": ttft, "events": rec.ev}))
 
     # ------------------------------------------------------------------
-    # poll-loop timeline (tracing only; host tid=0, device tid=1)
+    # poll-loop timeline (host tid=0, device tid=1): phases always,
+    # the Chrome ring when tracing
     # ------------------------------------------------------------------
 
     def _span(self, name: str, t0: float, t1: float, *, tid: int,
@@ -707,16 +816,18 @@ class Telemetry:
             ev["args"] = args
         self._events.append(ev)
 
-    def poll_span(self) -> _PollSpan:
-        return _PollSpan(self)
+    def poll_span(self) -> _PollPhase:
+        return _PollPhase(self)
 
-    def phase(self, name: str):
-        """Host-track phase span context (bookkeep/dispatch/land/
-        retire/drafter). Returns the shared null context when off —
-        zero allocation, zero stamps."""
-        if not self.trace:
-            return _NULL_SPAN
-        return _Span(self, name)
+    def phase(self, name: str) -> _Phase:
+        """One phase of the host path, as a context (HOST_PHASES has
+        the names; _Phase what it records). Always on."""
+        return _Phase(self, name)
+
+    def phase_seconds(self) -> Dict[str, float]:
+        """{phase: self seconds so far}, every phase, unrounded."""
+        return {name: c[0].value
+                for name, c in self._phase_totals.items()}
 
     def mark_dispatch(self, kind: str = "step") -> None:
         """Stamp a device-program dispatch; the matching

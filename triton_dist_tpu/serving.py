@@ -65,7 +65,12 @@ Prometheus text-exposition listener (`GET /metrics` over HTTP/1.0 —
 scrape `http://host:server.metrics_port/metrics`), and
 `TDTPU_TRACE=path` enables poll-loop tracing AND dumps the
 perfetto-loadable timeline + request traces to `path` when
-serve_forever exits (summarize with tools/trace_view.py).
+serve_forever exits (summarize with tools/trace_view.py). Tracing or
+not, every iteration of serve_forever is a `serve:loop` phase over
+`serve:accept_wait` / `poll` / `wire_write` / `probe` / `idle_sleep`
+(Telemetry.phase): self-time totals in stats()["host_phase_s"], and
+annotations that a jax.profiler session attached to the live server
+shows beside the device's operations.
 """
 
 from __future__ import annotations
@@ -325,6 +330,12 @@ class TokenServer:
         self._sock.listen(max(4, batch))
         self.host, self.port = self._sock.getsockname()
         self._stop = threading.Event()
+        # iterations of serve_forever's loop: over them, the loop's
+        # time outside accept() (host_phase_s of every phase but
+        # accept_wait and idle_sleep) is the mean gap for which a
+        # connecting client sits unseen in the kernel's backlog
+        self._c_iterations = self.sched.tele.registry.counter(
+            "serve_loop_iterations", "iterations of the serve loop")
         self._next_rid = 0
         self._conns: dict = {}          # rid -> _ClientStream
         self._lock = threading.Lock()   # guards scheduler submit + _conns
@@ -389,13 +400,16 @@ class TokenServer:
             except OSError:
                 pass
 
-    def _reader(self, conn: socket.socket) -> None:
+    def _reader(self, conn: socket.socket,
+                accepted_at: Optional[float] = None) -> None:
         """Connection thread: parse ONE request line (capped at
         _MAX_LINE bytes — a garbage firehose cannot balloon this
         thread), enqueue it for the model loop, leave the socket open
         for streaming replies. Every refusal — malformed JSON,
         over-capacity prompt, oversized line, full queue — is answered
-        with a structured line before the close."""
+        with a structured line before the close. accepted_at: the
+        monotonic stamp of accept()'s return, the first event of the
+        request's traced lifecycle."""
         import sys
         from triton_dist_tpu.models.scheduler import Request
         try:
@@ -514,7 +528,8 @@ class TokenServer:
                 accepted = self.sched.submit(Request(
                     rid=rid, ids=np.asarray(ids, np.int32),
                     gen_len=gen_len, seed=seed, n=n, grammar=gspec,
-                    deadline_ms=deadline_ms, slo=slo))
+                    deadline_ms=deadline_ms, slo=slo,
+                    accepted_at=accepted_at))
                 if accepted:
                     cs = self._ClientStream(conn, f)
                     cs.n_left = n
@@ -616,6 +631,8 @@ class TokenServer:
             cs.n += len(row)
         except OSError:
             cs.dead = True
+            return
+        self.sched.tele.wire_first(rid, len(row))
 
     def _probe_disconnects(self) -> None:
         """Detect clients that hung up WITHOUT a failed write: after
@@ -734,51 +751,66 @@ class TokenServer:
         verdict beats a silent freeze."""
         from triton_dist_tpu.runtime.stress import HangError
         done_count = 0
+        tele = self.sched.tele
         self._sock.settimeout(0.02)
         try:
             while not self._stop.is_set():
-                # drain the accept queue without blocking the decode
-                # loop (reader threads are daemonic and short-lived:
-                # one request line each, no tracking needed)
-                while True:
+                self._c_iterations.inc()
+                # one root phase per iteration; what no child phase
+                # names is its own self time, so the phases' totals
+                # partition this thread's wall time
+                with tele.phase("loop"):
+                    # drain the accept queue without blocking the
+                    # decode loop (reader threads are daemonic and
+                    # short-lived: one request line each, no tracking
+                    # needed)
+                    with tele.phase("accept_wait"):
+                        while True:
+                            try:
+                                conn, _ = self._sock.accept()
+                            except socket.timeout:
+                                break
+                            threading.Thread(
+                                target=self._reader,
+                                args=(conn, time.monotonic()),
+                                daemon=True).start()
+                    t0 = time.monotonic()
                     try:
-                        conn, _ = self._sock.accept()
-                    except socket.timeout:
+                        with tele.phase("poll"), self._lock:
+                            out, finished = self.sched.poll()
+                    except HangError as e:
+                        for rid in list(self._conns):
+                            self._finish(rid, error=str(e))
                         break
-                    threading.Thread(target=self._reader, args=(conn,),
-                                     daemon=True).start()
-                t0 = time.monotonic()
-                try:
-                    with self._lock:
-                        out, finished = self.sched.poll()
-                except HangError as e:
-                    for rid in list(self._conns):
-                        self._finish(rid, error=str(e))
-                    break
-                self._poll_ema = 0.9 * self._poll_ema + \
-                    0.1 * (time.monotonic() - t0)
-                for rid, toks in out.items():
-                    self._emit(rid, toks)
-                for rid in finished:
-                    if self._finish(rid):
-                        done_count += 1
-                # cancel-on-disconnect: a hung-up client's slot retires
-                # NOW (pages freed / inserted into the prefix tree)
-                # instead of decoding to gen_len for nobody
-                self._probe_disconnects()
-                dead = [rid for rid, cs in list(self._conns.items())
-                        if cs.dead]
-                for rid in dead:
-                    with self._lock:
-                        self.sched.cancel(rid)
-                    if self._finish(rid):
-                        done_count += 1
-                if max_requests is not None and done_count >= max_requests:
-                    break
-                if self.sched.idle:
-                    # nothing in flight: sleep on accept instead of
-                    # spinning the poll loop
-                    self._stop.wait(0.05)
+                    self._poll_ema = 0.9 * self._poll_ema + \
+                        0.1 * (time.monotonic() - t0)
+                    with tele.phase("wire_write"):
+                        for rid, toks in out.items():
+                            self._emit(rid, toks)
+                        for rid in finished:
+                            if self._finish(rid):
+                                done_count += 1
+                    # cancel-on-disconnect: a hung-up client's slot
+                    # retires NOW (pages freed / inserted into the
+                    # prefix tree) instead of decoding to gen_len for
+                    # nobody
+                    with tele.phase("probe"):
+                        self._probe_disconnects()
+                        dead = [rid for rid, cs
+                                in list(self._conns.items()) if cs.dead]
+                        for rid in dead:
+                            with self._lock:
+                                self.sched.cancel(rid)
+                            if self._finish(rid):
+                                done_count += 1
+                    if max_requests is not None \
+                            and done_count >= max_requests:
+                        break
+                    if self.sched.idle:
+                        # nothing in flight: sleep on accept instead
+                        # of spinning the poll loop
+                        with tele.phase("idle_sleep"):
+                            self._stop.wait(0.05)
         finally:
             self._sock.close()
             for rid in list(self._conns):
