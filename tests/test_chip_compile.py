@@ -92,6 +92,12 @@ def _flash_decode_paged(q, pk, pv, table, kv_lens):
                               kv_lens=kv_lens)
 
 
+def _flash_decode_paged_windows(q, pk, pv, table, kv_lens, q_lens):
+    from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
+    return flash_decode_paged(q, pk, pv, table, jnp.max(kv_lens),
+                              kv_lens=kv_lens, q_lens=q_lens)
+
+
 def _kv_update(cache, new, tile_pos):
     from triton_dist_tpu.kernels.flash_attn import kv_update
     return kv_update(cache, new, tile_pos)
@@ -111,6 +117,17 @@ def _kv(b, t):
 
 
 _POOL = ((NUM_PAGES, PAGE, D), BF16)
+
+
+def _paged_cell(hkv, b=32, max_seq=2048, s=1):
+    """flash_decode_paged's arguments for a chip that holds `hkv` KV
+    heads of every slot (and the 16 query heads over them); s > 1 adds
+    the per-slot query windows."""
+    maxp = max_seq // PAGE
+    pool = ((b * hkv * maxp + 1, PAGE, D), BF16)
+    return [((b, s, HQ, D), BF16), pool, pool,
+            ((b * hkv, maxp), I32), ((b,), I32)] + [((b,), I32)] * (s > 1)
+
 
 # name -> (function, [(shape, dtype), ...]); () is a traced scalar
 CASES = {
@@ -136,6 +153,17 @@ CASES = {
     "flash_decode_paged_b8_page16": (
         _flash_decode_paged, [_q(B, 1), _POOL, _POOL,
                               ((B * HKV, MAXP), I32), ((B,), I32)]),
+    # the same walk at the benchmark's cells (B=32 slots, max_seq
+    # 2048 = 128 table columns of page 16): one chip's 256 streams of
+    # 2 query rows, and a TP=4 chip's 64 streams of 8
+    "flash_decode_paged_cell_1chip": (
+        _flash_decode_paged, _paged_cell(hkv=8)),
+    "flash_decode_paged_cell_tp4": (
+        _flash_decode_paged, _paged_cell(hkv=2)),
+    # the mixed tick's 16-token window (chunked prefill, spec verify):
+    # 32 query rows a stream
+    "flash_decode_paged_cell_windows": (
+        _flash_decode_paged_windows, _paged_cell(hkv=8, s=16)),
     # cache row insert: a whole prompt, and one 8-row decode tile
     "kv_update_prefill_s128": (
         _kv_update, [_kv(B, T), _kv(B, 128), ((), I32)]),
@@ -155,6 +183,33 @@ def test_kernel_compiles_for_v5e(name, one_chip, for_chip):
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{name}: no Mosaic kernel in the compiled program")
+
+
+def test_int8_pool_compiles_at_page_128_only(one_chip, for_chip):
+    """The int8 pool's scale planes are [NP, page] f32 and the walk
+    copies one page's row of them: at page 16, as served, that is a
+    16-lane slice Mosaic refuses (so did the BlockSpec walk before PR
+    30), and the variant runs in the interpreter only. Recorded here so
+    that the day a [NP, 1, page] plane or a wider page lifts it, this
+    test says so (PERF.md, open questions)."""
+    from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
+
+    def lower(page, maxp=16):
+        np_ = B * HKV * maxp + 1
+        pool = jax.ShapeDtypeStruct((np_, page, D), jnp.int8,
+                                    sharding=one_chip)
+        scale = jax.ShapeDtypeStruct((np_, page), jnp.float32,
+                                     sharding=one_chip)
+        shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                  for s, dt in (_q(B, 1), ((B * HKV, maxp), I32),
+                                ((B,), I32))]
+        return jax.jit(lambda q, t, l, pk, pv, sk, sv: flash_decode_paged(
+            q, pk, pv, t, jnp.max(l), kv_lens=l, k_scale=sk, v_scale=sv)
+        ).lower(*shapes, pool, pool, scale, scale)
+
+    assert "tpu_custom_call" in lower(128).compile().as_text()
+    with pytest.raises(Exception, match="(?i)mosaic|tiling|align|shape"):
+        lower(16).compile()
 
 
 # --- the TP=4 comm kernels (chip_smoke.py --chips 4 runs gemm_ar; the

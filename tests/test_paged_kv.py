@@ -6,6 +6,7 @@ writes/appends, per-slot attention lengths."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from triton_dist_tpu.kernels.flash_attn import attention_cached_ref
 from triton_dist_tpu.kernels.paged_kv import (PageAllocator, PagedKVCache,
@@ -95,6 +96,12 @@ def test_paged_decode_stream_batch_widths():
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-4, rtol=2e-4,
                                    err_msg=f"B={B} Hkv={Hkv}")
+        # streams per step only regroups streams: bitwise the same
+        for bw in (w for w in (1, 4) if (B * Hkv) % w == 0):
+            again = flash_decode_paged(
+                q, cache.pages_k, cache.pages_v, cache.table,
+                jnp.int32(kv_len), block_w=bw)
+            assert _bits(again) == _bits(out), f"block_w={bw}"
 
 
 def _fill_contiguous(lens, ks, vs, Hkv, T, d):
@@ -379,3 +386,193 @@ def test_paged_decode_int8_scales_vs_dequant_oracle():
     ref = attention_cached_ref(q, kd, vd, kvl)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-4, rtol=2e-4)
+
+
+# ---------------------------------------------------------------------
+# the length-bounded multi-page walk: one launch, scattered pages, every
+# variant against gather + attention_cached_ref
+# ---------------------------------------------------------------------
+
+_PAGE, _D = 16, 128
+_BLOCK = 128            # positions of one block of pages (C * page)
+
+
+def _bits(a):
+    return np.ascontiguousarray(np.asarray(a)).tobytes()
+
+
+def _scattered_pool(rng, ks, vs, maxp, extra=5):
+    """Contiguous [B, Hkv, maxp*page, d] streams laid out as pages in
+    a random physical order behind a table (page 0 and `extra` more
+    stay unused, holding noise no stream may read)."""
+    B, Hkv = ks.shape[:2]
+    X = B * Hkv
+    NP = 1 + X * maxp + extra
+    table = (1 + rng.permutation(NP - 1)[:X * maxp]
+             ).astype(np.int32).reshape(X, maxp)
+    pk = rng.randn(NP, _PAGE, _D).astype(ks.dtype)
+    pv = rng.randn(NP, _PAGE, _D).astype(vs.dtype)
+    pk[table] = ks.reshape(X, maxp, _PAGE, _D)
+    pv[table] = vs.reshape(X, maxp, _PAGE, _D)
+    return jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(table)
+
+
+def _streams(rng, B, Hkv, maxp):
+    shape = (B, Hkv, maxp * _PAGE, _D)
+    return (rng.randn(*shape).astype(np.float32) * 0.5,
+            rng.randn(*shape).astype(np.float32) * 0.5)
+
+
+def _walk_ragged(rng):
+    """Lengths that are multiples of neither the page nor the block,
+    on either side of both, the table's whole capacity, and none at
+    all: with Hkv = 2 a step is four slots, so the first step has no
+    block to walk (it must start no copy that nothing waits for), the
+    second is all live, and the third mixes both."""
+    Hq, Hkv, maxp = 4, 2, 12
+    lens = [0, 0, 0, 0, 1, 15, 16, 17,
+            _BLOCK - 1, 0, _BLOCK + 1, maxp * _PAGE]
+    B = len(lens)
+    live = np.asarray(lens) > 0
+    ks, vs = _streams(rng, B, Hkv, maxp)
+    pk, pv, table = _scattered_pool(rng, ks, vs, maxp)
+    q = jnp.asarray(rng.randn(B, 1, Hq, _D), jnp.float32) * 0.5
+    kvl = jnp.asarray(lens, jnp.int32)
+    out = np.asarray(jax.jit(lambda q, l: flash_decode_paged(
+        q, pk, pv, table, jnp.max(l), kv_lens=l))(q, kvl))
+    ref = np.asarray(attention_cached_ref(
+        q, jnp.asarray(ks), jnp.asarray(vs), kvl))
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-4,
+                               rtol=2e-4)
+    assert (out[~live] == 0).all()      # nothing attended: zeros
+
+
+def _walk_per_stream_invariant(rng):
+    """A stream's rows depend on its own queries, pages and lengths
+    alone: the same two slots under another table width, among other
+    neighbours and in another grouping of streams per step come out
+    BITWISE the same."""
+    Hq, Hkv = 4, 2
+    lens = [37, 150]
+    ks, vs = _streams(rng, 2, Hkv, 12)
+    q = rng.randn(2, 1, Hq, _D).astype(np.float32) * 0.5
+
+    def run(maxp, before, after, block_w):
+        """the two slots between `before` and `after` neighbour
+        lengths, in a table of maxp columns"""
+        nb = len(before) + len(after)
+        nk, nv = _streams(rng, nb, Hkv, maxp)
+        pad = ((0, 0), (0, 0), (0, (maxp - 12) * _PAGE), (0, 0))
+        at = len(before)
+
+        def order(n, own):
+            return np.concatenate([n[:at], own, n[at:]])
+
+        kk = order(nk, np.pad(ks, pad))
+        vv = order(nv, np.pad(vs, pad))
+        qq = order(rng.randn(nb, 1, Hq, _D).astype(np.float32), q)
+        pk, pv, table = _scattered_pool(rng, kk, vv, maxp)
+        kvl = jnp.asarray(before + lens + after, jnp.int32)
+        out = flash_decode_paged(jnp.asarray(qq), pk, pv, table,
+                                 jnp.max(kvl), kv_lens=kvl,
+                                 block_w=block_w)
+        return _bits(out[at:at + 2])
+
+    alone = run(12, [], [], None)
+    assert run(20, [300], [5], None) == alone       # X=8: W=8
+    assert run(12, [], [90, 1], 2) == alone         # other neighbours
+    assert run(20, [7, 200], [], 1) == alone
+
+
+def _walk_query_windows(rng):
+    """Windows of one row and of several (and an empty one) in one
+    launch: spec verify and chunked prefill ride this mask. A parked
+    prefill slot that the chunk budget starved arrives as kv length 0
+    and window 0 (scheduler._build_mixed_window): a whole step of
+    those, then a live step, then a mixed one."""
+    Hq, Hkv, maxp, S = 4, 2, 12, 4
+    lens = [0, 0, 0, 0, 40, _BLOCK + 2, 17, 9, 150, 0, 0, 60]
+    qls = [0, 0, 0, 0, 1, 3, 4, 0, 2, 0, 0, 1]
+    B = len(lens)
+    live = np.asarray(lens) > 0
+    ks, vs = _streams(rng, B, Hkv, maxp)
+    pk, pv, table = _scattered_pool(rng, ks, vs, maxp)
+    q = jnp.asarray(rng.randn(B, S, Hq, _D), jnp.float32) * 0.5
+    kvl, ql = jnp.asarray(lens, jnp.int32), jnp.asarray(qls, jnp.int32)
+    out = np.asarray(jax.jit(lambda q, l, w: flash_decode_paged(
+        q, pk, pv, table, jnp.max(l), kv_lens=l, q_lens=w))(q, kvl, ql))
+    ref = np.asarray(attention_cached_ref(
+        q, jnp.asarray(ks), jnp.asarray(vs), kvl, q_lens=ql))
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-4,
+                               rtol=2e-4)
+    assert (out[~live] == 0).all()
+
+
+def _walk_int8(rng):
+    """The int8 pool: a page's scales arrive beside its payload."""
+    from triton_dist_tpu.kernels.quant import (dequantize_kv_int8,
+                                               quantize_kv_int8)
+    Hq, Hkv, maxp = 4, 2, 12
+    lens = [1, 17, _BLOCK + 1, maxp * _PAGE]
+    B = len(lens)
+    ks, vs = _streams(rng, B, Hkv, maxp)
+    k8, k_s = quantize_kv_int8(jnp.asarray(ks))
+    v8, v_s = quantize_kv_int8(jnp.asarray(vs))
+    pk, pv, table = _scattered_pool(rng, np.asarray(k8), np.asarray(v8),
+                                    maxp)
+    X = B * Hkv
+    sk = rng.rand(pk.shape[0], _PAGE).astype(np.float32)
+    sv = rng.rand(pk.shape[0], _PAGE).astype(np.float32)
+    sk[np.asarray(table)] = np.asarray(k_s).reshape(X, maxp, _PAGE)
+    sv[np.asarray(table)] = np.asarray(v_s).reshape(X, maxp, _PAGE)
+    q = jnp.asarray(rng.randn(B, 1, Hq, _D), jnp.float32) * 0.5
+    kvl = jnp.asarray(lens, jnp.int32)
+    out = jax.jit(lambda q, l: flash_decode_paged(
+        q, pk, pv, table, jnp.max(l), kv_lens=l,
+        k_scale=jnp.asarray(sk), v_scale=jnp.asarray(sv)))(q, kvl)
+    ref = attention_cached_ref(q, dequantize_kv_int8(k8, k_s),
+                               dequantize_kv_int8(v8, v_s), kvl)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-4, rtol=2e-4)
+
+
+def _walk_partial(rng):
+    """The SP partial: two chips own alternate tiles; the one-tile
+    stream is all chip 1's, so chip 0 returns the combine's neutral
+    element for it, and the partials combine to the full softmax. At
+    two streams a step the empty slot is a step with no block."""
+    from triton_dist_tpu.kernels.flash_attn import lse_combine
+    from triton_dist_tpu.kernels.paged_kv import flash_decode_paged_partial
+    Hq, Hkv, maxp, S = 4, 2, 12, 2
+    lens, qls = [9, 0, _BLOCK + 30, 60], [1, 0, 2, 1]
+    B = len(lens)
+    live = np.asarray(lens) > 0
+    X = B * Hkv
+    ks, vs = _streams(rng, B, Hkv, maxp)
+    pk, pv, table = _scattered_pool(rng, ks, vs, maxp)
+    q = jnp.asarray(rng.randn(B, S, Hq, _D), jnp.float32) * 0.5
+    kvl, ql = jnp.asarray(lens, jnp.int32), jnp.asarray(qls, jnp.int32)
+    tile = np.arange(maxp)[None].repeat(X, 0)
+    parts = []
+    for chip in (0, 1):
+        owned = (tile % 2 != chip).astype(np.int32)   # tile 0 -> chip 1
+        # a tile this chip does not own may name any page: never read
+        local = np.where(owned != 0, np.asarray(table), 0)
+        parts.append(flash_decode_paged_partial(
+            q, pk, pv, jnp.asarray(local), kv_lens=kvl, q_lens=ql,
+            tile_owned=jnp.asarray(owned), block_w=2))
+    acc0, m0, l0 = (np.asarray(a) for a in parts[0])
+    assert (acc0[:2] == 0).all() and (l0[:2] == 0).all()
+    assert (m0[:2] == np.float32(-1e30)).all()
+    out = np.asarray(lse_combine(*(jnp.stack(a) for a in zip(*parts))))
+    ref = np.asarray(attention_cached_ref(
+        q, jnp.asarray(ks), jnp.asarray(vs), kvl, q_lens=ql))
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-4,
+                               rtol=2e-4)
+
+
+@pytest.mark.parametrize("case", [
+    _walk_ragged, _walk_per_stream_invariant, _walk_query_windows,
+    _walk_int8, _walk_partial], ids=lambda f: f.__name__[6:])
+def test_paged_walk(case):
+    case(np.random.RandomState(30))

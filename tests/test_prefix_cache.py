@@ -315,7 +315,7 @@ def test_eviction_pressure_stays_bitwise():
 
 def test_paged_prefix_flash_backend():
     """The Pallas paged-decode kernel path (flash_decode_paged walks
-    the table in the BlockSpec index map): same bitwise contract."""
+    its own pages through the table): same bitwise contract."""
     cfg, model = _model()
     eng = Engine(model, max_seq=48, backend="flash")
     rng = np.random.RandomState(4)

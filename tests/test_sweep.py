@@ -161,6 +161,31 @@ def test_prune_drops_indivisible_stream_block():
     assert "block_w=8" in rejected[0][1]
 
 
+def test_estimator_counts_paged_walk_scratch():
+    """The walk's pools stay in HBM and its double K and V block
+    buffers are VMEM scratch, not BlockSpec blocks: the shared
+    estimator (the pruner's and tdcheck's) must count them, 2 planes x
+    2 halves x W streams x 128 positions x d, and grow with W."""
+    from triton_dist_tpu.analysis.contracts import estimate_vmem
+    from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
+    B, Hq, Hkv, d, page, maxp = 4, 4, 2, 128, 16, 16
+    NP = B * Hkv * maxp
+    q = jnp.zeros((B, 1, Hq, d), jnp.bfloat16)
+    pages = jnp.zeros((NP, page, d), jnp.bfloat16)
+    table = jnp.arange(NP, dtype=jnp.int32).reshape(B * Hkv, maxp)
+    lens = jnp.full((B,), 40, jnp.int32)
+
+    def vmem(w):
+        return estimate_vmem(
+            lambda *a: flash_decode_paged(*a[:4], None, kv_lens=a[4],
+                                          block_w=w),
+            (q, pages, pages, table, lens))
+
+    buffers = lambda w: 2 * 2 * w * 128 * d * 2
+    assert buffers(8) <= vmem(8) < 2 * buffers(8)
+    assert vmem(8) - vmem(2) >= buffers(8) - buffers(2)
+
+
 def test_prune_rejects_all_pruned_space():
     """A tunables space whose EVERY config fails the pruner is a typo'd
     registration: prune_space raises instead of silently sweeping

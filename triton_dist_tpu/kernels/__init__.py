@@ -393,7 +393,8 @@ def _b_flash_decode_paged(partial):
         from triton_dist_tpu.kernels.paged_kv import (
             flash_decode_paged, flash_decode_paged_partial)
         rng = _np_rng(14)
-        B, Hq, Hkv, d, page, maxp = 2, 4, 2, 128, 128, 4
+        # page 16 as served: 8 pages make one block of the walk
+        B, Hq, Hkv, d, page, maxp = 2, 4, 2, 128, 16, 32
         NP = B * Hkv * maxp
         q = _f32(rng, B, 1, Hq, d)
         pages = _f32(rng, NP, page, d)
@@ -472,6 +473,8 @@ def _grid(key, *vals):
 
 
 _TUNE_FLASH_DECODE = _grid("block_x", 32, 64, 128)
+# paged walk: W streams per grid step (the block of pages is fixed,
+# paged_kv._KV_TILE)
 _TUNE_PAGED = _grid("block_w", 1, 2, 4, 8)
 _TUNE_GROUPED_GEMM = ({"block_c": 128, "block_f": 256},
                       {"block_c": 256, "block_f": 512},

@@ -7,25 +7,38 @@ physical pool and grow without reallocation).
 
 Design: physical pages [NP, page, d] (one page = `page` contiguous KV
 positions of ONE (batch, kv-head) stream); a host/int32 page table
-[B*Hkv, max_pages] maps logical tiles to physical pages. The flash
-kernel walks logical tiles and resolves each one through the table IN
-THE BLOCKSPEC INDEX MAP — the page lookup costs nothing on the data
-path because the scalar-prefetch grid machinery already evaluates index
-maps ahead of the DMAs (the TPU analog of the reference's in-kernel
-`page_table[block_idx]` load).
+[B*Hkv, max_pages] maps logical tiles to physical pages.
 
-Pages of different streams are not contiguous, so one BLOCK cannot
-span streams — but one GRID STEP can: the walk batches W streams per
-step by giving the kernel W separate K/V operands, each with its own
-page-resolving index map (W k-blocks + W v-blocks DMA in parallel
-under the step's compute, per-stream online-softmax accumulators in
-one scratch). This cuts the grid to X/W * max_pages steps — the
-step-count overhead that made the r3 bx=1 walk slow — while keeping
-the pure-indirection layout. W = largest of (8, 4, 2, 1) dividing
-B*Hkv. The residual gap vs the contiguous cache is the per-stream dot
-shape ([rep, page] instead of a [64*rep, page] slab): paging still
-buys allocation flexibility first, but the walk is no longer
-step-bound.
+The walk is LENGTH-BOUNDED and MULTI-PAGE. The grid runs over blocks
+of W streams and nothing else; the pools stay in HBM (`pl.ANY`). Inside
+a step a loop runs over the blocks of C pages (one softmax tile of
+_KV_TILE positions) that the step's longest stream really has — a
+stream of 18 pages does the work of 18, whatever the table's width (a
+step whose streams are all empty walks one masked block) — and for
+every block the kernel reads each stream's page ids from the
+scalar-prefetched table and fetches its OWN pages of that block, K and
+V, with one `make_async_copy` each into a [W, C*page, d] VMEM buffer
+(the TPU analog of the reference's in-kernel `page_table[block_idx]`
+load). The buffer has two halves: block i+1's copies are started before
+block i's are waited for, so they fly under its compute. The QK and PV
+dots then run on [rows, d] x [C*page, d] for all W streams at once,
+with one online-softmax update per block, the accumulators carried in
+registers through the loop.
+
+A stream's result depends on its own queries, pages and lengths alone:
+not on the table's width, not on which streams share its step, not on W
+(the block is fixed). The scheduler's bitwise differentials (sync vs
+overlap, preempt/resume, prefix hit vs miss) lean on that.
+
+What the layout costs: a page of one (slot, kv-head) is page*d*2 bytes
+— 4 KiB at page 16 — so a call issues one small copy per page per
+plane, from the scalar core. A layout in which a slot's heads share a
+page would make each copy Hkv times larger; that is kv_cache.py's,
+prefix_cache.py's and the TP sharding's to change, not this kernel's
+(PERF.md, open questions). Paging still buys allocation flexibility
+first; W = largest of (8, 4, 2, 1) dividing B*Hkv unless the tune store
+says otherwise. C is not a tunable: on the chip 16 pages a block was
+slower than 8 at both served shapes (PERF.md, PR 30).
 """
 
 from __future__ import annotations
@@ -42,158 +55,205 @@ from jax.experimental.pallas import tpu as pltpu
 from triton_dist_tpu.runtime import interpret_mode
 
 
-def _paged_kernel(scale: float, rep: int, page: int, W: int,
-                  per_stream: bool, quant: bool, partial: bool,
-                  len_ref, *refs):
-    """Grid (X // W, max_pages); W (batch, kv-head) streams per grid
-    step (refs = q, k_0..k_{W-1}, v_0..v_{W-1}, [ks_0..ks_{W-1},
-    vs_0..vs_{W-1}], [lens], o, m/l/acc scratch). Same online softmax
-    as _flash_decode_kernel, block = one page; the W streams' pages
-    DMA in parallel under the step and each keeps its own accumulator
-    row.
+# Positions per block of pages, and so per online-softmax update.
+# FIXED, not a tunable: it regroups the float summation (like
+# flash_decode's block_t), and a stream's output must not depend on how
+# the launch was scheduled. A page larger than this is a block alone.
+_KV_TILE = 128
 
-    per_stream=True (continuous batching): a [W, 2] int32 lens block
-    of (kv length, query length) pairs rides as the last input and
-    stream j masks to its OWN lengths, so slots at different sequence
-    positions share one launch; tiles past a stream's length are a
-    bitwise no-op of its accumulator (and its index map clamps to its
-    own last page, so the surplus DMAs re-request the same block and
-    are elided). q_len == 1 is plain decode; q_len > 1 is a
-    prefill-shaped window — the speculative-verify draft
+
+def _block_pages(page: int) -> int:
+    """C: pages of one block."""
+    return max(1, _KV_TILE // page)
+
+
+def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
+                  quant: bool, partial: bool, s_ref, *refs):
+    """Grid (X // W,): one step walks W (batch, kv-head) streams through
+    THEIR OWN pages, C pages at a time (module docstring). refs = q
+    [W, rows, d], lens [W, 1, 2] of (kv length, query length), the K
+    and V pools in HBM, [the K and V scale planes in HBM], [own
+    [W, 1, L] per-position ownership], o, [m, l], then scratch: K and
+    V blocks [2, W, C*page, d], [scale blocks [2, W, 1, C*page]], one
+    DMA semaphore per buffer half. s_ref holds the X kv lengths, then
+    the page table row by row.
+
+    Stream j masks to its OWN lengths, so slots at different sequence
+    positions share one launch. q_len == 1 is plain decode; q_len > 1
+    is a prefill-shaped window — the speculative-verify draft
     (models/spec_decode.py) or a chunked-prefill prompt chunk
-    (models/scheduler.py step_mixed): row s of the stream's q_len
-    query rows sits at kv_len - q_len + s and attends causally within
-    the window; padded rows clamp to the last valid row (outputs
-    discarded by the caller).
+    (models/scheduler.py step_mixed): row s of the stream's q_len query
+    rows sits at kv_len - q_len + s and attends causally within the
+    window; padded rows clamp to the last valid row (outputs discarded
+    by the caller).
 
-    quant=True (int8 pool — kv_cache.PagedSlotCache scale planes):
-    each stream also carries [1, page] f32 scale blocks resolved
-    through the SAME page-table index maps as its payload. Dequant
-    mirrors the contiguous kernel (_flash_decode_kernel) exactly: K's
-    per-position scale multiplies the logits column-wise, V's folds
-    into p before the PV contraction — the int8->bf16 convert happens
-    in VMEM, so KV HBM traffic is halved. Scale rows of never-written
-    positions are finite (pool-init zeros or stale real scales, never
-    NaN), so the length mask that zeroes their p entries needs no
-    extra guard.
+    The step runs ceil(longest of its W streams / (C*page)) blocks, and
+    every stream fetches C pages in each: past its last page a stream
+    fetches that page again, so the buffer always holds pages of its
+    own and the copies need no branch. A block (or a column) past a
+    stream's end masks to a bitwise no-op of its accumulator; a step
+    whose streams are all empty still walks one such block, so every
+    copy that is started is waited for.
+
+    quant=True (int8 pool — kv_cache.PagedSlotCache scale planes): a
+    page's [page] f32 scales are fetched beside its payload, through
+    the same table entry. Dequant mirrors the contiguous kernel
+    (_flash_decode_kernel) exactly: K's per-position scale multiplies
+    the logits column-wise, V's folds into p before the PV contraction
+    — the int8->bf16 convert happens in VMEM, so KV HBM traffic is
+    halved.
 
     partial=True (the SEQUENCE-PARALLEL serving walk — the split-KV
     partial of the inter-chip LSE combine, kernels/sp_flash_decode.py):
-    an extra [W, maxp] int32 ownership block rides after the lens —
-    stream j's logical tile t contributes ONLY when own[j, t] != 0
-    (this chip holds the physical page; the table handed in is the
-    LOCAL redirected one) — and the epilogue emits the UNNORMALIZED
-    accumulator plus the (m, l) softmax stats instead of the
-    normalized output. Tiles a chip does not own mask to a bitwise
-    no-op of its accumulator, so the n per-chip partials LSE-combine
-    to exactly the full softmax."""
-    q_ref = refs[0]
-    k_refs = refs[1:1 + W]
-    v_refs = refs[1 + W:1 + 2 * W]
-    rest = refs[1 + 2 * W:]
+    a table entry below zero is a tile another chip holds. It is not
+    fetched (its rows of the buffer keep an earlier block's pages, or
+    the first step's zeros), its positions are masked by the
+    per-position ownership operand, and the epilogue emits the
+    UNNORMALIZED accumulator plus the (m, l) softmax stats instead of
+    the normalized output. Tiles a chip does not own are a bitwise
+    no-op of its accumulator, so the n per-chip partials LSE-combine to
+    exactly the full softmax."""
+    q_ref, lens_ref, k_hbm, v_hbm = refs[:4]
+    rest = refs[4:]
+    pools = [k_hbm, v_hbm]
     if quant:
-        ks_refs = rest[:W]
-        vs_refs = rest[W:2 * W]
-        rest = rest[2 * W:]
-    else:
-        ks_refs = vs_refs = None
-    if per_stream:
-        lens_ref = rest[0]
-        rest = rest[1:]
-    else:
-        lens_ref = None
-    own_ref = None
+        pools += rest[:2]
+        rest = rest[2:]
     if partial:
-        own_ref = rest[0]
-        o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = rest[1:]
+        own_ref, o_ref, m_ref, l_ref = rest[:4]
+        rest = rest[4:]
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-    t = pl.program_id(1)
-    nt = pl.num_programs(1)
-    rows = q_ref.shape[1]
-    kv_len = len_ref[0]
-    q_off = len_ref[1]
-    start = t * page
+        o_ref = rest[0]
+        rest = rest[1:]
+    *bufs, sem = rest
+    kbuf, vbuf, *scale_bufs = bufs
+    x = pl.program_id(0)
+    X = pl.num_programs(0) * W
+    rows, d = q_ref.shape[1:]
+    C = _block_pages(page)
+    CP = C * page
 
-    @pl.when(t == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, -1e30)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    if partial:
+        # a page that is not fetched leaves its rows of the buffer as
+        # they were: an earlier block's, or these zeros, never a NaN
+        # that 0 * v would keep
+        @pl.when(x == 0)
+        def _zero():
+            for buf in bufs:
+                buf[...] = jnp.zeros(buf.shape, buf.dtype)
 
-    @pl.when(start < kv_len)
-    def _compute():
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, page), 0) // rep
-        col = jax.lax.broadcasted_iota(jnp.int32, (rows, page), 1) + start
-        if not per_stream:
-            mask = (col <= (row + q_off)) & (col < kv_len)
-        if partial:
-            # this grid step's ownership column of the [W, maxp] block
-            # (iota-compare-select instead of a dynamic scalar index —
-            # the same generic-interpreter constraint the lens operand
-            # documents)
-            own_all = own_ref[...]                       # [W, maxp]
-            tcol = jax.lax.broadcasted_iota(
-                jnp.int32, own_all.shape, 1)
-            own_t = jnp.sum(
-                jnp.where(tcol == t, own_all, 0), axis=1)  # [W]
+    n_pages = [(s_ref[x * W + j] + (page - 1)) // page for j in range(W)]
+    # at least one block, so that the copies started below are waited
+    # for: a step of empty streams (kv length 0: a parked or
+    # budget-starved slot) walks one block, all of it masked. A branch
+    # round the first block's copies would do, and reads 29 s more
+    # set-up per served program on the chip (PERF.md, PR 30)
+    nblk = functools.reduce(
+        jnp.maximum, [(n + (C - 1)) // C for n in n_pages] + [1])
+    # each stream's last page and its row of the table, in s_ref
+    last = [jnp.maximum(n - 1, 0) for n in n_pages]
+    row0 = [X + (x * W + j) * maxp for j in range(W)]
+
+    def for_block(i, half, act):
+        """act(copy) for the C pages of block i of each of the step's
+        streams. Branch-free on the served path: past its last page a
+        stream fetches that page again (the mask drops it), which the
+        chip takes better than a loop over the pages it really has."""
         for j in range(W):
-            if per_stream:
-                # row s's causal frontier within stream j's draft
-                # window; q_len == 1 degenerates to col < kv_len
-                kvl = lens_ref[j, 0]
-                ql = lens_ref[j, 1]
-                mask = col <= (kvl - ql + jnp.minimum(row, ql - 1))
-            if partial:
-                # non-owned tile: bitwise no-op of stream j's
-                # accumulator (the combine supplies the other chips')
-                mask = mask & (own_t[j] != 0)
-            q = q_ref[pl.ds(j, 1)]                       # [1, rows, d]
-            kj = k_refs[j][...]
-            if quant:
-                kj = kj.astype(q.dtype)
-            s = jax.lax.dot_general(
-                q, kj, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32
-                ) * scale                                # [1, rows, page]
-            if quant:
-                # K's per-position scale multiplies the logits
-                # column-wise (exact: (q . k_int8) * s == q . k_deq)
-                s = s * ks_refs[j][...][:, None, :]
-            m_prev = m_scr[pl.ds(j, 1)]
-            m_new = jnp.maximum(
-                m_prev, jnp.max(jnp.where(mask[None], s, -1e30), -1))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.where(mask[None], jnp.exp(s - m_new[..., None]), 0.0)
-            l_scr[pl.ds(j, 1)] = (l_scr[pl.ds(j, 1)] * alpha
-                                  + jnp.sum(p, -1))
-            vj = v_refs[j][...]
-            if quant:
-                # V's scale folds into p (diag(sv) V == V rows scaled);
-                # the convert to the compute dtype happens in VMEM
-                vj = vj.astype(q.dtype)
-                p = p * vs_refs[j][...][:, None, :]
-            pv = jax.lax.dot_general(
-                p.astype(vj.dtype), vj,
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-            acc_scr[pl.ds(j, 1)] = (acc_scr[pl.ds(j, 1)]
-                                    * alpha[..., None] + pv)
-            m_scr[pl.ds(j, 1)] = m_new
+            for c in range(C):
+                pid = s_ref[row0[j] + jnp.minimum(i * C + c, last[j])]
+                at = pl.ds(c * page, page)
 
-    @pl.when(t == nt - 1)
-    def _done():
+                def copies():
+                    for n, (pool, buf) in enumerate(zip(pools, bufs)):
+                        dst = (buf.at[half, j, at] if n < 2
+                               else buf.at[half, j, 0, at])
+                        act(pltpu.make_async_copy(pool.at[pid], dst,
+                                                  sem.at[half]))
+                if partial:
+                    pl.when(pid >= 0)(copies)
+                else:
+                    copies()
+
+    def start(i, half):
+        for_block(i, half, lambda cp: cp.start())
+
+    def wait(i, half):
         if partial:
-            # the SP partial contract: unnormalized accumulator +
-            # softmax stats, combined across chips by lse_combine
-            # (kernels/flash_attn.py) / sp_combine_partials
-            o_ref[...] = acc_scr[...].astype(o_ref.dtype)
-            m_ref[...] = m_scr[...]
-            l_ref[...] = l_scr[...]
+            # as many waits as copies were started
+            for_block(i, half, lambda cp: cp.wait())
         else:
-            o_ref[...] = (acc_scr[...]
-                          / jnp.maximum(l_scr[...], 1e-30)[..., None]
-                          ).astype(o_ref.dtype)
+            # every copy of the block signals one semaphore by its
+            # bytes: one wait per plane, for the whole half's
+            for buf in bufs:
+                pltpu.make_async_copy(buf.at[half], buf.at[half],
+                                      sem.at[half]).wait()
+
+    start(0, 0)
+
+    q = q_ref[...]                                   # [W, rows, d]
+    lens = lens_ref[...]                             # [W, 1, 2]
+    kvl, ql = lens[:, :, 0:1], lens[:, :, 1:2]
+    # row s's causal frontier within its stream's query window;
+    # q_len == 1 degenerates to col < kv_len
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1) // rep
+    frontier = kvl - ql + jnp.minimum(row, ql - 1)   # [W, rows, 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, CP), 2)
+
+    def block(i, carry):
+        half = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < nblk)
+        def _ahead():
+            start(i + 1, 1 - half)
+
+        wait(i, half)
+        m, l, acc = carry
+        pos = i * CP
+        mask = (pos + lane) <= frontier              # [W, rows, CP]
+        if partial:
+            mask = mask & (own_ref[
+                :, :, pl.ds(pl.multiple_of(pos, CP), CP)] != 0)
+        k = kbuf[half]                               # [W, CP, d]
+        if quant:
+            k = k.astype(q.dtype)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale
+        if quant:
+            # K's per-position scale multiplies the logits column-wise
+            # (exact: (q . k_int8) * s == q . k_deq)
+            s = s * scale_bufs[0][half]
+        m_new = jnp.maximum(
+            m, jnp.max(jnp.where(mask, s, -1e30), -1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l = l * alpha + jnp.sum(p, -1, keepdims=True)
+        v = vbuf[half]
+        if quant:
+            # V's scale folds into p (diag(sv) V == V rows scaled); the
+            # convert to the compute dtype happens in VMEM
+            v = v.astype(q.dtype)
+            p = p * scale_bufs[1][half]
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m, l, acc = jax.lax.fori_loop(
+        0, nblk, block,
+        (jnp.full((W, rows, 1), -1e30, jnp.float32),
+         jnp.zeros((W, rows, 1), jnp.float32),
+         jnp.zeros((W, rows, d), jnp.float32)))
+    if partial:
+        # the SP partial contract: unnormalized accumulator + softmax
+        # stats, combined across chips by lse_combine
+        # (kernels/flash_attn.py) / sp_combine_partials
+        o_ref[...] = acc
+        m_ref[...] = m
+        l_ref[...] = l
+    else:
+        o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def flash_decode_paged(q, pages_k, pages_v, page_table, kv_len, *,
@@ -204,8 +264,9 @@ def flash_decode_paged(q, pages_k, pages_v, page_table, kv_len, *,
 
     q: [B, S, Hq, d] (S == 1 unless q_lens is given); pages_k/v:
     [NP, page, d]; page_table: [B*Hkv, max_pages] int32 (physical page
-    of each logical tile; rows beyond ceil(kv_len/page) may hold
-    anything); kv_len: traced scalar — valid positions INCLUDING the
+    of each logical tile; entries beyond ceil(kv_len/page) may hold
+    anything, but column 0, which an empty stream still fetches, names
+    a page of the pool); kv_len: traced scalar — valid positions INCLUDING the
     current query. Returns [B, S, Hq, d].
 
     k_scale/v_scale: per-position dequant scale planes [NP, page] f32
@@ -220,11 +281,9 @@ def flash_decode_paged(q, pages_k, pages_v, page_table, kv_len, *,
     kv_lens: optional per-BATCH-ROW lengths [B] int32 (continuous
     batching: each slot is a different request at a different sequence
     position). Row b attends exactly kv_lens[b] positions of its own
-    streams; kv_len is recomputed as their max (the walk bound). Each
-    stream's index map clamps to ITS OWN last valid page, so the tail
-    of a short slot's walk re-requests one block and its DMAs are
-    elided — a mixed-length batch pays max_len grid steps but only
-    sum(len_b) page traffic.
+    streams (kv_len is then unused). The walk of a grid step is as
+    long as the longest of ITS streams and no longer, and never reads
+    a table column past a stream's last page.
 
     q_lens: optional per-BATCH-ROW query-window lengths [B] int32
     (requires kv_lens): slot b's first q_lens[b] of the S query rows
@@ -255,13 +314,12 @@ def flash_decode_paged_partial(q, pages_k, pages_v, page_table, *,
     q_lens=..), with two changes for the sp-sharded pool
     (kv_cache.PagedSlotCache SP SHARDING):
 
-    - pages_k/v are THIS CHIP'S local pool shard and page_table is the
-      LOCAL redirected table (non-owned tiles point at some in-range
-      local page — layers/tp_attn.py redirects them to the last owned
-      page so the surplus DMAs elide);
+    - pages_k/v are THIS CHIP'S local pool shard and page_table holds
+      LOCAL page ids (a non-owned tile's entry may be anything: it is
+      never read);
     - tile_owned [B*Hkv, maxp] int32 marks which logical tiles this
-      chip owns: non-owned tiles are a bitwise no-op of the stream's
-      accumulator, so the returned (acc [B, S, Hq, d] f32 unnormalized,
+      chip owns: a non-owned tile is not fetched at all and is a
+      bitwise no-op of the stream's accumulator, so the returned (acc [B, S, Hq, d] f32 unnormalized,
       m [B, S, Hq], l [B, S, Hq]) LSE-combine across chips
       (sp_flash_decode.sp_combine_partials / flash_attn.lse_combine)
       to exactly the full-pool softmax. A stream none of whose tiles
@@ -275,6 +333,38 @@ def flash_decode_paged_partial(q, pages_k, pages_v, page_table, *,
         tune_name="flash_decode_paged_partial")
 
 
+def _stream_block(tune_name, dims, X, block_w):
+    """W: streams per grid step. Resolution: explicit block_w >
+    contextual profile > tune cache (tools/sweep) > the largest divisor
+    of X in (8, 4, 2, 1). W only regroups streams across grid steps and
+    never changes a stream's result. Strictness splits by provenance:
+    an indivisible W that was pinned explicitly or installed in the
+    contextual profile is a loud error (the sweep pruner probes configs
+    through the profile and relies on this trace failing), while a
+    DISK-cache winner is a hint from whatever shape it was swept at
+    (bucket fallback, another GQA ratio) and re-clamps to the default
+    instead of failing at serving time — the tuned_choice contract:
+    perf may degrade, never correctness. The two-step lookup below
+    mirrors sweep.resolve_config's precedence, split so provenance is
+    known."""
+    from triton_dist_tpu.tools.tune import contextual_choice
+    cfg = contextual_choice(tune_name)
+    strict = cfg is not None or block_w is not None
+    if cfg is None:
+        from triton_dist_tpu.tools.sweep import tuned_choice
+        cfg = tuned_choice(tune_name, dims) or {}
+    W = cfg.get("block_w") if block_w is None else block_w
+    if W is not None and X % W:
+        if strict:
+            raise ValueError(
+                f"{tune_name}: block_w={W} does not divide the "
+                f"stream count X={X} (B*Hkv)")
+        W = None
+    if W is None:
+        W = next(w for w in (8, 4, 2, 1) if X % w == 0)
+    return int(W)
+
+
 def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
                              scale, kv_lens, q_lens, k_scale, v_scale,
                              tile_owned, block_w=None,
@@ -285,10 +375,6 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
         assert kv_lens is not None, "q_lens rides on per-slot kv_lens"
     elif not partial:
         assert S == 1, "paged walk without q_lens is decode (S == 1)"
-    # the partial (sp) walk is per-stream by construction: the kernel
-    # rebinds the mask per stream only on the per_stream path, so a
-    # partial call without kv_lens would compound ownership bits
-    # across the W streams of a grid step
     assert not partial or kv_lens is not None, \
         "flash_decode_paged_partial requires per-slot kv_lens"
     quant = k_scale is not None
@@ -304,128 +390,73 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
     qx = (q.reshape(B, S, Hkv, rep, d)
            .transpose(0, 2, 1, 3, 4)
            .reshape(X, rows, d))
-    # W streams per grid step (see module docstring). Resolution:
-    # explicit block_w > contextual profile > tune cache (tools/sweep)
-    # > the largest divisor of X in (8, 4, 2, 1). W only regroups
-    # streams across grid steps — per-stream accumulators are
-    # untouched, so any legal W is bitwise-identical. Strictness splits
-    # by provenance: an indivisible block_w that was pinned explicitly
-    # or installed in the contextual profile is a loud error (the sweep
-    # pruner probes configs through the profile and relies on this
-    # trace failing), while a DISK-cache winner is a hint from whatever
-    # shape it was swept at (bucket fallback, another GQA ratio) and
-    # re-clamps to the divisor ladder instead of failing at serving
-    # time — the tuned_choice contract: perf may degrade, never
-    # correctness. The two-step lookup below mirrors
-    # sweep.resolve_config's precedence, split so provenance is known.
-    strict_w = block_w is not None
-    if block_w is None:
-        from triton_dist_tpu.tools.tune import contextual_choice
-        prof = contextual_choice(tune_name)
-        if prof is not None:
-            block_w = prof.get("block_w")
-            strict_w = block_w is not None
-        else:
-            from triton_dist_tpu.tools.sweep import tuned_choice
-            block_w = (tuned_choice(tune_name, (X, B * Hq, NP * page))
-                       or {}).get("block_w")
-    if block_w is not None and X % block_w:
-        if strict_w:
-            raise ValueError(
-                f"{tune_name}: block_w={block_w} does not divide the "
-                f"stream count X={X} (B*Hkv)")
-        block_w = None
-    if block_w is not None:
-        W = int(block_w)
-    else:
-        W = next(w for w in (8, 4, 2, 1) if X % w == 0)
-    per_stream = kv_lens is not None
-    if per_stream:
-        lens_x = jnp.repeat(jnp.asarray(kv_lens, jnp.int32), Hkv)  # [X]
-        kv_len = jnp.max(lens_x)
-        qlens_x = (jnp.ones_like(lens_x) if q_lens is None
-                   else jnp.repeat(jnp.asarray(q_lens, jnp.int32), Hkv))
-    # scalars: [kv_len, q_off, lens..., table...]; the kv index map
-    # resolves the logical tile through the table (clamped to the last
-    # valid tile so the tail is elided like the contiguous walk). The
-    # per-stream lens appear TWICE on purpose: in the scalars for the
-    # index-map clamp, and as a [X, 1] operand for the in-kernel mask
-    # (kernel bodies avoid dynamic scalar-table indexing, which the
-    # generic interpreter of older jax cannot evaluate).
-    n_lens = X if per_stream else 0
-    scalars = jnp.concatenate(
-        ([jnp.asarray([kv_len, kv_len - 1], jnp.int32)]
-         + ([lens_x] if per_stream else [])
-         + [page_table.reshape(-1).astype(jnp.int32)]))
-
-    def page_of(j, x, t, s_ref):
-        """Physical page of stream x*W+j's logical tile t, clamped to
-        the stream's own last valid tile (shared by the payload and
-        scale index maps — a page's scales always travel with it)."""
-        own = (s_ref[2 + x * W + j] if per_stream else s_ref[0])
-        last = jnp.maximum((own + page - 1) // page - 1, 0)
-        return s_ref[2 + n_lens + (x * W + j) * maxp
-                     + jnp.minimum(t, last)]
-
-    def kv_map_j(j):
-        def kv_map(x, t, s_ref):
-            return page_of(j, x, t, s_ref), 0, 0
-        return kv_map
-
-    def sc_map_j(j):
-        def sc_map(x, t, s_ref):
-            return page_of(j, x, t, s_ref), 0
-        return sc_map
-
-    def q_map(x, t, s_ref):
-        return (x, 0, 0)
-
-    def lens_map(x, t, s_ref):
-        return (x, 0)
-
-    def own_map(x, t, s_ref):
-        return (x, 0)
-
-    kv_specs = [pl.BlockSpec((1, page, d), kv_map_j(j)) for j in range(W)]
-    sc_specs = ([pl.BlockSpec((1, page), sc_map_j(j)) for j in range(W)]
-                if quant else [])
-    in_specs = ([pl.BlockSpec((W, rows, d), q_map)] + kv_specs + kv_specs
-                + sc_specs + sc_specs
-                + ([pl.BlockSpec((W, 2), lens_map)] if per_stream else [])
-                + ([pl.BlockSpec((W, maxp), own_map)] if partial else []))
-    args = ([qx] + [pages_k] * W + [pages_v] * W
-            + ([k_scale] * W + [v_scale] * W if quant else [])
-            + ([jnp.stack([lens_x, qlens_x], axis=1)]
-               if per_stream else [])
-            + ([jnp.asarray(tile_owned, jnp.int32)] if partial else []))
+    W = _stream_block(tune_name, (X, B * Hq, NP * page), X, block_w)
+    CP = _block_pages(page) * page
+    # every stream carries its own (kv length, query length): a launch
+    # without kv_lens is all streams at kv_len, one query row each
+    lens_x = (jnp.repeat(jnp.asarray(kv_lens, jnp.int32), Hkv)
+              if kv_lens is not None
+              else jnp.full((X,), kv_len, jnp.int32))
+    qlens_x = (jnp.ones_like(lens_x) if q_lens is None
+               else jnp.repeat(jnp.asarray(q_lens, jnp.int32), Hkv))
+    table = page_table.astype(jnp.int32)
     if partial:
-        out_specs = (pl.BlockSpec((W, rows, d), q_map),
-                     pl.BlockSpec((W, rows), lens_map),
-                     pl.BlockSpec((W, rows), lens_map))
+        # a tile another chip holds is a table entry below zero: the
+        # walk skips its copy outright
+        owned = jnp.asarray(tile_owned, jnp.int32) != 0
+        table = jnp.where(owned, table, -1)
+    # scalars: [lens..., table...]. The lens appear TWICE on purpose:
+    # here for the walk's bounds, and beside the query lengths as a
+    # [X, 1, 2] operand for the in-kernel mask (a vector per stream,
+    # which scalars cannot be broadcast into cheaply).
+    scalars = jnp.concatenate([lens_x, table.reshape(-1)])
+
+    def per_step(*tail):
+        return pl.BlockSpec((W,) + tail, lambda x, s_ref: (x, 0, 0))
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [per_step(rows, d), per_step(1, 2), hbm, hbm]
+    args = [qx, jnp.stack([lens_x, qlens_x], 1).reshape(X, 1, 2),
+            pages_k, pages_v]
+    scratch = [pltpu.VMEM((2, W, CP, d), pages_k.dtype),
+               pltpu.VMEM((2, W, CP, d), pages_v.dtype)]
+    if quant:
+        in_specs += [hbm, hbm]
+        args += [k_scale, v_scale]
+        scratch += [pltpu.VMEM((2, W, 1, CP), k_scale.dtype),
+                    pltpu.VMEM((2, W, 1, CP), v_scale.dtype)]
+    if partial:
+        # ownership per POSITION, out to a whole number of blocks
+        L = -(-maxp * page // CP) * CP
+        own = jnp.repeat(owned.astype(jnp.int32), page, axis=1)
+        own = jnp.pad(own, ((0, 0), (0, L - maxp * page)))
+        in_specs.append(per_step(1, L))
+        args.append(own.reshape(X, 1, L))
+        out_specs = (per_step(rows, d), per_step(rows, 1),
+                     per_step(rows, 1))
         out_shape = (jax.ShapeDtypeStruct((X, rows, d), jnp.float32),
-                     jax.ShapeDtypeStruct((X, rows), jnp.float32),
-                     jax.ShapeDtypeStruct((X, rows), jnp.float32))
+                     jax.ShapeDtypeStruct((X, rows, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((X, rows, 1), jnp.float32))
     else:
-        out_specs = pl.BlockSpec((W, rows, d), q_map)
+        out_specs = per_step(rows, d)
         out_shape = jax.ShapeDtypeStruct((X, rows, d), q.dtype)
     out = pl.pallas_call(
         functools.partial(_paged_kernel, float(scale), rep, page, W,
-                          per_stream, quant, partial),
+                          maxp, quant, partial),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(X // W, maxp),
+            grid=(X // W,),
             in_specs=in_specs,
             out_specs=out_specs,
-            scratch_shapes=[
-                pltpu.VMEM((W, rows), jnp.float32),
-                pltpu.VMEM((W, rows), jnp.float32),
-                pltpu.VMEM((W, rows, d), jnp.float32),
-            ],
+            scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))],
         ),
         out_shape=out_shape,
+        # the steps are independent, but for the partial walk, whose
+        # first step zeroes the block buffers for every later one
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary" if partial
+                                 else "parallel",)),
         interpret=interpret_mode(),
-        # the W k (v) operands are the SAME pool array — one buffer,
-        # W per-stream index maps
     )(scalars, *args)
 
     def unfold(a):
@@ -436,7 +467,7 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
 
     if partial:
         acc, m, l = out
-        return unfold(acc), unfold(m), unfold(l)
+        return unfold(acc), unfold(m[..., 0]), unfold(l[..., 0])
     return unfold(out)
 
 
@@ -553,7 +584,7 @@ class PagedKVCache:
         """Install allocator-assigned table rows for a slot:
         rows [Hkv, <=max_pages] int32 physical page ids (shorter rows
         pad with their own last entry — never attended past the slot's
-        length, but the index map must stay in range)."""
+        length, but every entry must be a page of the pool)."""
         Hkv, npg = rows.shape
         X, maxp = self.table.shape
         rows = jnp.asarray(rows, jnp.int32)
