@@ -5,7 +5,8 @@ No chip is attached and nothing runs: these catch what interpret mode
 cannot — a slice not aligned to the tiling, a kernel over the VMEM
 limit, a shape Mosaic refuses — at no chip time. Shapes are the ones
 `chip_smoke.py` drives (B=8, Hq=16, Hkv=8, d=128, max_seq 512, page 16,
-FFN 6144, bf16).
+FFN 6144, bf16). The `phi4_` cases are Phi-4-mini-flash's new kernels and
+walks at its published widths (models/phi4flash.py).
 
 The topology is described inside a module-scoped fixture and nowhere at
 import: the TPU library admits one process at a time, xdist workers all
@@ -129,8 +130,76 @@ def _paged_cell(hkv, b=32, max_seq=2048, s=1):
             ((b * hkv, maxp), I32), ((b,), I32)] + [((b,), I32)] * (s > 1)
 
 
+# --- Phi-4-mini-flash at its published widths (paired heads: 40 padded
+# queries of 128 over 10 pooled KV heads, scale 1/8; d_inner 5120,
+# d_state 16; window 512; page 16; the cell's batch 64, max_seq 4096)
+P4_B, P4_HQ, P4_HP, P4_E, P4_N, P4_W, P4_SEQ = 64, 40, 10, 5120, 16, 512, 4096
+F32 = jnp.float32
+
+
+def _ssm_scan(x, dt, bm, cm, a, d, s0, n):
+    from triton_dist_tpu.kernels.ssm import selective_scan
+    return selective_scan(x, dt, bm, cm, a, d, s0, n)
+
+
+def _ssm_step(x, dt, bm, cm, a, d, s, keep):
+    from triton_dist_tpu.kernels.ssm import ssm_step
+    return ssm_step(x, dt, bm, cm, a, d, s, keep)
+
+
+def _window_prefill(q, k, v):
+    from triton_dist_tpu.kernels.flash_attn import flash_decode
+    return flash_decode(q, k, v, jnp.int32(k.shape[2]), scale=0.125,
+                        window=P4_W)
+
+
+def _ring_decode(q, k, v, kv_lens):
+    from triton_dist_tpu.kernels.flash_attn import flash_decode
+    return flash_decode(q, k, v, jnp.max(kv_lens), scale=0.125,
+                        kv_lens=kv_lens)
+
+
+def _paired_paged_decode(q, pk, pv, table, kv_lens):
+    from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
+    return flash_decode_paged(q, pk, pv, table, jnp.max(kv_lens),
+                              scale=0.125, kv_lens=kv_lens)
+
+
+_P4_POOL = ((P4_B * P4_HP * (P4_SEQ // PAGE) + 1, PAGE, D), BF16)
+
+
 # name -> (function, [(shape, dtype), ...]); () is a traced scalar
 CASES = {
+    # the admission's chunked scan over a 2,048-token prompt, and the
+    # decode's one-token update of 64 slots' states
+    "phi4_ssm_scan_s2048": (
+        _ssm_scan, [((2048, P4_E), F32), ((2048, P4_E), F32),
+                    ((2048, P4_N), F32), ((2048, P4_N), F32),
+                    ((P4_N, P4_E), F32), ((P4_E,), F32),
+                    ((P4_N, P4_E), F32), ((), I32)]),
+    "phi4_ssm_step_b64": (
+        _ssm_step, [((P4_B, P4_E), F32), ((P4_B, P4_E), F32),
+                    ((P4_B, P4_N), F32), ((P4_B, P4_N), F32),
+                    ((P4_N, P4_E), F32), ((P4_E,), F32),
+                    ((P4_B, P4_N, P4_E), F32), ((P4_B,), jnp.bool_)]),
+    # window attention of a prefill: 256 query rows over the 768 keys
+    # they can see, the window mask in the kernel
+    "phi4_window_prefill_q256": (
+        _window_prefill, [((1, 256, P4_HQ, D), BF16),
+                          ((1, P4_HP, 768, D), BF16),
+                          ((1, P4_HP, 768, D), BF16)]),
+    # the window walk of decode: every slot's ring of 512 rows
+    "phi4_ring_decode_b64": (
+        _ring_decode, [((P4_B, 1, P4_HQ, D), BF16),
+                       ((P4_B, P4_HP, P4_W, D), BF16),
+                       ((P4_B, P4_HP, P4_W, D), BF16), ((P4_B,), I32)]),
+    # the full and cross layers' walk of layer 17's pool: 640 streams
+    # of 4 padded query rows, 256 table columns
+    "phi4_paged_decode_b64": (
+        _paired_paged_decode, [((P4_B, 1, P4_HQ, D), BF16), _P4_POOL,
+                               _P4_POOL,
+                               ((P4_B * P4_HP, P4_SEQ // PAGE), I32),
+                               ((P4_B,), I32)]),
     # Engine.prefill: B=8 prompts of 128 into the contiguous cache
     "flash_prefill_b8_s128": (
         _flash_decode, [_q(B, 128), _kv(B, T), _kv(B, T), ((), I32)]),

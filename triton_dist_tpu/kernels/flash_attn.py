@@ -34,7 +34,8 @@ from triton_dist_tpu.runtime import interpret_mode
 
 def _flash_decode_kernel(scale: float, rep: int, S: int, T: int,
                          partial: bool, quant: bool, per_stream: bool,
-                         len_ref, q_ref, k_ref, v_ref, *rest):
+                         len_ref, q_ref, k_ref, v_ref, *rest,
+                         window: int = 0):
     """Grid (X/bx, T/bt); X = B*Hkv. Online softmax over KV tiles.
 
     partial=False: rest = (o_ref, m_scr, l_scr, acc_scr); writes the
@@ -65,7 +66,13 @@ def _flash_decode_kernel(scale: float, rep: int, S: int, T: int,
     a stream's length are masked to a BITWISE no-op of the accumulator
     update (alpha == 1, p == 0), so a short slot's output is exactly
     what a uniform-length launch at its length produces; the grid/DMA
-    walk still runs to max_len (len_ref[0])."""
+    walk still runs to max_len (len_ref[0]).
+
+    window > 0 (sliding-window attention, uniform lengths only): a query
+    at position p sees the `window` keys p - window + 1 .. p. Only the
+    mask changes; a caller that wants the tiles before the window
+    skipped hands in K/V already cut to the span its queries can see
+    (models/phi4flash.py does, a query block at a time)."""
     if quant:
         ks_ref, vs_ref, *rest = rest
     else:
@@ -121,8 +128,10 @@ def _flash_decode_kernel(scale: float, rep: int, S: int, T: int,
             # col < T guards the last block's padding when a caller
             # shifts the causal frontier past the buffer (kv_len > T,
             # e.g. the non-causal mode of sp_ring_attention)
-            mask = ((col <= (row + q_off))
-                    & (col < jnp.minimum(kv_len, T)))[None]
+            mask = (col <= (row + q_off)) & (col < jnp.minimum(kv_len, T))
+            if window:
+                mask = mask & (col > (row + q_off) - window)
+            mask = mask[None]
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev,
                             jnp.max(jnp.where(mask, s, -1e30), -1))
@@ -201,7 +210,8 @@ def _pick_bx(X: int, rows: int, d: int, bt: int, itemsize: int,
 def flash_decode(q, k, v, kv_len, *, scale: Optional[float] = None,
                  block_x: Optional[int] = None,
                  block_t: Optional[int] = None,
-                 k_scale=None, v_scale=None, kv_lens=None, q_lens=None):
+                 k_scale=None, v_scale=None, kv_lens=None, q_lens=None,
+                 window: int = 0):
     """Cached GQA attention (decode and prefill-into-cache).
 
     q: [B, S, Hq, d]; k, v: [B, Hkv, T, d] (T = static cache capacity);
@@ -232,10 +242,16 @@ def flash_decode(q, k, v, kv_len, *, scale: Optional[float] = None,
     the caller drops). Without q_lens, S must be 1 (plain per-slot
     decode).
 
+    window: sliding-window attention — query s sees only the `window`
+    keys ending at its own position (0 = full causal). Uniform lengths
+    only (no kv_lens).
+
     Reference: flash_decode.py:130 (split-KV GQA kernel) + :308
     (combine); here split-KV partial results live in VMEM scratch and
     combine is the online-softmax update, so nothing round-trips HBM.
     """
+    assert not (window and kv_lens is not None), \
+        "the window mask rides the uniform-length path"
     B, S, Hq, d = q.shape
     _, Hkv, T, _ = k.shape
     rep = Hq // Hkv
@@ -277,7 +293,8 @@ def flash_decode(q, k, v, kv_len, *, scale: Optional[float] = None,
         lens_x = jnp.stack([kv_x, q_x], axis=1)          # [X, 2]
     out = _flash_call(qx, kx, vx, kv_len, kv_len - S, scale=float(scale),
                       rep=rep, S=S, T=T, partial=False, block_x=block_x,
-                      block_t=block_t, ks=ks, vs=vs, lens=lens_x)
+                      block_t=block_t, ks=ks, vs=vs, lens=lens_x,
+                      window=window)
     return (out.reshape(B, Hkv, S, rep, d)
                .transpose(0, 2, 1, 3, 4)
                .reshape(B, S, Hq, d))
@@ -345,7 +362,7 @@ def lse_combine(accs, ms, ls, dtype=None):
 
 def _flash_call(qx, kx, vx, kv_len, q_off, *, scale: float, rep: int,
                 S: int, T: int, partial: bool, block_x: int, block_t: int,
-                ks=None, vs=None, lens=None):
+                ks=None, vs=None, lens=None, window: int = 0):
     X, rows, d = qx.shape
     quant = ks is not None
     bt = min(block_t, T)
@@ -353,7 +370,9 @@ def _flash_call(qx, kx, vx, kv_len, q_off, *, scale: float, rep: int,
                   kv_itemsize=jnp.dtype(kx.dtype).itemsize,
                   partial=partial)
     kernel = functools.partial(_flash_decode_kernel, scale, rep, S, T,
-                               partial, quant, lens is not None)
+                               partial, quant, lens is not None,
+                               **({"window": int(window)} if window
+                                  else {}))
 
     # KV-tile index map clamps t to the last block containing valid keys:
     # grid steps past kv_len re-request the same block, and the Pallas
@@ -462,7 +481,7 @@ def kv_update(cache, new, tile_pos):
 
 
 def attention_cached_ref(q, k, v, kv_len, *, scale: Optional[float] = None,
-                         q_lens=None):
+                         q_lens=None, window: int = 0):
     """jnp oracle for flash_decode (same layout/contract): masked f32
     softmax over the full static T — the role the torch attention plays
     for the reference's differential tests. kv_len may be a scalar
@@ -490,6 +509,8 @@ def attention_cached_ref(q, k, v, kv_len, *, scale: Optional[float] = None,
         mask = ti[None] <= frontier
     elif kv_len.ndim == 0:
         mask = (ti <= (si + (kv_len - S)))[None]              # [1, S, T]
+        if window:
+            mask = mask & (ti > (si + (kv_len - S)) - window)[None]
     else:
         mask = ti[None] <= (si[None] + (kv_len[:, None, None] - S))
     logits = jnp.where(mask[:, None, :, None], logits, -jnp.inf)

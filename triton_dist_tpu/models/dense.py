@@ -425,3 +425,11 @@ class DenseLLM:
                               cfg.num_kv_heads, cfg.head_dim,
                               mesh=self.mesh, axis=self.axis,
                               dtype=dtype or cfg.jax_dtype)
+
+    def serving_traits(self):
+        from triton_dist_tpu.models.utils import attn_stack_traits
+        return attn_stack_traits(self)
+
+    def make_paged_cache(self, batch: int, max_seq: int, **kw):
+        from triton_dist_tpu.models.kv_cache import uniform_paged_cache
+        return uniform_paged_cache(self, batch, max_seq, **kw)

@@ -259,7 +259,7 @@ class PrefillWorker:
         self.cache = engine.make_paged_slot_cache(1, page=page,
                                                   num_pages=num_pages,
                                                   for_ticks=False)
-        Hkv = engine.model.config.num_kv_heads
+        Hkv = engine.traits.kv_heads
         self.hkv = Hkv
         self.pool = RefcountedPages(self.cache.num_pages, Hkv)
         assert self.pool.trash == self.cache.trash
@@ -375,6 +375,9 @@ class DisaggScheduler(ContinuousScheduler):
         if prefill_workers < 1:
             raise ValueError(f"prefill_workers must be >= 1, got "
                              f"{prefill_workers}")
+        engine.refuse_slot_state(
+            "disaggregated serving",
+            "state handoff: the prefill plane ships KV pages only")
         super().__init__(engine, batch=batch, chunk=chunk, paged=True,
                          prefix_cache=prefix_cache, page=page,
                          num_pages=num_pages, spec=spec, drafter=drafter,

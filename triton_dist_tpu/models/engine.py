@@ -73,6 +73,24 @@ class Engine:
             raise ValueError(f"unknown backend {backend!r}; this engine "
                              f"serves {known}")
         self.moe_family = bool(getattr(model.config, "is_moe", False))
+        # what the model says of itself (models/utils.ServingTraits):
+        # the ONE place the engine learns the pool's heads, the
+        # projections' form and whether slots hold state beside pages
+        self.traits = model.serving_traits()
+        # a slot of such a model is pages PLUS state that pages cannot
+        # express: what moves or rebuilds pages alone is refused, here
+        # and wherever an option is taken, through refuse_slot_state
+        if backend not in ("flash", "xla"):
+            self.refuse_slot_state(
+                f"backend={backend!r}",
+                ("megakernel tick" if backend == "mega"
+                 else "TP comm-kernel projections")
+                + f" over {self.traits.slot_state}; its layers run "
+                  f"single-chip on 'flash' or the 'xla' oracle")
+        if kv_dtype is not None:
+            self.refuse_slot_state(
+                f"kv_dtype={jnp.dtype(kv_dtype)}",
+                f"int8 pool beside {self.traits.slot_state}")
         # SEQUENCE-PARALLEL serving (long-context — the sp-sharded
         # paged pool, kv_cache.PagedSlotCache SP SHARDING): capability
         # gates live HERE, at construction, naming what is missing —
@@ -180,9 +198,7 @@ class Engine:
                     "routed-expert FFN) — MoE models serve their "
                     "grouped-GEMM tick on backend='flash' (TP-MoE) or "
                     "'ep'/'ep_flash' (expert-sharded)")
-            from triton_dist_tpu.kernels.quant import QuantW
-            if model.layers and isinstance(model.layers[0].attn.w_qkv,
-                                           QuantW):
+            if self.traits.int8_weights:
                 raise ValueError(
                     "backend='mega' repacks raw bf16 weight panels and "
                     "has no WEIGHT dequant path; int8-weight models run "
@@ -196,7 +212,7 @@ class Engine:
             n_mega = model.mesh.shape[model.mesh.axis_names[0]]
             if n_mega > 1 and (
                     model.config.num_heads % n_mega
-                    or model.config.num_kv_heads % n_mega
+                    or self.traits.kv_heads % n_mega
                     or model.config.intermediate_size % n_mega):
                 raise ValueError(
                     "backend='mega' TP needs heads/kv-heads/ffn "
@@ -280,6 +296,7 @@ class Engine:
         self._paged_slot_scan = progs["paged_slot_scan"]
         self._paged_admit = progs["paged_admit"]
         self._paged_set_table = progs["paged_set_table"]
+        self._state_admit = progs["state_admit"]
         self._paged_scratch = None
         if sampling != "greedy":
             self._spec_seed = progs["spec_seed"]
@@ -299,8 +316,27 @@ class Engine:
                 "engine_mega_dispatches", "fused paged mega decode "
                                           "ticks")
 
+    def refuse_slot_state(self, option: str, capability: str) -> None:
+        """Refuse `option` for a model whose slots hold state beside
+        their pages (ServingTraits.slot_state): the option moves,
+        shares or rebuilds a slot from pages alone, and `capability`
+        names what would have to exist for it. A no-op for a model
+        whose whole context is its pages."""
+        if self.traits.slot_state:
+            raise ValueError(
+                f"{option}: {type(self.model).__name__} slots hold "
+                f"{self.traits.slot_state} beside their pages (missing "
+                f"capability: {capability})")
+
+    def _contiguous_only(self, what: str) -> None:
+        self.refuse_slot_state(
+            what, f"contiguous cache over {self.traits.slot_state}; "
+                  f"serve with ContinuousScheduler(paged=True) / "
+                  f"TokenServer(paged=True)")
+
     def prefill(self, input_ids):
         """Run the prefill pass on a fresh cache; returns (logits, cache)."""
+        self._contiguous_only("Engine.prefill / serve")
         input_ids = jnp.asarray(input_ids, dtype=jnp.int32)
         cache = self.model.make_cache(input_ids.shape[0], self.max_seq,
                                       dtype=self.kv_dtype)
@@ -377,6 +413,7 @@ class Engine:
 
     def make_slot_cache(self, batch: int):
         """Fresh cache whose batch rows are independent decode SLOTS."""
+        self._contiguous_only("contiguous decode slots")
         if self.sp_size > 1:
             raise ValueError(
                 "sequence-parallel serving shards the PAGE-ID space — "
@@ -742,6 +779,9 @@ class Engine:
         suffix prefill left to the mixed-chunk ticks (which resolve
         their KV scatter and attention through the table just
         installed). Same rows/cow contract as admit_slot_paged."""
+        self.refuse_slot_state(
+            "install_slot_paged (chunked admission, KV fork)",
+            "a table install that also carries the state")
         return self._paged_install(
             self.model, pcache, jnp.asarray(rows, jnp.int32),
             jnp.int32(slot), jnp.asarray(cow_src, jnp.int32),
@@ -784,7 +824,6 @@ class Engine:
         with a real error instead of a shard shape mismatch deep in
         compile); GQA replication (num_heads > num_kv_heads) is a
         query-side property and changes nothing about the pool split."""
-        from triton_dist_tpu.models.kv_cache import PagedSlotCache
         if self.backend == "mega" and \
                 self.model.mesh.shape[self.model.axis] > 1:
             raise ValueError(
@@ -795,8 +834,8 @@ class Engine:
         if not hasattr(self.model, "forward_tokens_slots_paged"):
             raise ValueError(
                 f"{type(self.model).__name__} has no paged slot decode "
-                "path (DenseLLM and Qwen3MoE carry the serving "
-                "surface)")
+                "path (DenseLLM, Qwen3MoE and Phi4Flash carry the "
+                "serving surface)")
         if for_ticks:
             # a pool that will DRIVE decode/verify/mixed ticks feeds
             # its batch rows to the row-sharded EP dispatch; staging
@@ -804,21 +843,22 @@ class Engine:
             # bucketed admit forwards and skip the batch gate
             self._moe_batch_check(batch)
         cfg = self.model.config
+        Hkv = self.traits.kv_heads
         tp = self.model.mesh.shape[self.model.axis]
-        if cfg.num_kv_heads % tp:
-            rep = cfg.num_heads // max(cfg.num_kv_heads, 1)
+        if Hkv % tp:
+            rep = cfg.num_heads // max(Hkv, 1)
             raise ValueError(
                 f"paged TP serving needs num_kv_heads "
-                f"({cfg.num_kv_heads}) divisible by the TP mesh size "
+                f"({Hkv}) divisible by the TP mesh size "
                 f"({tp}); this model's GQA replication factor is {rep} "
                 f"(query heads replicate per kv head, but the KV pool "
                 f"itself splits on kv heads) — serve on a mesh whose "
-                f"size divides {cfg.num_kv_heads}, or replicate kv "
+                f"size divides {Hkv}, or replicate kv "
                 f"heads in the checkpoint")
         maxp = -(-self.max_seq // page)
         sp_ax = getattr(self.model, "sp_axis", None)
         if num_pages is None:
-            num_pages = batch * cfg.num_kv_heads * maxp + 1
+            num_pages = batch * Hkv * maxp + 1
             if self.sp_size > 1:
                 # the default rounds UP to the sp partition (each chip
                 # owns a whole contiguous id block)
@@ -830,11 +870,11 @@ class Engine:
                 f"page-id space partitions into equal per-chip blocks "
                 f"— round num_pages up to a multiple of {self.sp_size} "
                 f"or shrink the sp axis")
-        return PagedSlotCache.create(
-            cfg.num_layers, batch, self.max_seq, cfg.num_kv_heads,
-            cfg.head_dim, page=page, num_pages=num_pages,
-            mesh=self.model.mesh, axis=self.model.axis,
-            dtype=self.kv_dtype or cfg.jax_dtype,
+        # the model lays out its own cache: one pool a layer for the
+        # Qwen families, pages + rings + planes for a hybrid
+        return self.model.make_paged_cache(
+            batch, self.max_seq, page=page, num_pages=num_pages,
+            dtype=self.kv_dtype,
             sp_axis=sp_ax if self.sp_size > 1 else None)
 
     def admit_slot_paged(self, pcache, slot: int, ids, rows,
@@ -873,6 +913,18 @@ class Engine:
         P = -(-s // pad_to) * pad_to
         padded = jnp.zeros((1, P), jnp.int32).at[0, :s].set(ids[m:])
         self._c_prefills.inc()
+        if self.traits.slot_state:
+            # the model's own admission program: pages, rings and
+            # planes of the slot in one pass, no scratch, no prefix
+            if m:
+                raise ValueError(
+                    f"kv_start={m}: a cached prefix holds no "
+                    f"{self.traits.slot_state} (missing capability: "
+                    f"prefix reuse over {self.traits.slot_state})")
+            logits, pcache = self._state_admit(
+                self.model, padded, pcache, jnp.asarray(rows, jnp.int32),
+                jnp.int32(slot), jnp.int32(n))
+            return logits[0], pcache
         with self._scratch_lock:
             scr = self._paged_scratch
             if scr is None or scr.k[0].shape[2] != T_pool + pad_to:
@@ -953,9 +1005,8 @@ class Engine:
         trash page (the write sink): the slot scan keeps stepping
         masked rows, and their scatters must never land on a page the
         allocator may have handed to someone else."""
-        Hkv = self.model.config.num_kv_heads
-        rows = jnp.full((Hkv, pcache.table.shape[1]), pcache.trash,
-                        jnp.int32)
+        rows = jnp.full((self.traits.kv_heads, pcache.table.shape[1]),
+                        pcache.trash, jnp.int32)
         return self._paged_set_table(pcache, rows, jnp.int32(slot))
 
     # ------------------------------------------------------------------
@@ -1002,7 +1053,7 @@ class Engine:
         padded[:n] = ids
         owners = np.zeros((P,), np.int32)
         if heads is not None and G > 1:
-            hkv_loc = self.model.config.num_kv_heads // G
+            hkv_loc = self.traits.kv_heads // G
             owners[:n] = np.asarray(heads, np.int32) // hkv_loc
         out = self._gather_pages(self.model, pcache, jnp.asarray(padded),
                                  jnp.asarray(owners))
@@ -1153,6 +1204,9 @@ def _jit_programs(backend: str, sampling: str, pkey: tuple,
         donate_argnums=(2, 3))
     P["paged_set_table"] = jax.jit(_paged_set_table_fn,
                                    donate_argnums=(0,))
+    P["state_admit"] = jax.jit(
+        functools.partial(_state_admit_fn, mode=prefill_mode),
+        donate_argnums=(2,))
     if greedy:
         vfn = functools.partial(_slot_verify_fn, fb)
         pvfn = functools.partial(_paged_slot_verify_fn, fb)
@@ -1894,11 +1948,20 @@ def _paged_admit_fn(model, ids, scratch, pcache, rows, slot, m, n,
 
 
 def _paged_set_table_fn(pcache, rows, slot):
+    """Retire: the slot's table rows to `rows` (the trash page), and
+    whatever else the cache keeps for a slot cleared (clear_slot: a
+    no-op for a cache that is pages alone)."""
     import dataclasses
     Hkv = rows.shape[0]
     table = jax.lax.dynamic_update_slice(pcache.table, rows,
                                          (slot * Hkv, 0))
-    return dataclasses.replace(pcache, table=table)
+    return dataclasses.replace(pcache, table=table).clear_slot(slot)
+
+
+def _state_admit_fn(model, ids, pcache, rows, slot, n, *, mode):
+    """Admission of a model whose slots hold state beside pages
+    (ServingTraits.slot_state): the model's own program."""
+    return model.admit_slot_paged(ids, pcache, rows, slot, n, mode=mode)
 
 
 def _gather_pages_fn(model, pcache, ids, owners):
@@ -2184,7 +2247,7 @@ def _mega_scan_decode_fn(model, logits0, cache, *, gen_len: int):
         n_kv_heads=cfg.num_kv_heads // n_mega, head_dim=hd,
         ffn=cfg.intermediate_size // n_mega, T=T, eps=cfg.rms_norm_eps,
         block_n=_pick_mega_bn(cfg, n_mega),
-        qk_norm=model.layers[0].attn.q_norm is not None,
+        qk_norm=model.serving_traits().qk_norm,
         tp=n_mega, axis=ax_mega)
     ones = jnp.ones((1, hd), jnp.float32)
     bf = jnp.bfloat16
@@ -2278,7 +2341,7 @@ def _paged_slot_mega_scan_fn(model, logits0, pcache, pos, active, *,
         n_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
         ffn=cfg.intermediate_size, page=pcache.page, maxp=maxp,
         eps=cfg.rms_norm_eps, block_n=_pick_mega_bn(cfg),
-        qk_norm=model.layers[0].attn.q_norm is not None)
+        qk_norm=model.serving_traits().qk_norm)
     ones = jnp.ones((1, cfg.head_dim), jnp.float32)
     bf = jnp.bfloat16
     weights = []

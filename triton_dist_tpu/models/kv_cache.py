@@ -293,6 +293,11 @@ class PagedSlotCache:
         """Logical positions addressable per slot (table width x page)."""
         return self.table.shape[1] * self.page
 
+    def clear_slot(self, slot) -> "PagedSlotCache":
+        """Retire hook: a slot whose whole state is its pages has
+        nothing to clear (its table rows go to the trash page)."""
+        return self
+
     def layer(self, idx: int):
         """Per-layer pool tuple for the paged attends: (pages_k,
         pages_v) — or (pages_k, pages_v, scales_k, scales_v) when
@@ -313,3 +318,107 @@ class PagedSlotCache:
                 out, scales_k=put(self.scales_k, kv[2]),
                 scales_v=put(self.scales_v, kv[3]))
         return out
+
+
+def uniform_paged_cache(model, batch: int, max_seq: int, *, page: int,
+                        num_pages: int, dtype=None,
+                        sp_axis: Optional[str] = None) -> PagedSlotCache:
+    """The paged cache of a model whose every layer is an attention
+    layer with its own K/V (DenseLLM, Qwen3MoE): one pool a layer."""
+    cfg = model.config
+    return PagedSlotCache.create(
+        cfg.num_layers, batch, max_seq, cfg.num_kv_heads, cfg.head_dim,
+        page=page, num_pages=num_pages, mesh=model.mesh, axis=model.axis,
+        dtype=dtype or cfg.jax_dtype, sp_axis=sp_axis)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class HybridSlotCache(PagedSlotCache):
+    """One slot cache for a model whose layers keep THREE kinds of
+    per-slot state (models/phi4flash.py; ROADMAP Queue 2 A.3 / A.5):
+
+    (a) pages behind the shared table, inherited from PagedSlotCache —
+        here ONE pool (`pages_k[0]`), the full-attention layer's, which
+        that layer and every cross-attention layer read. Allocator,
+        table install, retire-to-trash: all the paged path's own.
+    (b) `win_k` / `win_v`: one RING of `window` positions per slot and
+        window-attention layer, [B, heads, window, d]. Position t lives
+        in row t % window, so a slot's bytes are bounded by the window
+        whatever max_seq is. The model has no positional encoding, so
+        attention is indifferent to the order of the rows: a decode
+        step attends the first min(t + 1, window) rows of the ring as
+        an ordinary cached attention (the choice between a ring and
+        pages freed behind the window: the ring needs no allocator
+        traffic per 16 tokens and no window start in the paged walk).
+    (c) `conv` [B, d_conv - 1, E] and `ssm` [B, d_state, E] float32
+        planes per state-space layer: fixed size, not pageable.
+
+    `live` [B] marks the slots that hold an admitted request: a decode
+    step advances (c) for live slots only and leaves every other slot's
+    planes ALONE (zero since its retire, which clears them)."""
+
+    win_k: Tuple[jax.Array, ...] = ()
+    win_v: Tuple[jax.Array, ...] = ()
+    conv: Tuple[jax.Array, ...] = ()
+    ssm: Tuple[jax.Array, ...] = ()
+    live: Optional[jax.Array] = None
+    # how many attention layers read K/V in this model: what a uniform
+    # cache (every one of them its own full-length pool) would multiply
+    # a slot's pages by (slot_bytes)
+    attn_layers: int = dataclasses.field(default=1,
+                                         metadata=dict(static=True))
+
+    @staticmethod
+    def create_hybrid(batch: int, max_seq: int, *, heads: int,
+                      head_dim: int, window: int, window_layers: int,
+                      state_layers: int, d_inner: int, d_state: int,
+                      d_conv: int, attn_layers: int, page: int,
+                      num_pages: int, mesh: Mesh, axis: str = "tp",
+                      dtype=jnp.bfloat16) -> "HybridSlotCache":
+        base = PagedSlotCache.create(1, batch, max_seq, heads, head_dim,
+                                     page=page, num_pages=num_pages,
+                                     mesh=mesh, axis=axis, dtype=dtype)
+        rep = NamedSharding(mesh, P())
+
+        def planes(n, shape, dt):
+            return tuple(jax.device_put(jnp.zeros(shape, dt), rep)
+                         for _ in range(n))
+
+        ring = (batch, heads, window, head_dim)
+        return HybridSlotCache(
+            pages_k=base.pages_k, pages_v=base.pages_v, table=base.table,
+            trash=base.trash,
+            win_k=planes(window_layers, ring, dtype),
+            win_v=planes(window_layers, ring, dtype),
+            conv=planes(state_layers, (batch, d_conv - 1, d_inner),
+                        jnp.float32),
+            ssm=planes(state_layers, (batch, d_state, d_inner),
+                       jnp.float32),
+            live=jax.device_put(jnp.zeros((batch,), bool), rep),
+            attn_layers=attn_layers)
+
+    def clear_slot(self, slot) -> "HybridSlotCache":
+        """Retire: zero the slot's recurrent planes and mark it dead.
+        (Its ring rows stay: a later occupant's lengths mask them until
+        it has overwritten them.)"""
+        zero = lambda t: tuple(  # noqa: E731
+            jax.lax.dynamic_update_slice(
+                a, jnp.zeros((1,) + a.shape[1:], a.dtype), (slot, 0, 0))
+            for a in t)
+        return dataclasses.replace(
+            self, conv=zero(self.conv), ssm=zero(self.ssm),
+            live=self.live.at[slot].set(False))
+
+    def slot_bytes(self) -> dict:
+        """Bytes ONE slot holds of each kind: a mapped page group of (a)
+        (K and V, every head), its rings (b), its planes (c); and what
+        a page group would cost in a uniform cache, where each of the
+        `attn_layers` attention layers keeps its own."""
+        nbytes = lambda t: sum(a[0].nbytes for a in t)  # noqa: E731
+        heads = self.table.shape[0] // self.live.shape[0]
+        group = 2 * heads * self.pages_k[0][0].nbytes
+        return {"page_group": group,
+                "window": nbytes(self.win_k) + nbytes(self.win_v),
+                "state": nbytes(self.conv) + nbytes(self.ssm),
+                "uniform_page_group": group * self.attn_layers}

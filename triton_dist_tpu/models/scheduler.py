@@ -1451,12 +1451,28 @@ class PagedDecodeSlots(DecodeSlots):
         never demotes). fault: chaos hook consulted on demotions
         (runtime/chaos.py::FaultInjector.host_demotion)."""
         from triton_dist_tpu.models.prefix_cache import PrefixCache
+        # a slot that holds state beside its pages (engine.traits
+        # .slot_state) cannot be rebuilt from pages: every option that
+        # would is refused here, by the capability it lacks
+        if prefix_cache:
+            engine.refuse_slot_state(
+                "prefix_cache=True", "prefix reuse: a radix-tree hit "
+                "maps keys, not the state that followed them; serve "
+                "with prefix_cache=False")
+        if host_pool_pages:
+            engine.refuse_slot_state(
+                f"host_pool_pages={host_pool_pages}",
+                "host KV tier: demoted pages carry no state")
+        if spec:
+            engine.refuse_slot_state(
+                f"spec={spec}", "speculative verify: a rejected draft "
+                "cannot be rolled back out of a recurrent state")
         self.page = page
         self.margin = margin
         self._num_pages = num_pages
         super().__init__(engine, batch, spec=spec, drafter=drafter,
                          telemetry=telemetry)
-        Hkv = engine.model.config.num_kv_heads
+        Hkv = engine.traits.kv_heads
         # the prefix cache publishes its counters into the SAME
         # registry, so the scheduler's stats() snapshot covers it
         # a SEQUENCE-PARALLEL pool partitions the page-id space per sp
@@ -1494,6 +1510,21 @@ class PagedDecodeSlots(DecodeSlots):
             "boundary pages copy-on-written at fork time")
         self._g_forks = freg.gauge(
             "forks_active", "live forked decode slots")
+        # a cache of several kinds of per-slot state
+        # (kv_cache.HybridSlotCache) reports the bytes the live slots
+        # hold of each, beside what a uniform cache would hold for them
+        self._slot_bytes = (self.cache.slot_bytes()
+                            if hasattr(self.cache, "slot_bytes") else None)
+        if self._slot_bytes:
+            self._g_cache_bytes = {
+                kind: freg.gauge(
+                    "cache_bytes", "bytes the live slots hold, by kind "
+                    "of state", labels={"kind": kind})
+                for kind in ("pages", "window", "state")}
+            self._g_uniform_bytes = freg.gauge(
+                "cache_uniform_bytes",
+                "bytes a uniform cache (every attention layer its own "
+                "full-length pool) would hold for the same live slots")
 
     def _make_cache(self):
         return self.engine.make_paged_slot_cache(
@@ -1529,7 +1560,7 @@ class PagedDecodeSlots(DecodeSlots):
         every page's owning payload plane (Engine.extract_pages_host
         heads contract)."""
         ids = np.concatenate([np.asarray(g, np.int32) for g in groups])
-        Hkv = self.engine.model.config.num_kv_heads
+        Hkv = self.engine.traits.kv_heads
         heads = np.tile(np.arange(Hkv, dtype=np.int32), len(groups))
         out = self.engine.extract_pages_host(self.cache, ids,
                                              heads=heads)
@@ -1557,6 +1588,16 @@ class PagedDecodeSlots(DecodeSlots):
         out["fork_shared_pages"] = self._c_fork_shared.value
         out["fork_cow_breaks"] = self._c_fork_cow.value
         out.update(self.prefix.stats())
+        if self._slot_bytes:
+            sb, live = self._slot_bytes, self.occupied
+            groups = sum(len(self._groups[b]) for b in live)
+            held = {"pages": groups * sb["page_group"],
+                    "window": len(live) * sb["window"],
+                    "state": len(live) * sb["state"]}
+            for kind, v in held.items():
+                self._g_cache_bytes[kind].set(v)
+            self._g_uniform_bytes.set(
+                groups * sb["uniform_page_group"] + held["state"])
         return out
 
     def validate_admission(self, req: Request, tokens: np.ndarray
@@ -1985,6 +2026,11 @@ class ContinuousScheduler:
         if prefill_budget is not None and prefill_budget < 1:
             raise ValueError(f"prefill_budget must be >= 1, got "
                              f"{prefill_budget}")
+        if prefill_budget is not None:
+            engine.refuse_slot_state(
+                f"prefill_budget={prefill_budget}",
+                "chunked prefill: the mixed tick carries no state "
+                "between a prompt's chunks")
         if telemetry is not None:
             self.tele = telemetry
         else:
@@ -2132,6 +2178,10 @@ class ContinuousScheduler:
         re-queues (preemption) bypass the bound — a preempted request
         was already admitted once and must never be dropped.
         Thread-safe: any thread may submit while the driver polls."""
+        if req.n > 1:
+            self.slots.engine.refuse_slot_state(
+                f"request {req.rid!r} n={req.n}",
+                "KV fork: a fork shares pages, and state is not shared")
         with self._lock:
             if self.max_queue is not None \
                     and len(self._queue) >= self.max_queue:

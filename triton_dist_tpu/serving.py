@@ -459,6 +459,10 @@ class TokenServer:
                     raise ValueError(
                         "n>1 parallel sampling needs paged=True (the "
                         "KV fork shares the prompt's pages)")
+                if n > 1:
+                    self.engine.refuse_slot_state(
+                        f"n={n}", "KV fork: a fork shares pages, and "
+                                  "state is not shared")
                 grammar = req.get("grammar")
                 gspec = None
                 if grammar is not None:
