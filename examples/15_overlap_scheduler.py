@@ -10,6 +10,9 @@ inter-token floor even though the device finished long ago. With
 BEFORE reading back tick N: the same host work now runs while the
 device computes, every blocking readback is one coalesced
 ``jax.device_get``, and token streams stay BITWISE identical.
+``TokenServer`` dispatches ahead by default; ``overlap=False`` there,
+and the scheduler's own default, is the synchronous control this demo
+compares against, not a tuning choice.
 
 This demo serves the same request mix three ways and prints:
 - overlap off/on: identical streams, and the ``host_ms_per_poll``

@@ -32,7 +32,37 @@ _NEEDS_SHIM = (not _real and (os.cpu_count() or 1) < 4 * 8
                and os.environ.get("TDTPU_NO_FAKECPUS") != "1")
 
 
+# --- the long files first (xdist --dist loadfile) ---------------------
+# The tier-1 gate hands whole files to its workers. xdist's default
+# hands them out by their number of tests, so a file of few long tests
+# (test_serving.py opens with ten minutes of interpreted comm kernels)
+# starts in the middle of the run and its tail is the run's wall time.
+# Under xdist the files below go out first, longest first (seconds of a
+# whole -n 6 run, PR 32), and the others after them in collection
+# order; a file keeps its own tests' order. A serial run is untouched.
+_LONG_FILES = (
+    "test_serving.py", "test_e2e_inference.py", "test_moe_e2e.py",
+    "test_moe_layers.py", "test_scheduler.py", "test_overlap.py",
+    "test_phi4flash.py", "test_resilience.py", "test_chunked_prefill.py",
+    "test_sp_attention.py", "test_telemetry.py", "test_sp_serving.py",
+    "test_stress.py", "test_moe_reduce_rs.py", "test_paged_kv.py",
+    "test_tp_serving.py", "test_chip_compile.py", "test_prefix_cache.py",
+    "test_flash_attn.py")
+
+
+def pytest_collection_modifyitems(config, items):
+    if not hasattr(config, "workerinput"):
+        return
+    rank = {name: i for i, name in enumerate(_LONG_FILES)}
+    items.sort(key=lambda it: rank.get(
+        os.path.basename(it.nodeid.split("::")[0]), len(rank)))
+
+
 def pytest_configure(config):
+    # the controller keeps the workers' order instead of re-sorting the
+    # files by their number of tests
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
     if not _NEEDS_SHIM:
         return
     if not os.path.exists(_SHIM) and os.path.exists(_SHIM_SRC):

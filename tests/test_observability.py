@@ -625,7 +625,11 @@ def test_disagg_trace_bitwise_with_slo():
         np.testing.assert_array_equal(got[rid], ref[rid])
 
 
-def test_profiler_trace_holds_the_host_phases(tmp_path):
+@pytest.mark.parametrize("kw,tick", [
+    ({}, {"sched:dispatch", "sched:land"}),
+    # the control: the synchronous poll's one `step` round its tick
+    (dict(overlap=False), {"sched:step"})], ids=["default", "sync"])
+def test_profiler_trace_holds_the_host_phases(tmp_path, kw, tick):
     """An operator's `jax.profiler` session round a live TokenServer
     (trace off) sees the serve loop's and the scheduler's phases as
     `serve:` / `sched:` annotations on a host line, i.e. on the clock
@@ -636,7 +640,7 @@ def test_profiler_trace_holds_the_host_phases(tmp_path):
                                          request_stream)
     cfg, eng = _engine()
     srv = TokenServer(eng, ByteTokenizer(cfg.vocab_size), batch=2,
-                      chunk=4, paged=True, page=8)
+                      chunk=4, paged=True, page=8, **kw)
     th = threading.Thread(target=srv.serve_forever,
                           kwargs=dict(max_requests=2), daemon=True)
     opts = jax.profiler.ProfileOptions()
@@ -658,7 +662,7 @@ def test_profiler_trace_holds_the_host_phases(tmp_path):
                               / "*.xplane.pb"))
     want = {"serve:loop", "serve:accept_wait", "serve:poll",
             "serve:wire_write", "sched:poll", "sched:admit",
-            "sched:step", "sched:device_wait"}
+            "sched:device_wait"} | tick
     lines = [{e.name for e in line.events}
              for plane in ProfileData.from_file(xplane).planes
              if plane.name.startswith("/host:")

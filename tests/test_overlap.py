@@ -384,3 +384,139 @@ def test_host_ms_gauge_reports():
         assert st["overlap"] is overlap
         assert st["host_ms_per_poll"] > 0.0
         assert st["device_wait_s"] > 0.0
+
+
+# ----------------------------------------------------------------------
+# the served path: TokenServer dispatches ahead unless told otherwise
+# ----------------------------------------------------------------------
+
+def _decode_dispatches():
+    from triton_dist_tpu.runtime.telemetry import default_registry
+    return default_registry().snapshot()["engine_decode_dispatches"]
+
+
+def _served(srv, payloads, hang_up=()):
+    """Serve `payloads` (request_stream keyword dicts) through `srv` on
+    its own thread until all are done; client i in `hang_up` closes its
+    socket after its first message. Returns {i: token ids}."""
+    import json
+    import socket
+    import threading
+
+    from triton_dist_tpu.serving import request_stream
+
+    th = threading.Thread(target=srv.serve_forever,
+                          kwargs=dict(max_requests=len(payloads)),
+                          daemon=True)
+    th.start()
+    got = {}
+
+    def client(i):
+        toks = []
+        if i in hang_up:
+            with socket.create_connection(("127.0.0.1", srv.port),
+                                          timeout=600) as s:
+                f = s.makefile("rw")
+                f.write(json.dumps(payloads[i]) + "\n")
+                f.flush()
+                toks.extend(json.loads(f.readline())["token_ids"])
+        else:
+            kw = dict(payloads[i])
+            for msg in request_stream("127.0.0.1", srv.port,
+                                      kw.pop("prompt"), **kw):
+                if msg.get("done"):
+                    assert "error" not in msg, msg
+                    break
+                toks.extend(msg["token_ids"])
+        got[i] = toks
+
+    cts = [threading.Thread(target=client, args=(i,))
+           for i in range(len(payloads))]
+    for t in cts:
+        t.start()
+    for t in cts:
+        t.join(timeout=600)
+    th.join(timeout=600)
+    assert not th.is_alive() and len(got) == len(payloads)
+    return got
+
+
+def test_token_server_dispatches_ahead_by_default():
+    """A TokenServer built without `overlap` hands overlap=True to its
+    scheduler: every decode tick of a served batch is dispatched ahead
+    (`ticks_dispatched_ahead` keeps step with the engine's own count of
+    decode dispatches), nothing drains the pipeline until a client
+    hangs up mid-stream, and that cancel drains it exactly once. Both
+    counters are in stats() and in the /metrics exposition."""
+    import socket
+
+    from triton_dist_tpu.serving import ByteTokenizer, TokenServer
+
+    cfg, eng = _engine("greedy")
+    tok = ByteTokenizer(cfg.vocab_size)
+
+    def server():
+        return TokenServer(eng, tok, batch=4, chunk=4, paged=True,
+                           page=8, metrics_port=0)
+
+    srv = server()
+    assert srv.sched.overlap is True
+    assert srv.stats()["ticks_dispatched_ahead"] == 0
+    d0 = _decode_dispatches()
+    got = _served(srv, [dict(prompt=p, gen_len=16) for p in
+                        ("alpha prompt", "second one!", "and a third")])
+    assert all(len(t) == 16 for t in got.values())
+    st = srv.stats()
+    assert st["ticks_dispatched_ahead"] == _decode_dispatches() - d0 > 0
+    assert st["pipeline_drains"] == 0 == st["preemptions"]
+    with socket.create_connection(("127.0.0.1", srv.metrics_port),
+                                  timeout=30) as s:
+        s.sendall(b"GET /metrics HTTP/1.0\r\n\r\n")
+        text = b"".join(iter(lambda: s.recv(65536), b"")).decode()
+    assert (f"tdtpu_ticks_dispatched_ahead "
+            f"{st['ticks_dispatched_ahead']}") in text
+    assert "tdtpu_pipeline_drains 0" in text
+    srv.stop()
+
+    # a hang-up is seen by the probe of some later iteration; where the
+    # stream had finished by then nothing was cancelled: serve again
+    for _ in range(5):
+        srv = server()
+        _served(srv, [dict(prompt="hangs up", gen_len=48),
+                      dict(prompt="stays on!", gen_len=48)], hang_up={0})
+        st = srv.stats()
+        srv.stop()
+        if st.get("requests_cancelled"):
+            break
+    assert st["requests_cancelled"] == 1 == st["pipeline_drains"], st
+
+
+def test_token_server_grammar_request_matches_sync():
+    """A constrained request through the default server collapses the
+    pipeline to the synchronous tick for as long as it is live, beside
+    an unconstrained stream that goes on dispatching ahead after it:
+    both stream what the overlap=False server streams."""
+    from triton_dist_tpu.serving import ByteTokenizer, TokenServer
+
+    cfg, eng = _engine("greedy")
+    tok = ByteTokenizer(cfg.vocab_size)
+    schema = {"type": "object",
+              "properties": {"a": {"type": "integer", "maxDigits": 2}}}
+    payloads = [dict(prompt="free running", gen_len=40),
+                dict(prompt="abcdefgh", gen_len=24,
+                     grammar={"type": "json_schema", "schema": schema})]
+    runs = {}
+    for label, kw in (("sync", dict(overlap=False)), ("default", {})):
+        srv = TokenServer(eng, tok, batch=2, chunk=4, paged=True,
+                          page=8, **kw)
+        d0 = _decode_dispatches()
+        runs[label] = _served(srv, payloads)
+        st = srv.stats()
+        srv.stop()
+        assert st["grammar_mask_tokens"] > 0
+        ahead = st["ticks_dispatched_ahead"]
+        if label == "sync":
+            assert ahead == 0 == st["pipeline_drains"]
+        else:
+            assert 0 < ahead < _decode_dispatches() - d0
+    assert runs["default"] == runs["sync"]

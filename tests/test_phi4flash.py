@@ -249,7 +249,8 @@ def _gaps(reqs, out):
 
 @pytest.mark.parametrize("backend,overlap", [("xla", False),
                                              ("flash", False),
-                                             ("xla", True)])
+                                             ("xla", True),
+                                             ("flash", True)])
 def test_scheduler_streams_are_the_references_best(model, backend,
                                                    overlap):
     """Three requests over two slots (the third reuses a slot): every
@@ -305,48 +306,64 @@ def test_preempted_stream_is_bitwise_the_unpreempted_one(model):
         assert len(runs["small"][r.rid]) == r.gen_len
 
 
-def test_token_server_serves_a_batch(model):
-    """Through TokenServer and its wire at batch 3: streams are the
-    reference's best tokens."""
+@pytest.mark.parametrize("backend,batch,spec", [
+    ("xla", 3, [(18, 10), (25, 6), (5, 14), (12, 9)]),
+    # interpreted: the scheduler tests' batch and prompt buckets (24,
+    # 32, 16), so it compiles nothing of its own; three short requests
+    # over two slots, the third admitted behind a chunk in flight
+    ("flash", 2, [(18, 6), (25, 5), (12, 7)])], ids=["xla", "flash"])
+def test_token_server_serves_a_batch(model, backend, batch, spec):
+    """Through TokenServer and its wire: streams are the reference's
+    best tokens, and the server as it is built by default (dispatching
+    ahead) streams what the synchronous control (overlap=False)
+    streams."""
     import threading
     from triton_dist_tpu.serving import TokenServer, request_stream
-    eng = Engine(model, max_seq=MAX_SEQ, backend="xla")
-    srv = TokenServer(eng, hybrid_server.IdTokenizer(256), batch=3,
-                      chunk=CHUNK, paged=True, prefix_cache=False,
-                      page=PAGE)
-    th = threading.Thread(target=srv.serve_forever)
-    th.start()
-    reqs = _requests([(18, 10), (25, 6), (5, 14), (12, 9)], seed=2)
-    out, errs = {}, []
+    eng = Engine(model, max_seq=MAX_SEQ, backend=backend)
+    reqs = _requests(spec, seed=2)
 
-    def client(r):
-        toks = []
+    def serve(**kw):
+        srv = TokenServer(eng, hybrid_server.IdTokenizer(256),
+                          batch=batch, chunk=CHUNK, paged=True,
+                          prefix_cache=False, page=PAGE, **kw)
+        th = threading.Thread(target=srv.serve_forever)
+        th.start()
+        out, errs = {}, []
+
+        def client(r):
+            toks = []
+            try:
+                for msg in request_stream(
+                        srv.host, srv.port,
+                        hybrid_server.prompt_text(r.ids),
+                        gen_len=r.gen_len, timeout=300.0):
+                    if msg.get("done"):
+                        if msg.get("error"):
+                            errs.append(msg["error"])
+                        break
+                    toks.extend(msg.get("token_ids") or [])
+            except Exception as e:                   # surfaced below
+                errs.append(repr(e))
+            out[r.rid] = toks
+
         try:
-            for msg in request_stream(
-                    srv.host, srv.port, hybrid_server.prompt_text(r.ids),
-                    gen_len=r.gen_len, timeout=300.0):
-                if msg.get("done"):
-                    if msg.get("error"):
-                        errs.append(msg["error"])
-                    break
-                toks.extend(msg.get("token_ids") or [])
-        except Exception as e:                   # surfaced below
-            errs.append(repr(e))
-        out[r.rid] = toks
+            clients = [threading.Thread(target=client, args=(r,))
+                       for r in reqs]
+            for c in clients:
+                c.start()
+            for c in clients:
+                c.join(600.0)
+        finally:
+            srv.stop()
+            th.join(60.0)
+        assert not errs, errs
+        assert srv.sched.overlap is kw.get("overlap", True)
+        return out
 
-    try:
-        clients = [threading.Thread(target=client, args=(r,))
-                   for r in reqs]
-        for c in clients:
-            c.start()
-        for c in clients:
-            c.join(600.0)
-    finally:
-        srv.stop()
-        th.join(60.0)
-    assert not errs, errs
+    out = serve()
     assert all(len(out[r.rid]) == r.gen_len for r in reqs)
     assert float(_gaps(reqs, out).max()) < TOL
+    assert serve(overlap=False) == out
 
 
 # ----------------------------------------------------------------------

@@ -642,20 +642,26 @@ def test_server_deadline_reported_to_client():
         th.join(timeout=60)
 
 
-def test_server_hang_ends_with_error_not_freeze():
+@pytest.mark.parametrize("kw,wedged", [
+    # dispatch-ahead: a dispatch cannot hang, the readback can
+    ({}, ("land", lambda: time.sleep(30.0))),
+    # the control: the synchronous tick, dispatch and readback in one
+    (dict(overlap=False), ("step_chunk", lambda chunk: time.sleep(30.0)))],
+    ids=["default", "sync"])
+def test_server_hang_ends_with_error_not_freeze(kw, wedged):
     """A hung decode chunk (watchdog_s) must end serve_forever with a
     structured HANG error to the live client instead of freezing."""
     cfg, model = _model()
     eng = Engine(model, max_seq=64, backend="xla")
     srv, th, tok = _start_server(eng, cfg, batch=1, chunk=CHUNK,
-                                 watchdog_s=120.0)
+                                 watchdog_s=120.0, **kw)
     try:
         # healthy first so programs are warm (the opening chunk pays
         # the XLA compile), then tighten the deadline and wedge
         from triton_dist_tpu.serving import request_stream
         list(request_stream("127.0.0.1", srv.port, "warm", gen_len=4))
         srv.sched.watchdog_s = 0.25
-        srv.sched.slots.step_chunk = lambda chunk: time.sleep(30.0)
+        setattr(srv.sched.slots, *wedged)
         msgs = list(request_stream("127.0.0.1", srv.port, "doomed",
                                    gen_len=8, timeout=30.0))
         assert msgs and msgs[-1].get("done"), msgs

@@ -551,9 +551,14 @@ def served_pair():
 
     cfg, eng = _engine("greedy")
     tok = ByteTokenizer(cfg.vocab_size)
-    # gen_len 3 < chunk: retired inside its first chunk, before the wire
+    # gen_len 3 < chunk: retired inside its first chunk, before the wire.
+    # No two prompts share a first byte: the reader threads submit in
+    # any order, and a cached prefix would make the admissions' eager
+    # pad-and-slice shapes follow that order (whichever of two arrives
+    # second is admitted from the offset), so the second run would
+    # compile what the first did not on a different arrival order.
     work = [("alpha prompt", 12), ("second one!", 9), ("third", 3),
-            ("and a fourth one", 10), ("fifth", 6)]
+            ("now a fourth one", 10), ("fifth", 6)]
 
     def serve(trace):
         opened = []
@@ -624,10 +629,11 @@ def test_phase_self_times_partition_the_serve_loop(served_pair):
     assert sum(phases.values()) == pytest.approx(sum(roots), rel=1e-9)
     assert phases["device_wait"] == run["device_wait_s"] > 0
     # the labeled series are the same totals, and every phase the
-    # served path ran through has exits
+    # served path ran through has exits (the server dispatches ahead:
+    # its ticks are `dispatch` and `land`, not the synchronous `step`)
     for name in ("accept_wait", "poll", "wire_write", "probe",
-                 "sched_poll", "bookkeep", "admit", "step", "retire",
-                 "device_wait"):
+                 "sched_poll", "bookkeep", "admit", "dispatch", "land",
+                 "retire", "device_wait"):
         assert st[f"host_phase_s{{phase={name}}}"] == phases[name] > 0
         assert st[f"host_phase_n{{phase={name}}}"] > 0
     json.dumps(st)
@@ -656,7 +662,8 @@ def test_lifecycle_from_accept_to_the_wire(served_pair):
              served_pair["on"]["export"]["traceEvents"]}
     assert {"serve:loop", "serve:accept_wait", "serve:poll",
             "serve:wire_write", "serve:probe", "poll", "bookkeep",
-            "admit", "step", "device_wait", "retire"} <= names
+            "admit", "dispatch", "land", "device_wait",
+            "retire"} <= names
 
 
 def test_served_streams_and_programs_same_trace_on_off(served_pair):

@@ -90,7 +90,17 @@ inter-token floor. Mechanics:
   BITWISE identical overlap-on vs overlap-off across every mode
   (tests/test_overlap.py);
 - the watchdog and deadline checks move to LANDED-tick boundaries
-  (a dispatch cannot hang; the readback can).
+  (a dispatch cannot hang; the readback can);
+- ``TokenServer`` hands ``overlap=True`` down unless told otherwise
+  (serving.py: dispatch-ahead is what the served path runs; this
+  class's own default stays False for callers that step it poll by
+  poll). Two registry counters say how the mechanism fares:
+  ``ticks_dispatched_ahead`` (a spec=0 tick dispatched with the
+  previous tick's retires, the server's wire writes and its next
+  accept() still to run under it; over the engine's dispatches of
+  the same ticks, the share that engaged) and ``pipeline_drains``
+  (every collapse: preemption, cancel, an in-flight deadline, a
+  PoolExhausted admission, a grammar tick).
 
 Resilience (the degradation ladder under pressure — vLLM's
 preemption/recompute design over the Orca operational model,
@@ -1991,7 +2001,8 @@ class ContinuousScheduler:
 
         overlap: DISPATCH-AHEAD OVERLAP SCHEDULING (the SGLang
         zero-overhead overlap scheduler — module docstring has the
-        pipeline design). False (default) keeps the synchronous poll:
+        pipeline design; TokenServer passes True unless told
+        otherwise). False (default here) keeps the synchronous poll:
         dispatch, block on the readback, then do host bookkeeping with
         the device idle. True dispatches tick N+1 before reading back
         tick N (non-spec; spec=K overlaps the deferred retire/admit
@@ -2148,6 +2159,17 @@ class ContinuousScheduler:
             1.0 if getattr(engine, "backend", None) == "mega" else 0.0)
         self._c_tokens = reg.counter(
             "tokens_emitted", "tokens delivered to client streams")
+        # how often dispatch-ahead engages (module docstring): ticks
+        # that ran with host work under them, and the collapses
+        self._c_ahead = reg.counter(
+            "ticks_dispatched_ahead",
+            "ticks dispatched before the previous tick's retires, the "
+            "wire writes and the next accept() (overlap, spec=0)")
+        self._c_drains = reg.counter(
+            "pipeline_drains",
+            "overlap pipeline collapses: preemption, cancel, an "
+            "in-flight deadline, a PoolExhausted admission, a grammar "
+            "tick")
         self._busy_s = 0.0
         self._hang: Optional[str] = None
 
@@ -2633,6 +2655,7 @@ class ContinuousScheduler:
         here. The land runs watchdogged (_land_watchdog) — a drain's
         readback can hang exactly like a poll's."""
         self.tele.instant("drain")
+        self._c_drains.inc()
         out, finished = self._land_watchdog()
         rid_of = self.slots.rids
         for b, t in out.items():
@@ -2961,6 +2984,7 @@ class ContinuousScheduler:
                     else:
                         slots.begin_chunk(self.chunk, skip=skip)
                     self._mark_dispatch()
+                    self._c_ahead.inc()
                 else:
                     self._last_mark = None  # idle: no dispatch stamp
             with tele.phase("retire"):

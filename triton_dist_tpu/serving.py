@@ -172,7 +172,7 @@ class TokenServer:
                  drafter=None, max_queue: Optional[int] = None,
                  watchdog_s: Optional[float] = None, fault=None,
                  prefill_budget: Optional[int] = None,
-                 host_pool_pages: int = 0, overlap: bool = False,
+                 host_pool_pages: int = 0, overlap: bool = True,
                  metrics_port: Optional[int] = None,
                  trace: Optional[bool] = None,
                  disagg: bool = False, prefill_workers: int = 1,
@@ -218,18 +218,24 @@ class TokenServer:
         "cache" dict) then reports host_hits / host_pages_resident /
         demotions / promotions / restore_latency_ms live.
 
-        overlap enables the DISPATCH-AHEAD OVERLAP SCHEDULER
-        (models/scheduler.py module docstring): the driver dispatches
-        the next device tick before reading back the previous one, so
-        this server's per-poll host work — admissions, drafting, the
-        socket writes between polls — runs while the device computes
-        instead of serializing with it. Token streams are bitwise
-        identical either way; the watchdog and deadline checks move to
-        landed-tick boundaries (a dispatch cannot hang — the readback
-        can). The win is visible as stats()["host_ms_per_poll"] (also
-        in every done message): when that approaches the device step
-        time, overlap=True is the difference between host-bound and
-        device-bound serving.
+        overlap: the server DISPATCHES AHEAD (models/scheduler.py
+        module docstring): the driver dispatches the next device tick
+        before it reads back the previous one, so this server's host
+        work a poll — admissions, drafting, the socket writes, the
+        disconnect probes and the next accept() wait — runs while the
+        device computes instead of serializing with it. Token streams
+        are bitwise those of the synchronous loop; the watchdog and
+        deadline checks sit at landed-tick boundaries (a dispatch
+        cannot hang — the readback can); a first token leaves one poll
+        later and a freed slot re-admits one tick later.
+        overlap=False is the CONTROL, the synchronous loop the bitwise
+        tests compare against, not a tuning choice. stats() says how
+        often the mechanism engages (`ticks_dispatched_ahead` over the
+        engine's `engine_decode_dispatches`), how often the pipeline
+        collapsed (`pipeline_drains`: a preemption, a cancel, an
+        in-flight deadline, a PoolExhausted admission, a grammar
+        tick), and the host time it hides (`host_ms_per_poll`, also in
+        every done message).
 
         metrics_port: not None starts a Prometheus text-exposition
         listener on that TCP port (0 = ephemeral; the bound port is
