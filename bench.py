@@ -933,67 +933,6 @@ def _bench():
             "backend": jax.default_backend(),
         })
 
-    # --- megakernel paged decode tick row (ISSUE 12 / ROADMAP item
-    # 5): the SAME greedy paged serving burst through backend="mega"
-    # (one fused Pallas kernel per layer per tick) vs the per-op
-    # backend — inter-token p99 over the live streams' whole window.
-    # Single chip only (the fused tick's contract); on the CPU smoke
-    # the interpreted megakernel is orders slower by construction
-    # (every DMA is a python callback) — real chips via
-    # tools/onchip_regen.sh are the measurement, the row exists so the
-    # ledger tracks it.
-    if ndev == 1:
-        if on_tpu:
-            cfg_m = qwen3_1p7b()
-            mg_n, mg_plen, mg_gen, mg_batch = 8, 64, 64, 4
-        else:
-            cfg_m = tiny_qwen3(1, hidden_size=128,
-                               intermediate_size=256, num_heads=2,
-                               num_kv_heads=1, head_dim=64,
-                               dtype="bfloat16",
-                               max_position_embeddings=256)
-            mg_n, mg_plen, mg_gen, mg_batch = 3, 6, 6, 2
-        model_m = AutoLLM.from_config(cfg_m, mesh)
-        mg_seq = mg_plen + mg_gen + 16     # margin headroom; the mega
-        # engine rounds its max_seq up to the flash block anyway
-
-        def mega_run(backend_m):
-            eng_m = Engine(model_m, max_seq=mg_seq, backend=backend_m)
-            sched = ContinuousScheduler(eng_m, batch=mg_batch,
-                                        chunk=2, paged=True, page=8)
-            rngm = np.random.RandomState(11)
-            reqs = [Request(rid=i,
-                            ids=rngm.randint(
-                                0, cfg_m.vocab_size,
-                                size=(mg_plen,)).astype(np.int32),
-                            gen_len=mg_gen) for i in range(mg_n)]
-            for r in reqs:
-                sched.submit(r)
-            last, gaps = {}, []
-            while not sched.idle:
-                out, _ = sched.poll()
-                now = time.perf_counter()
-                for rid, t in out.items():
-                    if len(t) and rid in last:
-                        gaps.append(now - last[rid])
-                    if len(t):
-                        last[rid] = now
-            return gaps
-
-        mega_p99 = {}
-        for arm in ("flash", "mega"):
-            mega_run(arm)                     # warm the programs
-            g = mega_run(arm)
-            mega_p99[arm] = float(np.percentile(g, 99) * 1e3)
-        _emit_json({
-            "metric": "mega_inter_token_p99_ms",
-            "value": round(mega_p99["mega"], 2),
-            "unit": "ms",
-            "per_op_p99_ms": round(mega_p99["flash"], 2),
-            "requests": mg_n, "slots": mg_batch,
-            "backend": jax.default_backend(),
-        })
-
     # --- AOT warm-start row (ISSUE 12: tools/aot.py AOTProgramCache):
     # wall seconds from Engine construction to a drained serving burst
     # on a COLD process-wide program cache, vs the same rebuild with

@@ -1,0 +1,145 @@
+"""The seam `benchmark/systems/token_server.py` holds the program by.
+
+A PR that may not touch `benchmark/` still has to keep every name the
+adapter reaches into alive: the attributes `annotate()` wraps, the
+handles `stats()` / `lifecycle()` / `pool_pages()` read, and the
+counters the readers take from `stats()` (a reader that misses a key
+reads 0 or null, it does not fail). This file builds the adapter's own
+`Served` once, on the self-check's tiny configuration, and gives every
+such name a case of its own, so that a rename fails under its name here
+and not as a null metric on the chip. It reads `benchmark/`, and edits
+nothing there.
+"""
+
+import collections
+import json
+import os
+import sys
+import threading
+
+import jax
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)        # `benchmark` is a top-level package
+
+# what annotate() wraps, as paths from the TokenServer
+_WRAPPED = ("sched.poll", "sched._admit", "sched.slots.step_chunk",
+            "sched.slots._fetch", "_emit", "_probe_disconnects",
+            "_stop.wait", "_sock.accept")
+# what stats() / lifecycle() / pool_pages() read
+_HANDLES = ("stats", "sched.tele.export",
+            "sched.slots.prefix.pool.num_pages")
+# the keys of stats() that a reader or the harness takes by name
+# (benchmark/readers/*.py, benchmark/metrics/*.json, harness.py)
+_STATS_KEYS = ("device_wait_s_by_kind", "host_phase_s", "tokens_emitted",
+               "engine_decode_dispatches", "engine_prefill_dispatches",
+               "ticks_dispatched_ahead", "serve_loop_iterations",
+               "prompt_tokens", "prefill_tokens_skipped", "admissions",
+               "hits", "preemptions")
+# the wrapped hooks a dispatch-ahead serve loop enters while it serves
+_ENTERED = ("sched.poll", "sched._admit", "sched.slots._fetch", "_emit",
+            "_probe_disconnects")
+
+
+def _resolve(root, path):
+    *parents, attr = path.split(".")
+    for name in parents:
+        root = getattr(root, name)
+    return root, attr
+
+
+@pytest.fixture(scope="module")
+def served():
+    from benchmark.systems.token_server import Served
+    from triton_dist_tpu import finalize_distributed
+    cache_dir = jax.config.jax_compilation_cache_dir
+    with open(os.path.join(_REPO, "benchmark", "testdata",
+                           "tiny-qwen3.json")) as f:
+        cfg = json.load(f)
+    s = Served(cfg, 2**31 + 34, jax.devices()[:1], trace=True)
+    try:
+        yield s
+    finally:
+        s.stop()
+        assert not s.errors, s.errors
+        finalize_distributed()
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+
+
+@pytest.mark.parametrize("path", _WRAPPED)
+def test_annotate_finds_what_it_wraps(served, path):
+    obj, attr = _resolve(served.srv, path)
+    assert callable(getattr(obj, attr)), path
+
+
+@pytest.mark.parametrize("path", _HANDLES)
+def test_adapter_handle_is_there(served, path):
+    obj, attr = _resolve(served.srv, path)
+    assert hasattr(obj, attr), path
+
+
+def test_adapter_reads_through_its_handles(served):
+    assert served.pool_pages() > 0
+    assert isinstance(served.lifecycle(), dict)
+    assert served.host and served.port
+
+
+@pytest.mark.parametrize("key", _STATS_KEYS)
+def test_stats_carries_the_key_a_reader_takes(served, key):
+    assert key in served.stats(), key
+
+
+def test_wrapped_hooks_are_entered_while_serving(served):
+    """annotate() as the harness calls it (on a running server), over
+    counting wrappers: two requests later the hooks the dispatch-ahead
+    loop calls were each entered, and the counters the per-layer
+    metrics read have the form the readers expect."""
+    from benchmark.systems.token_server import request
+    counts = collections.Counter()
+
+    def count(path):
+        obj, attr = _resolve(served.srv, path)
+        inner = getattr(obj, attr)
+
+        def outer(*a, **kw):
+            counts[path] += 1
+            return inner(*a, **kw)
+        setattr(obj, attr, outer)
+
+    for path in _WRAPPED[:-1]:        # a socket takes no new attribute
+        count(path)
+    before = served.stats()
+    served.annotate()
+    count("_sock.accept")             # the adapter's proxy does
+
+    streams = {}
+
+    def client(i):
+        streams[i] = list(request(served.host, served.port,
+                                  [3 + i, 5, 7, 11, 13], 8, 300.0))
+
+    threads = [threading.Thread(target=client, args=(i,))
+               for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(600.0)
+        assert not t.is_alive()
+    for msgs in streams.values():
+        assert msgs[-1].get("done") and not msgs[-1].get("error"), msgs[-1]
+        assert sum(len(m.get("token_ids") or []) for m in msgs) == 8
+
+    after = served.stats()
+    print("wrapped hooks entered:", dict(counts))
+    for path in _ENTERED:
+        assert counts[path] > 0, (path, dict(counts))
+    by_kind = after["device_wait_s_by_kind"]
+    assert isinstance(by_kind, dict) and by_kind
+    assert all(isinstance(v, (int, float)) for v in by_kind.values())
+    assert sum(by_kind.values()) > sum(
+        before["device_wait_s_by_kind"].values())
+    assert after["ticks_dispatched_ahead"] > before["ticks_dispatched_ahead"]
+    assert set(served.lifecycle()) and all(
+        ev[1] for evs in served.lifecycle().values() for ev in evs)

@@ -62,6 +62,20 @@ def test_cache_decode_matches_full_forward():
                                rtol=2e-2)
 
 
+def test_deleted_backend_is_an_unknown_backend():
+    """The megakernel tick is gone (PR 34): its string ends at the
+    unknown-backend refusal, which names every string the engine does
+    serve, and that list is the one the module's docstring tabulates."""
+    import re
+    from triton_dist_tpu.models import engine
+    assert "mega" not in engine.BACKENDS and len(engine.BACKENDS) == 7
+    with pytest.raises(ValueError, match="unknown backend 'mega'") as e:
+        Engine(model, max_seq=16, backend="mega")
+    assert all(repr(b) in str(e.value) for b in engine.BACKENDS)
+    documented = re.findall(r'^  "(\w+)" +<- ', engine.__doc__, re.M)
+    assert tuple(documented) == engine.BACKENDS
+
+
 @pytest.mark.parametrize("backend", ["ar", "gemm_ar"])
 def test_engine_generates_same_tokens_as_oracle(backend):
     B, S, gen = 1, 8, 6

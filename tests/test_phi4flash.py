@@ -386,8 +386,6 @@ def _sched(model, **kw):
     (lambda m: _sched(m, paged=False), "contiguous cache"),
     (lambda m: _sched(m).submit(Request(
         rid=0, ids=np.zeros(4, np.int32), gen_len=2, n=2)), "KV fork"),
-    (lambda m: Engine(m, max_seq=MAX_SEQ, backend="mega"),
-     "megakernel tick"),
     (lambda m: Engine(m, max_seq=MAX_SEQ, backend="gemm_ar"),
      "TP comm-kernel projections"),
     (lambda m: Engine(m, max_seq=MAX_SEQ, backend="xla",
@@ -395,7 +393,7 @@ def _sched(model, **kw):
     (lambda m: Engine(m, max_seq=MAX_SEQ, backend="xla").prefill(
         np.zeros((1, 8), np.int32)), "contiguous cache"),
 ], ids=["prefix_cache", "host_tier", "spec", "prefill_budget",
-        "contiguous_slots", "fork", "mega", "comm_backend", "int8_kv",
+        "contiguous_slots", "fork", "comm_backend", "int8_kv",
         "engine_prefill"])
 def test_option_is_refused_by_capability(model, make, names):
     with pytest.raises(ValueError, match="missing capability") as e:
@@ -424,9 +422,7 @@ def test_qwen_models_report_their_traits():
     from triton_dist_tpu.models import DenseLLM, tiny_qwen3
     m = DenseLLM.random_init(tiny_qwen3(1), jax.make_mesh((1,), ("tp",)))
     t = m.serving_traits()
-    assert (t.kv_heads, t.slot_state, t.qk_norm, t.int8_weights) == \
-        (1, None, True, False)
-    assert m.quantize_int8().serving_traits().int8_weights
+    assert (t.kv_heads, t.slot_state) == (1, None)
     eng = Engine(m, max_seq=32, backend="xla")
     assert eng.traits == t
     eng.refuse_slot_state("anything", "nothing")        # a no-op here

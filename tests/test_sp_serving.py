@@ -199,9 +199,6 @@ def test_sp_capability_gates():
     pattern)."""
     from triton_dist_tpu.models.kv_cache import PagedSlotCache
     cfg, model_sp = _model(_SP)
-    # sp + backend='mega': the fused tick has no sp combine
-    with pytest.raises(ValueError, match="mega"):
-        Engine(model_sp, max_seq=64, backend="mega")
     # sp + comm-kernel backends: weights replicate over sp
     with pytest.raises(ValueError, match="flash"):
         Engine(model_sp, max_seq=64, backend="gemm_ar")

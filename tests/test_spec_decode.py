@@ -304,21 +304,6 @@ def test_sampled_spec_paged_stream_smoke():
         np.testing.assert_array_equal(a[rid], b[rid])
 
 
-def test_spec_rejects_mega_backend():
-    from triton_dist_tpu.models import AutoLLM
-    cfg = tiny_qwen3(1, hidden_size=128, intermediate_size=256,
-                     num_heads=2, num_kv_heads=1, head_dim=64,
-                     dtype="bfloat16", max_position_embeddings=256)
-    model = AutoLLM.from_config(cfg, mesh1)
-    eng = Engine(model, max_seq=64, backend="mega")
-    # contiguous slots: refused for the paged-only fused tick
-    with pytest.raises(ValueError, match="paged=True"):
-        ContinuousScheduler(eng, batch=2, spec=2)
-    # paged but spec=K: the verify window is the named missing piece
-    with pytest.raises(ValueError, match="verify"):
-        ContinuousScheduler(eng, batch=2, paged=True, page=8, spec=2)
-
-
 def test_sampled_spec_stream_smoke():
     """Sampled spec end-to-end: streams complete at full length and the
     per-slot PRNG chains keep slots independent (two runs at the same

@@ -11,7 +11,7 @@ The contracts under test, all bitwise:
     (masked == unconstrained), and jump-ahead (spec=K over the forced
     automaton run) changes throughput, never tokens;
   - every invalid structured request (bad n, fork over batch,
-    non-paged fork, mega+grammar, vocab mismatch, dead-end automaton)
+    non-paged fork, vocab mismatch, dead-end automaton)
     is refused loudly per-request — the loop survives, nothing leaks;
   - the fork/mask machinery compiles ZERO programs the plain paged
     loop did not already compile (the in-program mask operand rides
@@ -276,18 +276,6 @@ def test_capability_validations_reject_loudly():
     s2 = ContinuousScheduler(eng, batch=4, chunk=4)
     s2.run([Request(rid="c", ids=prompt, gen_len=4, n=2)])
     assert "needs the paged KV pool" in s2.rejected["c"]
-    # the mega backend's fused argmax takes no mask operand
-    mesh1 = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("tp",))
-    mcfg = tiny_qwen3(1, hidden_size=128, intermediate_size=256,
-                      num_heads=2, num_kv_heads=1, head_dim=64,
-                      dtype="bfloat16", max_position_embeddings=256)
-    meng = Engine(AutoLLM.from_config(mcfg, mesh1), max_seq=64,
-                  backend="mega")
-    s3 = ContinuousScheduler(meng, batch=2, chunk=4, paged=True,
-                             page=4)
-    s3.run([Request(rid="m", ids=prompt, gen_len=4,
-                    grammar=GrammarSpec.all_tokens(mcfg.vocab_size))])
-    assert "takes no grammar mask operand" in s3.rejected["m"]
 
 
 def _struct_soak(eng, cfg, seed):

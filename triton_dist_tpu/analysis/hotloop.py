@@ -26,7 +26,7 @@ canonical tiny-model program set compiles nothing and runs in seconds.
 from __future__ import annotations
 
 import hashlib
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 from triton_dist_tpu.analysis import Report, eqn_src, iter_eqns
 
@@ -104,17 +104,30 @@ def check_program_cache_identity(report: Report) -> None:
                     "program recompiles per engine")
 
 
+def tick_bodies(engine) -> Dict[str, Callable]:
+    """The engine's own greedy program set (engine._jit_programs),
+    each program unwrapped to the function its jit traces: which
+    function serves a tick, and with which static arguments, is
+    decided there and only there."""
+    from triton_dist_tpu.models.engine import _jit_programs, _params_key
+    progs = _jit_programs(engine.backend, "greedy",
+                          _params_key(engine._sample_params),
+                          engine.prefill_backend)
+    return {name: p.__wrapped__ for name, p in progs.items()}
+
+
 def canonical_programs(engine, batch: int = 2
                        ) -> Dict[str, Tuple]:
     """(fn, args, kwargs) per decode-tick program at canonical tiny
-    shapes — the hot-loop surface ContinuousScheduler polls."""
+    shapes — the hot-loop surface ContinuousScheduler polls. The
+    functions are tick_bodies'; the names listed are the programs a
+    poll dispatches, and the arguments are what each takes."""
     import jax
     import jax.numpy as jnp
-    from triton_dist_tpu.models import engine as eng_mod
     model = engine.model
     V = model.config.vocab_size
     B = batch
-    fb = "flash" if engine.backend == "mega" else engine.backend
+    body = tick_bodies(engine)
     cache = engine.make_slot_cache(B)
     pcache = engine.make_paged_slot_cache(B)
     logits0 = jnp.zeros((B, V), jnp.float32)
@@ -126,47 +139,30 @@ def canonical_programs(engine, batch: int = 2
     keys = jax.random.split(jax.random.PRNGKey(0), B)
     ids = jnp.zeros((2,), jnp.int32)
     owners = jnp.zeros((2,), jnp.int32)
-    params = dict(temperature=0.0, k=0, p=1.0)
 
-    progs = {
-        "slot_scan": (
-            lambda *a: eng_mod._slot_scan_decode_fn(fb, *a, gen_len=2),
-            (model, logits0, cache, pos, active), {}),
-        "paged_slot_scan": (
-            lambda *a: eng_mod._paged_slot_scan_decode_fn(
-                fb, *a, gen_len=2),
-            (model, logits0, pcache, pos, active), {}),
-        "slot_verify": (
-            lambda *a: eng_mod._slot_verify_fn(fb, *a),
-            (model, cache, pos, active, tokens, q_lens), {}),
-        "paged_slot_verify": (
-            lambda *a: eng_mod._paged_slot_verify_fn(fb, *a),
-            (model, pcache, pos, active, tokens, q_lens), {}),
-        "slot_mixed": (
-            lambda *a: eng_mod._mixed_step_fn(fb, None, params,
-                                              False, *a),
-            (model, logits0, cache, pos, active, prefilling, tokens,
-             q_lens, keys), {}),
-        "paged_slot_mixed": (
-            lambda *a: eng_mod._mixed_step_fn(fb, None, params,
-                                              True, *a),
-            (model, logits0, pcache, pos, active, prefilling, tokens,
-             q_lens, keys), {}),
-        "gather_pages": (
-            eng_mod._gather_pages_fn, (model, pcache, ids, owners), {}),
-    }
-    if engine.backend == "mega":
-        progs["paged_slot_mega"] = (
-            lambda *a: eng_mod._paged_slot_mega_scan_fn(*a, gen_len=2),
-            (model, logits0, pcache, pos, active), {})
     # restore_pages' payload shapes come from the gather's avals
-    gshape = jax.eval_shape(eng_mod._gather_pages_fn, model, pcache,
-                            ids, owners)
+    gshape = jax.eval_shape(body["gather_pages"], model, pcache, ids,
+                            owners)
     hk = jnp.zeros(gshape[0].shape, gshape[0].dtype)
     hv = jnp.zeros(gshape[1].shape, gshape[1].dtype)
-    progs["restore_pages"] = (
-        eng_mod._restore_pages_fn, (model, pcache, ids, hk, hv), {})
-    return progs
+    scan = {"gen_len": 2}
+    calls = {
+        "slot_scan": ((model, logits0, cache, pos, active), scan),
+        "paged_slot_scan": ((model, logits0, pcache, pos, active),
+                            scan),
+        "slot_verify": ((model, cache, pos, active, tokens, q_lens),
+                        {}),
+        "paged_slot_verify": ((model, pcache, pos, active, tokens,
+                               q_lens), {}),
+        "slot_mixed": ((model, logits0, cache, pos, active, prefilling,
+                        tokens, q_lens, keys), {}),
+        "paged_slot_mixed": ((model, logits0, pcache, pos, active,
+                              prefilling, tokens, q_lens, keys), {}),
+        "gather_pages": ((model, pcache, ids, owners), {}),
+        "restore_pages": ((model, pcache, ids, hk, hv), {}),
+    }
+    return {name: (body[name], args, kwargs)
+            for name, (args, kwargs) in calls.items()}
 
 
 def check_engine(engine, batch: int = 2,

@@ -2,12 +2,12 @@
 
 The paged serving stack's correctness rests on WRITE EXCLUSIVITY: in
 one tick, no two (slot, kv-head) streams may write the same physical
-page (kernels/paged_kv.py append_slots, mega/decode_layer.py's fused
-table walk), and no stream may write a page whose refcount exceeds 1 —
-a shared page is radix-tree prefix KV, writable only through the CoW
-boundary-copy path (models/prefix_cache.py). A violation corrupts a
-DIFFERENT request's stream, which the bitwise suites only catch after
-the fact. Three complementary proofs:
+page (kernels/paged_kv.py append_slots), and no stream may write a
+page whose refcount exceeds 1 — a shared page is radix-tree prefix KV,
+writable only through the CoW boundary-copy path
+(models/prefix_cache.py). A violation corrupts a DIFFERENT request's
+stream, which the bitwise suites only catch after the fact. Three
+complementary proofs:
 
 1. **state check** (`check_state` / `check_scheduler`): over the live
    host-side state — page table, per-slot positions, pool refcounts —
@@ -19,8 +19,8 @@ the fact. Three complementary proofs:
    its scatter indices from the page TABLE input (taint analysis) —
    a kernel that writes pool rows at indices not resolved through the
    table (the bug class the table indirection exists to prevent) is
-   rejected at trace time, covering the XLA scatter appends AND the
-   megakernel's scalar-prefetch table walk alike.
+   rejected at trace time, covering the XLA scatter appends AND a
+   kernel's in-place update through a scalar-prefetch operand alike.
 3. **shadow-page dynamic mode** (`snapshot_pool` / `check_shadow`):
    under interpret, snapshot the pool's bytes around ONE real tick and
    prove the changed-page set is contained in the expected write set
@@ -46,8 +46,8 @@ _HERE = "triton_dist_tpu/analysis/races.py"
 def page_write_targets(table: np.ndarray, pos: np.ndarray, page: int,
                        n_kv_heads: int) -> np.ndarray:
     """Physical page each (slot, kv-head) stream writes at its current
-    position: [B, Hkv] int32 (the exact resolution append_slots and the
-    mega table walk perform: table[slot*Hkv+h, pos//page])."""
+    position: [B, Hkv] int32 (the exact resolution append_slots
+    performs: table[slot*Hkv+h, pos//page])."""
     B = pos.shape[0]
     maxp = table.shape[1]
     tile = np.minimum(np.asarray(pos, np.int64) // page, maxp - 1)
@@ -278,9 +278,9 @@ def _taint_jaxpr(jaxpr, table_in: set, buf_in: set, findings: list,
                 if not bt(v):
                     continue
                 if i in aliased:
-                    # in-place pool update inside a kernel (the mega
-                    # table walk): its write offsets ride the scalar-
-                    # prefetch operand, which must be table-derived
+                    # in-place pool update inside a kernel: its
+                    # write offsets ride the scalar-prefetch
+                    # operand, which must be table-derived
                     if n_idx and not any(tt(eqn.invars[j])
                                          for j in range(n_idx)):
                         findings.append((
@@ -362,10 +362,10 @@ def check_tick_jaxpr(fn, args, pcache, subject: str,
 def check_engine_tick(engine, batch: int = 2,
                       report: Optional[Report] = None) -> Report:
     """check_tick_jaxpr over the engine's canonical paged decode tick
-    (the program PagedDecodeSlots drives every poll) — and the mega
-    fused tick when the engine serves backend='mega'."""
+    (the program PagedDecodeSlots drives every poll)."""
+    import functools
     import jax.numpy as jnp
-    from triton_dist_tpu.models import engine as eng_mod
+    from triton_dist_tpu.analysis.hotloop import tick_bodies
     if report is None:
         report = Report("races")
     model = engine.model
@@ -375,21 +375,11 @@ def check_engine_tick(engine, batch: int = 2,
     pos = jnp.zeros((batch,), jnp.int32)
     active = jnp.ones((batch,), bool)
 
-    def tick(model, logits0, pcache, pos, active):
-        return eng_mod._paged_slot_scan_decode_fn(
-            "flash" if engine.backend == "mega" else engine.backend,
-            model, logits0, pcache, pos, active, gen_len=2)
-
+    tick = functools.partial(tick_bodies(engine)["paged_slot_scan"],
+                             gen_len=2)
     check_tick_jaxpr(tick, (model, logits0, pcache, pos, active),
                      pcache, f"paged_slot_scan[{engine.backend}]",
                      report)
-    if engine.backend == "mega":
-        def mega_tick(model, logits0, pcache, pos, active):
-            return eng_mod._paged_slot_mega_scan_fn(
-                model, logits0, pcache, pos, active, gen_len=2)
-        check_tick_jaxpr(mega_tick,
-                         (model, logits0, pcache, pos, active),
-                         pcache, "paged_slot_mega", report)
     return report
 
 

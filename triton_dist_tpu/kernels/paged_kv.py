@@ -510,7 +510,7 @@ class PagedKVCache:
         (stream, offset // page, offset % page). A single-row write into
         a paged pool is a scatter (cannot be a tile-aligned DMA), so
         appends go through XLA DUS — the paged cache trades append/walk
-        speed for allocation flexibility (mega/CEILING.md)."""
+        speed for allocation flexibility."""
         B, Hkv, _, d = k_new.shape
         X, maxp = self.table.shape
         if not isinstance(self.offset, jax.core.Tracer):

@@ -21,7 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from triton_dist_tpu.layers import TP_Attn, TP_MLP, precompute_rope, rms_norm
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.kv_cache import KVCache
-from triton_dist_tpu.models.utils import place_replicated
+from triton_dist_tpu.models.utils import ServingTraits, place_replicated
 from triton_dist_tpu.runtime import auto_mesh
 
 
@@ -427,8 +427,7 @@ class DenseLLM:
                               dtype=dtype or cfg.jax_dtype)
 
     def serving_traits(self):
-        from triton_dist_tpu.models.utils import attn_stack_traits
-        return attn_stack_traits(self)
+        return ServingTraits(kv_heads=self.config.num_kv_heads)
 
     def make_paged_cache(self, batch: int, max_seq: int, **kw):
         from triton_dist_tpu.models.kv_cache import uniform_paged_cache

@@ -14,17 +14,9 @@ Backends (reference backend strings engine.py:126-135):
   "dist"    <- triton_dist      (AG-GEMM / GEMM-RS)
   "ar"      <- triton_dist_AR   (partial GEMMs + AR kernel)
   "gemm_ar" <- triton_dist_gemm_ar (fused GEMM+AR)
-  "mega"    <- mega_triton_kernel (models/engine.py backend "mega",
-               mega_triton_kernel/models/model_builder.py:86): each
-               decode layer is ONE Pallas megakernel
-               (mega/decode_layer.py); single chip, decode only
-               (prefill runs the flash backend). Measured on a v5e with
-               Qwen3-1.7B bsz=128: ~21 ms/step vs ~12.5 for "flash" —
-               on TPU the XLA scan already fuses and software-pipelines
-               across ops/layers, so the hand-scheduled megakernel is
-               the architecture-parity path, not the fast path (the
-               reference's megakernel wins by eliminating GPU launch
-               overhead, which the TPU path never pays).
+  "ep"      <- AG-GEMM / GEMM-RS attention + EP dispatch / combine FFN
+               (an expert-sharded Qwen3MoE, models/qwen_moe.py)
+  "ep_flash" <- the framework attention kernels + the same EP FFN
 """
 
 from __future__ import annotations
@@ -37,6 +29,9 @@ import jax
 import jax.numpy as jnp
 
 from triton_dist_tpu.runtime.telemetry import default_registry
+
+# every string Engine(backend=) serves: the module docstring's table
+BACKENDS = ("xla", "flash", "dist", "ar", "gemm_ar", "ep", "ep_flash")
 
 
 class Engine:
@@ -67,15 +62,13 @@ class Engine:
         # combination refuses HERE, at construction, naming the missing
         # capability — not as a shape/attribute error deep inside the
         # first jitted forward.
-        known = ("xla", "flash", "dist", "ar", "gemm_ar", "ep",
-                 "ep_flash", "mega")
-        if backend not in known:
+        if backend not in BACKENDS:
             raise ValueError(f"unknown backend {backend!r}; this engine "
-                             f"serves {known}")
+                             f"serves {BACKENDS}")
         self.moe_family = bool(getattr(model.config, "is_moe", False))
         # what the model says of itself (models/utils.ServingTraits):
-        # the ONE place the engine learns the pool's heads, the
-        # projections' form and whether slots hold state beside pages
+        # the ONE place the engine learns the pool's heads and
+        # whether slots hold state beside pages
         self.traits = model.serving_traits()
         # a slot of such a model is pages PLUS state that pages cannot
         # express: what moves or rebuilds pages alone is refused, here
@@ -83,10 +76,9 @@ class Engine:
         if backend not in ("flash", "xla"):
             self.refuse_slot_state(
                 f"backend={backend!r}",
-                ("megakernel tick" if backend == "mega"
-                 else "TP comm-kernel projections")
-                + f" over {self.traits.slot_state}; its layers run "
-                  f"single-chip on 'flash' or the 'xla' oracle")
+                f"TP comm-kernel projections over "
+                f"{self.traits.slot_state}; its layers run "
+                f"single-chip on 'flash' or the 'xla' oracle")
         if kv_dtype is not None:
             self.refuse_slot_state(
                 f"kv_dtype={jnp.dtype(kv_dtype)}",
@@ -106,14 +98,6 @@ class Engine:
                     f"head-group split (axis {model.axis!r}, size "
                     f"{tp}) yet (missing capability: sp + TP hybrid "
                     f"paged pool) — size one of the axes to 1")
-            if backend == "mega":
-                raise ValueError(
-                    "backend='mega' fuses the paged tick single-chip "
-                    "only; the sp-sharded pool's split-KV partial + "
-                    "cross-chip LSE combine stay on the per-op "
-                    "shard_map path (missing capability: megakernel "
-                    "sp combine) — serve sp meshes with "
-                    "backend='flash'")
             if backend not in ("flash",):
                 raise ValueError(
                     f"backend={backend!r} routes projections through "
@@ -148,12 +132,6 @@ class Engine:
             self._ep_rows = math.lcm(8, ep)
             self.max_seq = -(-self.max_seq // self._ep_rows) \
                 * self._ep_rows
-        if sampling != "greedy" and backend == "mega":
-            raise ValueError(
-                "backend='mega' serves GREEDY streams only (the fused "
-                "tick and the decode scan both carry the argmax token); "
-                "sampled decode is still unsupported — use the per-op "
-                "backends for sampled generation")
         self.sampling = sampling
         self._sample_params = dict(temperature=temperature, k=top_k,
                                    p=top_p)
@@ -189,40 +167,7 @@ class Engine:
         # panels and dequant per column after the dot (exact), so the
         # bandwidth win survives multi-chip TP decode (reference analog:
         # quantized comm payloads, low_latency_all_to_all_v2.py:213).
-        if backend == "mega":
-            if self.moe_family or not all(hasattr(l, "mlp")
-                                          for l in model.layers):
-                raise ValueError(
-                    "backend='mega' fuses dense (attention + MLP) "
-                    "decode layers only (missing capability: megakernel "
-                    "routed-expert FFN) — MoE models serve their "
-                    "grouped-GEMM tick on backend='flash' (TP-MoE) or "
-                    "'ep'/'ep_flash' (expert-sharded)")
-            if self.traits.int8_weights:
-                raise ValueError(
-                    "backend='mega' repacks raw bf16 weight panels and "
-                    "has no WEIGHT dequant path; int8-weight models run "
-                    "on the other backends (int8 paged KV is fine — "
-                    "the fused tick dequants the pool in-kernel)")
-            if kv_dtype is not None and jnp.dtype(kv_dtype) != jnp.int8:
-                raise ValueError(
-                    f"backend='mega' supports kv_dtype=None (pool "
-                    f"dtype) or jnp.int8 (in-kernel scale-plane "
-                    f"dequant), not {jnp.dtype(kv_dtype)}")
-            n_mega = model.mesh.shape[model.mesh.axis_names[0]]
-            if n_mega > 1 and (
-                    model.config.num_heads % n_mega
-                    or self.traits.kv_heads % n_mega
-                    or model.config.intermediate_size % n_mega):
-                raise ValueError(
-                    "backend='mega' TP needs heads/kv-heads/ffn "
-                    "divisible by the mesh size (single-chip decode "
-                    "has no such constraint)")
-            # the megakernel's flash loop walks the cache in
-            # block_t-sized tiles; round the cache capacity up
-            from triton_dist_tpu.mega import MegaDecodeLayer
-            bt = MegaDecodeLayer.block_t
-            self.max_seq = -(-max_seq // bt) * bt
+
         # the reference prefills with the torch fwd (engine.py:121); the
         # analog here is the XLA-collective mode unless overridden.
         # The ep backends prefill through THEMSELVES: chunked-prefill
@@ -230,7 +175,7 @@ class Engine:
         # one numerics path (the same reason "dist"/"flash" do).
         self.prefill_backend = prefill_backend or (
             backend if backend in ("dist", "flash", "ep", "ep_flash")
-            else "flash" if backend == "mega" else "xla")
+            else "xla")
         # MoE-family serving telemetry (ISSUE 13): every slot-tick
         # program additionally returns the tick's routing-load vector
         # [expert_tokens[0..E-1], capacity_dropped]; the engine stashes
@@ -276,10 +221,7 @@ class Engine:
         # slot-masked chunked decode (continuous batching,
         # models/scheduler.py) + the paged/verify/mixed program
         # family — all lazy-compiled on first use (the program
-        # roles are documented on _jit_programs). backend='mega'
-        # carries the SAME per-op family (built at its prefill
-        # backend) as the admission/mixed/tier fallback plus the
-        # fused paged tick program (paged_slot_mega).
+        # roles are documented on _jit_programs).
         self._slot_scan = progs["slot_scan"]
         self._prefill_slot = progs["prefill_slot"]
         self._write_slot = progs["write_slot"]
@@ -310,11 +252,6 @@ class Engine:
         self._paged_install = progs["paged_install"]
         self._gather_pages = progs["gather_pages"]
         self._restore_pages = progs["restore_pages"]
-        if backend == "mega":
-            self._paged_slot_mega = progs["paged_slot_mega"]
-            self._c_mega = _reg.counter(
-                "engine_mega_dispatches", "fused paged mega decode "
-                                          "ticks")
 
     def refuse_slot_state(self, option: str, capability: str) -> None:
         """Refuse `option` for a model whose slots hold state beside
@@ -348,14 +285,7 @@ class Engine:
         benchmark times this call alone — it is the reference's measured
         decode loop (engine.py:166). `seed` feeds the sampler key for
         the non-greedy modes (ignored under greedy)."""
-        if self.backend == "mega" and self.kv_dtype is not None:
-            raise ValueError(
-                "backend='mega' dequants int8 KV only on the PAGED "
-                "pool (the fused tick's scale-plane dequant); the "
-                "contiguous decode scan reads the cache directly — "
-                "serve int8 through ContinuousScheduler(paged=True), "
-                "or use kv_dtype=None here")
-        if self.sampling == "greedy" or self.backend == "mega":
+        if self.sampling == "greedy":
             toks, _, _ = self._decode_scan(self.model, logits, cache,
                                            gen_len=gen_len)
         else:
@@ -494,12 +424,6 @@ class Engine:
         the existing operands — requires chunk == 1 (the mask is a
         scan constant); mask=None leaves every call expression
         byte-identical, so unconstrained serving never retraces."""
-        if self.backend == "mega":
-            raise ValueError(
-                "backend='mega' fuses the PAGED decode tick only "
-                "(paged_slot_chunk); contiguous slot serving runs the "
-                "per-op backends — use ContinuousScheduler(paged=True) "
-                "or backend='flash'")
         if mask is not None and chunk != 1:
             raise ValueError(
                 f"grammar masks are per-step (scan constants): serve "
@@ -563,11 +487,6 @@ class Engine:
         mask: [B, S, V] bool per-position grammar masks
         (structured.window_masks) constraining acceptance + reseed.
         """
-        if self.backend == "mega":
-            raise ValueError(
-                "backend='mega' does not fuse the spec-decode verify "
-                "window yet (per-slot q_lens stay on the per-op "
-                "programs); serve spec=K on the per-op backends")
         tokens = jnp.asarray(tokens, jnp.int32)
         q_lens = jnp.asarray(q_lens, jnp.int32)
         self._c_verify.inc()
@@ -604,11 +523,6 @@ class Engine:
         never touch a live or cached page; rejected rows stay in the
         slot's own mapped pages until the next window overwrites them).
         """
-        if self.backend == "mega":
-            raise ValueError(
-                "backend='mega' does not fuse the spec-decode verify "
-                "window yet (the fused tick is the greedy S == 1 "
-                "paged step); serve spec=K on the per-op backends")
         tokens = jnp.asarray(tokens, jnp.int32)
         q_lens = jnp.asarray(q_lens, jnp.int32)
         self._c_verify.inc()
@@ -668,11 +582,6 @@ class Engine:
         cache, pos, keys). pos advances by q_lens for prefill rows and
         by 1 for active decode rows. mask: [B, V] grammar masks over
         the decode rows' token selection (sel_logits stay raw)."""
-        if self.backend == "mega":
-            raise ValueError(
-                "backend='mega' fuses the PAGED decode tick only; "
-                "contiguous mixed ticks run the per-op backends (the "
-                "paged mixed tick falls back automatically)")
         tokens = jnp.asarray(tokens, jnp.int32)
         q_lens = jnp.asarray(q_lens, jnp.int32)
         prefilling = jnp.asarray(prefilling, bool)
@@ -727,10 +636,6 @@ class Engine:
         sel_logits [B, V] — arming logits at each row's last valid
         window position, cache, pos, keys). mask: [B, S, V] grammar
         window masks over acceptance (sel_logits stay raw)."""
-        if self.backend == "mega":
-            raise ValueError(
-                "backend='mega' does not fuse the spec-decode verify "
-                "window yet; serve spec=K on the per-op backends")
         tokens = jnp.asarray(tokens, jnp.int32)
         q_lens = jnp.asarray(q_lens, jnp.int32)
         prefilling = jnp.asarray(prefilling, bool)
@@ -824,13 +729,6 @@ class Engine:
         with a real error instead of a shard shape mismatch deep in
         compile); GQA replication (num_heads > num_kv_heads) is a
         query-side property and changes nothing about the pool split."""
-        if self.backend == "mega" and \
-                self.model.mesh.shape[self.model.axis] > 1:
-            raise ValueError(
-                "backend='mega' fuses the paged tick single-chip only "
-                "(the TP pool's head-group plane split stays on the "
-                "per-op shard_map path); serve TP meshes with "
-                "backend='flash'/'dist'/'ar'/'gemm_ar'")
         if not hasattr(self.model, "forward_tokens_slots_paged"):
             raise ValueError(
                 f"{type(self.model).__name__} has no paged slot decode "
@@ -948,29 +846,11 @@ class Engine:
         row's table maps the trash page, so its masked-out writes can
         never touch a live or cached page).
 
-        backend='mega' routes this tick through the FUSED program
-        (_paged_slot_mega_scan_fn — one MegaPagedDecodeLayer kernel
-        per layer per step instead of the per-op dispatch chain),
-        greedy-only by construction; same contract, same carry.
-
         mask: [B, V] grammar masks (chunk == 1 required, see
-        slot_chunk); the fused mega tick does not take them — its
-        in-kernel argmax never sees a mask operand."""
+        slot_chunk)."""
         self._c_decode.inc()
         if self._comm_backend:
             self._c_comm.inc()
-        if self.backend == "mega":
-            if mask is not None:
-                raise ValueError(
-                    "backend='mega' fuses the greedy paged tick with "
-                    "an in-kernel argmax and takes no grammar mask "
-                    "operand; serve constrained requests on the "
-                    "per-op backends (backend='flash'/'dist'/...)")
-            assert keys is None   # greedy enforced at __init__
-            self._c_mega.inc()
-            toks, logits, pcache, pos = self._paged_slot_mega(
-                self.model, logits, pcache, pos, active, gen_len=chunk)
-            return toks, logits, pcache, pos, None
         if mask is not None and chunk != 1:
             raise ValueError(
                 f"grammar masks are per-step (scan constants): serve "
@@ -1151,40 +1031,20 @@ def _jit_programs(backend: str, sampling: str, pkey: tuple,
     byte-identical. ep/ep_flash backends (expert-sharded FFN over the
     a2a kernels) flow through the same program set as a mode string.
 
-    backend='mega' (the fused paged decode tick — ISSUE 12): the
-    per-op family above is built at the FALLBACK backend ("flash" —
-    the mega engine's prefill/mixed/admission programs are per-op by
-    design), decode_scan is the contiguous megakernel loop, and
-    paged_slot_mega is the fused greedy paged tick (one
-    MegaPagedDecodeLayer kernel per layer per step, scanned with a
-    donated pool).
-
     All lazy-compiled: a path never exercised costs nothing."""
     params = dict(temperature=pkey[0], k=pkey[1], p=pkey[2])
     greedy = sampling == "greedy"
-    # the per-op fallback backend: mega serves its admissions, mixed
-    # prefill+decode ticks and host-tier hops through these programs
-    fb = "flash" if backend == "mega" else backend
     P = {}
     P["prefill"] = jax.jit(functools.partial(_prefill_fn,
                                              mode=prefill_mode))
-    if backend == "mega":
-        P["decode_scan"] = jax.jit(
-            _mega_scan_decode_fn, static_argnames=("gen_len",),
-            donate_argnums=(2,))
-        P["paged_slot_mega"] = jax.jit(
-            _paged_slot_mega_scan_fn, static_argnames=("gen_len",),
-            donate_argnums=(2,))
-    else:
-        scan_fn = (functools.partial(_scan_decode_fn, backend) if greedy
-                   else functools.partial(_sampled_scan_decode_fn,
-                                          backend, sampling, params))
-        P["decode_scan"] = jax.jit(scan_fn,
-                                   static_argnames=("gen_len",),
-                                   donate_argnums=(2,))
-    slot_fn = (functools.partial(_slot_scan_decode_fn, fb)
+    scan_fn = (functools.partial(_scan_decode_fn, backend) if greedy
+               else functools.partial(_sampled_scan_decode_fn,
+                                      backend, sampling, params))
+    P["decode_scan"] = jax.jit(scan_fn, static_argnames=("gen_len",),
+                               donate_argnums=(2,))
+    slot_fn = (functools.partial(_slot_scan_decode_fn, backend)
                if greedy else
-               functools.partial(_sampled_slot_scan_decode_fn, fb,
+               functools.partial(_sampled_slot_scan_decode_fn, backend,
                                  sampling, params))
     P["slot_scan"] = jax.jit(slot_fn, static_argnames=("gen_len",),
                              donate_argnums=(2,))
@@ -1192,9 +1052,9 @@ def _jit_programs(backend: str, sampling: str, pkey: tuple,
         functools.partial(_prefill_slot_fn, mode=prefill_mode),
         donate_argnums=(2,))
     P["write_slot"] = jax.jit(_write_slot_fn, donate_argnums=(0,))
-    paged_fn = (functools.partial(_paged_slot_scan_decode_fn, fb)
+    paged_fn = (functools.partial(_paged_slot_scan_decode_fn, backend)
                 if greedy else
-                functools.partial(_sampled_paged_slot_scan_fn, fb,
+                functools.partial(_sampled_paged_slot_scan_fn, backend,
                                   sampling, params))
     P["paged_slot_scan"] = jax.jit(paged_fn,
                                    static_argnames=("gen_len",),
@@ -1208,12 +1068,12 @@ def _jit_programs(backend: str, sampling: str, pkey: tuple,
         functools.partial(_state_admit_fn, mode=prefill_mode),
         donate_argnums=(2,))
     if greedy:
-        vfn = functools.partial(_slot_verify_fn, fb)
-        pvfn = functools.partial(_paged_slot_verify_fn, fb)
+        vfn = functools.partial(_slot_verify_fn, backend)
+        pvfn = functools.partial(_paged_slot_verify_fn, backend)
     else:
-        vfn = functools.partial(_sampled_slot_verify_fn, fb,
+        vfn = functools.partial(_sampled_slot_verify_fn, backend,
                                 sampling, params)
-        pvfn = functools.partial(_sampled_paged_slot_verify_fn, fb,
+        pvfn = functools.partial(_sampled_paged_slot_verify_fn, backend,
                                  sampling, params)
         P["spec_seed"] = jax.jit(functools.partial(_spec_seed_fn,
                                                    sampling, params))
@@ -1221,17 +1081,17 @@ def _jit_programs(backend: str, sampling: str, pkey: tuple,
     P["paged_slot_verify"] = jax.jit(pvfn, donate_argnums=(1,))
     samp = None if greedy else sampling
     P["slot_mixed"] = jax.jit(
-        functools.partial(_mixed_step_fn, fb, samp, params, False),
+        functools.partial(_mixed_step_fn, backend, samp, params, False),
         donate_argnums=(2,))
     P["paged_slot_mixed"] = jax.jit(
-        functools.partial(_mixed_step_fn, fb, samp, params, True),
+        functools.partial(_mixed_step_fn, backend, samp, params, True),
         donate_argnums=(2,))
     P["slot_mixed_verify"] = jax.jit(
-        functools.partial(_mixed_verify_fn, fb, samp, params,
+        functools.partial(_mixed_verify_fn, backend, samp, params,
                           False),
         donate_argnums=(1,))
     P["paged_slot_mixed_verify"] = jax.jit(
-        functools.partial(_mixed_verify_fn, fb, samp, params,
+        functools.partial(_mixed_verify_fn, backend, samp, params,
                           True),
         donate_argnums=(1,))
     P["paged_install"] = jax.jit(_paged_install_fn, donate_argnums=(1,))
@@ -2197,209 +2057,3 @@ def _sampled_scan_decode_fn(backend, sampling, params, model, logits0,
     (logits, cache, key), toks = jax.lax.scan(
         step, (logits0, cache, key), None, length=gen_len)
     return toks.T, logits, cache, key                # [B, gen_len]
-
-
-def _pick_mega_bn(cfg, n: int = 1) -> int:
-    """Largest 128-multiple weight tile dividing the LOCAL projection
-    widths the megakernel asserts on (D, ffn/n, Hq*hd/n); the qkv
-    matmul down-tiles its own width independently (decode_layer.py
-    _pick_bn). A swept "mega_decode" tune-cache entry (tools/sweep)
-    overrides the ladder when it divides the widths — block_n tiles
-    output columns only, so the tick stays bitwise-identical."""
-    widths = (cfg.hidden_size, cfg.intermediate_size // n,
-              cfg.num_heads * cfg.head_dim // n)
-    from triton_dist_tpu.tools.sweep import resolve_config
-    tuned = resolve_config("mega_decode", widths).get("block_n")
-    if tuned and tuned % 128 == 0 and all(w % tuned == 0
-                                          for w in widths):
-        return int(tuned)
-    for bn in (512, 384, 256, 128):
-        if all(w % bn == 0 for w in widths):
-            return bn
-    raise ValueError(
-        f"no 128-multiple tile divides the projection widths {widths}; "
-        "backend='mega' needs 128-aligned layer geometry")
-
-
-def _mega_scan_decode_fn(model, logits0, cache, *, gen_len: int):
-    """Megakernel decode loop: one Pallas kernel per layer per step
-    (reference: the megakernel engine backend replaying the built task
-    graph, mega_triton_kernel/models/model_builder.py:86). Weights are
-    repacked into the megakernel's layout ONCE (outside the scan); the
-    KV cache converts to the head-major [Hkv, B, T, hd] layout the
-    kernel's per-head DMA walk wants."""
-    from triton_dist_tpu.layers.common import rms_norm
-    from triton_dist_tpu.mega import MegaDecodeLayer
-
-    cfg = model.config
-    hd = cfg.head_dim
-    T = cache.k[0].shape[2]
-    # TP (n > 1): the layer runs on LOCAL head/ffn shards with the two
-    # cross-chip reductions as in-kernel AR tasks (decode_layer.py
-    # module docstring — the reference's flagship TP megakernel). The
-    # model's packed weights are already per-rank-block layouts
-    # ([q_r|k_r|v_r], [gate_r|up_r]), so a contiguous column split IS
-    # the right shard.
-    ax_mega = model.mesh.axis_names[0]
-    n_mega = model.mesh.shape[ax_mega]
-    mega = MegaDecodeLayer(
-        d_model=cfg.hidden_size, n_heads=cfg.num_heads // n_mega,
-        n_kv_heads=cfg.num_kv_heads // n_mega, head_dim=hd,
-        ffn=cfg.intermediate_size // n_mega, T=T, eps=cfg.rms_norm_eps,
-        block_n=_pick_mega_bn(cfg, n_mega),
-        qk_norm=model.serving_traits().qk_norm,
-        tp=n_mega, axis=ax_mega)
-    ones = jnp.ones((1, hd), jnp.float32)
-    bf = jnp.bfloat16
-    weights = []
-    for layer in model.layers:
-        attn, mlp = layer.attn, layer.mlp
-        weights.append(dict(
-            w_ln1=layer.ln_attn[None].astype(jnp.float32),
-            w_qkv=attn.w_qkv.astype(bf),
-            q_norm=(ones if attn.q_norm is None
-                    else attn.q_norm[None].astype(jnp.float32)),
-            k_norm=(ones if attn.k_norm is None
-                    else attn.k_norm[None].astype(jnp.float32)),
-            w_o=attn.w_o.astype(bf),
-            w_ln2=layer.ln_mlp[None].astype(jnp.float32),
-            w_gu=mlp.w_gate_up.astype(bf),
-            w_d=mlp.w_down.astype(bf),
-        ))
-    ks = tuple(jnp.transpose(k, (1, 0, 2, 3)) for k in cache.k)
-    vs = tuple(jnp.transpose(v, (1, 0, 2, 3)) for v in cache.v)
-
-    # pallas_call needs Manual mesh axes: run each layer's megakernel
-    # under a shard_map, with every array an ARGUMENT (closures over
-    # sharded arrays are rejected in explicit-sharding mode). tp=1:
-    # fully replicated; tp>1: head/ffn-sharded weights + head-sharded
-    # cache, replicated activations (the TP mega layout).
-    from jax.sharding import PartitionSpec as P
-    if n_mega > 1:
-        ax = ax_mega
-        rep2 = P(None, None)
-        cspec = P(ax, None, None, None)
-        wspec = {"w_ln1": rep2, "w_qkv": P(None, ax), "q_norm": rep2,
-                 "k_norm": rep2, "w_o": P(ax, None), "w_ln2": rep2,
-                 "w_gu": P(None, ax), "w_d": P(ax, None),
-                 "cos_row": rep2, "sin_row": rep2}
-        in_specs = (rep2, P(), wspec, cspec, cspec)
-        out_specs = (rep2, cspec, cspec)
-    else:
-        in_specs = (P(), P(), P(), P(), P())
-        out_specs = (P(), P(), P())
-    mega_call = jax.shard_map(
-        lambda x, pos, wd, ck, cv: mega(x, pos, wd, ck, cv),
-        mesh=model.mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=False)
-
-    def step(carry, _):
-        tok, pos, ks, vs = carry
-        x = model.embed[tok].astype(jnp.float32)    # [B, D]
-        crow = model.cos[pos][None]
-        srow = model.sin[pos][None]
-        new_ks, new_vs = [], []
-        for li, w in enumerate(weights):
-            wd = dict(w, cos_row=crow, sin_row=srow)
-            x, ck, cv = mega_call(x, pos, wd, ks[li], vs[li])
-            new_ks.append(ck)
-            new_vs.append(cv)
-        xf = rms_norm(x, model.final_norm.astype(jnp.float32),
-                      cfg.rms_norm_eps)
-        logits = jnp.dot(xf.astype(model.lm_head.dtype), model.lm_head,
-                         preferred_element_type=jnp.float32)
-        return (jnp.argmax(logits, axis=-1), pos + 1,
-                tuple(new_ks), tuple(new_vs)), tok
-
-    (tok, _, ks, vs), toks = jax.lax.scan(
-        step, (jnp.argmax(logits0, axis=-1), cache.offset, ks, vs),
-        None, length=gen_len)
-    return toks.T, tok, None                         # [B, gen_len]
-
-
-def _paged_slot_mega_scan_fn(model, logits0, pcache, pos, active, *,
-                             gen_len: int):
-    """FUSED paged greedy decode tick (ISSUE 12 / ROADMAP item 5): the
-    paged_slot_chunk contract — same carry (logits, pcache, pos), same
-    masking, same token stream — with each scan step running ONE
-    MegaPagedDecodeLayer kernel per layer (mega/decode_layer.py: the
-    paged table walk, per-slot kv_lens, the trash-page write sink and
-    the int8 scale-plane dequant all inside the fused layer) instead
-    of the per-op dispatch chain. Weights repack into the megakernel
-    layout ONCE outside the scan; per-slot rope rows gather at each
-    slot's own position. Greedy only (the carry is the argmax chain);
-    single chip (make_paged_slot_cache refuses TP meshes up front)."""
-    from jax.sharding import PartitionSpec as P
-    from triton_dist_tpu.layers.common import rms_norm
-    from triton_dist_tpu.mega import MegaPagedDecodeLayer
-
-    cfg = model.config
-    maxp = pcache.table.shape[1]
-    quant = pcache.quantized
-    layer = MegaPagedDecodeLayer(
-        d_model=cfg.hidden_size, n_heads=cfg.num_heads,
-        n_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
-        ffn=cfg.intermediate_size, page=pcache.page, maxp=maxp,
-        eps=cfg.rms_norm_eps, block_n=_pick_mega_bn(cfg),
-        qk_norm=model.serving_traits().qk_norm)
-    ones = jnp.ones((1, cfg.head_dim), jnp.float32)
-    bf = jnp.bfloat16
-    weights = []
-    for ly in model.layers:
-        attn, mlp = ly.attn, ly.mlp
-        weights.append(dict(
-            w_ln1=ly.ln_attn[None].astype(jnp.float32),
-            w_qkv=attn.w_qkv.astype(bf),
-            q_norm=(ones if attn.q_norm is None
-                    else attn.q_norm[None].astype(jnp.float32)),
-            k_norm=(ones if attn.k_norm is None
-                    else attn.k_norm[None].astype(jnp.float32)),
-            w_o=attn.w_o.astype(bf),
-            w_ln2=ly.ln_mlp[None].astype(jnp.float32),
-            w_gu=mlp.w_gate_up.astype(bf),
-            w_d=mlp.w_down.astype(bf)))
-    act = active.astype(jnp.int32)
-    cap = pcache.capacity
-    # pallas_call needs Manual mesh axes (the contiguous mega scan's
-    # rule): each layer call runs under shard_map, pool operands on
-    # the head-group sharding they were created with (size-1 plane at
-    # tp=1 — TP meshes are refused at pool construction)
-    ax = model.axis
-    pool4 = P(None, ax, None, None)
-    sc3 = P(None, ax, None)
-    rep2 = P(None, None)
-    wspec = {k: rep2 for k in ("w_ln1", "w_qkv", "q_norm", "k_norm",
-                               "w_o", "w_ln2", "w_gu", "w_d",
-                               "cos_row", "sin_row")}
-    in_specs = (rep2, P(None), wspec, pool4, pool4, rep2) + (
-        (sc3, sc3) if quant else ())
-    out_specs = (rep2, pool4, pool4) + ((sc3, sc3) if quant else ())
-    mega_call = jax.shard_map(
-        lambda x, p, wd, *kv: layer(x, p, wd, *kv),
-        mesh=model.mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=False)
-
-    def step(carry, _):
-        logits, pc, pos_ = carry
-        tok = jnp.where(active, jnp.argmax(logits, axis=-1), 0)
-        x = model.embed[tok].astype(jnp.float32)       # [B, D]
-        crow = model.cos[pos_]                         # [B, hd//2]
-        srow = model.sin[pos_]
-        for li, w in enumerate(weights):
-            wd = dict(w, cos_row=crow, sin_row=srow)
-            extra = ((pc.scales_k[li], pc.scales_v[li]) if quant
-                     else ())
-            outs = mega_call(x, pos_, wd, pc.pages_k[li],
-                             pc.pages_v[li], pc.table, *extra)
-            x = outs[0]
-            pc = pc.set_layer(li, *outs[1:])
-        xf = rms_norm(x, model.final_norm.astype(jnp.float32),
-                      cfg.rms_norm_eps)
-        logits = jnp.dot(xf.astype(model.lm_head.dtype), model.lm_head,
-                         preferred_element_type=jnp.float32)
-        pos_ = jnp.minimum(pos_ + act, cap - 1)
-        return (logits, pc, pos_), tok
-
-    (logits, pcache, pos), toks = jax.lax.scan(
-        step, (logits0, pcache, pos), None, length=gen_len)
-    return toks.T, logits, pcache, pos               # [B, gen_len]

@@ -160,16 +160,6 @@ class PagedSlotCache:
     stale bytes); the host-tier d2h gather selects the owning plane
     per page (Engine.extract_pages_host heads=...).
 
-    MEGAKERNEL TICK (mega/decode_layer.py MegaPagedDecodeLayer —
-    ISSUE 12): the fused decode tick consumes this exact layout —
-    [NP, 1, page, d] single-plane pools + the shared trash-padded
-    table as a scalar-prefetch operand, scale planes riding the same
-    page id — so everything host-side (allocator, radix tree, CoW,
-    preemption, host tier) is oblivious to WHICH program walks the
-    pool; the engine swaps the tick per poll
-    (engine.paged_slot_chunk). The fused tick is single-plane by
-    contract: TP pools (G > 1) stay on the per-op shard_map path.
-
     SP SHARDING (sequence-parallel long-context serving — ROADMAP
     long-context item; the promotion of kernels/sp_flash_decode.py
     into the serving path, Ring Attention arXiv:2310.01889 /
@@ -190,8 +180,8 @@ class PagedSlotCache:
     (kernels/sp_flash_decode.sp_combine_partials): per-chip KV reads
     and attention FLOPs drop to ~1/S. sp composes with int8 scale
     planes (they shard alongside the payload) but not (yet) with the
-    TP head-group split or the fused megakernel tick — both refused
-    capability-named at Engine construction."""
+    TP head-group split — refused capability-named at Engine
+    construction."""
 
     pages_k: Tuple[jax.Array, ...]   # L x [NP, G, page, d]
     pages_v: Tuple[jax.Array, ...]

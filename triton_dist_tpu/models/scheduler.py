@@ -351,11 +351,11 @@ class _InFlight:
 # mixed verify is still a mixed tick — the operator-facing question is
 # "which program CLASS am I waiting on", not which jit entry point
 _DISPATCH_KIND = {"chunk": "decode", "mixed_verify": "mixed",
-                  "mega": "mega", "sp": "sp_combine"}
+                  "sp": "sp_combine"}
 
 # _InFlight.kind -> the same buckets, for the overlap land (which must
 # charge the LANDED tick's kind, not whatever dispatched since)
-_INFLIGHT_KIND = {"chunk": "decode", "mega": "mega", "mixed": "mixed",
+_INFLIGHT_KIND = {"chunk": "decode", "mixed": "mixed",
                   "spec": "verify", "mixed_spec": "mixed",
                   "sp": "sp_combine"}
 
@@ -480,7 +480,7 @@ class DecodeSlots:
         self.device_wait_by_kind: Dict[str, float] = {
             "prefill": 0.0, "decode": 0.0, "verify": 0.0,
             "mixed": 0.0, "admit": 0.0, "transfer": 0.0,
-            "mega": 0.0, "sp_combine": 0.0, "other": 0.0}
+            "sp_combine": 0.0, "other": 0.0}
         # MoE-family serving telemetry (ISSUE 13): every tick program
         # of a Qwen3MoE engine appends its routing-load vector
         # [expert_tokens[0..E-1], capacity_dropped]; _fetch pops ONE
@@ -514,12 +514,6 @@ class DecodeSlots:
         self.spec = int(spec)
         if self.spec:
             from triton_dist_tpu.models.spec_decode import NgramDrafter
-            if engine.backend == "mega":
-                raise ValueError(
-                    "backend='mega' does not fuse the spec-decode "
-                    "verify window yet (the fused tick is the greedy "
-                    "S == 1 paged step); serve spec=K on the per-op "
-                    "backends")
             self.drafter = drafter if drafter is not None \
                 else NgramDrafter()
             self._vocab = V
@@ -560,9 +554,8 @@ class DecodeSlots:
 
     def _tick_kind(self) -> str:
         """mark_dispatch kind of one plain decode tick ("chunk"; the
-        paged subclass reports "mega" when the engine routes the tick
-        through the fused megakernel program — device_wait_s_by_kind
-        then attributes the fused tick separately)."""
+        paged subclass reports "sp" over a sequence-parallel pool —
+        device_wait_s_by_kind then attributes that tick separately)."""
         return "chunk"
 
     @property
@@ -1391,7 +1384,7 @@ class DecodeSlots:
             return {}, []
         out: Dict[int, np.ndarray] = {}
         finished: List[Tuple[int, object]] = []
-        if inf.kind in ("chunk", "mega", "sp", "mixed"):
+        if inf.kind in ("chunk", "sp", "mixed"):
             (toks,) = self._fetch(inf.arrs,
                                   kind=_INFLIGHT_KIND[inf.kind])
             toks = np.asarray(toks)
@@ -1541,16 +1534,11 @@ class PagedDecodeSlots(DecodeSlots):
             self.batch, page=self.page, num_pages=self._num_pages)
 
     def _tick_kind(self) -> str:
-        # backend='mega' routes the pure-decode paged tick through the
-        # fused megakernel program (engine.paged_slot_chunk) — mixed
-        # ticks still dispatch per-op and keep their "mixed" kind.
         # A SEQUENCE-PARALLEL pool's decode tick runs the split-KV
         # partial + cross-chip LSE combine (layers/tp_attn.py
         # fwd_cached_slots_paged_sp) — attributed as "sp_combine" in
         # device_wait_kind_s so an operator sees what the long-context
         # path actually waits on.
-        if self.engine.backend == "mega":
-            return "mega"
         if getattr(self.engine, "sp_size", 1) > 1:
             return "sp"
         return "chunk"
@@ -2049,12 +2037,6 @@ class ContinuousScheduler:
                 trace = trace_env_enabled()
             self.tele = Telemetry(trace=trace)
         self.tele.configure_slo(slo_classes)
-        if getattr(engine, "backend", None) == "mega" and not paged:
-            raise ValueError(
-                "backend='mega' fuses the PAGED decode tick only "
-                "(engine.paged_slot_chunk); construct "
-                "ContinuousScheduler(paged=True), or serve contiguous "
-                "slots on a per-op backend such as 'flash'")
         if paged:
             self.slots = PagedDecodeSlots(
                 engine, batch, page=page, num_pages=num_pages,
@@ -2149,14 +2131,6 @@ class ContinuousScheduler:
         reg.gauge("sp_size",
                   "sp mesh size the paged pool shards over").set(
             self.sp_size)
-        # megakernel serving gauge (ISSUE 12 satellite): 1 when the
-        # pure-decode paged tick runs the fused program — paired with
-        # device_wait_kind_s{kind="mega"} it tells an operator the
-        # fused tick is live and what the host actually waits on
-        reg.gauge("mega_enabled",
-                  "1 = decode ticks run the fused megakernel "
-                  "program").set(
-            1.0 if getattr(engine, "backend", None) == "mega" else 0.0)
         self._c_tokens = reg.counter(
             "tokens_emitted", "tokens delivered to client streams")
         # how often dispatch-ahead engages (module docstring): ticks
@@ -2321,7 +2295,7 @@ class ContinuousScheduler:
             by_kind = {k: round(v, 4) for k, v in
                        self.slots.device_wait_by_kind.items()}
             for k in ("prefill", "decode", "verify", "mixed",
-                      "mega", "sp_combine", "admit", "transfer"):
+                      "sp_combine", "admit", "transfer"):
                 reg.gauge("device_wait_kind_s",
                           labels={"kind": k}).set(by_kind.get(k, 0.0))
             # live throughput, aggregate AND per-chip (one scheduler
@@ -2443,12 +2417,6 @@ class ContinuousScheduler:
             raise ValueError(
                 f"request {req.rid!r}: n must be >= 1, got {n}")
         if g is not None:
-            if getattr(self.slots.engine, "backend", None) == "mega":
-                raise ValueError(
-                    f"request {req.rid!r}: backend='mega' fuses the "
-                    f"greedy paged tick with an in-kernel argmax and "
-                    f"takes no grammar mask operand; serve constrained "
-                    f"requests on the per-op backends")
             if g.vocab_size != self.slots._vocab_size:
                 raise ValueError(
                     f"request {req.rid!r}: grammar compiled for vocab "

@@ -24,28 +24,14 @@ class ServingTraits:
     of layer (every model class has a `serving_traits()`).
 
     kv_heads: the page-table rows one slot holds, i.e. the heads of the
-    paged pool (a model may pool heads in pairs); int8_weights /
-    qk_norm: what the megakernel backend must know of the projections;
+    paged pool (a model may pool heads in pairs);
     slot_state: the capability name of state a slot holds BESIDE its
     pages and that pages cannot express (recurrent state, a window
     ring) — None for a model whose whole context is its pages. A model
     with slot_state admits through its own `admit_slot_paged`, and
     every option that rebuilds a slot from pages alone is refused."""
     kv_heads: int
-    int8_weights: bool = False
-    qk_norm: bool = False
     slot_state: str | None = None
-
-
-def attn_stack_traits(model) -> ServingTraits:
-    """Traits of a model whose layers all carry a TP_Attn (`.attn`) and
-    a ModelConfig: the Qwen3 dense and MoE families."""
-    from triton_dist_tpu.kernels.quant import QuantW
-    attn = model.layers[0].attn if model.layers else None
-    return ServingTraits(
-        kv_heads=model.config.num_kv_heads,
-        int8_weights=attn is not None and isinstance(attn.w_qkv, QuantW),
-        qk_norm=attn is not None and attn.q_norm is not None)
 
 
 def place_replicated(tree, mesh):

@@ -125,9 +125,6 @@ def decode_stream(engine, logits, cache, gen_len: int, *, chunk: int = 4,
     the sampled stream equals Engine.serve() at the same seed for every
     chunk size (it used to re-split a fresh key per chunk and diverge)."""
     import jax
-    if engine.backend == "mega":
-        raise ValueError("mega decode carries no resumable logits; "
-                         "stream with the per-op backends")
     key = jax.random.key(seed)
     done = 0
     while done < gen_len:
@@ -152,17 +149,7 @@ class TokenServer:
     streams keep flowing. Still single-threaded ON THE MODEL: socket
     threads only parse requests and write replies; every jax dispatch
     happens on the serve_forever thread (concurrency is batching, not
-    model threads — the discipline the old one-request loop had, kept).
-
-    Engine(backend="mega") engines serve here unchanged with
-    paged=True (greedy streams): pure-decode polls run the FUSED
-    megakernel tick (one Pallas kernel per layer —
-    engine.paged_slot_chunk routes it), admissions and chunked-prefill
-    mixed polls fall back per-op per poll, and the `mega_enabled`
-    gauge + `device_wait_kind_s{kind="mega"}` ride the stats()/
-    Prometheus surfacing below. Unsupported combinations (sampled,
-    spec=K, paged=False, TP meshes) refuse at construction with the
-    precise missing capability named — never mid-stream."""
+    model threads — the discipline the old one-request loop had, kept)."""
 
     def __init__(self, engine, tokenizer, *, batch: int,
                  host: str = "127.0.0.1", port: int = 0,

@@ -64,9 +64,9 @@ def aot_roundtrip(fn: Callable, args: Sequence[Any], **kw) -> Callable:
 # static auxdata — config, Mesh — has no serialized form), while
 # OUTPUTS keep their pytree classes (KVCache / PagedSlotCache), whose
 # treedefs register below with JSON-encoded auxdata. Programs the
-# host cannot serialize (Pallas interpreter callbacks off-TPU, e.g.
-# the mega tick on a CPU substrate) fall back to their live jit
-# wrappers and are counted — the cache degrades, never breaks.
+# host cannot serialize (Pallas interpreter callbacks off-TPU) fall
+# back to their live jit wrappers and are counted — the cache
+# degrades, never breaks.
 #
 # Known trade: an exported program does not DONATE its inputs the way
 # the live jit wrappers do, so an AOT-served tick transiently holds
