@@ -51,8 +51,8 @@ def main():
 
     SP = min(4, len(jax.devices()))
     cfg = tiny_qwen3(4)
-    page, chip_groups = 8, 4            # one chip's pool: 4 page groups
-    chip_pages = (chip_groups + 1) * cfg.num_kv_heads
+    page = 8
+    chip_pages = 4 + 1                  # one chip's pool: 4 pages + trash
 
     # one config, two topologies — random_init is mesh-independent, so
     # the weights are bitwise identical; only the pool layout differs

@@ -26,7 +26,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 B, HQ, HKV, D, T, PAGE = 8, 16, 8, 128, 512, 16
 HIDDEN, FFN = 2048, 6144
 MAXP = T // PAGE
-NUM_PAGES = B * HKV * MAXP + 1
+NUM_PAGES = B * MAXP + 1
 BF16, I32 = jnp.bfloat16, jnp.int32
 
 
@@ -117,17 +117,18 @@ def _kv(b, t):
     return ((b, HKV, t, D), BF16)
 
 
-_POOL = ((NUM_PAGES, PAGE, D), BF16)
+_POOL = ((NUM_PAGES, HKV, PAGE, D), BF16)   # a page: all heads of a slot
 
 
 def _paged_cell(hkv, b=32, max_seq=2048, s=1):
     """flash_decode_paged's arguments for a chip that holds `hkv` KV
-    heads of every slot (and the 16 query heads over them); s > 1 adds
+    heads of every slot (and the 16 query heads over them): its shard
+    [NP, hkv, page, d] of the pool, one table row a slot; s > 1 adds
     the per-slot query windows."""
     maxp = max_seq // PAGE
-    pool = ((b * hkv * maxp + 1, PAGE, D), BF16)
+    pool = ((b * maxp + 1, hkv, PAGE, D), BF16)
     return [((b, s, HQ, D), BF16), pool, pool,
-            ((b * hkv, maxp), I32), ((b,), I32)] + [((b,), I32)] * (s > 1)
+            ((b, maxp), I32), ((b,), I32)] + [((b,), I32)] * (s > 1)
 
 
 # --- Phi-4-mini-flash at its published widths (paired heads: 40 padded
@@ -165,7 +166,7 @@ def _paired_paged_decode(q, pk, pv, table, kv_lens):
                               scale=0.125, kv_lens=kv_lens)
 
 
-_P4_POOL = ((P4_B * P4_HP * (P4_SEQ // PAGE) + 1, PAGE, D), BF16)
+_P4_POOL = ((P4_B * (P4_SEQ // PAGE) + 1, P4_HP, PAGE, D), BF16)
 
 
 # name -> (function, [(shape, dtype), ...]); () is a traced scalar
@@ -193,12 +194,13 @@ CASES = {
         _ring_decode, [((P4_B, 1, P4_HQ, D), BF16),
                        ((P4_B, P4_HP, P4_W, D), BF16),
                        ((P4_B, P4_HP, P4_W, D), BF16), ((P4_B,), I32)]),
-    # the full and cross layers' walk of layer 17's pool: 640 streams
-    # of 4 padded query rows, 256 table columns
+    # the full and cross layers' walk of layer 17's pool: 64 slots of
+    # 10 paired heads x 4 padded query rows (40 KiB a copy), 256 table
+    # columns
     "phi4_paged_decode_b64": (
         _paired_paged_decode, [((P4_B, 1, P4_HQ, D), BF16), _P4_POOL,
                                _P4_POOL,
-                               ((P4_B * P4_HP, P4_SEQ // PAGE), I32),
+                               ((P4_B, P4_SEQ // PAGE), I32),
                                ((P4_B,), I32)]),
     # Engine.prefill: B=8 prompts of 128 into the contiguous cache
     "flash_prefill_b8_s128": (
@@ -221,10 +223,11 @@ CASES = {
     # the serving tick: per-slot lengths through the page table
     "flash_decode_paged_b8_page16": (
         _flash_decode_paged, [_q(B, 1), _POOL, _POOL,
-                              ((B * HKV, MAXP), I32), ((B,), I32)]),
+                              ((B, MAXP), I32), ((B,), I32)]),
     # the same walk at the benchmark's cells (B=32 slots, max_seq
-    # 2048 = 128 table columns of page 16): one chip's 256 streams of
-    # 2 query rows, and a TP=4 chip's 64 streams of 8
+    # 2048 = 128 table columns of page 16): one chip's 32 slots x 8
+    # heads x 2 query rows (32 KiB a copy, W = 1), and a TP=4 chip's
+    # 32 x 2 x 8 (8 KiB, W = 4)
     "flash_decode_paged_cell_1chip": (
         _flash_decode_paged, _paged_cell(hkv=8)),
     "flash_decode_paged_cell_tp4": (
@@ -255,22 +258,23 @@ def test_kernel_compiles_for_v5e(name, one_chip, for_chip):
 
 
 def test_int8_pool_compiles_at_page_128_only(one_chip, for_chip):
-    """The int8 pool's scale planes are [NP, page] f32 and the walk
-    copies one page's row of them: at page 16, as served, that is a
-    16-lane slice Mosaic refuses (so did the BlockSpec walk before PR
-    30), and the variant runs in the interpreter only. Recorded here so
-    that the day a [NP, 1, page] plane or a wider page lifts it, this
-    test says so (PERF.md, open questions)."""
+    """The int8 pool's scale planes are [NP, Hkv, page] f32 and the
+    walk copies one page's rows of them: at page 16, as served, that is
+    a 16-lane slice Mosaic refuses (so did the per-head planes and the
+    BlockSpec walk before PR 30), and the variant runs in the
+    interpreter only. Recorded here so that the day a wider page or
+    another plane lifts it, this test says so (PERF.md, open
+    questions)."""
     from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
 
     def lower(page, maxp=16):
-        np_ = B * HKV * maxp + 1
-        pool = jax.ShapeDtypeStruct((np_, page, D), jnp.int8,
+        np_ = B * maxp + 1
+        pool = jax.ShapeDtypeStruct((np_, HKV, page, D), jnp.int8,
                                     sharding=one_chip)
-        scale = jax.ShapeDtypeStruct((np_, page), jnp.float32,
+        scale = jax.ShapeDtypeStruct((np_, HKV, page), jnp.float32,
                                      sharding=one_chip)
         shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
-                  for s, dt in (_q(B, 1), ((B * HKV, maxp), I32),
+                  for s, dt in (_q(B, 1), ((B, maxp), I32),
                                 ((B,), I32))]
         return jax.jit(lambda q, t, l, pk, pv, sk, sv: flash_decode_paged(
             q, pk, pv, t, jnp.max(l), kv_lens=l, k_scale=sk, v_scale=sv)

@@ -243,9 +243,8 @@ def test_preempt_mid_prefill_exact_resume():
     cfg, model = _model()
     eng = Engine(model, max_seq=64, backend="xla")
     page, chunk, L, g = 8, 4, 16, 8
-    Hkv = cfg.num_kv_heads
     worst = -(-(L + g + chunk - 1) // page)
-    tiny = worst * Hkv + 1 + Hkv
+    tiny = worst + 1 + 1
     ample = ContinuousScheduler(
         eng, batch=2, chunk=chunk, paged=True, page=page,
         prefill_budget=3).run(_uniform_requests(cfg))
@@ -266,9 +265,8 @@ def test_preempt_targets_prefilling_slot():
     cfg, model = _model()
     eng = Engine(model, max_seq=64, backend="xla")
     page, chunk, L, g = 8, 4, 16, 8
-    Hkv = cfg.num_kv_heads
     worst = -(-(L + g + chunk - 1) // page)
-    tiny = worst * Hkv + 1 + Hkv
+    tiny = worst + 1 + 1
     reqs = _uniform_requests(cfg, n=2)
     ample = ContinuousScheduler(
         eng, batch=2, chunk=chunk, paged=True, page=page,

@@ -73,11 +73,11 @@ def _assert_no_leak_two_tier(sched):
 
 def test_host_pool_accounting_and_lru():
     hp = HostKVPool(10)
-    h1 = hp.put("a", n_pages=4, n_groups=2)
-    h2 = hp.put("b", n_pages=4, n_groups=2)
+    h1 = hp.put("a", n_pages=4)
+    h2 = hp.put("b", n_pages=4)
     assert hp.pages_resident == 8 and len(hp) == 2 and hp.room == 2
     with pytest.raises(ValueError):
-        hp.put("c", n_pages=4, n_groups=2)       # no room: caller evicts
+        hp.put("c", n_pages=4)       # no room: caller evicts
     assert hp.victim() == h1                     # LRU first
     assert hp.victim(pinned={h1}) == h2          # pins respected
     assert hp.get(h1).payload == "a"             # touch -> h2 is now LRU
@@ -85,7 +85,7 @@ def test_host_pool_accounting_and_lru():
     hp.drop(h2)
     assert hp.pages_resident == 4 and hp.drops == 1
     e = hp.pop(h1)
-    assert e.payload == "a" and e.n_groups == 2
+    assert e.payload == "a" and e.n_pages == 4
     assert hp.pages_resident == 0 and hp.pops == 1
     assert hp.victim() is None
     with pytest.raises(ValueError):
@@ -96,26 +96,24 @@ def test_demote_promote_roundtrip_bookkeeping():
     """Pure host bookkeeping with fake copy callbacks: eviction under a
     host tier demotes (device refs released, node host-resident, pool
     invariants intact) and a lookup promotes the span back into fresh
-    groups — with the EXACT payload the demotion extracted handed to
+    pages — with the EXACT payload the demotion extracted handed to
     the restore callback."""
-    page, Hkv = 4, 2
-    pc = PrefixCache(16, Hkv, page, host_pool_pages=64)
+    page = 4
+    pc = PrefixCache(8, page, host_pool_pages=32)
     extracted, restored = [], []
     pc.attach_host_tier(
-        lambda groups: extracted.append(
-            [g.copy() for g in groups]) or len(extracted) - 1,
-        lambda payload, groups: restored.append(
-            (payload, [g.copy() for g in groups])))
+        lambda pages: extracted.append(list(pages)) or len(extracted) - 1,
+        lambda payload, pages: restored.append((payload, list(pages))))
     pool = pc.pool
-    seq = np.arange(10, dtype=np.int32)          # 3 groups
-    groups = [pool.alloc_group() for _ in range(3)]
-    assert pc.insert(seq, groups) == 10
-    for g in groups:
+    seq = np.arange(10, dtype=np.int32)          # 3 pages
+    pages = [pool.alloc_page() for _ in range(3)]
+    assert pc.insert(seq, pages) == 10
+    for g in pages:
         pool.release(g)
-    assert pc.tree.evict_until(pool.available + 6)   # forces demotion
+    assert pc.tree.evict_until(pool.available + 3)   # forces demotion
     st = pc.stats()
     assert st["demotions"] == 1 and st["evictions"] == 0
-    assert st["host_pages_resident"] == 6 and st["host_entries"] == 1
+    assert st["host_pages_resident"] == 3 and st["host_entries"] == 1
     assert pool.pages_in_use == 0
     assert pool.available + pool.outstanding == pool.num_pages
     # the demoted node stayed in the tree but is unmatchable raw...
@@ -128,9 +126,9 @@ def test_demote_promote_roundtrip_bookkeeping():
     assert st["promotions"] == 1 and st["host_hits"] == 1
     assert st["host_entries"] == 0 and st["host_pages_resident"] == 0
     assert st["restore_latency_ms"] > 0.0
-    # the restore got the demotion's payload and 3 fresh groups
-    (payload, fresh_groups), = restored
-    assert payload == 0 and len(fresh_groups) == 3
+    # the restore got the demotion's payload and 3 fresh pages
+    (payload, fresh_pages), = restored
+    assert payload == 0 and len(fresh_pages) == 3
     assert pool.available + pool.outstanding == pool.num_pages
     # the promoted node matches like any device node now
     m2, _ = pc.tree.match(seq)
@@ -141,30 +139,30 @@ def test_host_pool_true_drop_and_insert_opacity():
     """A host pool too small for the working set TRUE-DROPS its LRU
     spans (the only place KV is forgotten); insert stops at a
     host-resident child instead of splitting/descending it."""
-    page, Hkv = 4, 2
-    pc = PrefixCache(64, Hkv, page, host_pool_pages=8)   # 4 groups max
-    pc.attach_host_tier(lambda groups: None,
-                        lambda payload, groups: None)
+    page = 4
+    pc = PrefixCache(32, page, host_pool_pages=4)        # 4 pages max
+    pc.attach_host_tier(lambda pages: None,
+                        lambda payload, pages: None)
     pool = pc.pool
     seq = np.arange(10, dtype=np.int32)
-    groups = [pool.alloc_group() for _ in range(3)]
-    pc.insert(seq, groups)
+    pages = [pool.alloc_page() for _ in range(3)]
+    pc.insert(seq, pages)
     seq2 = np.concatenate([seq[:7], np.asarray([99, 98, 97], np.int32)])
-    g2_cow, g2_tail = pool.alloc_group(), pool.alloc_group()
+    g2_cow, g2_tail = pool.alloc_page(), pool.alloc_page()
     pc.insert(seq2, [None, g2_cow, g2_tail])
-    for grp in groups + [g2_cow, g2_tail]:
+    for grp in pages + [g2_cow, g2_tail]:
         pool.release(grp)
     assert pc.tree.evict_until(10 ** 9) is False  # drains every span
     st = pc.stats()
     assert st["demotions"] >= 2
-    assert st["host_drops"] >= 1, "8-page host pool must have dropped"
-    assert st["host_pages_resident"] <= 8
+    assert st["host_drops"] >= 1, "4-page host pool must have dropped"
+    assert st["host_pages_resident"] <= 4
     assert pool.pages_in_use == 0
-    assert pool.available == 64 - 1
+    assert pool.available == 32 - 1
     assert set(pc.tree._host_nodes) == set(pc.host._entries)
     # insert through a host-resident child is a no-op (opacity)
     more = np.concatenate([seq, np.asarray([7, 7, 7], np.int32)])
-    fresh = [pool.alloc_group() for _ in range(4)]
+    fresh = [pool.alloc_page() for _ in range(4)]
     kept = pc.insert(more, fresh)
     assert kept == 0
     for g in fresh:
@@ -176,24 +174,24 @@ def test_chaos_fault_forces_true_drop_bookkeeping():
     """FaultInjector.host_demotion refusals turn demotions into plain
     drops — the tierless eviction path — without corrupting either
     tier's accounting."""
-    page, Hkv = 4, 2
+    page = 4
     fault = FaultInjector(exhaust_host_demotions=(0,))
-    pc = PrefixCache(32, Hkv, page, host_pool_pages=64, fault=fault)
-    pc.attach_host_tier(lambda groups: None,
-                        lambda payload, groups: None)
+    pc = PrefixCache(16, page, host_pool_pages=32, fault=fault)
+    pc.attach_host_tier(lambda pages: None,
+                        lambda payload, pages: None)
     pool = pc.pool
     for start in (0, 100):
         seq = np.arange(start, start + 8, dtype=np.int32)
-        groups = [pool.alloc_group() for _ in range(2)]
-        pc.insert(seq, groups)
-        for g in groups:
+        pages = [pool.alloc_page() for _ in range(2)]
+        pc.insert(seq, pages)
+        for g in pages:
             pool.release(g)
     assert pc.tree.evict_until(10 ** 9) is False
     st = pc.stats()
     assert fault.injected["host_exhausted"] == 1
     assert st["evictions"] == 1 and st["demotions"] == 1
     assert pool.pages_in_use == 0
-    assert pool.available == 32 - 1
+    assert pool.available == 16 - 1
 
 
 # ----------------------------------------------------------------------
@@ -266,7 +264,7 @@ def _run_three_ways(eng, cfg, reqs_fn, *, num_pages, spec=0,
 
 def _pressure_pool(cfg, slots_worth, max_prompt=24, max_gen=6):
     worst = -(-(max_prompt + max_gen + CHUNK - 1) // PAGE)
-    return slots_worth * worst * cfg.num_kv_heads + 1 + cfg.num_kv_heads
+    return slots_worth * worst + 1 + 1
 
 
 def test_warm_from_host_bitwise_greedy():
@@ -411,7 +409,7 @@ def test_chaos_host_exhaustion_stays_bitwise():
     sched = ContinuousScheduler(
         eng, batch=2, chunk=CHUNK, paged=True, page=PAGE,
         num_pages=_pressure_pool(cfg, 2),
-        host_pool_pages=4 * cfg.num_kv_heads,    # fits ~4 groups: drops
+        host_pool_pages=4,                       # fits ~4 pages: drops
         fault=fault)
     got = sched.run(reqs_fn())
     st = sched.stats()
@@ -425,11 +423,11 @@ def test_chaos_host_exhaustion_stays_bitwise():
 
 
 # ----------------------------------------------------------------------
-# TP-sharded pool: the gather-to-host layout (PR "TP-sharded paged
-# serving" satellite) — extract_pages_host must pick each page's
-# OWNING head-group plane of the [NP, G, page, d] payload, and the
-# restore must land the bytes back where the owner reads them, so the
-# d2h -> h2d round trip is bitwise on multi-chip pools too.
+# TP-sharded pool: the gather-to-host layout — extract_pages_host
+# assembles every page's heads from the chips that hold them
+# ([NP, Hkv, page, d] sharded on the head axis), and the restore lands
+# each head's bytes back on its chip, so the d2h -> h2d round trip is
+# bitwise on multi-chip pools too.
 # ----------------------------------------------------------------------
 
 
@@ -437,6 +435,7 @@ def test_extract_restore_bitwise_on_sharded_pool():
     import dataclasses as _dc
 
     import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     n = min(4, len(jax.devices()))
     mesh = jax.make_mesh((n,), ("tp",))
@@ -444,44 +443,35 @@ def test_extract_restore_bitwise_on_sharded_pool():
     model = AutoLLM.from_config(cfg, mesh)
     eng = Engine(model, max_seq=32, backend="flash")
     pc = eng.make_paged_slot_cache(2, page=PAGE)
-    Hkv, G = cfg.num_kv_heads, pc.head_groups
-    hkv_loc = Hkv // G
+    Hkv = cfg.num_kv_heads
     NP, page, d = pc.num_pages, pc.page, cfg.head_dim
-    # distinct bytes per (layer, page, PLANE): the owning plane's value
-    # is the one the round trip must preserve — a gather that read the
-    # wrong plane (or summed planes) cannot reproduce it
+    assert pc.kv_heads == Hkv
+    assert pc.pages_k[0].sharding.shard_shape(
+        pc.pages_k[0].shape) == (NP, Hkv // n, page, d)
+    # distinct bytes per (layer, page, head): a gather that read
+    # another chip's heads (or summed them) cannot reproduce them
     rng = np.random.RandomState(0)
-    pats_k = [rng.randn(NP, G, page, d).astype(np.float32)
+    shd = NamedSharding(model.mesh, P(None, model.axis, None, None))
+    pats_k = [rng.randn(NP, Hkv, page, d).astype(np.float32)
               for _ in pc.pages_k]
-    pats_v = [rng.randn(NP, G, page, d).astype(np.float32)
+    pats_v = [rng.randn(NP, Hkv, page, d).astype(np.float32)
               for _ in pc.pages_v]
     pc = _dc.replace(
         pc,
-        pages_k=tuple(jnp.asarray(p) for p in pats_k),
-        pages_v=tuple(jnp.asarray(p) for p in pats_v))
-    # one page per kv head (a head-ordered group, ids distinct)
-    ids = np.arange(1, 1 + Hkv, dtype=np.int32)
-    heads = np.arange(Hkv, dtype=np.int32)
-    out = eng.extract_pages_host(pc, ids, heads=heads)
+        pages_k=tuple(jax.device_put(jnp.asarray(p), shd) for p in pats_k),
+        pages_v=tuple(jax.device_put(jnp.asarray(p), shd) for p in pats_v))
+    ids = np.asarray([3, 1, 5], np.int32)
+    out = eng.extract_pages_host(pc, ids)
     k, v = out[0], out[1]
-    assert k.shape == (cfg.num_layers, Hkv, page, d)
+    assert k.shape == (cfg.num_layers, len(ids), Hkv, page, d)
     for li in range(cfg.num_layers):
-        for i, (pid, h) in enumerate(zip(ids, heads)):
-            own = int(h) // hkv_loc
-            np.testing.assert_array_equal(
-                k[li, i], pats_k[li][pid, own],
-                err_msg=f"layer {li} page {pid}: gathered bytes are "
-                        f"not the owning plane {own}'s")
-            np.testing.assert_array_equal(v[li, i], pats_v[li][pid, own])
+        np.testing.assert_array_equal(k[li], pats_k[li][ids])
+        np.testing.assert_array_equal(v[li], pats_v[li][ids])
     # restore into DIFFERENT pages of a zeroed pool, re-extract: the
     # round trip is bitwise through the sharded layout
     pc2 = eng.make_paged_slot_cache(2, page=PAGE)
-    ids2 = np.arange(1 + Hkv, 1 + 2 * Hkv, dtype=np.int32)
+    ids2 = np.asarray([2, 6, 4], np.int32)
     pc2 = eng.restore_pages_host(pc2, ids2, k, v)
-    out2 = eng.extract_pages_host(pc2, ids2, heads=heads)
+    out2 = eng.extract_pages_host(pc2, ids2)
     np.testing.assert_array_equal(out2[0], k)
     np.testing.assert_array_equal(out2[1], v)
-    # a TP-sharded pool refuses a head-blind extract (G > 1)
-    if G > 1:
-        with pytest.raises(ValueError, match="heads"):
-            eng.extract_pages_host(pc2, ids2)

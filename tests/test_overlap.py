@@ -121,10 +121,9 @@ def test_overlap_matches_sync(mode, paged):
 @pytest.mark.parametrize("mode", ["greedy", "spec"])
 def test_overlap_preemption_bitwise(mode):
     cfg, eng = _engine(mode)
-    Hkv = cfg.num_kv_heads
     page, chunk = 8, 4
     worst = -(-(10 + 8 + chunk - 1) // page)
-    tiny = worst * Hkv + 1 + Hkv          # ~1 slot's worst case
+    tiny = worst + 1 + 1                  # ~1 slot's worst case
 
     def reqs():
         rng = np.random.RandomState(3)
@@ -150,9 +149,8 @@ def test_overlap_preemption_bitwise(mode):
 
 def test_overlap_host_tier_bitwise():
     cfg, eng = _engine("greedy")
-    Hkv = cfg.num_kv_heads
     worst = -(-(10 + 8 + 4 - 1) // 8)
-    tiny = worst * Hkv + 1 + Hkv
+    tiny = worst + 1 + 1
 
     def reqs():
         rng = np.random.RandomState(5)
@@ -238,9 +236,8 @@ def test_overlap_no_new_programs():
     the same executables with the same shapes (a shape-driven recompile
     would silently hand back the host time the overlap just hid)."""
     cfg, eng = _engine("greedy")
-    Hkv = cfg.num_kv_heads
     worst = -(-(31 + 10 + 4 - 1) // 8)
-    pool = 2 * worst * Hkv + 1 + Hkv
+    pool = 2 * worst + 1 + 1
 
     def soak(overlap):
         sched = ContinuousScheduler(eng, batch=3, chunk=4, paged=True,

@@ -53,8 +53,7 @@ def test_paged_cache_scattered_table():
     perm = np.asarray(rng.permutation(NP), np.int32)
     inv = np.empty_like(perm)
     inv[perm] = np.arange(NP, dtype=np.int32)
-    table2 = jnp.asarray(inv)[cache.table.reshape(-1)].reshape(
-        cache.table.shape)
+    table2 = jnp.asarray(inv)[cache.table]
     pk = np.zeros_like(np.asarray(cache.pages_k))
     pv = np.zeros_like(np.asarray(cache.pages_v))
     pk[inv] = np.asarray(cache.pages_k)
@@ -70,10 +69,11 @@ def test_paged_cache_scattered_table():
 
 
 def test_paged_decode_stream_batch_widths():
-    """The batched page walk (W streams per grid step, VERDICT r4 next
-    #10) at W=8 (X=8 streams) and the W=1 fallback (X=3, coprime to
-    every batch width) must both match the contiguous oracle."""
-    for B, Hkv in ((4, 2), (3, 1)):       # X=8 -> W=8; X=3 -> W=1
+    """The batched page walk (W slots per grid step) at W=4 (4 slots
+    of 2 heads: 8 streams a step) and the W=1 fallback (3 slots,
+    coprime to every batch width) must both match the contiguous
+    oracle."""
+    for B, Hkv in ((4, 2), (3, 1)):       # B=4 -> W=4; B=3 -> W=1
         Hq, d, page, T = 2 * Hkv, 128, 16, 64
         rng = np.random.RandomState(B)
         cache = PagedKVCache.create(B, Hkv, T, d, page=page,
@@ -96,8 +96,8 @@ def test_paged_decode_stream_batch_widths():
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-4, rtol=2e-4,
                                    err_msg=f"B={B} Hkv={Hkv}")
-        # streams per step only regroups streams: bitwise the same
-        for bw in (w for w in (1, 4) if (B * Hkv) % w == 0):
+        # slots per step only regroups streams: bitwise the same
+        for bw in (w for w in (1, 2) if B % w == 0):
             again = flash_decode_paged(
                 q, cache.pages_k, cache.pages_v, cache.table,
                 jnp.int32(kv_len), block_w=bw)
@@ -128,7 +128,7 @@ def test_paged_slots_mixed_lengths_share_pool():
     lens = [37, 9, 50]
     for b, L in enumerate(lens):
         cache = cache.set_slot_table(
-            b, alloc.alloc_slot(Hkv, L + 1, page))
+            b, alloc.alloc_slot(L + 1, page))
     ks = [rng.randn(Hkv, L, d).astype(np.float32) * 0.5 for L in lens]
     vs = [rng.randn(Hkv, L, d).astype(np.float32) * 0.5 for L in lens]
     for b in range(B):
@@ -171,8 +171,8 @@ def test_paged_retire_returns_pages_to_free_list():
                                 dtype=jnp.float32)
     alloc = PageAllocator(cache.pages_k.shape[0])
     # slot 0: long-lived; slot 1: short request that retires
-    blk0 = alloc.alloc_slot(Hkv, 33, page)
-    blk1 = alloc.alloc_slot(Hkv, 10, page)
+    blk0 = alloc.alloc_slot(33, page)
+    blk1 = alloc.alloc_slot(10, page)
     cache = cache.set_slot_table(0, blk0).set_slot_table(1, blk1)
     k0 = rng.randn(Hkv, 30, d).astype(np.float32) * 0.5
     v0 = rng.randn(Hkv, 30, d).astype(np.float32) * 0.5
@@ -183,7 +183,7 @@ def test_paged_retire_returns_pages_to_free_list():
     # retire slot 1 -> its pages go back; a bigger request reuses them
     freed = blk1.ravel().tolist()
     alloc.free(freed)
-    blk2 = alloc.alloc_slot(Hkv, 25, page)
+    blk2 = alloc.alloc_slot(25, page)
     assert set(blk2.ravel()) & set(freed), \
         "readmission must draw from the freed pages"
     cache = cache.set_slot_table(1, blk2)
@@ -308,8 +308,8 @@ def test_refcounted_pages_error_paths():
     BEFORE the pool is touched, and the conservation invariant must
     hold after every refused call."""
     from triton_dist_tpu.models.prefix_cache import RefcountedPages
-    pool = RefcountedPages(8, n_kv_heads=2)
-    g = pool.alloc_group()
+    pool = RefcountedPages(8)
+    g = pool.alloc_page()
     pool.retain(g)
     pool.release(g)
     pool.release(g)            # refcount 2 -> 0: pages freed
@@ -322,15 +322,15 @@ def test_refcounted_pages_error_paths():
         else:
             raise AssertionError(f"{msg} must raise")
         assert pool.available + pool.outstanding == pool.num_pages
-    # double-release within one live group: first release frees, the
+    # double-release of one live page: first release frees, the
     # second underflows without corrupting the ledger
-    g2 = pool.alloc_group()
+    g2 = pool.alloc_page()
     pool.release(g2)
     try:
         pool.release(g2)
     except ValueError as e:
         assert "refcount underflow" in str(e)
-        assert "released a group twice" in str(e)
+        assert "released a page twice" in str(e)
     else:
         raise AssertionError("double release must raise")
     assert pool.available + pool.outstanding == pool.num_pages
@@ -350,31 +350,28 @@ def test_paged_decode_int8_scales_vs_dequant_oracle():
     B, Hq, Hkv, d, page, T = 2, 4, 2, 128, 16, 64
     rng = np.random.RandomState(3)
     maxp = T // page
-    X = B * Hkv
-    NP = 1 + X * maxp                    # page 0 = trash
+    NP = 1 + B * maxp                    # page 0 = trash
     lens = [37, 23]
     ks = rng.randn(B, Hkv, T, d).astype(np.float32) * 0.5
     vs = rng.randn(B, Hkv, T, d).astype(np.float32) * 0.5
     k8, k_s = quantize_kv_int8(jnp.asarray(ks))
     v8, v_s = quantize_kv_int8(jnp.asarray(vs))
     # lay the quantized streams out as pages + scale planes behind a
-    # sequential table (stream x, tile t -> page 1 + x*maxp + t)
-    pk = np.zeros((NP, page, d), np.int8)
-    pv = np.zeros((NP, page, d), np.int8)
-    sk = np.zeros((NP, page), np.float32)
-    sv = np.zeros((NP, page), np.float32)
-    table = np.zeros((X, maxp), np.int32)
+    # sequential table (slot b, tile t -> page 1 + b*maxp + t)
+    pk = np.zeros((NP, Hkv, page, d), np.int8)
+    pv = np.zeros((NP, Hkv, page, d), np.int8)
+    sk = np.zeros((NP, Hkv, page), np.float32)
+    sv = np.zeros((NP, Hkv, page), np.float32)
+    table = np.zeros((B, maxp), np.int32)
     for b in range(B):
-        for h in range(Hkv):
-            x = b * Hkv + h
-            for t in range(maxp):
-                pid = 1 + x * maxp + t
-                table[x, t] = pid
-                sl = slice(t * page, (t + 1) * page)
-                pk[pid] = np.asarray(k8)[b, h, sl]
-                pv[pid] = np.asarray(v8)[b, h, sl]
-                sk[pid] = np.asarray(k_s)[b, h, sl]
-                sv[pid] = np.asarray(v_s)[b, h, sl]
+        for t in range(maxp):
+            pid = 1 + b * maxp + t
+            table[b, t] = pid
+            sl = slice(t * page, (t + 1) * page)
+            pk[pid] = np.asarray(k8)[b, :, sl]
+            pv[pid] = np.asarray(v8)[b, :, sl]
+            sk[pid] = np.asarray(k_s)[b, :, sl]
+            sv[pid] = np.asarray(v_s)[b, :, sl]
     q = jnp.asarray(rng.randn(B, 1, Hq, d), jnp.float32) * 0.5
     kvl = jnp.asarray(lens, jnp.int32)
     out = jax.jit(lambda q, l: flash_decode_paged(
@@ -401,19 +398,26 @@ def _bits(a):
     return np.ascontiguousarray(np.asarray(a)).tobytes()
 
 
+def _paged(a, maxp):
+    """[B, Hkv, maxp*page(, d)] streams as pages [B, maxp, Hkv,
+    page(, d)]: a page holds a slot's positions for all its heads."""
+    B, Hkv = a.shape[:2]
+    a = a.reshape((B, Hkv, maxp, _PAGE) + a.shape[3:])
+    return np.moveaxis(a, 2, 1)
+
+
 def _scattered_pool(rng, ks, vs, maxp, extra=5):
     """Contiguous [B, Hkv, maxp*page, d] streams laid out as pages in
     a random physical order behind a table (page 0 and `extra` more
     stay unused, holding noise no stream may read)."""
     B, Hkv = ks.shape[:2]
-    X = B * Hkv
-    NP = 1 + X * maxp + extra
-    table = (1 + rng.permutation(NP - 1)[:X * maxp]
-             ).astype(np.int32).reshape(X, maxp)
-    pk = rng.randn(NP, _PAGE, _D).astype(ks.dtype)
-    pv = rng.randn(NP, _PAGE, _D).astype(vs.dtype)
-    pk[table] = ks.reshape(X, maxp, _PAGE, _D)
-    pv[table] = vs.reshape(X, maxp, _PAGE, _D)
+    NP = 1 + B * maxp + extra
+    table = (1 + rng.permutation(NP - 1)[:B * maxp]
+             ).astype(np.int32).reshape(B, maxp)
+    pk = rng.randn(NP, Hkv, _PAGE, _D).astype(ks.dtype)
+    pv = rng.randn(NP, Hkv, _PAGE, _D).astype(vs.dtype)
+    pk[table] = _paged(ks, maxp)
+    pv[table] = _paged(vs, maxp)
     return jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(table)
 
 
@@ -450,7 +454,7 @@ def _walk_ragged(rng):
 def _walk_per_stream_invariant(rng):
     """A stream's rows depend on its own queries, pages and lengths
     alone: the same two slots under another table width, among other
-    neighbours and in another grouping of streams per step come out
+    neighbours and in another grouping of slots per step come out
     BITWISE the same."""
     Hq, Hkv = 4, 2
     lens = [37, 150]
@@ -478,8 +482,8 @@ def _walk_per_stream_invariant(rng):
                                  block_w=block_w)
         return _bits(out[at:at + 2])
 
-    alone = run(12, [], [], None)
-    assert run(20, [300], [5], None) == alone       # X=8: W=8
+    alone = run(12, [], [], None)                   # B=2: W=2
+    assert run(20, [300], [5], None) == alone       # B=4: W=4
     assert run(12, [], [90, 1], 2) == alone         # other neighbours
     assert run(20, [7, 200], [], 1) == alone
 
@@ -520,11 +524,10 @@ def _walk_int8(rng):
     v8, v_s = quantize_kv_int8(jnp.asarray(vs))
     pk, pv, table = _scattered_pool(rng, np.asarray(k8), np.asarray(v8),
                                     maxp)
-    X = B * Hkv
-    sk = rng.rand(pk.shape[0], _PAGE).astype(np.float32)
-    sv = rng.rand(pk.shape[0], _PAGE).astype(np.float32)
-    sk[np.asarray(table)] = np.asarray(k_s).reshape(X, maxp, _PAGE)
-    sv[np.asarray(table)] = np.asarray(v_s).reshape(X, maxp, _PAGE)
+    sk = rng.rand(pk.shape[0], Hkv, _PAGE).astype(np.float32)
+    sv = rng.rand(pk.shape[0], Hkv, _PAGE).astype(np.float32)
+    sk[np.asarray(table)] = _paged(np.asarray(k_s), maxp)
+    sv[np.asarray(table)] = _paged(np.asarray(v_s), maxp)
     q = jnp.asarray(rng.randn(B, 1, Hq, _D), jnp.float32) * 0.5
     kvl = jnp.asarray(lens, jnp.int32)
     out = jax.jit(lambda q, l: flash_decode_paged(
@@ -540,19 +543,18 @@ def _walk_partial(rng):
     """The SP partial: two chips own alternate tiles; the one-tile
     stream is all chip 1's, so chip 0 returns the combine's neutral
     element for it, and the partials combine to the full softmax. At
-    two streams a step the empty slot is a step with no block."""
+    one slot a step the empty slot is a step with no block."""
     from triton_dist_tpu.kernels.flash_attn import lse_combine
     from triton_dist_tpu.kernels.paged_kv import flash_decode_paged_partial
     Hq, Hkv, maxp, S = 4, 2, 12, 2
     lens, qls = [9, 0, _BLOCK + 30, 60], [1, 0, 2, 1]
     B = len(lens)
     live = np.asarray(lens) > 0
-    X = B * Hkv
     ks, vs = _streams(rng, B, Hkv, maxp)
     pk, pv, table = _scattered_pool(rng, ks, vs, maxp)
     q = jnp.asarray(rng.randn(B, S, Hq, _D), jnp.float32) * 0.5
     kvl, ql = jnp.asarray(lens, jnp.int32), jnp.asarray(qls, jnp.int32)
-    tile = np.arange(maxp)[None].repeat(X, 0)
+    tile = np.arange(maxp)[None].repeat(B, 0)
     parts = []
     for chip in (0, 1):
         owned = (tile % 2 != chip).astype(np.int32)   # tile 0 -> chip 1
@@ -560,7 +562,7 @@ def _walk_partial(rng):
         local = np.where(owned != 0, np.asarray(table), 0)
         parts.append(flash_decode_paged_partial(
             q, pk, pv, jnp.asarray(local), kv_lens=kvl, q_lens=ql,
-            tile_owned=jnp.asarray(owned), block_w=2))
+            tile_owned=jnp.asarray(owned), block_w=1))
     acc0, m0, l0 = (np.asarray(a) for a in parts[0])
     assert (acc0[:2] == 0).all() and (l0[:2] == 0).all()
     assert (m0[:2] == np.float32(-1e30)).all()
@@ -576,3 +578,69 @@ def _walk_partial(rng):
     _walk_int8, _walk_partial], ids=lambda f: f.__name__[6:])
 def test_paged_walk(case):
     case(np.random.RandomState(30))
+
+
+# ---------------------------------------------------------------------
+# the cells' head counts: a page holds 8 (one chip of Qwen3-1.7B), 2 (a
+# TP=4 chip) or 10 (Phi-4's paired heads) heads of its slot, and the
+# walk picks W = 1 / 4 / 1 slots a step from them
+# ---------------------------------------------------------------------
+
+def _cell_walk(rng, Hkv, rep, block_w=None, lens=None):
+    """One launch of 12 slots at `Hkv` heads a page: empty slots as a
+    whole grid step at every W the walk may pick (slots 0..3) and
+    beside live ones. Returns (out, ref, live)."""
+    maxp = 10
+    if lens is None:
+        lens = [0, 0, 0, 0, 1, 0, _BLOCK + 1, 17,
+                maxp * _PAGE, 0, 33, _BLOCK]
+    B = len(lens)
+    ks, vs = _streams(rng, B, Hkv, maxp)
+    pk, pv, table = _scattered_pool(rng, ks, vs, maxp)
+    q = jnp.asarray(rng.randn(B, 1, rep * Hkv, _D), jnp.float32) * 0.5
+    kvl = jnp.asarray(lens, jnp.int32)
+    out = np.asarray(jax.jit(lambda q, l: flash_decode_paged(
+        q, pk, pv, table, jnp.max(l), kv_lens=l, block_w=block_w))(q, kvl))
+    ref = np.asarray(attention_cached_ref(
+        q, jnp.asarray(ks), jnp.asarray(vs), kvl))
+    return out, ref, np.asarray(lens) > 0
+
+
+@pytest.mark.parametrize("Hkv,rep,W", [(8, 2, 1), (2, 8, 4), (10, 4, 1)])
+def test_paged_walk_at_the_cells_heads(Hkv, rep, W):
+    from triton_dist_tpu.kernels.paged_kv import _slot_block
+    assert _slot_block("flash_decode_paged", None, 12, Hkv, None) == W
+    out, ref, live = _cell_walk(np.random.RandomState(35), Hkv, rep)
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-4,
+                               rtol=2e-4)
+    assert (out[~live] == 0).all()      # nothing attended: zeros
+
+
+@pytest.mark.parametrize("Hkv,rep", [(8, 2), (2, 8), (10, 4)])
+def test_paged_walk_bitwise_whatever_w_and_neighbours(Hkv, rep):
+    """A stream's output is bitwise independent of W and of which
+    slots share its step: the same launch at every W that divides it,
+    and the live slots alone in another order of neighbours."""
+    base, _, live = _cell_walk(np.random.RandomState(36), Hkv, rep)
+    for w in (2, 12):
+        again, _, _ = _cell_walk(np.random.RandomState(36), Hkv, rep,
+                                 block_w=w)
+        assert _bits(again) == _bits(base), f"block_w={w}"
+
+
+def test_allocator_hands_out_one_id_a_tile_and_conserves_them():
+    """A slot of n positions takes ceil(n / page) ids, whatever its
+    head count (a page holds them all), no id twice, and every id comes
+    back: available + outstanding == num_pages throughout."""
+    alloc = PageAllocator(64)
+    held = {}
+    for slot, n in enumerate((1, 15, 16, 17, 128, 129, 300)):
+        row = alloc.alloc_slot(n, 16)
+        assert row.shape == (-(-n // 16),) and row.dtype == np.int32
+        held[slot] = row
+        assert alloc.available + alloc.outstanding == alloc.num_pages
+    ids = np.concatenate(list(held.values()))
+    assert len(set(ids.tolist())) == len(ids) == alloc.outstanding
+    for row in held.values():
+        alloc.free(row)
+    assert alloc.available == 64 and alloc.outstanding == 0

@@ -47,15 +47,14 @@ def want(ids):
 
 
 def _rows(slot: int, maxp: int):
-    """Table rows of `slot`: its own run of pages (page 0 is trash)."""
-    return (1 + slot * maxp + np.arange(maxp, dtype=np.int32))[None]
+    """Table row of `slot`: its own run of pages (page 0 is trash)."""
+    return 1 + slot * maxp + np.arange(maxp, dtype=np.int32)
 
 
 def _admit(eng, pc, slot, prompt):
     maxp = pc.table.shape[1]
-    z = np.zeros((1,), np.int32)
     return eng.admit_slot_paged(pc, slot, prompt, _rows(slot, maxp), 0,
-                                z, z, 0)
+                                0, 0, 0)
 
 
 def test_the_small_model_has_every_kind_of_layer():
@@ -226,8 +225,8 @@ def test_window_bytes_do_not_grow_with_max_seq(model):
         .make_paged_slot_cache(2, page=PAGE).slot_bytes()
     assert small["window"] == large["window"] == 2 * 2 * 1 * 16 * 128 * 4
     assert small["state"] == large["state"] == 3 * (3 + 16) * 512 * 4
-    assert small["page_group"] == 2 * PAGE * 128 * 4
-    assert small["uniform_page_group"] == 4 * small["page_group"]
+    assert small["page"] == 2 * PAGE * 128 * 4
+    assert small["uniform_page"] == 4 * small["page"]
 
 
 # ----------------------------------------------------------------------
@@ -276,12 +275,12 @@ def test_cache_gauges_count_live_slots(model):
     sched.poll()
     st = sched.stats()
     sb = sched.slots.cache.slot_bytes()
-    groups = -(-(10 + 30 + CHUNK - 1) // PAGE)
-    assert st["cache_bytes{kind=pages}"] == groups * sb["page_group"]
+    pages = -(-(10 + 30 + CHUNK - 1) // PAGE)
+    assert st["cache_bytes{kind=pages}"] == pages * sb["page"]
     assert st["cache_bytes{kind=window}"] == sb["window"]
     assert st["cache_bytes{kind=state}"] == sb["state"]
     assert st["cache_uniform_bytes"] == \
-        groups * sb["uniform_page_group"] + sb["state"]
+        pages * sb["uniform_page"] + sb["state"]
 
 
 def test_preempted_stream_is_bitwise_the_unpreempted_one(model):

@@ -54,14 +54,14 @@ def _shared_prefix_requests(rng, cfg, prefix_len, spec, seed0=100):
 
 
 def test_radix_match_insert_split_refcounts():
-    page, Hkv = 4, 2
-    pc = PrefixCache(64, Hkv, page)
+    page = 4
+    pc = PrefixCache(64, page)
     pool = pc.pool
     seq = np.arange(10, dtype=np.int32)          # pages 0..2 (10 tokens)
-    groups = [pool.alloc_group() for _ in range(3)]
-    assert pc.insert(seq, groups) == 10
+    pages = [pool.alloc_page() for _ in range(3)]
+    assert pc.insert(seq, pages) == 10
     # tree holds one ref on top of ours
-    assert all(pool.refcount(p) == 2 for g in groups for p in g)
+    assert all(pool.refcount(p) == 2 for p in pages)
     # full / partial / capped matches
     m, g = pc.tree.match(seq)
     assert m == 10 and len(g) == 3
@@ -72,20 +72,20 @@ def test_radix_match_insert_split_refcounts():
     # divergence mid-node at token 7 (mid-page): insert splits, and the
     # boundary page (page 1) gains a ref for the second node
     seq2 = np.concatenate([seq[:7], np.asarray([99, 98, 97], np.int32)])
-    g2_cow, g2_tail = pool.alloc_group(), pool.alloc_group()
+    g2_cow, g2_tail = pool.alloc_page(), pool.alloc_page()
     # the diverging branch supplies its own complete boundary page (the
     # CoW page); index 0 of its page list is never read (leaf starts in
     # page 1)
     assert pc.insert(seq2, [None, g2_cow, g2_tail]) == 3
     m, g = pc.tree.match(seq2)
     assert m == 10
-    assert np.array_equal(g[1], g2_cow)          # the CoW page, not groups[1]
+    assert g[1] == g2_cow                        # the CoW page, not pages[1]
     m, g = pc.tree.match(seq)                    # original branch intact
-    assert m == 10 and np.array_equal(g[1], groups[1])
+    assert m == 10 and g[1] == pages[1]
     # boundary page 1 of the ORIGINAL chain: ours + head node + tail node
-    assert all(pool.refcount(p) == 3 for p in groups[1])
+    assert pool.refcount(pages[1]) == 3
     # release our refs; evict everything; pool must drain to empty
-    for grp in groups + [g2_cow, g2_tail]:
+    for grp in pages + [g2_cow, g2_tail]:
         pool.release(grp)
     assert not pc.tree.evict_until(10 ** 9)      # cannot satisfy, drains all
     assert pool.pages_in_use == 0
@@ -99,18 +99,17 @@ def test_refcount_random_admit_retire_evict():
     table mirrors outstanding pages exactly, and no page is writable
     by two live slots at once."""
     rng = np.random.RandomState(0)
-    page, Hkv, num_pages = 4, 2, 40
-    pc = PrefixCache(num_pages, Hkv, page)
+    page, num_pages = 4, 20
+    pc = PrefixCache(num_pages, page)
     pool = pc.pool
     alloc = pool._alloc
     vocab = 6                        # tiny vocab -> heavy prefix overlap
-    live = {}                        # slot -> (tokens, groups, writable)
+    live = {}                        # slot -> (tokens, pages, writable)
 
     def check():
         assert alloc.available + alloc.outstanding == num_pages
         assert pool.pages_in_use == alloc.outstanding - 1   # - trash
-        writable = [p for (_, _, w) in live.values()
-                    for grp in w for p in grp]
+        writable = [p for (_, _, w) in live.values() for p in w]
         assert len(writable) == len(set(writable)), \
             "page writable by two slots"
 
@@ -129,34 +128,34 @@ def test_refcount_random_admit_retire_evict():
             if boundary is not None:
                 pool.retain(boundary)
             need = -(-(n + gen + 3) // page) - full
-            if not pc.ensure_pages(need * Hkv):
+            if not pc.ensure_pages(need):
                 for g in retained + ([boundary] if r else []):
                     pool.release(g)
                 check()
                 continue
-            fresh = [pool.alloc_group() for _ in range(need)]
+            fresh = [pool.alloc_page() for _ in range(need)]
             if boundary is not None:
                 pool.release(boundary)
-            groups = retained + fresh
+            pages = retained + fresh
             # generated tokens extend the sequence before insert
             toks_full = np.concatenate(
                 [toks, rng.randint(0, vocab, size=(gen,))]
             ).astype(np.int32)
-            pc.insert(toks, groups[:-(-n // page)])
-            live[step] = (toks_full, groups, fresh)
+            pc.insert(toks, pages[:-(-n // page)])
+            live[step] = (toks_full, pages, fresh)
         elif op < 0.85 and live:
             slot = list(live)[int(rng.randint(len(live)))]
-            toks_full, groups, _ = live.pop(slot)
+            toks_full, pages, _ = live.pop(slot)
             pc.insert(toks_full,
-                      groups[:-(-len(toks_full) // page)])
-            for g in groups:
+                      pages[:-(-len(toks_full) // page)])
+            for g in pages:
                 pool.release(g)
         else:
             pc.tree.evict_until(pool.available + int(rng.randint(1, 9)))
         check()
     # drain: retire everything, evict the whole tree -> zero leaks
-    for toks_full, groups, _ in live.values():
-        for g in groups:
+    for toks_full, pages, _ in live.values():
+        for g in pages:
             pool.release(g)
     pc.tree.evict_until(10 ** 9)
     assert pool.pages_in_use == 0
@@ -285,7 +284,7 @@ def test_eviction_pressure_stays_bitwise():
     cfg, model = _model()
     eng = Engine(model, max_seq=64, backend="xla")
     rng = np.random.RandomState(7)
-    Hkv, page = cfg.num_kv_heads, 8
+    page = 8
     pre_a = rng.randint(0, cfg.vocab_size, size=(11,)).astype(np.int32)
     pre_b = rng.randint(0, cfg.vocab_size, size=(9,)).astype(np.int32)
     reqs = []
@@ -296,7 +295,7 @@ def test_eviction_pressure_stays_bitwise():
         ).astype(np.int32)
         reqs.append(Request(rid=i, ids=ids, gen_len=5 + (i % 3), seed=i))
     worst = -(-(22 + 7 + 3) // page)
-    num_pages = 2 * worst * Hkv + 1 + Hkv
+    num_pages = 2 * worst + 1 + 1
     runs = {}
     for pc_on, npages in ((False, None), (True, num_pages)):
         sched = ContinuousScheduler(eng, batch=2, chunk=4, paged=True,
@@ -368,9 +367,9 @@ def test_pool_exhaustion_preempts_instead_of_rejecting():
     cfg, model = _model()
     eng = Engine(model, max_seq=64, backend="xla")
     rng = np.random.RandomState(6)
-    Hkv, page = cfg.num_kv_heads, 8
+    page = 8
     ids = rng.randint(0, cfg.vocab_size, size=(2, 20)).astype(np.int32)
-    num_pages = -(-(20 + 6 + 3) // page) * Hkv + 1
+    num_pages = -(-(20 + 6 + 3) // page) + 1
     reqs = lambda: [Request(rid=i, ids=ids[i], gen_len=6)
                     for i in range(2)]
     sched = ContinuousScheduler(eng, batch=2, chunk=4, paged=True,

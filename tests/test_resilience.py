@@ -71,11 +71,11 @@ def _mixed_requests(cfg, spec, seed=42, repetitive=False):
 
 
 def _small_pool(cfg, max_prompt, max_gen):
-    """Pages for ONE worst-case slot (+ trash + one spare group): with
+    """Pages for ONE worst-case slot (+ trash + one spare page): with
     batch 2+ this guarantees pool pressure, and any single request of
     the workload still fits alone — preemption, not rejection."""
     worst = -(-(max_prompt + max_gen + CHUNK - 1) // PAGE)
-    return worst * cfg.num_kv_heads + 1 + cfg.num_kv_heads
+    return worst + 1 + 1
 
 
 def _assert_no_leak(sched):
@@ -468,8 +468,8 @@ def test_chaos_host_tier_exhaustion_no_leak():
     fault = FaultInjector(exhaust_host_demotions=(1, 2))
     sched = ContinuousScheduler(
         eng, batch=2, chunk=CHUNK, paged=True, prefix_cache=True,
-        page=PAGE, num_pages=_small_pool(cfg, 21, 8) + cfg.num_kv_heads,
-        host_pool_pages=6 * cfg.num_kv_heads, fault=fault)
+        page=PAGE, num_pages=_small_pool(cfg, 21, 8) + 1,
+        host_pool_pages=6, fault=fault)
     got = sched.run(reqs())
     st = sched.stats()
     assert st["demotions"] > 0, st

@@ -142,13 +142,12 @@ def test_paged_partial_combine_vs_oracle():
     from triton_dist_tpu.kernels.flash_attn import lse_combine
     from triton_dist_tpu.kernels.paged_kv import (
         flash_decode_paged, flash_decode_paged_partial)
-    B, Hq, Hkv, d, page, maxp, NP = 2, 4, 2, 32, 8, 4, 33
-    X = B * Hkv
+    B, Hq, Hkv, d, page, maxp, NP = 2, 4, 2, 32, 8, 4, 17
     rng = np.random.RandomState(7)
-    pk = jnp.asarray(rng.randn(NP, page, d), jnp.float32) * 0.5
-    pv = jnp.asarray(rng.randn(NP, page, d), jnp.float32) * 0.5
+    pk = jnp.asarray(rng.randn(NP, Hkv, page, d), jnp.float32) * 0.5
+    pv = jnp.asarray(rng.randn(NP, Hkv, page, d), jnp.float32) * 0.5
     tbl = jnp.asarray(
-        rng.permutation(NP - 1)[:X * maxp].reshape(X, maxp) + 1,
+        rng.permutation(NP - 1)[:B * maxp].reshape(B, maxp) + 1,
         jnp.int32)
     q = jnp.asarray(rng.randn(B, 1, Hq, d), jnp.float32) * 0.5
     kv_lens = jnp.asarray([13, 27], jnp.int32)
@@ -157,7 +156,7 @@ def test_paged_partial_combine_vs_oracle():
     accs, ms, ls = [], [], []
     for s in range(2):          # 2 fake chips, tiles split by parity
         own = np.broadcast_to(
-            (np.arange(maxp)[None, :] % 2 == s), (X, maxp))
+            (np.arange(maxp)[None, :] % 2 == s), (B, maxp))
         acc, m, l = flash_decode_paged_partial(
             q, pk, pv, tbl, kv_lens=kv_lens,
             tile_owned=jnp.asarray(own.astype(np.int32)))
@@ -167,8 +166,8 @@ def test_paged_partial_combine_vs_oracle():
     np.testing.assert_allclose(np.asarray(out), np.asarray(full),
                                atol=2e-5, rtol=2e-5)
     # and against the extended oracle on the gathered cache
-    kfull = pk[tbl].reshape(B, Hkv, maxp * page, d)
-    vfull = pv[tbl].reshape(B, Hkv, maxp * page, d)
+    from triton_dist_tpu.kernels.paged_kv import gather_pages
+    kfull, vfull = gather_pages(pk, tbl), gather_pages(pv, tbl)
     ref = sp_flash_decode_ref(q, kfull, vfull, kv_lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=5e-5, rtol=1e-5)

@@ -131,10 +131,8 @@ def test_long_context_capacity_sp():
     stream bitwise equal to a single-chip reference on a pool big
     enough for both. Max context grew x sp."""
     cfg, _ = _model(1)
-    Hkv = cfg.num_kv_heads
     page = 8
-    chip_groups = 4                      # one chip's pool: 4 groups
-    chip_pages = chip_groups * Hkv + Hkv
+    chip_pages = 4 + 1                   # one chip's pool: 4 pages + 1
     long_req = Request(rid="long",
                        ids=(np.arange(40) % cfg.vocab_size
                             ).astype(np.int32),
@@ -224,19 +222,17 @@ def test_sp_capability_gates():
 
 def test_sp_allocator_per_shard_unit():
     """Host-side allocator unit: the page-id space partitions per
-    shard, fresh groups ROTATE across shards (consecutive logical
+    shard, fresh pages ROTATE across shards (consecutive logical
     tiles interleave chips), frees return to the page's own shard, and
     conservation holds per shard through arbitrary churn. The trash
     reserves shard 0's page 0."""
     from triton_dist_tpu.models.prefix_cache import RefcountedPages
-    pool = RefcountedPages(4 * 8, n_kv_heads=2, shards=4)
+    pool = RefcountedPages(4 * 8, shards=4)
     assert pool.trash == 0 and pool.shards == 4
     assert pool.pages_per_shard == 8
-    gs = [pool.alloc_group() for _ in range(6)]
-    shard_of = lambda g: {int(p) // 8 for p in g}
-    # rotation: consecutive groups land on different shards
-    seen = [shard_of(g) for g in gs]
-    assert len({frozenset(s) for s in seen[:4]}) > 1
+    gs = [pool.alloc_page() for _ in range(6)]
+    # rotation: consecutive pages land on different shards
+    assert len({g // 8 for g in gs[:4]}) == 4
     for g in gs[::2]:
         pool.release(g)
     av, outst = pool.available_by_shard, pool.outstanding_by_shard
@@ -249,7 +245,7 @@ def test_sp_allocator_per_shard_unit():
     assert pool.available == 4 * 8 - 1          # trash stays reserved
     # divisibility is validated at construction
     with pytest.raises(ValueError, match="divide"):
-        RefcountedPages(31, n_kv_heads=2, shards=4)
+        RefcountedPages(31, shards=4)
 
 
 def _dist_combine_usable():
@@ -336,13 +332,12 @@ def test_sp_preemption_host_tier_and_chaos():
     zero-leak invariant checked after every arm."""
     from triton_dist_tpu.runtime.chaos import FaultInjector
     cfg, _ = _model(1)
-    Hkv = cfg.num_kv_heads
-    # ~6 usable page groups: two mid-size slots fit, further
+    # ~6 usable pages: two mid-size slots fit, further
     # admissions must evict (and preempt once victims have progress)
-    pool_kw = dict(num_pages=(6 * Hkv + _SP) // _SP * _SP, page=8)
+    pool_kw = dict(num_pages=(6 + _SP) // _SP * _SP, page=8)
     s1 = _assert_same_streams(cfg, {}, pool_kw, "preemption pressure sp")
     _assert_per_shard_conservation(s1)
-    tier = dict(pool_kw, host_pool_pages=64 * Hkv)
+    tier = dict(pool_kw, host_pool_pages=64)
     s2 = _assert_same_streams(cfg, {}, tier, "host tier sp")
     _assert_per_shard_conservation(s2)
     pressure = (s2.stats()["demotions"] + s1.stats()["evictions"]

@@ -102,22 +102,20 @@ def test_flash_decode_paged_qlens_vs_ref():
     rng = np.random.RandomState(1)
     B, Hq, Hkv, d, T, S, page = 2, 4, 2, 32, 64, 3, 8
     maxp = T // page
-    X = B * Hkv
     q = jnp.asarray(rng.randn(B, S, Hq, d), jnp.float32)
     k = np.asarray(rng.randn(B, Hkv, T, d), np.float32)
     v = np.asarray(rng.randn(B, Hkv, T, d), np.float32)
-    NP = X * maxp
-    pk = np.zeros((NP, page, d), np.float32)
-    pv = np.zeros((NP, page, d), np.float32)
-    table = np.zeros((X, maxp), np.int32)
+    NP = B * maxp
+    pk = np.zeros((NP, Hkv, page, d), np.float32)
+    pv = np.zeros((NP, Hkv, page, d), np.float32)
+    table = np.zeros((B, maxp), np.int32)
     # scramble the physical layout: page ids in reverse order
     pid = NP - 1
-    for x in range(X):
-        b, h = divmod(x, Hkv)
+    for b in range(B):
         for t in range(maxp):
-            table[x, t] = pid
-            pk[pid] = k[b, h, t * page:(t + 1) * page]
-            pv[pid] = v[b, h, t * page:(t + 1) * page]
+            table[b, t] = pid
+            pk[pid] = k[b, :, t * page:(t + 1) * page]
+            pv[pid] = v[b, :, t * page:(t + 1) * page]
             pid -= 1
     kv_lens = jnp.asarray([17, 50], jnp.int32)
     q_lens = jnp.asarray([3, 2], jnp.int32)

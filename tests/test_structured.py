@@ -421,7 +421,6 @@ def test_fork_under_real_pool_pressure():
     admissions (prefix-cache hit keeps them bitwise) and evictions/
     preemptions fire for real. Streams must equal the ample-pool run."""
     cfg, _, eng = _engine()
-    Hkv = cfg.num_kv_heads
     worst = -(-(10 + 8 + 4 - 1) // 4)        # pages per full slot head
     reqs = lambda: [
         Request(rid="F", ids=_prompt(cfg, 10, seed=8), gen_len=8,
@@ -436,7 +435,7 @@ def test_fork_under_real_pool_pressure():
     ref = ample.run(reqs())
     tight = ContinuousScheduler(eng, batch=4, chunk=4, paged=True,
                                 page=4,
-                                num_pages=2 * worst * Hkv + 1 + Hkv)
+                                num_pages=2 * worst + 1 + 1)
     got = tight.run(reqs())
     for rid in ref:
         np.testing.assert_array_equal(got[rid], ref[rid],

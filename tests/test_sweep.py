@@ -151,7 +151,7 @@ def test_registry_declares_schedule_spaces():
 # ---------------------------------------------------------------------------
 
 def test_prune_drops_indivisible_stream_block():
-    """flash_decode_paged's canonical build has X = B*Hkv = 4 streams:
+    """flash_decode_paged's canonical build has B = 4 slots:
     block_w=8 cannot divide them and must be pruned statically, with
     the reason recorded; the legal grouping survives intact."""
     spec = kernel_registry()["flash_decode_paged"]
@@ -165,14 +165,15 @@ def test_estimator_counts_paged_walk_scratch():
     """The walk's pools stay in HBM and its double K and V block
     buffers are VMEM scratch, not BlockSpec blocks: the shared
     estimator (the pruner's and tdcheck's) must count them, 2 planes x
-    2 halves x W streams x 128 positions x d, and grow with W."""
+    2 halves x W slots x Hkv heads x 128 positions x d, and grow with
+    W."""
     from triton_dist_tpu.analysis.contracts import estimate_vmem
     from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
-    B, Hq, Hkv, d, page, maxp = 4, 4, 2, 128, 16, 16
-    NP = B * Hkv * maxp
+    B, Hq, Hkv, d, page, maxp = 8, 4, 2, 128, 16, 16
+    NP = B * maxp
     q = jnp.zeros((B, 1, Hq, d), jnp.bfloat16)
-    pages = jnp.zeros((NP, page, d), jnp.bfloat16)
-    table = jnp.arange(NP, dtype=jnp.int32).reshape(B * Hkv, maxp)
+    pages = jnp.zeros((NP, Hkv, page, d), jnp.bfloat16)
+    table = jnp.arange(NP, dtype=jnp.int32).reshape(B, maxp)
     lens = jnp.full((B,), 40, jnp.int32)
 
     def vmem(w):
@@ -181,7 +182,7 @@ def test_estimator_counts_paged_walk_scratch():
                                           block_w=w),
             (q, pages, pages, table, lens))
 
-    buffers = lambda w: 2 * 2 * w * 128 * d * 2
+    buffers = lambda w: 2 * 2 * w * Hkv * 128 * d * 2
     assert buffers(8) <= vmem(8) < 2 * buffers(8)
     assert vmem(8) - vmem(2) >= buffers(8) - buffers(2)
 
@@ -344,12 +345,13 @@ def test_flash_decode_paged_bitwise_identical_under_cache(monkeypatch,
     block_w=2 must match the default divisor pick (4) byte-for-byte."""
     from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
     rng = np.random.RandomState(5)
-    B, Hq, Hkv, d, page, maxp = 2, 4, 2, 128, 128, 4
-    NP = B * Hkv * maxp
+    B, Hq, Hkv, d, page, maxp = 4, 4, 2, 128, 128, 4
+    NP = B * maxp
     q = jnp.asarray(rng.randn(B, 1, Hq, d), jnp.float32)
-    pages = jnp.asarray(rng.randn(NP, page, d), jnp.float32)
-    table = jnp.arange(NP, dtype=jnp.int32).reshape(B * Hkv, maxp)
-    kv_lens = jnp.asarray([page * maxp, page], jnp.int32)
+    pages = jnp.asarray(rng.randn(NP, Hkv, page, d), jnp.float32)
+    table = jnp.arange(NP, dtype=jnp.int32).reshape(B, maxp)
+    kv_lens = jnp.asarray([page * maxp, page, 3, 2 * page + 5],
+                          jnp.int32)
     path = _store(monkeypatch, tmp_path)
     base = _bits(flash_decode_paged(q, pages, pages, table, None,
                                     kv_lens=kv_lens))
@@ -367,19 +369,19 @@ def test_flash_decode_paged_bitwise_identical_under_cache(monkeypatch,
 
 def test_paged_tuned_block_w_reclamps_at_foreign_shape(monkeypatch,
                                                        tmp_path):
-    """A tune-cache block_w that does not divide this call's X = B*Hkv
-    (single-bucket fallback from a sweep at another GQA ratio) must
+    """A tune-cache block_w that does not divide this call's slots B
+    (single-bucket fallback from a sweep at another batch) must
     re-clamp to the divisor ladder, not raise at serving time — only an
-    EXPLICIT indivisible block_w is an error. Exercised at B=1, Hkv=2
-    (X=2) against a cached winner of 8."""
+    EXPLICIT indivisible block_w is an error. Exercised at B=2, Hkv=2
+    against a cached winner of 8."""
     from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
     rng = np.random.RandomState(6)
-    B, Hq, Hkv, d, page, maxp = 1, 4, 2, 128, 128, 2
-    NP = B * Hkv * maxp
+    B, Hq, Hkv, d, page, maxp = 2, 4, 2, 128, 128, 2
+    NP = B * maxp
     q = jnp.asarray(rng.randn(B, 1, Hq, d), jnp.float32)
-    pages = jnp.asarray(rng.randn(NP, page, d), jnp.float32)
-    table = jnp.arange(NP, dtype=jnp.int32).reshape(B * Hkv, maxp)
-    kv_lens = jnp.asarray([page * maxp], jnp.int32)
+    pages = jnp.asarray(rng.randn(NP, Hkv, page, d), jnp.float32)
+    table = jnp.arange(NP, dtype=jnp.int32).reshape(B, maxp)
+    kv_lens = jnp.asarray([page * maxp, page + 1], jnp.int32)
     path = _store(monkeypatch, tmp_path)
     base = _bits(flash_decode_paged(q, pages, pages, table, None,
                                     kv_lens=kv_lens))

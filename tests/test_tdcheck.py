@@ -293,10 +293,10 @@ def test_races_clean_tick_jaxpr():
 
 def test_races_flags_write_collision():
     """Two slots mapped to one physical page at their write position."""
-    table = np.arange(16, dtype=np.int32).reshape(4, 4)
-    table[2, 0] = table[0, 0]            # slot 1 head 0 == slot 0 head 0
+    table = np.arange(8, dtype=np.int32).reshape(2, 4)
+    table[1, 0] = table[0, 0]            # slot 1's page == slot 0's
     r = races.check_state(table, np.zeros(2, np.int32),
-                          np.ones(2, bool), 8, 2, trash=15)
+                          np.ones(2, bool), 8, trash=15)
     msgs = _errors(r)
     assert any("write race" in m for m in msgs), msgs
 
@@ -305,27 +305,27 @@ def test_races_flags_cow_violation():
     """Slot 0's write page sits inside slot 1's mapped valid extent —
     the reader sees the writer's bytes (the exact hazard the
     boundary-page CoW exists to prevent)."""
-    table = np.arange(16, dtype=np.int32).reshape(4, 4)
-    table[2, 0] = 99  # decouple slot 1's write tile from slot 0's...
-    table[2, 1] = table[0, 0]   # ...but its EXTENT maps slot 0's page
+    table = np.arange(8, dtype=np.int32).reshape(2, 4)
+    table[1, 0] = 99  # decouple slot 1's write tile from slot 0's...
+    table[1, 1] = table[0, 0]   # ...but its EXTENT maps slot 0's page
     r = races.check_state(table, np.asarray([0, 9], np.int32),
-                          np.ones(2, bool), 8, 2, trash=15)
+                          np.ones(2, bool), 8, trash=15)
     msgs = _errors(r)
     assert any("CoW violation" in m for m in msgs), msgs
     # a slot tail-extending a page only the radix TREE shares
     # (refcount 2, no other slot's extent) is the SANCTIONED path
-    clean = races.check_state(np.arange(16, dtype=np.int32
-                                        ).reshape(4, 4),
+    clean = races.check_state(np.arange(4, dtype=np.int32
+                                        ).reshape(1, 4),
                               np.asarray([4], np.int32),
-                              np.ones(1, bool), 8, 2, trash=15,
+                              np.ones(1, bool), 8, trash=15,
                               refcount=lambda p: 2)
     assert not clean.errors, _errors(clean)
 
 
 def test_races_flags_write_to_freed_page():
-    table = np.arange(16, dtype=np.int32).reshape(4, 4)
+    table = np.arange(4, dtype=np.int32).reshape(1, 4)
     r = races.check_state(table, np.zeros(1, np.int32),
-                          np.ones(1, bool), 8, 2, trash=15,
+                          np.ones(1, bool), 8, trash=15,
                           refcount=lambda p: 0)
     msgs = _errors(r)
     assert msgs and all("freed page" in m for m in msgs), msgs
@@ -419,10 +419,9 @@ def test_races_fork_sharing_legal_and_violation_fires():
     # prevent
     table = np.asarray(jax.device_get(slots.cache.table)).copy()
     pos = np.asarray(jax.device_get(slots.pos))
-    Hkv = cfg.num_kv_heads
     fork = int(np.nonzero(slots._is_fork)[0][0])
-    shared_page = int(slots._groups[fork][0][0])
-    table[fork * Hkv, int(pos[fork]) // slots.page] = shared_page
+    shared_page = int(slots._pages[fork][0])
+    table[fork, int(pos[fork]) // slots.page] = shared_page
     slots.cache = dataclasses.replace(slots.cache,
                                       table=jnp.asarray(table))
     r = races.check_scheduler(sched)

@@ -133,13 +133,12 @@ def test_paged_preemption_and_host_tier_tp_equals_tp1():
     host_pool_pages the evicted spans take the d2h/h2d round trip on
     the sharded pool."""
     cfg, _ = _model(1)
-    Hkv = cfg.num_kv_heads
-    # ~9 usable page groups: two mid-size slots fit, the third
+    # ~9 usable pages: two mid-size slots fit, the third
     # admission must evict (and preempt once victims have progress)
-    pool_kw = dict(num_pages=9 * Hkv + 1, page=8)
+    pool_kw = dict(num_pages=9 + 1, page=8)
     s1 = _assert_same_streams(cfg, dict(backend="flash"), pool_kw,
                               "preemption pressure")
-    tier = dict(pool_kw, host_pool_pages=64 * Hkv)
+    tier = dict(pool_kw, host_pool_pages=64)
     sched = _assert_same_streams(cfg, dict(backend="flash"), tier,
                                  "host tier")
     pressure = (sched.stats()["demotions"] + s1.stats()["evictions"]
@@ -244,3 +243,93 @@ def test_paged_gemm_ar_backend_dispatches_comm_kernels():
     for r in reqs:
         np.testing.assert_array_equal(out[r.rid], out_ref[r.rid],
                                       err_msg=f"rid={r.rid}")
+
+
+def test_every_chip_reads_only_its_own_head_shard():
+    """One decode tick of the paged attend on the TP=4 mesh. A chip's
+    shard of a plane is [NP, Hkv/4, page, d]: its own kv heads of every
+    page. With every OTHER chip's heads of the pool turned to NaN, the
+    query heads that chip computes must come out bitwise what the clean
+    pool gives them (and the poisoned chips' NaN): nothing crosses."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from triton_dist_tpu.layers import TP_Attn, precompute_rope
+    from triton_dist_tpu.models.kv_cache import PagedSlotCache
+    if len(jax.devices()) < _TP:
+        pytest.skip(f"needs >= {_TP} devices")
+    mesh = jax.make_mesh((_TP,), ("tp",))
+    B, Hq, Hkv, hd, D, page, T = 3, 8, 4, 32, 64, 8, 32
+    rng = np.random.RandomState(35)
+    w = lambda *s: rng.randn(*s).astype(np.float32) * 0.1
+    attn = TP_Attn.init(w(D, Hq * hd), w(D, Hkv * hd), w(D, Hkv * hd),
+                        w(Hq * hd, D), mesh=mesh, n_heads=Hq,
+                        n_kv_heads=Hkv, head_dim=hd)
+    cos, sin = precompute_rope(hd, T)
+    pc = PagedSlotCache.create(1, B, T, Hkv, hd, page=page,
+                               num_pages=B * (T // page) + 1, mesh=mesh,
+                               dtype=jnp.float32)
+    NP = pc.num_pages
+    assert pc.pages_k[0].sharding.shard_shape(pc.pages_k[0].shape) == (
+        NP, Hkv // _TP, page, hd)
+    assert pc.table.shape == (B, T // page)
+    shd = NamedSharding(mesh, P(None, "tp", None, None))
+    pk, pv = (rng.randn(NP, Hkv, page, hd).astype(np.float32)
+              for _ in range(2))
+    table = jnp.asarray(
+        1 + rng.permutation(NP - 1).reshape(B, T // page), jnp.int32)
+    pos = jnp.asarray([5, 17, 30], jnp.int32)
+    qkv = jax.device_put(
+        jnp.asarray(rng.randn(B, (Hq + 2 * Hkv) * hd), jnp.float32),
+        NamedSharding(mesh, P(None, "tp")))
+
+    @jax.jit
+    def tick(a, qkv, k, v):
+        o, kv = a._attend_paged_slots(qkv, cos, sin, B, (k, v), table,
+                                      pos, "flash")
+        return o, kv[0]
+
+    def run(k, v):
+        o, k2 = tick(attn, qkv, jax.device_put(jnp.asarray(k), shd),
+                     jax.device_put(jnp.asarray(v), shd))
+        assert k2.sharding.is_equivalent_to(shd, 4)
+        return np.asarray(o).reshape(B, _TP, -1)    # a chip's columns
+
+    clean = run(pk, pv)
+    assert np.isfinite(clean).all()
+    hkv = Hkv // _TP
+    for chip in range(_TP):
+        own = slice(chip * hkv, (chip + 1) * hkv)
+        k, v = np.full_like(pk, np.nan), np.full_like(pv, np.nan)
+        k[:, own], v[:, own] = pk[:, own], pv[:, own]
+        got = run(k, v)
+        np.testing.assert_array_equal(got[:, chip], clean[:, chip])
+        assert np.isnan(np.delete(got, chip, axis=1)).all()
+
+
+@pytest.mark.parametrize("tp,heads,want", [(1, 8, 32768), (_TP, 8, 8192),
+                                           (1, 10, 40960)])
+def test_kv_page_copy_bytes_at_the_cells_shapes(tp, heads, want):
+    """What one K-plane copy of the decode walk moves on a chip, at
+    the three cells' layouts (page 16, d 128, bf16): Qwen3-1.7B's 8
+    heads on one chip, 2 of 8 on a TP=4 chip, Phi-4's 10 paired heads."""
+    import jax.numpy as jnp
+    from triton_dist_tpu.models.kv_cache import PagedSlotCache
+    if len(jax.devices()) < tp:
+        pytest.skip(f"needs >= {tp} devices")
+    mesh = jax.make_mesh((tp,), ("tp",))
+    pc = PagedSlotCache.create(1, 2, 64, heads, 128, page=16,
+                               num_pages=9, mesh=mesh,
+                               dtype=jnp.bfloat16)
+    assert pc.page_copy_bytes == want
+
+
+def test_kv_page_copy_bytes_is_in_stats_and_the_registry():
+    cfg, _ = _model(_TP)
+    sched = ContinuousScheduler(_engine(_TP, backend="flash"), batch=2,
+                                paged=True, chunk=2, page=8)
+    want = (cfg.num_kv_heads // _TP) * 8 * cfg.head_dim \
+        * np.dtype(cfg.jax_dtype).itemsize
+    assert sched.stats()["kv_page_copy_bytes"] == want
+    from triton_dist_tpu.runtime.telemetry import prometheus_text
+    assert f"tdtpu_kv_page_copy_bytes {want:g}\n" in \
+        prometheus_text(sched.slots.tele.registry) + "\n"

@@ -20,9 +20,12 @@ Three phases, each one JSON line per reading, `{"ok": true, ...}` last:
   backend's streams against the XLA backend's under the same chunking,
   beside the same pair under whole-prompt admission (the control).
 - `time`: ms a call, 28 chained calls in one jitted scan (a decode
-  step's worth on Qwen3-1.7B), lengths uniform 256..640, page 16, 128
-  table columns, at one chip's (256 streams x 2 rows) and a TP=4 chip's
-  (64 x 8). PERF.md's table of forms and of W was read from this.
+  step's worth on Qwen3-1.7B), page 16, at the three cells' shapes
+  (`SHAPES`): one chip's 32 slots x 8 heads x 2 rows and a TP=4 chip's
+  32 x 2 x 8, lengths uniform 256..640 over 128 table columns; and
+  Phi-4's 64 slots x 10 paired heads x 4 rows, lengths 2,048..3,300
+  over 256 columns. PERF.md's tables of forms, of W and of ns a copy
+  were read from this.
 """
 
 from __future__ import annotations
@@ -30,12 +33,23 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
+import sys
 import time
 
 import numpy as np
 
+# run as a script from anywhere: the package lives one directory up
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 PAGE, D, CALLS = 16, 128, 28
-SHAPES = {"1chip": dict(B=32, Hkv=8, Hq=16), "tp4": dict(B=32, Hkv=2, Hq=16)}
+# what a chip holds of every slot in each cell, and the lengths `time`
+# draws there (the cells' contexts)
+SHAPES = {"1chip": dict(B=32, Hkv=8, Hq=16),
+          "tp4": dict(B=32, Hkv=2, Hq=16),
+          "phi4": dict(B=64, Hkv=10, Hq=40)}
+TIMED = {"1chip": (128, 256, 640), "tp4": (128, 256, 640),
+         "phi4": (256, 2048, 3300)}      # table columns, shortest, longest
 TOL = 0.03      # bf16 pools and P against float32: ~0.01 at these sizes
 
 
@@ -44,14 +58,17 @@ def _log(**fields) -> None:
 
 
 def _build(rng, B, Hkv, Hq, maxp, lens, S=1):
-    """Random pools behind a shuffled table (page 0 unused)."""
+    """Random pools behind a shuffled table (page 0 unused): a page is
+    a slot's PAGE positions for all of its Hkv heads."""
+    import jax
     import jax.numpy as jnp
-    X = B * Hkv
-    NP = X * maxp + 1
-    pk = jnp.asarray(rng.randn(NP, PAGE, D) * 0.5, jnp.bfloat16)
-    pv = jnp.asarray(rng.randn(NP, PAGE, D) * 0.5, jnp.bfloat16)
+    NP = B * maxp + 1
+    # made on the device: Phi-4's two pools are 1.3 GB
+    pk, pv = (jax.random.normal(
+        jax.random.PRNGKey(rng.randint(1 << 30)), (NP, Hkv, PAGE, D),
+        jnp.bfloat16) * 0.5 for _ in range(2))
     table = jnp.asarray(
-        1 + rng.permutation(NP - 1)[:X * maxp].reshape(X, maxp), jnp.int32)
+        1 + rng.permutation(NP - 1).reshape(B, maxp), jnp.int32)
     q = jnp.asarray(rng.randn(B, S, Hq, D) * 0.5, jnp.bfloat16)
     return q, pk, pv, table, jnp.asarray(lens, jnp.int32)
 
@@ -59,30 +76,36 @@ def _build(rng, B, Hkv, Hq, maxp, lens, S=1):
 def _reference(q, pk, pv, table, lens, q_lens=None):
     from triton_dist_tpu.kernels.flash_attn import attention_cached_ref
     import jax.numpy as jnp
-    B = q.shape[0]
-    X, maxp = table.shape
-    k = pk[table].reshape(B, X // B, maxp * PAGE, D)
-    v = pv[table].reshape(B, X // B, maxp * PAGE, D)
+    B, maxp = table.shape
+
+    def gather(pool):       # [B, maxp, Hkv, PAGE, D] -> [B, Hkv, T, D]
+        return pool[table].transpose(0, 2, 1, 3, 4).reshape(
+            B, pool.shape[1], maxp * PAGE, D)
+
+    k, v = gather(pk), gather(pv)
     return attention_cached_ref(q.astype(jnp.float32), k, v, lens,
                                 q_lens=q_lens)
 
 
-def check(maxp: int) -> None:
+def check(rehearse: bool) -> None:
     import jax
     import jax.numpy as jnp
     from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
     rng = np.random.RandomState(30)
-    cap = maxp * PAGE
     for name, kw in SHAPES.items():
         B = kw["B"]
+        maxp = 16 if rehearse else TIMED[name][0]
+        cap = maxp * PAGE
         for windows in (False, True):
             S = 4 if windows else 1
             lens = rng.randint(cap // 8, cap // 3, size=B)
             qls = rng.randint(1, S + 1, size=B)
-            # empty slots: a lone one, a run of them, the first, the last
-            for b in (0, 5, 6, 7, 8, 17, B - 1):
+            # empty slots: the first, the last, a lone one, and a run
+            # that is a whole grid step at every W (4 at TP=4) with
+            # more of them beside live slots
+            for b in (0, 5, 6, 7, 8, 9, 10, 11, 17, B - 1):
                 lens[b], qls[b] = 0, (0 if windows else 1)
-            lens[9], lens[18] = 1, cap
+            lens[12], lens[18] = 1, cap
             qls = np.minimum(qls, lens)     # a window lies inside its stream
             q, pk, pv, table, kvl = _build(rng, maxp=maxp, lens=lens, S=S,
                                            **kw)
@@ -110,8 +133,8 @@ def mixed(rehearse: bool) -> None:
     from triton_dist_tpu.models.config import qwen3_1p7b, tiny_qwen3
     from triton_dist_tpu.models.scheduler import Request
     from triton_dist_tpu.runtime import initialize_distributed
-    # the 1.7B's widths (Hkv = 8 = W: a parked slot is a whole grid
-    # step), two layers of it
+    # the 1.7B's widths (8 kv heads a slot, so W = 1: a parked slot is
+    # a whole grid step), two layers of it
     cfg = (tiny_qwen3(1) if rehearse
            else dataclasses.replace(qwen3_1p7b(), num_layers=2))
     L, g, budget, max_seq = (16, 6, 2, 64) if rehearse else (72, 12, 16, 256)
@@ -172,13 +195,17 @@ def _ms_per_call(args, block_w):
     return min(ts) / CALLS * 1e3, float(np.median(ts)) / CALLS * 1e3, first
 
 
-def timing(maxp: int, widths, lo: int, hi: int) -> None:
+def timing(widths, lens) -> None:
     rng = np.random.RandomState(0)
     for name, kw in SHAPES.items():
+        maxp, lo, hi = TIMED[name]
+        if lens:
+            lo, hi = lens
         args = _build(rng, maxp=maxp,
                       lens=rng.randint(lo, hi + 1, size=kw["B"]), **kw)
         for w in widths or [None]:
-            if w is not None and (kw["B"] * kw["Hkv"]) % w:
+            w = w or None           # 0 = the kernel's own pick
+            if w is not None and kw["B"] % w:
                 continue
             mn, med, first = _ms_per_call(args, w)
             _log(phase="time", shape=name, block_w=w, lens=[lo, hi],
@@ -193,25 +220,26 @@ def main() -> None:
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes for the CPU interpreter; no timing")
     ap.add_argument("--block-w", type=int, nargs="*", default=None,
-                    help="streams per grid step to time (default: the "
-                         "kernel's own pick)")
-    ap.add_argument("--lens", type=int, nargs=2, default=[256, 640])
+                    help="slots per grid step to time (default, and 0: "
+                         "the kernel's own pick)")
+    ap.add_argument("--lens", type=int, nargs=2, default=None,
+                    help="shortest and longest context to time (default: "
+                         "each shape's own, the cells')")
     args = ap.parse_args()
     import jax
     dev = jax.devices()[0]
     _log(device=str(dev), kind=dev.device_kind)
     if not args.rehearse:
         assert dev.platform == "tpu", f"not a TPU: {dev}"
-    maxp = 16 if args.rehearse else 128
     if args.rehearse:
         for kw in SHAPES.values():
             kw["B"] = 20
     if "check" in args.phases:
-        check(maxp)
+        check(args.rehearse)
     if "mixed" in args.phases:
         mixed(args.rehearse)
     if "time" in args.phases and not args.rehearse:
-        timing(maxp, args.block_w, *args.lens)
+        timing(args.block_w, args.lens)
     _log(ok=True, device={"platform": dev.platform, "kind": dev.device_kind})
 
 

@@ -138,11 +138,9 @@ def canonical_programs(engine, batch: int = 2
     prefilling = jnp.zeros((B,), bool)
     keys = jax.random.split(jax.random.PRNGKey(0), B)
     ids = jnp.zeros((2,), jnp.int32)
-    owners = jnp.zeros((2,), jnp.int32)
 
     # restore_pages' payload shapes come from the gather's avals
-    gshape = jax.eval_shape(body["gather_pages"], model, pcache, ids,
-                            owners)
+    gshape = jax.eval_shape(body["gather_pages"], model, pcache, ids)
     hk = jnp.zeros(gshape[0].shape, gshape[0].dtype)
     hv = jnp.zeros(gshape[1].shape, gshape[1].dtype)
     scan = {"gen_len": 2}
@@ -158,7 +156,7 @@ def canonical_programs(engine, batch: int = 2
                         tokens, q_lens, keys), {}),
         "paged_slot_mixed": ((model, logits0, pcache, pos, active,
                               prefilling, tokens, q_lens, keys), {}),
-        "gather_pages": ((model, pcache, ids, owners), {}),
+        "gather_pages": ((model, pcache, ids), {}),
         "restore_pages": ((model, pcache, ids, hk, hv), {}),
     }
     return {name: (body[name], args, kwargs)

@@ -1,8 +1,9 @@
 """Paged-KV race detector (tdcheck checker 2).
 
 The paged serving stack's correctness rests on WRITE EXCLUSIVITY: in
-one tick, no two (slot, kv-head) streams may write the same physical
-page (kernels/paged_kv.py append_slots), and no stream may write a
+one tick, no two slots may write the same physical page (a page
+holds one slot's positions for all of its kv heads:
+kernels/paged_kv.py append_slots), and no slot may write a
 page whose refcount exceeds 1 — a shared page is radix-tree prefix KV,
 writable only through the CoW boundary-copy path
 (models/prefix_cache.py). A violation corrupts a DIFFERENT request's
@@ -43,26 +44,25 @@ _HERE = "triton_dist_tpu/analysis/races.py"
 # 1. host-state write-exclusivity proof
 # ---------------------------------------------------------------------------
 
-def page_write_targets(table: np.ndarray, pos: np.ndarray, page: int,
-                       n_kv_heads: int) -> np.ndarray:
-    """Physical page each (slot, kv-head) stream writes at its current
-    position: [B, Hkv] int32 (the exact resolution append_slots
-    performs: table[slot*Hkv+h, pos//page])."""
+def page_write_targets(table: np.ndarray, pos: np.ndarray,
+                       page: int) -> np.ndarray:
+    """Physical page each slot writes at its current position (every
+    kv head of it: a page holds them all): [B] int32 (the exact
+    resolution the paged append performs: table[slot, pos//page])."""
     B = pos.shape[0]
     maxp = table.shape[1]
     tile = np.minimum(np.asarray(pos, np.int64) // page, maxp - 1)
-    streams = np.arange(B * n_kv_heads).reshape(B, n_kv_heads)
-    return table[streams, tile[:, None]]
+    return table[np.arange(B), tile]
 
 
-def check_state(table, pos, active, page: int, n_kv_heads: int, *,
+def check_state(table, pos, active, page: int, *,
                 trash: int, refcount=None, shared=None,
                 subject: str = "paged-state",
                 report: Optional[Report] = None) -> Report:
     """Write-exclusivity + CoW discipline over one host-side snapshot.
 
     Four rules:
-    - no two (slot, head) streams write one physical page this tick;
+    - no two slots write one physical page this tick;
     - no slot writes a page that lies inside ANOTHER slot's mapped
       valid extent (tiles 0..pos//page) — that reader would see the
       writer's bytes, which is exactly what admission's boundary-page
@@ -85,7 +85,7 @@ def check_state(table, pos, active, page: int, n_kv_heads: int, *,
     table = np.asarray(table)
     pos = np.asarray(pos)
     active = np.asarray(active, bool)
-    wp = page_write_targets(table, pos, page, n_kv_heads)
+    wp = page_write_targets(table, pos, page)
     maxp = table.shape[1]
     # per-slot mapped valid extent: the pages tiles 0..pos//page map
     extent: Dict[int, set] = {}
@@ -93,51 +93,48 @@ def check_state(table, pos, active, page: int, n_kv_heads: int, *,
         if not active[b]:
             continue
         last = min(int(pos[b]) // page, maxp - 1)
-        extent[b] = {int(p)
-                     for h in range(n_kv_heads)
-                     for p in table[b * n_kv_heads + h, :last + 1]}
-    owner: Dict[int, tuple] = {}
+        extent[b] = {int(p) for p in table[b, :last + 1]}
+    owner: Dict[int, int] = {}
     for b in range(pos.shape[0]):
         if not active[b]:
             continue
-        for h in range(n_kv_heads):
-            p = int(wp[b, h])
-            if p == trash:
-                continue
-            if p in owner:
-                ob, oh = owner[p]
+        p = int(wp[b])
+        if p == trash:
+            continue
+        if p in owner:
+            ob = owner[p]
+            report.add(
+                "error", _HERE + ":check_state", subject,
+                f"write race: slot {b} (pos {int(pos[b])})"
+                f" and slot {ob} (pos {int(pos[ob])}) "
+                f"both write physical page {p} this tick — one "
+                f"stream's KV will corrupt the other's")
+        else:
+            owner[p] = b
+        for ob, pages in extent.items():
+            if ob != b and p in pages:
                 report.add(
                     "error", _HERE + ":check_state", subject,
-                    f"write race: slot {b} head {h} (pos {int(pos[b])})"
-                    f" and slot {ob} head {oh} (pos {int(pos[ob])}) "
-                    f"both write physical page {p} this tick — one "
-                    f"stream's KV will corrupt the other's")
-            else:
-                owner[p] = (b, h)
-            for ob, pages in extent.items():
-                if ob != b and p in pages:
-                    report.add(
-                        "error", _HERE + ":check_state", subject,
-                        f"CoW violation: slot {b} head {h} writes page "
-                        f"{p} which slot {ob}'s table maps inside its "
-                        f"valid extent (pos {int(pos[ob])}) — the "
-                        f"reader sees the writer's bytes; admission "
-                        f"must boundary-copy before mapping a shared "
-                        f"page writable")
-            if refcount is not None and refcount(p) == 0:
-                report.add(
-                    "error", _HERE + ":check_state", subject,
-                    f"write to freed page: slot {b} head {h} writes "
-                    f"page {p} at refcount 0 — the allocator may "
-                    f"re-issue it to another slot mid-write")
-            if shared is not None and p in shared:
-                report.add(
-                    "error", _HERE + ":check_state", subject,
-                    f"fork CoW violation: slot {b} head {h} writes "
-                    f"page {p} which two or more live slots map "
-                    f"(fork-shared prefix KV) — a fork's appends must "
-                    f"land on a boundary-copied page, never the "
-                    f"shared original (every sibling reads it)")
+                    f"CoW violation: slot {b} writes page "
+                    f"{p} which slot {ob}'s table maps inside its "
+                    f"valid extent (pos {int(pos[ob])}) — the "
+                    f"reader sees the writer's bytes; admission "
+                    f"must boundary-copy before mapping a shared "
+                    f"page writable")
+        if refcount is not None and refcount(p) == 0:
+            report.add(
+                "error", _HERE + ":check_state", subject,
+                f"write to freed page: slot {b} writes "
+                f"page {p} at refcount 0 — the allocator may "
+                f"re-issue it to another slot mid-write")
+        if shared is not None and p in shared:
+            report.add(
+                "error", _HERE + ":check_state", subject,
+                f"fork CoW violation: slot {b} writes "
+                f"page {p} which two or more live slots map "
+                f"(fork-shared prefix KV) — a fork's appends must "
+                f"land on a boundary-copied page, never the "
+                f"shared original (every sibling reads it)")
     report.covered.append(subject)
     return report
 
@@ -145,7 +142,7 @@ def check_state(table, pos, active, page: int, n_kv_heads: int, *,
 def check_scheduler(sched, report: Optional[Report] = None) -> Report:
     """check_state over a live PagedDecodeSlots/ContinuousScheduler
     (device table+pos are tiny: one coalesced device_get). Fork-aware:
-    the pages mapped by two or more live slots' host group mirrors
+    the pages mapped by two or more live slots' host page mirrors
     form the `shared` set — KV-fork siblings reading them is legal,
     any write target among them fires. Also re-proves the pool
     conservation invariant as a finding instead of an assert."""
@@ -158,13 +155,12 @@ def check_scheduler(sched, report: Optional[Report] = None) -> Report:
     pool = slots.prefix.pool
     # fork sharing set: a page counted once per live slot that maps it
     holders: Dict[int, int] = {}
-    for b, groups in enumerate(getattr(slots, "_groups", ())):
+    for b, pages in enumerate(getattr(slots, "_pages", ())):
         if b < len(active) and active[b]:
-            for p in {int(p) for g in groups for p in g}:
+            for p in set(pages):
                 holders[p] = holders.get(p, 0) + 1
     shared = {p for p, c in holders.items() if c >= 2}
     check_state(table, pos, active, slots.page,
-                slots.engine.model.config.num_kv_heads,
                 trash=slots.cache.trash, refcount=pool.refcount,
                 shared=shared, subject=type(slots).__name__,
                 report=report)
@@ -440,7 +436,6 @@ def expected_write_pages(sched, steps: int) -> set:
     table, pos, active = jax.device_get(
         (slots.cache.table, slots.pos, slots.active))
     table = np.asarray(table)
-    Hkv = slots.engine.model.config.num_kv_heads
     maxp = table.shape[1]
     out = set()
     for b in range(len(pos)):
@@ -448,8 +443,7 @@ def expected_write_pages(sched, steps: int) -> set:
             continue
         for k in range(steps):
             tile = min((int(pos[b]) + k) // slots.page, maxp - 1)
-            for h in range(Hkv):
-                out.add(int(table[b * Hkv + h, tile]))
+            out.add(int(table[b, tile]))
     return out
 
 

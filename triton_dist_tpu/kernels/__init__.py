@@ -394,17 +394,18 @@ def _b_flash_decode_paged(partial):
             flash_decode_paged, flash_decode_paged_partial)
         rng = _np_rng(14)
         # page 16 as served: 8 pages make one block of the walk
-        B, Hq, Hkv, d, page, maxp = 2, 4, 2, 128, 16, 32
-        NP = B * Hkv * maxp
+        B, Hq, Hkv, d, page, maxp = 4, 4, 2, 128, 16, 16
+        NP = B * maxp
         q = _f32(rng, B, 1, Hq, d)
-        pages = _f32(rng, NP, page, d)
-        table = jnp.arange(NP, dtype=jnp.int32).reshape(B * Hkv, maxp)
-        kv_lens = jnp.asarray([page * maxp, page], jnp.int32)
+        pages = _f32(rng, NP, Hkv, page, d)
+        table = jnp.arange(NP, dtype=jnp.int32).reshape(B, maxp)
+        kv_lens = jnp.asarray([page * maxp, page, 0, 3 * page + 1],
+                              jnp.int32)
         # the table rides as a positional arg so tune_dims can read
-        # X = B*Hkv off it (the dim block_w legality divides)
+        # B off it (the dim block_w legality divides)
         if partial:
             owned = jnp.asarray(
-                np.ones((B * Hkv, maxp), np.int32))
+                np.ones((B, maxp), np.int32))
             return (lambda q_, pk, pv, t_: flash_decode_paged_partial(
                 q_, pk, pv, t_, kv_lens=kv_lens, tile_owned=owned),
                 (q, pages, pages, table))
@@ -473,7 +474,7 @@ def _grid(key, *vals):
 
 
 _TUNE_FLASH_DECODE = _grid("block_x", 32, 64, 128)
-# paged walk: W streams per grid step (the block of pages is fixed,
+# paged walk: W slots per grid step (the block of pages is fixed,
 # paged_kv._KV_TILE)
 _TUNE_PAGED = _grid("block_w", 1, 2, 4, 8)
 _TUNE_GROUPED_GEMM = ({"block_c": 128, "block_f": 256},
@@ -489,16 +490,17 @@ _TUNE_EP_FUSED = _grid("resident_w", True, False)
 
 # bucketing dims, shared convention with the consuming kernel (see
 # KernelSpec docstring): flash_decode (X=B*Hkv, T); paged (X=B*Hkv,
-# B*Hq, pool positions) — X leads because block_w legality divides X,
-# so the bucket key must separate GQA ratios; grouped_gemm (C, F);
+# B*Hq, pool positions) — X leads because block_w legality divides
+# the slots B = X / Hkv, so the bucket key must separate GQA ratios
+# and head counts; grouped_gemm (C, F);
 # ag_group_gemm (E, capT, N); moe_reduce_rs (E, capT, D).
 # Context-scoped kernels (ag_gemm/gemm_rs/gemm_ar/ep_fused) have no
 # shapes at resolution time: tune_dims=None.
 _DIMS_FLASH_DECODE = lambda q, k, v: (q.shape[0] * k.shape[1],  # noqa: E731
                                       k.shape[2])
-_DIMS_PAGED = lambda q, pk, pv, t: (t.shape[0],                 # noqa: E731
+_DIMS_PAGED = lambda q, pk, pv, t: (t.shape[0] * pk.shape[1],   # noqa: E731
                                     q.shape[0] * q.shape[2],
-                                    pk.shape[0] * pk.shape[1])
+                                    pk.shape[0] * pk.shape[2])
 _DIMS_GROUPED = lambda x, w: (x.shape[1], w.shape[2])           # noqa: E731
 _DIMS_EXPERT = lambda a, b: (a.shape[0], a.shape[1],            # noqa: E731
                              b.shape[2])

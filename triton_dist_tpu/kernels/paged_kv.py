@@ -5,40 +5,46 @@ TPU-native re-design of the reference megakernel's paged KV cache
 logical KV blocks indirected through a page table so sequences share a
 physical pool and grow without reallocation).
 
-Design: physical pages [NP, page, d] (one page = `page` contiguous KV
-positions of ONE (batch, kv-head) stream); a host/int32 page table
-[B*Hkv, max_pages] maps logical tiles to physical pages.
+Design: a physical page is [H, page, d] — `page` contiguous KV
+positions of ONE slot (batch row) for ALL of the kv heads the pool
+holds of it — so a layer's pool plane is [NP, H, page, d]; an int32
+page table [B, max_pages], one row a slot, maps logical tiles to
+physical pages. Under TP the plane is sharded on the head axis and a
+chip's shard [NP, H/tp, page, d] is the pool this kernel is handed: H
+is whatever the chip holds (8 on one chip of Qwen3-1.7B, 2 at TP=4, 10
+paired heads for Phi-4).
 
 The walk is LENGTH-BOUNDED and MULTI-PAGE. The grid runs over blocks
-of W streams and nothing else; the pools stay in HBM (`pl.ANY`). Inside
+of W slots and nothing else; the pools stay in HBM (`pl.ANY`). Inside
 a step a loop runs over the blocks of C pages (one softmax tile of
-_KV_TILE positions) that the step's longest stream really has — a
-stream of 18 pages does the work of 18, whatever the table's width (a
-step whose streams are all empty walks one masked block) — and for
-every block the kernel reads each stream's page ids from the
+_KV_TILE positions) that the step's longest slot really has — a
+slot of 18 pages does the work of 18, whatever the table's width (a
+step whose slots are all empty walks one masked block) — and for
+every block the kernel reads each slot's page ids from the
 scalar-prefetched table and fetches its OWN pages of that block, K and
-V, with one `make_async_copy` each into a [W, C*page, d] VMEM buffer
-(the TPU analog of the reference's in-kernel `page_table[block_idx]`
-load). The buffer has two halves: block i+1's copies are started before
-block i's are waited for, so they fly under its compute. The QK and PV
-dots then run on [rows, d] x [C*page, d] for all W streams at once,
-with one online-softmax update per block, the accumulators carried in
+V, with ONE `make_async_copy` a page and plane for all H heads
+(H*page*d*2 bytes: 32 KiB at 8 heads of page 16) into a
+[W, H, C*page, d] VMEM buffer (the TPU analog of the reference's
+in-kernel `page_table[block_idx]` load). The buffer has two halves:
+block i+1's copies are started before block i's are waited for, so
+they fly under its compute. The QK and PV dots then run on
+[rows, d] x [C*page, d] for all W*H (slot, head) streams at once, with
+one online-softmax update per block, the accumulators carried in
 registers through the loop.
 
 A stream's result depends on its own queries, pages and lengths alone:
-not on the table's width, not on which streams share its step, not on W
+not on the table's width, not on which slots share its step, not on W
 (the block is fixed). The scheduler's bitwise differentials (sync vs
 overlap, preempt/resume, prefix hit vs miss) lean on that.
 
-What the layout costs: a page of one (slot, kv-head) is page*d*2 bytes
-— 4 KiB at page 16 — so a call issues one small copy per page per
-plane, from the scalar core. A layout in which a slot's heads share a
-page would make each copy Hkv times larger; that is kv_cache.py's,
-prefix_cache.py's and the TP sharding's to change, not this kernel's
-(PERF.md, open questions). Paging still buys allocation flexibility
-first; W = largest of (8, 4, 2, 1) dividing B*Hkv unless the tune store
-says otherwise. C is not a tunable: on the chip 16 pages a block was
-slower than 8 at both served shapes (PERF.md, PR 30).
+Why the heads share a page: the walk is bound by how many copies the
+scalar core issues, not by what they carry (~20 ns a 4 KiB copy against
+the 5 ns its bytes take; PERF.md, PR 30 and PR 35). A page of one
+(slot, head) made a call issue H times the copies for the same bytes.
+W = the largest of (8, 4, 2, 1) dividing B that keeps W*H <= 8 streams
+a step, unless the tune store says otherwise. C is not a tunable: on
+the chip 16 pages a block was slower than 8 at both served shapes
+(PERF.md, PR 30).
 """
 
 from __future__ import annotations
@@ -69,17 +75,18 @@ def _block_pages(page: int) -> int:
 
 def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
                   quant: bool, partial: bool, s_ref, *refs):
-    """Grid (X // W,): one step walks W (batch, kv-head) streams through
-    THEIR OWN pages, C pages at a time (module docstring). refs = q
-    [W, rows, d], lens [W, 1, 2] of (kv length, query length), the K
-    and V pools in HBM, [the K and V scale planes in HBM], [own
-    [W, 1, L] per-position ownership], o, [m, l], then scratch: K and
-    V blocks [2, W, C*page, d], [scale blocks [2, W, 1, C*page]], one
-    DMA semaphore per buffer half. s_ref holds the X kv lengths, then
-    the page table row by row.
+    """Grid (B // W,): one step walks W slots — W*H (slot, kv-head)
+    streams, H the heads of the pool it is handed — through THEIR OWN
+    pages, C pages at a time (module docstring). refs = q
+    [W*H, rows, d], lens [W*H, 1, 2] of (kv length, query length), the
+    K and V pools [NP, H, page, d] in HBM, [the K and V scale planes
+    [NP, H, page] in HBM], [own [W*H, 1, L] per-position ownership], o,
+    [m, l], then scratch: K and V blocks [2, W, H, C*page, d], [scale
+    blocks [2, W, H, 1, C*page]], one DMA semaphore per buffer half.
+    s_ref holds the B kv lengths, then the page table row by row.
 
-    Stream j masks to its OWN lengths, so slots at different sequence
-    positions share one launch. q_len == 1 is plain decode; q_len > 1
+    A slot's streams mask to its OWN lengths, so slots at different
+    sequence positions share one launch. q_len == 1 is plain decode; q_len > 1
     is a prefill-shaped window — the speculative-verify draft
     (models/spec_decode.py) or a chunked-prefill prompt chunk
     (models/scheduler.py step_mixed): row s of the stream's q_len query
@@ -87,13 +94,14 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
     window; padded rows clamp to the last valid row (outputs discarded
     by the caller).
 
-    The step runs ceil(longest of its W streams / (C*page)) blocks, and
-    every stream fetches C pages in each: past its last page a stream
-    fetches that page again, so the buffer always holds pages of its
-    own and the copies need no branch. A block (or a column) past a
-    stream's end masks to a bitwise no-op of its accumulator; a step
-    whose streams are all empty still walks one such block, so every
-    copy that is started is waited for.
+    The step runs ceil(longest of its W slots / (C*page)) blocks, and
+    every slot fetches C pages in each, one copy a page and plane for
+    all of its heads: past its last page a slot fetches that page
+    again, so the buffer always holds pages of its own and the copies
+    need no branch. A block (or a column) past a slot's end masks to a
+    bitwise no-op of its streams' accumulators; a step whose slots are
+    all empty still walks one such block, so every copy that is started
+    is waited for.
 
     quant=True (int8 pool — kv_cache.PagedSlotCache scale planes): a
     page's [page] f32 scales are fetched beside its payload, through
@@ -128,8 +136,8 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
     *bufs, sem = rest
     kbuf, vbuf, *scale_bufs = bufs
     x = pl.program_id(0)
-    X = pl.num_programs(0) * W
-    rows, d = q_ref.shape[1:]
+    B = pl.num_programs(0) * W      # slots of the call
+    WH, rows, d = q_ref.shape
     C = _block_pages(page)
     CP = C * page
 
@@ -150,14 +158,15 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
     # set-up per served program on the chip (PERF.md, PR 30)
     nblk = functools.reduce(
         jnp.maximum, [(n + (C - 1)) // C for n in n_pages] + [1])
-    # each stream's last page and its row of the table, in s_ref
+    # each slot's last page and its row of the table, in s_ref
     last = [jnp.maximum(n - 1, 0) for n in n_pages]
-    row0 = [X + (x * W + j) * maxp for j in range(W)]
+    row0 = [B + (x * W + j) * maxp for j in range(W)]
 
     def for_block(i, half, act):
         """act(copy) for the C pages of block i of each of the step's
-        streams. Branch-free on the served path: past its last page a
-        stream fetches that page again (the mask drops it), which the
+        slots: a page is [H, page, d], all of the slot's heads in one
+        copy. Branch-free on the served path: past its last page a
+        slot fetches that page again (the mask drops it), which the
         chip takes better than a loop over the pages it really has."""
         for j in range(W):
             for c in range(C):
@@ -166,8 +175,8 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
 
                 def copies():
                     for n, (pool, buf) in enumerate(zip(pools, bufs)):
-                        dst = (buf.at[half, j, at] if n < 2
-                               else buf.at[half, j, 0, at])
+                        dst = (buf.at[half, j, :, at] if n < 2
+                               else buf.at[half, j, :, 0, at])
                         act(pltpu.make_async_copy(pool.at[pid], dst,
                                                   sem.at[half]))
                 if partial:
@@ -191,13 +200,13 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
 
     start(0, 0)
 
-    q = q_ref[...]                                   # [W, rows, d]
-    lens = lens_ref[...]                             # [W, 1, 2]
+    q = q_ref[...]                                   # [W*H, rows, d]
+    lens = lens_ref[...]                             # [W*H, 1, 2]
     kvl, ql = lens[:, :, 0:1], lens[:, :, 1:2]
     # row s's causal frontier within its stream's query window;
     # q_len == 1 degenerates to col < kv_len
     row = jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1) // rep
-    frontier = kvl - ql + jnp.minimum(row, ql - 1)   # [W, rows, 1]
+    frontier = kvl - ql + jnp.minimum(row, ql - 1)   # [W*H, rows, 1]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, CP), 2)
 
     def block(i, carry):
@@ -210,11 +219,12 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
         wait(i, half)
         m, l, acc = carry
         pos = i * CP
-        mask = (pos + lane) <= frontier              # [W, rows, CP]
+        mask = (pos + lane) <= frontier              # [W*H, rows, CP]
         if partial:
             mask = mask & (own_ref[
                 :, :, pl.ds(pl.multiple_of(pos, CP), CP)] != 0)
-        k = kbuf[half]                               # [W, CP, d]
+        # a slot's heads lie side by side in the buffer: its streams
+        k = kbuf[half].reshape(WH, CP, d)
         if quant:
             k = k.astype(q.dtype)
         s = jax.lax.dot_general(
@@ -223,18 +233,18 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
         if quant:
             # K's per-position scale multiplies the logits column-wise
             # (exact: (q . k_int8) * s == q . k_deq)
-            s = s * scale_bufs[0][half]
+            s = s * scale_bufs[0][half].reshape(WH, 1, CP)
         m_new = jnp.maximum(
             m, jnp.max(jnp.where(mask, s, -1e30), -1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         l = l * alpha + jnp.sum(p, -1, keepdims=True)
-        v = vbuf[half]
+        v = vbuf[half].reshape(WH, CP, d)
         if quant:
             # V's scale folds into p (diag(sv) V == V rows scaled); the
             # convert to the compute dtype happens in VMEM
             v = v.astype(q.dtype)
-            p = p * scale_bufs[1][half]
+            p = p * scale_bufs[1][half].reshape(WH, 1, CP)
         acc = acc * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
@@ -242,9 +252,9 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
 
     m, l, acc = jax.lax.fori_loop(
         0, nblk, block,
-        (jnp.full((W, rows, 1), -1e30, jnp.float32),
-         jnp.zeros((W, rows, 1), jnp.float32),
-         jnp.zeros((W, rows, d), jnp.float32)))
+        (jnp.full((WH, rows, 1), -1e30, jnp.float32),
+         jnp.zeros((WH, rows, 1), jnp.float32),
+         jnp.zeros((WH, rows, d), jnp.float32)))
     if partial:
         # the SP partial contract: unnormalized accumulator + softmax
         # stats, combined across chips by lse_combine
@@ -263,13 +273,14 @@ def flash_decode_paged(q, pages_k, pages_v, page_table, kv_len, *,
     """Cached GQA decode attention through a page table.
 
     q: [B, S, Hq, d] (S == 1 unless q_lens is given); pages_k/v:
-    [NP, page, d]; page_table: [B*Hkv, max_pages] int32 (physical page
-    of each logical tile; entries beyond ceil(kv_len/page) may hold
-    anything, but column 0, which an empty stream still fetches, names
-    a page of the pool); kv_len: traced scalar — valid positions INCLUDING the
-    current query. Returns [B, S, Hq, d].
+    [NP, Hkv, page, d] (Hkv = the kv heads this pool holds of every
+    slot); page_table: [B, max_pages] int32 (physical page of each
+    logical tile of a slot; entries beyond ceil(kv_len/page) may hold
+    anything, but column 0, which an empty slot still fetches, names a
+    page of the pool); kv_len: traced scalar — valid positions
+    INCLUDING the current query. Returns [B, S, Hq, d].
 
-    k_scale/v_scale: per-position dequant scale planes [NP, page] f32
+    k_scale/v_scale: per-position dequant scale planes [NP, Hkv, page] f32
     for an INT8 page pool (pages_k/v int8 —
     kv_cache.PagedSlotCache.scales_k/v): a page's scales ride behind
     the same table indirection as its payload, and dequant folds into
@@ -317,7 +328,7 @@ def flash_decode_paged_partial(q, pages_k, pages_v, page_table, *,
     - pages_k/v are THIS CHIP'S local pool shard and page_table holds
       LOCAL page ids (a non-owned tile's entry may be anything: it is
       never read);
-    - tile_owned [B*Hkv, maxp] int32 marks which logical tiles this
+    - tile_owned [B, maxp] int32 marks which logical tiles this
       chip owns: a non-owned tile is not fetched at all and is a
       bitwise no-op of the stream's accumulator, so the returned (acc [B, S, Hq, d] f32 unnormalized,
       m [B, S, Hq], l [B, S, Hq]) LSE-combine across chips
@@ -333,20 +344,21 @@ def flash_decode_paged_partial(q, pages_k, pages_v, page_table, *,
         tune_name="flash_decode_paged_partial")
 
 
-def _stream_block(tune_name, dims, X, block_w):
-    """W: streams per grid step. Resolution: explicit block_w >
-    contextual profile > tune cache (tools/sweep) > the largest divisor
-    of X in (8, 4, 2, 1). W only regroups streams across grid steps and
-    never changes a stream's result. Strictness splits by provenance:
-    an indivisible W that was pinned explicitly or installed in the
-    contextual profile is a loud error (the sweep pruner probes configs
-    through the profile and relies on this trace failing), while a
-    DISK-cache winner is a hint from whatever shape it was swept at
-    (bucket fallback, another GQA ratio) and re-clamps to the default
-    instead of failing at serving time — the tuned_choice contract:
-    perf may degrade, never correctness. The two-step lookup below
-    mirrors sweep.resolve_config's precedence, split so provenance is
-    known."""
+def _slot_block(tune_name, dims, B, H, block_w):
+    """W: slots per grid step. Resolution: explicit block_w >
+    contextual profile > tune cache (tools/sweep) > the largest W
+    dividing B with W*H <= 8 streams a step (1 slot on a chip that
+    holds 8 or more kv heads of every slot, 4 where it holds 2). W only
+    regroups slots across grid steps and never changes a stream's
+    result. Strictness splits by provenance: an indivisible W that was
+    pinned explicitly or installed in the contextual profile is a loud
+    error (the sweep pruner probes configs through the profile and
+    relies on this trace failing), while a DISK-cache winner is a hint
+    from whatever shape it was swept at (bucket fallback, another head
+    count) and re-clamps to the default instead of failing at serving
+    time — the tuned_choice contract: perf may degrade, never
+    correctness. The two-step lookup below mirrors
+    sweep.resolve_config's precedence, split so provenance is known."""
     from triton_dist_tpu.tools.tune import contextual_choice
     cfg = contextual_choice(tune_name)
     strict = cfg is not None or block_w is not None
@@ -354,14 +366,15 @@ def _stream_block(tune_name, dims, X, block_w):
         from triton_dist_tpu.tools.sweep import tuned_choice
         cfg = tuned_choice(tune_name, dims) or {}
     W = cfg.get("block_w") if block_w is None else block_w
-    if W is not None and X % W:
+    if W is not None and B % W:
         if strict:
             raise ValueError(
                 f"{tune_name}: block_w={W} does not divide the "
-                f"stream count X={X} (B*Hkv)")
+                f"slot count B={B}")
         W = None
     if W is None:
-        W = next(w for w in (8, 4, 2, 1) if X % w == 0)
+        W = next(w for w in (8, 4, 2, 1)
+                 if B % w == 0 and (w * H <= 8 or w == 1))
     return int(W)
 
 
@@ -380,25 +393,26 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
     quant = k_scale is not None
     assert (k_scale is None) == (v_scale is None), \
         "int8 pool carries BOTH scale planes"
-    NP, page, _ = pages_k.shape
-    X, maxp = page_table.shape
-    Hkv = X // B
-    rep = Hq // Hkv
+    NP, H, page, _ = pages_k.shape
+    assert page_table.shape[0] == B, "the table has one row a slot"
+    maxp = page_table.shape[1]
+    X = B * H
+    rep = Hq // H
     if scale is None:
         scale = d ** -0.5
     rows = S * rep
-    qx = (q.reshape(B, S, Hkv, rep, d)
+    qx = (q.reshape(B, S, H, rep, d)
            .transpose(0, 2, 1, 3, 4)
            .reshape(X, rows, d))
-    W = _stream_block(tune_name, (X, B * Hq, NP * page), X, block_w)
+    W = _slot_block(tune_name, (X, B * Hq, NP * page), B, H, block_w)
+    WH = W * H
     CP = _block_pages(page) * page
-    # every stream carries its own (kv length, query length): a launch
-    # without kv_lens is all streams at kv_len, one query row each
-    lens_x = (jnp.repeat(jnp.asarray(kv_lens, jnp.int32), Hkv)
-              if kv_lens is not None
-              else jnp.full((X,), kv_len, jnp.int32))
-    qlens_x = (jnp.ones_like(lens_x) if q_lens is None
-               else jnp.repeat(jnp.asarray(q_lens, jnp.int32), Hkv))
+    # every slot carries its own (kv length, query length): a launch
+    # without kv_lens is all slots at kv_len, one query row each
+    lens_b = (jnp.asarray(kv_lens, jnp.int32) if kv_lens is not None
+              else jnp.full((B,), kv_len, jnp.int32))
+    qlens_b = (jnp.ones_like(lens_b) if q_lens is None
+               else jnp.asarray(q_lens, jnp.int32))
     table = page_table.astype(jnp.int32)
     if partial:
         # a tile another chip holds is a table entry below zero: the
@@ -406,32 +420,34 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
         owned = jnp.asarray(tile_owned, jnp.int32) != 0
         table = jnp.where(owned, table, -1)
     # scalars: [lens..., table...]. The lens appear TWICE on purpose:
-    # here for the walk's bounds, and beside the query lengths as a
-    # [X, 1, 2] operand for the in-kernel mask (a vector per stream,
-    # which scalars cannot be broadcast into cheaply).
-    scalars = jnp.concatenate([lens_x, table.reshape(-1)])
+    # here, one a slot, for the walk's bounds, and beside the query
+    # lengths as a [X, 1, 2] operand for the in-kernel mask (a vector
+    # per stream, which scalars cannot be broadcast into cheaply).
+    scalars = jnp.concatenate([lens_b, table.reshape(-1)])
 
     def per_step(*tail):
-        return pl.BlockSpec((W,) + tail, lambda x, s_ref: (x, 0, 0))
+        return pl.BlockSpec((WH,) + tail, lambda x, s_ref: (x, 0, 0))
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [per_step(rows, d), per_step(1, 2), hbm, hbm]
-    args = [qx, jnp.stack([lens_x, qlens_x], 1).reshape(X, 1, 2),
+    args = [qx, jnp.repeat(jnp.stack([lens_b, qlens_b], 1), H,
+                           axis=0).reshape(X, 1, 2),
             pages_k, pages_v]
-    scratch = [pltpu.VMEM((2, W, CP, d), pages_k.dtype),
-               pltpu.VMEM((2, W, CP, d), pages_v.dtype)]
+    scratch = [pltpu.VMEM((2, W, H, CP, d), pages_k.dtype),
+               pltpu.VMEM((2, W, H, CP, d), pages_v.dtype)]
     if quant:
         in_specs += [hbm, hbm]
         args += [k_scale, v_scale]
-        scratch += [pltpu.VMEM((2, W, 1, CP), k_scale.dtype),
-                    pltpu.VMEM((2, W, 1, CP), v_scale.dtype)]
+        scratch += [pltpu.VMEM((2, W, H, 1, CP), k_scale.dtype),
+                    pltpu.VMEM((2, W, H, 1, CP), v_scale.dtype)]
     if partial:
-        # ownership per POSITION, out to a whole number of blocks
+        # ownership per POSITION, out to a whole number of blocks, the
+        # slot's row once for each of its streams
         L = -(-maxp * page // CP) * CP
         own = jnp.repeat(owned.astype(jnp.int32), page, axis=1)
         own = jnp.pad(own, ((0, 0), (0, L - maxp * page)))
         in_specs.append(per_step(1, L))
-        args.append(own.reshape(X, 1, L))
+        args.append(jnp.repeat(own, H, axis=0).reshape(X, 1, L))
         out_specs = (per_step(rows, d), per_step(rows, 1),
                      per_step(rows, 1))
         out_shape = (jax.ShapeDtypeStruct((X, rows, d), jnp.float32),
@@ -445,7 +461,7 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
                           maxp, quant, partial),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(X // W,),
+            grid=(B // W,),
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))],
@@ -461,7 +477,7 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
 
     def unfold(a):
         tail = a.shape[2:]
-        return (a.reshape((B, Hkv, S, rep) + tail)
+        return (a.reshape((B, H, S, rep) + tail)
                  .transpose(0, 2, 1, 3, *range(4, 4 + len(tail)))
                  .reshape((B, S, Hq) + tail))
 
@@ -471,16 +487,49 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
     return unfold(out)
 
 
+def set_page_rows(pool, pidx, r, u):
+    """Write rows into a paged pool plane [NP, h, page(, d)]
+    (kv_cache.PagedSlotCache): u [..., h(, d)] lands at in-page row
+    r [...] of every head of page pidx [...], whatever else the page
+    holds left alone. An id past the pool (a padded window row, a tile
+    another chip owns) drops.
+
+    The scatter runs over the plane viewed as [NP*h, page(, d)], its
+    (page, head) pairs in a row: two LEADING index dims update in
+    place — index for index the append of a pool whose pages were one
+    (slot, head)'s, and in the served decode scan it reads what that
+    one read (1.07 ms a step of 56 appends against 1.14; PERF.md, PR
+    35). A single index over [NP*h*page(, d)] read 1.43 there, and a
+    head axis between the two indexed ones 90.7 us an append against
+    52.0 at Phi-4's shape (models/phi4flash.py's rings met the
+    same)."""
+    NP, h = pool.shape[:2]
+    rows = pidx[..., None] * h + jnp.arange(h)           # [..., h]
+    flat = pool.reshape((NP * h,) + pool.shape[2:])
+    return flat.at[rows, r[..., None]].set(
+        u.astype(pool.dtype)).reshape(pool.shape)
+
+
+def gather_pages(pool, table):
+    """The oracle's read of a paged pool plane: [NP, h, page, d]
+    through table [B, maxp] -> the slots' contiguous [B, h, maxp*page,
+    d]."""
+    g = pool[table]                         # [B, maxp, h, page, d]
+    B, maxp, h, page, d = g.shape
+    return g.transpose(0, 2, 1, 3, 4).reshape(B, h, maxp * page, d)
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PagedKVCache:
     """Page-table KV cache for one layer (reference:
     paged_kv_cache.py:28). Pages are allocated lazily as sequences grow;
-    the table rows are per (batch, kv-head) stream.
+    the table has one row a batch row (slot), and a page holds the
+    `page` positions of that slot for all of its kv heads.
 
-    pages_k/v: [NP, page, d]; table: [B*Hkv, max_pages] int32;
+    pages_k/v: [NP, Hkv, page, d]; table: [B, max_pages] int32;
     offset: valid positions. The allocator is the trivial static one —
-    stream i's tile t lives at page i*max_pages + t — so `alloc` is a
+    slot b's tile t lives at page b*max_pages + t — so `alloc` is a
     table initialization, not a runtime free-list; a serving layer can
     swap in its own table (the indirection is what the kernel needs,
     not the policy)."""
@@ -494,25 +543,24 @@ class PagedKVCache:
     def create(batch: int, n_kv_heads: int, max_seq: int, head_dim: int,
                *, page: int = 128, dtype=jnp.bfloat16) -> "PagedKVCache":
         maxp = -(-max_seq // page)
-        X = batch * n_kv_heads
-        NP = X * maxp
-        table = jnp.arange(NP, dtype=jnp.int32).reshape(X, maxp)
-        z = jnp.zeros((NP, page, head_dim), dtype)
+        NP = batch * maxp
+        table = jnp.arange(NP, dtype=jnp.int32).reshape(batch, maxp)
+        z = jnp.zeros((NP, n_kv_heads, page, head_dim), dtype)
         return PagedKVCache(pages_k=z, pages_v=z, table=table,
                             offset=jnp.int32(0))
 
     @property
     def page(self) -> int:
-        return self.pages_k.shape[1]
+        return self.pages_k.shape[2]
 
     def append(self, k_new, v_new) -> "PagedKVCache":
-        """Append one position: k/v_new [B, Hkv, 1, d] -> the page row
-        (stream, offset // page, offset % page). A single-row write into
-        a paged pool is a scatter (cannot be a tile-aligned DMA), so
-        appends go through XLA DUS — the paged cache trades append/walk
-        speed for allocation flexibility."""
-        B, Hkv, _, d = k_new.shape
-        X, maxp = self.table.shape
+        """Append one position: k/v_new [B, Hkv, 1, d] -> row
+        offset % page of every head of the slot's page
+        table[b, offset // page]. A single-row write into a paged pool
+        is a scatter (cannot be a tile-aligned DMA), so appends go
+        through XLA DUS — the paged cache trades append/walk speed for
+        allocation flexibility."""
+        B, maxp = self.table.shape
         if not isinstance(self.offset, jax.core.Tracer):
             # eager appends (the common serving pattern) get a real
             # capacity error; a clamped OOB table read would silently
@@ -521,42 +569,32 @@ class PagedKVCache:
                 raise ValueError(
                     f"PagedKVCache full: offset {int(self.offset)} at "
                     f"capacity {maxp * self.page}")
-        rows = k_new.reshape(X, d)
-        vrows = v_new.reshape(X, d)
-        pidx = self.table[:, self.offset // self.page]     # [X]
-        r = self.offset % self.page
-
-        def scat(pages, rows):
-            return pages.at[pidx, r].set(rows.astype(pages.dtype))
-
-        return dataclasses.replace(
-            self, pages_k=scat(self.pages_k, rows),
-            pages_v=scat(self.pages_v, vrows), offset=self.offset + 1)
+        out = self.append_slots(k_new, v_new,
+                                jnp.full((B,), self.offset, jnp.int32))
+        return dataclasses.replace(out, offset=self.offset + 1)
 
     # ------------------------------------------------------------------
     # continuous-batching slot paths (models/scheduler.py design): the
-    # batch rows of the table are independent SLOTS at their own
-    # per-slot positions; a real allocator (PageAllocator) owns the
-    # physical pages, so slots of very different lengths share the pool
-    # and a retired slot's pages go back on the free list.
+    # rows of the table are independent SLOTS at their own per-slot
+    # positions; a real allocator (PageAllocator) owns the physical
+    # pages, so slots of very different lengths share the pool and a
+    # retired slot's pages go back on the free list.
     # ------------------------------------------------------------------
 
     def write_slot(self, slot: int, k, v) -> "PagedKVCache":
         """Prefill-into-slot: write a new request's whole prompt KV
-        (k/v [Hkv, n, d]) through the slot's table rows — positions
-        0..n-1 of streams slot*Hkv..slot*Hkv+Hkv-1. Touches only the
-        slot's own (allocator-assigned) pages, so live slots are
-        undisturbed. The shared offset is NOT advanced — per-slot
-        lengths live with the scheduler."""
-        Hkv, n, d = k.shape
-        X, maxp = self.table.shape
+        (k/v [Hkv, n, d]) through the slot's table row — positions
+        0..n-1 of every head. Touches only the slot's own
+        (allocator-assigned) pages, so live slots are undisturbed. The
+        shared offset is NOT advanced — per-slot lengths live with the
+        scheduler."""
+        n = k.shape[1]
         p = jnp.arange(n)
-        streams = slot * Hkv + jnp.arange(Hkv)
-        pidx = self.table[streams][:, p // self.page]      # [Hkv, n]
+        pidx = self.table[slot][p // self.page]            # [n]
         r = p % self.page                                  # [n]
 
-        def scat(pages, rows):
-            return pages.at[pidx, r[None]].set(rows.astype(pages.dtype))
+        def scat(pages, rows):     # rows [Hkv, n, d] -> [n, Hkv, d]
+            return set_page_rows(pages, pidx, r, rows.transpose(1, 0, 2))
 
         return dataclasses.replace(
             self, pages_k=scat(self.pages_k, k),
@@ -565,35 +603,33 @@ class PagedKVCache:
     def append_slots(self, k_new, v_new, pos) -> "PagedKVCache":
         """Per-slot decode append: k/v_new [B, Hkv, 1, d], pos [B] —
         slot b's new row lands at ITS position pos[b] (page
-        table[b*Hkv+h, pos[b]//page], row pos[b]%page). One scatter for
-        the whole batch; the shared offset is untouched."""
-        B, Hkv, _, d = k_new.shape
-        X, maxp = self.table.shape
-        pos_x = jnp.repeat(jnp.asarray(pos, jnp.int32), Hkv)   # [X]
-        pidx = self.table[jnp.arange(X), pos_x // self.page]
-        r = pos_x % self.page
+        table[b, pos[b]//page], row pos[b]%page of every head). One
+        scatter for the whole batch; the shared offset is untouched."""
+        B = k_new.shape[0]
+        pos = jnp.asarray(pos, jnp.int32)
+        pidx = self.table[jnp.arange(B), pos // self.page]     # [B]
+        r = pos % self.page
 
-        def scat(pages, rows):
-            return pages.at[pidx, r].set(rows.astype(pages.dtype))
+        def scat(pages, rows):     # rows [B, Hkv, 1, d]
+            return set_page_rows(pages, pidx, r, rows[:, :, 0])
 
         return dataclasses.replace(
-            self, pages_k=scat(self.pages_k, k_new.reshape(X, d)),
-            pages_v=scat(self.pages_v, v_new.reshape(X, d)))
+            self, pages_k=scat(self.pages_k, k_new),
+            pages_v=scat(self.pages_v, v_new))
 
-    def set_slot_table(self, slot: int, rows) -> "PagedKVCache":
-        """Install allocator-assigned table rows for a slot:
-        rows [Hkv, <=max_pages] int32 physical page ids (shorter rows
-        pad with their own last entry — never attended past the slot's
+    def set_slot_table(self, slot: int, row) -> "PagedKVCache":
+        """Install the allocator-assigned table row of a slot:
+        row [<=max_pages] int32 physical page ids (a shorter row pads
+        with its own last entry — never attended past the slot's
         length, but every entry must be a page of the pool)."""
-        Hkv, npg = rows.shape
-        X, maxp = self.table.shape
-        rows = jnp.asarray(rows, jnp.int32)
+        maxp = self.table.shape[1]
+        row = jnp.asarray(row, jnp.int32)
+        npg = row.shape[0]
         if npg < maxp:
-            rows = jnp.concatenate(
-                [rows, jnp.broadcast_to(rows[:, -1:],
-                                        (Hkv, maxp - npg))], axis=1)
-        table = jax.lax.dynamic_update_slice(self.table, rows,
-                                             (slot * Hkv, 0))
+            row = jnp.concatenate(
+                [row, jnp.broadcast_to(row[-1:], (maxp - npg,))])
+        table = jax.lax.dynamic_update_slice(self.table, row[None],
+                                             (slot, 0))
         return dataclasses.replace(self, table=table)
 
 
@@ -713,12 +749,10 @@ class PageAllocator:
             self._free_by_shard[p // self.pages_per_shard].append(p)
         self._check()
 
-    def alloc_slot(self, Hkv: int, n_positions: int, page: int):
-        """Pages for one slot: Hkv streams x ceil(n_positions/page)
-        pages each. Returns an [Hkv, n_pages] int32 table block (feed
-        to PagedKVCache.set_slot_table); free a retired slot with
-        free(block.ravel())."""
+    def alloc_slot(self, n_positions: int, page: int):
+        """Pages for one slot: ceil(n_positions/page) ids, each a page
+        of all the slot's kv heads. Returns the [n_pages] int32 table
+        row (feed to PagedKVCache.set_slot_table); free a retired slot
+        with free(row)."""
         import numpy as np
-        npg = -(-n_positions // page)
-        return np.asarray([self.alloc(npg) for _ in range(Hkv)],
-                          np.int32)
+        return np.asarray(self.alloc(-(-n_positions // page)), np.int32)

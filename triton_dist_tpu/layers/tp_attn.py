@@ -26,6 +26,7 @@ from triton_dist_tpu.kernels import (ag_gemm, all_reduce,
                                      create_gemm_ar_context,
                                      create_gemm_rs_context, gemm_allreduce,
                                      gemm_rs)
+from triton_dist_tpu.kernels.paged_kv import gather_pages, set_page_rows
 from triton_dist_tpu.layers.common import (apply_rope, apply_rope_slots,
                                            rms_norm, shard_cols_packed)
 
@@ -653,9 +654,10 @@ class TP_Attn:
 
     def _paged_specs(self, quant: bool):
         """shard_map in/out specs of one layer's paged pool tuple:
-        payloads [NP, G, page, d] and (int8) scale planes [NP, G, page]
-        split on the HEAD-GROUP axis G (kv_cache.PagedSlotCache TP
-        sharding) — each rank's plane holds its own kv heads' pages."""
+        payloads [NP, Hkv, page, d] and (int8) scale planes
+        [NP, Hkv, page] split on the HEAD axis (kv_cache.PagedSlotCache
+        TP sharding) — each rank's shard holds its own kv heads of
+        every page."""
         pool_spec = P(None, self.axis, None, None)
         sc_spec = P(None, self.axis, None)
         return ((pool_spec, pool_spec, sc_spec, sc_spec) if quant
@@ -669,53 +671,46 @@ class TP_Attn:
         attention walks the pool through the table (flash_decode_paged,
         or a gather + contiguous oracle under impl="ref").
 
-        kv: (pages_k, pages_v) [NP, G, page, d] — ONE layer's pool —
+        kv: (pages_k, pages_v) [NP, Hkv, page, d] — ONE layer's pool —
         or (pages_k, pages_v, scales_k, scales_v) for the INT8 pool
         (kv_cache.PagedSlotCache with dtype=int8): the new row
         quantizes per position (kernels/quant.quantize_kv_int8 — the
         contiguous cache's exact quantizer) and its scale lands in the
-        [NP, G, page] scale plane at the SAME page/row/plane the
+        [NP, Hkv, page] scale plane at the SAME page/head/row the
         payload takes, so scales follow pages through sharing, CoW,
         eviction and the host tier for free; attention dequants
         in-kernel (flash_decode_paged k_scale/v_scale).
-        table: [B*Hkv, max_pages] int32 shared by all layers,
-        replicated (the host owns it).
+        table: [B, max_pages] int32, one row a slot, shared by all
+        layers, replicated (the host owns it).
 
-        TP-NATIVE (the head-sharded pool of kv_cache.PagedSlotCache —
-        ROADMAP open item 1): this attend runs under jax.shard_map
-        exactly like the contiguous _attend_cached_slots — each rank
-        scatters its OWN kv heads' new rows into its local pool plane
-        and walks only its local streams (its slice of the table), so
-        a TP=N mesh reads 1/N of the KV and does 1/N of the attention
-        FLOPs per chip while the page table, allocator and radix tree
-        stay host-replicated and layout-oblivious."""
+        TP-NATIVE (the head-sharded pool of kv_cache.PagedSlotCache):
+        this attend runs under jax.shard_map exactly like the
+        contiguous _attend_cached_slots — each rank scatters its OWN
+        kv heads' new rows into its shard of the pool and walks only
+        its local heads of every page, so a TP=N mesh reads 1/N of the
+        KV and does 1/N of the attention FLOPs per chip while the page
+        table, allocator and radix tree stay host-replicated and
+        layout-oblivious."""
         from triton_dist_tpu.kernels.flash_attn import attention_cached_ref
         from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
         from triton_dist_tpu.kernels.quant import (dequantize_kv_int8,
                                                    quantize_kv_int8)
         hq, hkv, hd = self._hq_loc, self._hkv_loc, self.head_dim
-        Hkv = self.n_kv_heads
         scale = hd ** -0.5
         quant = len(kv) == 4
         kv_specs = self._paged_specs(quant)
         B = qkv.shape[0]
         maxp = table.shape[1]
-        # table rows regrouped [B, Hkv, maxp] so the head axis blocks
-        # contiguously per rank (row b*Hkv+h of the flat table is
-        # stream (b, h); rank r owns heads [r*hkv, (r+1)*hkv))
-        table3 = table.reshape(B, Hkv, maxp)
 
         @functools.partial(
             jax.shard_map, mesh=self.mesh,
             in_specs=(P(None, self.axis),) + kv_specs
-                     + (P(None, self.axis, None), P(None)),
+                     + (P(None, None), P(None)),
             out_specs=((P(None, self.axis),) + kv_specs),
             check_vma=False)
-        def f(qkv_loc, ck4, cv4, *rest):
-            *scales4, tbl, pos = rest
-            ck, cv = ck4[:, 0], cv4[:, 0]          # local plane
-            page = ck.shape[1]
-            tbl = tbl.reshape(B * hkv, maxp)       # local streams
+        def f(qkv_loc, ck, cv, *rest):
+            *scales, tbl, pos = rest
+            page = ck.shape[2]
             q = qkv_loc[:, :hq * hd].reshape(B, 1, hq, hd)
             k = qkv_loc[:, hq * hd:(hq + hkv) * hd].reshape(B, 1, hkv, hd)
             v = qkv_loc[:, (hq + hkv) * hd:].reshape(B, 1, hkv, hd)
@@ -725,21 +720,20 @@ class TP_Attn:
                 k = rms_norm(k, self.k_norm)
             q = apply_rope_slots(q, cos, sin, pos)
             k = apply_rope_slots(k, cos, sin, pos)
-            X = B * hkv
-            pos_x = jnp.repeat(pos, hkv)                     # [X]
-            pidx = tbl[jnp.arange(X), pos_x // page]
-            r = pos_x % page
+            pidx = tbl[jnp.arange(B), pos // page]           # [B]
+            r = pos % page
+            k, v = k[:, 0], v[:, 0]                          # [B, hkv, hd]
             if quant:
-                sk, sv = scales4[0][:, 0], scales4[1][:, 0]
-                k8, k_s = quantize_kv_int8(k.reshape(X, hd))
-                v8, v_s = quantize_kv_int8(v.reshape(X, hd))
-                ck = ck.at[pidx, r].set(k8)
-                cv = cv.at[pidx, r].set(v8)
-                sk = sk.at[pidx, r].set(k_s)
-                sv = sv.at[pidx, r].set(v_s)
+                sk, sv = scales
+                k8, k_s = quantize_kv_int8(k)
+                v8, v_s = quantize_kv_int8(v)
+                ck = set_page_rows(ck, pidx, r, k8)
+                cv = set_page_rows(cv, pidx, r, v8)
+                sk = set_page_rows(sk, pidx, r, k_s)
+                sv = set_page_rows(sv, pidx, r, v_s)
             else:
-                ck = ck.at[pidx, r].set(k.reshape(X, hd).astype(ck.dtype))
-                cv = cv.at[pidx, r].set(v.reshape(X, hd).astype(cv.dtype))
+                ck = set_page_rows(ck, pidx, r, k)
+                cv = set_page_rows(cv, pidx, r, v)
                 sk = sv = None
             lens = pos + 1
             qd = jnp.bfloat16 if quant else ck.dtype
@@ -749,21 +743,19 @@ class TP_Attn:
                                        kv_lens=lens, k_scale=sk,
                                        v_scale=sv)
             else:
-                T = maxp * page
                 kd = dequantize_kv_int8(ck, sk) if quant else ck
                 vd = dequantize_kv_int8(cv, sv) if quant else cv
-                kfull = kd[tbl].reshape(B, hkv, T, hd)
-                vfull = vd[tbl].reshape(B, hkv, T, hd)
                 o = attention_cached_ref(q.astype(jnp.float32) if quant
                                          else q.astype(ck.dtype),
-                                         kfull, vfull, lens, scale=scale)
+                                         gather_pages(kd, tbl),
+                                         gather_pages(vd, tbl), lens,
+                                         scale=scale)
             o = o.reshape(B, hq * hd)
             if quant:
-                return (o.astype(qkv_loc.dtype), ck[:, None], cv[:, None],
-                        sk[:, None], sv[:, None])
-            return o, ck[:, None], cv[:, None]
+                return o.astype(qkv_loc.dtype), ck, cv, sk, sv
+            return o, ck, cv
 
-        out = f(qkv, *kv, table3, jnp.asarray(pos, jnp.int32))
+        out = f(qkv, *kv, table, jnp.asarray(pos, jnp.int32))
         return out[0], tuple(out[1:])
 
     def _attend_paged_slots_verify(self, qkv, cos, sin, batch: int, kv,
@@ -781,13 +773,12 @@ class TP_Attn:
         (page, row) destinations — OOB-dropped alongside the payload —
         exactly like _attend_paged_slots. Runs under jax.shard_map on
         the head-sharded pool (see _attend_paged_slots): each rank
-        writes and walks only its own kv-head plane."""
+        writes and walks only its own kv heads of every page."""
         from triton_dist_tpu.kernels.flash_attn import attention_cached_ref
         from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
         from triton_dist_tpu.kernels.quant import (dequantize_kv_int8,
                                                    quantize_kv_int8)
         hq, hkv, hd = self._hq_loc, self._hkv_loc, self.head_dim
-        Hkv = self.n_kv_heads
         scale = hd ** -0.5
         quant = len(kv) == 4
         kv_specs = self._paged_specs(quant)
@@ -795,19 +786,16 @@ class TP_Attn:
         S = qkv.shape[0] // B
         NP = kv[0].shape[0]
         maxp = table.shape[1]
-        table3 = table.reshape(B, Hkv, maxp)
 
         @functools.partial(
             jax.shard_map, mesh=self.mesh,
             in_specs=(P(None, self.axis),) + kv_specs
-                     + (P(None, self.axis, None), P(None), P(None)),
+                     + (P(None, None), P(None), P(None)),
             out_specs=((P(None, self.axis),) + kv_specs),
             check_vma=False)
-        def f(qkv_loc, ck4, cv4, *rest):
-            *scales4, tbl, pos, q_lens = rest
-            ck, cv = ck4[:, 0], cv4[:, 0]
-            page = ck.shape[1]
-            tbl = tbl.reshape(B * hkv, maxp)
+        def f(qkv_loc, ck, cv, *rest):
+            *scales, tbl, pos, q_lens = rest
+            page = ck.shape[2]
             M = qkv_loc.shape[0]
             q = qkv_loc[:, :hq * hd].reshape(B, S, hq, hd)
             k = qkv_loc[:, hq * hd:(hq + hkv) * hd].reshape(B, S, hkv, hd)
@@ -821,24 +809,22 @@ class TP_Attn:
             p = pos[:, None] + jnp.arange(S)[None]             # [B, S]
             valid = ((jnp.arange(S)[None] < q_lens[:, None])
                      & (p < maxp * page))
-            streams = (jnp.arange(B) * hkv)[:, None, None] \
-                + jnp.arange(hkv)[None, None, :]               # [B, 1, hkv]
-            pidx = tbl[streams,
-                       jnp.minimum(p // page, maxp - 1)[:, :, None]]
+            pidx = tbl[jnp.arange(B)[:, None],
+                       jnp.minimum(p // page, maxp - 1)]
             # invalid rows scatter to page NP (out of bounds -> dropped)
-            dest = jnp.where(valid[:, :, None], pidx, NP)      # [B, S, hkv]
-            r = (p % page)[:, :, None]
+            dest = jnp.where(valid, pidx, NP)                  # [B, S]
+            r = p % page
             if quant:
-                sk, sv = scales4[0][:, 0], scales4[1][:, 0]
+                sk, sv = scales
                 k8, k_s = quantize_kv_int8(k)      # [B, S, hkv, d] / [..]
                 v8, v_s = quantize_kv_int8(v)
-                ck = ck.at[dest, r].set(k8)
-                cv = cv.at[dest, r].set(v8)
-                sk = sk.at[dest, r].set(k_s)
-                sv = sv.at[dest, r].set(v_s)
+                ck = set_page_rows(ck, dest, r, k8)
+                cv = set_page_rows(cv, dest, r, v8)
+                sk = set_page_rows(sk, dest, r, k_s)
+                sv = set_page_rows(sv, dest, r, v_s)
             else:
-                ck = ck.at[dest, r].set(k.astype(ck.dtype))
-                cv = cv.at[dest, r].set(v.astype(cv.dtype))
+                ck = set_page_rows(ck, dest, r, k)
+                cv = set_page_rows(cv, dest, r, v)
                 sk = sv = None
             lens = pos + q_lens
             qd = jnp.bfloat16 if quant else ck.dtype
@@ -848,22 +834,19 @@ class TP_Attn:
                                        kv_lens=lens, q_lens=q_lens,
                                        k_scale=sk, v_scale=sv)
             else:
-                T = maxp * page
                 kd = dequantize_kv_int8(ck, sk) if quant else ck
                 vd = dequantize_kv_int8(cv, sv) if quant else cv
-                kfull = kd[tbl].reshape(B, hkv, T, hd)
-                vfull = vd[tbl].reshape(B, hkv, T, hd)
                 o = attention_cached_ref(q.astype(jnp.float32) if quant
                                          else q.astype(ck.dtype),
-                                         kfull, vfull, lens, scale=scale,
-                                         q_lens=q_lens)
+                                         gather_pages(kd, tbl),
+                                         gather_pages(vd, tbl), lens,
+                                         scale=scale, q_lens=q_lens)
             o = o.reshape(M, hq * hd)
             if quant:
-                return (o.astype(qkv_loc.dtype), ck[:, None], cv[:, None],
-                        sk[:, None], sv[:, None])
-            return o, ck[:, None], cv[:, None]
+                return o.astype(qkv_loc.dtype), ck, cv, sk, sv
+            return o, ck, cv
 
-        out = f(qkv, *kv, table3, jnp.asarray(pos, jnp.int32),
+        out = f(qkv, *kv, table, jnp.asarray(pos, jnp.int32),
                 jnp.asarray(q_lens, jnp.int32))
         return out[0], tuple(out[1:])
 
@@ -910,7 +893,6 @@ class TP_Attn:
             sp_combine_partials
         from triton_dist_tpu.runtime import next_collective_id
         hq, hkv, hd = self._hq_loc, self._hkv_loc, self.head_dim
-        Hkv = self.n_kv_heads
         scale = hd ** -0.5
         quant = len(kv) == 4
         B = batch
@@ -935,17 +917,15 @@ class TP_Attn:
         @functools.partial(
             jax.shard_map, mesh=self.mesh, in_specs=in_specs,
             out_specs=out_specs, check_vma=False)
-        def f(qkv_loc, ck4, cv4, *rest):
+        def f(qkv_loc, ck, cv, *rest):
             if verify:
-                *scales4, tbl, pos_, ql = rest
+                *scales, tbl, pos_, ql = rest
             else:
-                *scales4, tbl, pos_ = rest
+                *scales, tbl, pos_ = rest
                 ql = None
             me = jax.lax.axis_index(sp_axis)
-            ck, cv = ck4[:, 0], cv4[:, 0]       # local shard, plane 0
-            NP_loc = ck.shape[0]
-            page = ck.shape[1]
-            X = B * hkv
+            NP_loc = ck.shape[0]                # local shard of the ids
+            page = ck.shape[2]
             q = qkv_loc[:, :hq * hd].reshape(B, S, hq, hd)
             k = qkv_loc[:, hq * hd:(hq + hkv) * hd].reshape(B, S, hkv, hd)
             v = qkv_loc[:, (hq + hkv) * hd:].reshape(B, S, hkv, hd)
@@ -960,40 +940,35 @@ class TP_Attn:
                 p = pos_[:, None] + jnp.arange(S)[None]        # [B, S]
                 valid = ((jnp.arange(S)[None] < ql[:, None])
                          & (p < maxp * page))
-                streams = (jnp.arange(B) * hkv)[:, None, None] \
-                    + jnp.arange(hkv)[None, None, :]
-                pidx_g = tbl[streams,
-                             jnp.minimum(p // page, maxp - 1)[:, :, None]]
-                owned_w = valid[:, :, None] & ((pidx_g // pps) == me)
-                dest = jnp.where(owned_w, pidx_g - me * pps, NP_loc)
-                r = (p % page)[:, :, None]
+                pidx_g = tbl[jnp.arange(B)[:, None],
+                             jnp.minimum(p // page, maxp - 1)]
+                owned_w = valid & ((pidx_g // pps) == me)
+                r = p % page
                 k_rows, v_rows = k, v
             else:
-                pos_x = jnp.repeat(pos_, hkv)                  # [X]
-                pidx_g = tbl[jnp.arange(X), pos_x // page]
+                pidx_g = tbl[jnp.arange(B), pos_ // page]      # [B]
                 owned_w = (pidx_g // pps) == me
-                dest = jnp.where(owned_w, pidx_g - me * pps, NP_loc)
-                r = pos_x % page
-                k_rows = k.reshape(X, hd)
-                v_rows = v.reshape(X, hd)
+                r = pos_ % page
+                k_rows, v_rows = k[:, 0], v[:, 0]
+            dest = jnp.where(owned_w, pidx_g - me * pps, NP_loc)
             if quant:
-                sk, sv = scales4[0][:, 0], scales4[1][:, 0]
+                sk, sv = scales
                 k8, k_s = quantize_kv_int8(k_rows)
                 v8, v_s = quantize_kv_int8(v_rows)
-                ck = ck.at[dest, r].set(k8)
-                cv = cv.at[dest, r].set(v8)
-                sk = sk.at[dest, r].set(k_s)
-                sv = sv.at[dest, r].set(v_s)
+                ck = set_page_rows(ck, dest, r, k8)
+                cv = set_page_rows(cv, dest, r, v8)
+                sk = set_page_rows(sk, dest, r, k_s)
+                sv = set_page_rows(sv, dest, r, v_s)
             else:
-                ck = ck.at[dest, r].set(k_rows.astype(ck.dtype))
-                cv = cv.at[dest, r].set(v_rows.astype(cv.dtype))
+                ck = set_page_rows(ck, dest, r, k_rows)
+                cv = set_page_rows(cv, dest, r, v_rows)
                 sk = sv = None
             lens = pos_ + (ql if verify else 1)
             # --- local redirected table + per-tile ownership mask:
             # non-owned tiles repeat the last owned local page (their
             # surplus DMAs elide) and mask to accumulator no-ops ---
-            owned_t = (tbl // pps) == me                   # [X, maxp]
-            ti = jax.lax.broadcasted_iota(jnp.int32, (X, maxp), 1)
+            owned_t = (tbl // pps) == me                   # [B, maxp]
+            ti = jax.lax.broadcasted_iota(jnp.int32, (B, maxp), 1)
             lastown = jax.lax.cummax(jnp.where(owned_t, ti, -1), axis=1)
             tbl_loc = jnp.take_along_axis(
                 jnp.where(owned_t, tbl - me * pps, 0),
@@ -1009,9 +984,8 @@ class TP_Attn:
                                     out_dtype=jnp.float32)
             o = o.reshape(M, hq * hd).astype(qkv_loc.dtype)
             if quant:
-                return (o, ck[:, None], cv[:, None],
-                        sk[:, None], sv[:, None])
-            return o, ck[:, None], cv[:, None]
+                return o, ck, cv, sk, sv
+            return o, ck, cv
 
         args = (qkv,) + tuple(kv) + (table, jnp.asarray(pos, jnp.int32))
         if verify:

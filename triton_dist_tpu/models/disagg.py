@@ -10,7 +10,7 @@ prompt tokens through the decode mesh's forward, so prefill traffic
 sets the inter-token floor whenever admissions are hot. The production
 topology separates the two regimes onto different hardware: PREFILL
 WORKERS (compute-bound, batch=1 long forwards) compute a prompt's KV
-into a staging paged pool and push the finished page-groups to the
+into a staging paged pool and push the finished pages to the
 DECODE workers (bandwidth-bound, q_len=1 forever), which install the
 pages and arm the slot. Decode ticks never see a prefill q_len again:
 `stats()["max_prefill_tokens_per_poll"]` is structurally 0 on the
@@ -63,7 +63,7 @@ SCHEDULING (DisaggScheduler): admission becomes two-pool —
    mid-transfer releases staging and retries).
 4. INSTALL: the decode side runs the normal `_reserve_pages` flow
    (prefix lookup, refcounts, eviction, CoW bookkeeping), restores
-   the transferred payload into the fresh groups covering the
+   the transferred payload into the fresh pages covering the
    uncached extent, installs the table, inserts the prompt into the
    radix tree (a transferred prefix is immediately shareable) and
    arms the slot with the transferred logits (`kv_install` instant,
@@ -122,7 +122,7 @@ class PrefillWorkerDied(RuntimeError):
 class KVHandoff:
     """One finished prefill in flight to the decode mesh: the request,
     the prompt's page payload in extract_pages_host wire format
-    (k/v [L, npp*Hkv, page, d] raw pool-dtype bytes, ks/vs scale
+    (k/v [L, npp, Hkv, page, d] raw pool-dtype bytes, ks/vs scale
     planes when the pool is int8), and the arming logits row the
     decode slot needs (the fused admission gets it from the same
     forward — the device transports ship it alongside the pages).
@@ -134,7 +134,7 @@ class KVHandoff:
     off, no chain)."""
     req: Request
     n: int                              # prompt length
-    npp: int                            # prompt page-groups staged
+    npp: int                            # prompt pages staged
     payload: Dict[str, Optional[np.ndarray]]
     logits_row: np.ndarray              # [V] f32
     t_push: float = 0.0
@@ -239,8 +239,8 @@ class PrefillWorker:
     existing bucketed prefill program (`Engine.admit_slot_paged` at
     kv_start=0 — the SAME executable the fused admission runs, which
     is what makes the handoff bitwise), one job at a time. A job
-    allocates the prompt's page groups, runs the forward, extracts the
-    payload (+ arming logits) and ALWAYS releases the staging groups —
+    allocates the prompt's pages, runs the forward, extracts the
+    payload (+ arming logits) and ALWAYS releases the staging pages —
     the staging allocator's zero-leak invariant
     (available + outstanding == num_pages) holds between jobs even
     under injected worker death (tests/test_disagg.py)."""
@@ -259,9 +259,7 @@ class PrefillWorker:
         self.cache = engine.make_paged_slot_cache(1, page=page,
                                                   num_pages=num_pages,
                                                   for_ticks=False)
-        Hkv = engine.traits.kv_heads
-        self.hkv = Hkv
-        self.pool = RefcountedPages(self.cache.num_pages, Hkv)
+        self.pool = RefcountedPages(self.cache.num_pages)
         assert self.pool.trash == self.cache.trash
         self.fault = fault
         self.prefill_tokens = 0      # prompt tokens this worker forwarded
@@ -279,14 +277,14 @@ class PrefillWorker:
     @property
     def capacity(self) -> int:
         """Longest prompt one job can stage."""
-        usable = (self.pool.num_pages - 1) // self.hkv
+        usable = self.pool.num_pages - 1
         return min(self.cache.capacity, usable * self.page)
 
     def prefill(self, req: Request) -> KVHandoff:
         """Run one job: full-prompt prefill into staging pages, then
-        extract the payload in the host-tier wire format (per-page
-        owning-plane gather on TP-sharded pools) and the arming
-        logits. Staging groups are released on every exit path."""
+        extract the payload in the host-tier wire format and the
+        arming logits. Staging pages are released on every exit
+        path."""
         import jax
         tokens = np.asarray(req.ids, np.int32).reshape(-1)
         n = len(tokens)
@@ -297,37 +295,33 @@ class PrefillWorker:
                 f"request {req.rid!r}: prompt {n} exceeds prefill "
                 f"staging capacity {self.capacity}")
         npp = -(-n // self.page)
-        groups: List[np.ndarray] = []
+        pages: List[int] = []
         t_dev = time.perf_counter()
         try:
             for _ in range(npp):
-                groups.append(self.pool.alloc_group())
+                pages.append(self.pool.alloc_page())
             if self.pool.pages_in_use > self.pages_peak:
                 self.pages_peak = self.pool.pages_in_use
-            maxp = self.cache.table.shape[1]
-            rows = np.full((self.hkv, maxp), self.cache.trash, np.int32)
-            for j, g in enumerate(groups):
-                rows[:, j] = g
-            trash_vec = np.full((self.hkv,), self.cache.trash, np.int32)
+            trash = self.cache.trash
+            rows = np.full((self.cache.table.shape[1],), trash, np.int32)
+            rows[:npp] = pages
             row, self.cache = self.engine.admit_slot_paged(
-                self.cache, 0, tokens, rows, 0, trash_vec, trash_vec, 0)
+                self.cache, 0, tokens, rows, 0, trash, trash, 0)
             if self.fault is not None and getattr(
                     self.fault, "prefill_worker", None) is not None \
                     and self.fault.prefill_worker(req.rid):
                 raise PrefillWorkerDied(
                     f"request {req.rid!r}: prefill worker killed "
                     f"mid-transfer (chaos injection)")
-            ids = np.concatenate(groups)
-            heads = np.tile(np.arange(self.hkv, dtype=np.int32), npp)
-            out = self.engine.extract_pages_host(self.cache, ids,
-                                                 heads=heads)
+            out = self.engine.extract_pages_host(
+                self.cache, np.asarray(pages, np.int32))
             payload = dict(zip(("k", "v", "ks", "vs"), out))
             payload.setdefault("ks", None)
             payload.setdefault("vs", None)
             logits_np = np.asarray(jax.device_get(row), np.float32)
         finally:
             self.device_s += time.perf_counter() - t_dev
-            for g in groups:
+            for g in pages:
                 self.pool.release(g)
         self.prefill_tokens += n
         return KVHandoff(req=req, n=n, npp=npp, payload=payload,
@@ -563,7 +557,7 @@ class DisaggScheduler(ContinuousScheduler):
                   args={"rid": str(rid),
                         "transport": getattr(self.transport, "name",
                                              "?")})
-        self._c_pages.inc(handoff.npp * worker.hkv)
+        self._c_pages.inc(handoff.npp)
         self._c_bytes.inc(sum(a.nbytes for a in
                               handoff.wire_arrays().values()
                               if a is not None))
@@ -619,7 +613,7 @@ class DisaggScheduler(ContinuousScheduler):
         slots = self.slots
         req, n = handoff.req, handoff.n
         tokens = np.asarray(req.ids, np.int32).reshape(-1)
-        slot_groups, m, rows, _cs, _cd, r, boundary = \
+        slot_pages, m, rows, _cs, _cd, r, boundary = \
             slots._reserve_pages(req, tokens)
         pool = slots.prefix.pool
         if boundary is not None:
@@ -627,26 +621,25 @@ class DisaggScheduler(ContinuousScheduler):
             # page arrives in the payload — the cached source is not
             # read at all
             pool.release(boundary)
-        hkv = pool.n_kv_heads
         npp = -(-n // slots.page)
         full = m // slots.page
         t_dev = time.perf_counter()
         t_span = time.monotonic()
         try:
-            trash_vec = np.full((hkv,), slots.cache.trash, np.int32)
+            trash = slots.cache.trash
             slots.cache = self.engine.install_slot_paged(
-                slots.cache, slot, rows, trash_vec, trash_vec, 0)
-            target = slot_groups[full:npp]
+                slots.cache, slot, rows, trash, trash, 0)
+            target = slot_pages[full:npp]
             if target:
-                ids = np.concatenate(target)
-                sl = slice(full * hkv, npp * hkv)
+                ids = np.asarray(target, np.int32)
+                sl = slice(full, npp)
                 pl = handoff.payload
                 slots.cache = self.engine.restore_pages_host(
                     slots.cache, ids, pl["k"][:, sl], pl["v"][:, sl],
                     None if pl["ks"] is None else pl["ks"][:, sl],
                     None if pl["vs"] is None else pl["vs"][:, sl])
         except Exception:
-            for g in slot_groups:
+            for g in slot_pages:
                 pool.release(g)
             raise
         # the table install + payload restore are the transfer plane's
@@ -655,12 +648,12 @@ class DisaggScheduler(ContinuousScheduler):
         slots.device_wait_by_kind["transfer"] = \
             slots.device_wait_by_kind.get("transfer", 0.0) \
             + (time.perf_counter() - t_dev)
-        slots._groups[slot] = slot_groups
+        slots._pages[slot] = slot_pages
         slots._tokens[slot] = _TokenLog(tokens)
         slots.prefix.record(n, m)
         # a transferred prefix is immediately shareable: the next
         # admission — even one installing in the same poll — maps it
-        slots.prefix.insert(tokens, slot_groups[:npp])
+        slots.prefix.insert(tokens, slot_pages[:npp])
         slots._arm_slot(slot, req, jnp.asarray(handoff.logits_row), n)
         self._c_transfers.inc()
         if handoff.t_push:

@@ -28,6 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from triton_dist_tpu.kernels.paged_kv import set_page_rows
 from triton_dist_tpu.runtime.telemetry import default_registry
 
 # every string Engine(backend=) serves: the module docstring's table
@@ -679,7 +680,7 @@ class Engine:
     def install_slot_paged(self, pcache, slot: int, rows, cow_src,
                            cow_dst, cow_rows: int):
         """Chunk 0 of a CHUNKED paged admission: install the slot's
-        table row block and copy-on-write the partially matched
+        table row and copy-on-write the partially matched
         boundary page — the one-time half of admit_slot_paged, with the
         suffix prefill left to the mixed-chunk ticks (which resolve
         their KV scatter and attention through the table just
@@ -756,7 +757,7 @@ class Engine:
         maxp = -(-self.max_seq // page)
         sp_ax = getattr(self.model, "sp_axis", None)
         if num_pages is None:
-            num_pages = batch * Hkv * maxp + 1
+            num_pages = batch * maxp + 1
             if self.sp_size > 1:
                 # the default rounds UP to the sp partition (each chip
                 # owns a whole contiguous id block)
@@ -783,12 +784,13 @@ class Engine:
         n - kv_start uncached suffix tokens are computed, bucketed to
         `pad_to` like prefill_into_slot).
 
-        rows: [Hkv, max_pages] int32 — the slot's full table row block
-        (shared prefix pages + fresh writable pages, trash-padded).
-        cow_src/cow_dst: [Hkv] page groups for the copy-on-write of a
-        partially-matched boundary page (cow_rows valid rows are copied
-        src -> dst before anything reads the slot's table; pass the
-        trash page for both when kv_start is page-aligned).
+        rows: [max_pages] int32 — the slot's full table row (shared
+        prefix pages + fresh writable pages, trash-padded).
+        cow_src/cow_dst: page ids for the copy-on-write of a
+        partially-matched boundary page (cow_rows valid rows of every
+        head are copied src -> dst before anything reads the slot's
+        table; pass the trash page for both when kv_start is
+        page-aligned).
 
         Returns (next-token logits [V], pcache). One XLA program per
         suffix bucket; kv_start/slot/cow are traced data.
@@ -881,13 +883,12 @@ class Engine:
         return toks, logits, pcache, pos, keys
 
     def retire_slot_paged(self, pcache, slot: int):
-        """Point the whole table row block of a retired slot at the
-        trash page (the write sink): the slot scan keeps stepping
-        masked rows, and their scatters must never land on a page the
+        """Point the whole table row of a retired slot at the trash
+        page (the write sink): the slot scan keeps stepping masked
+        rows, and their scatters must never land on a page the
         allocator may have handed to someone else."""
-        rows = jnp.full((self.traits.kv_heads, pcache.table.shape[1]),
-                        pcache.trash, jnp.int32)
-        return self._paged_set_table(pcache, rows, jnp.int32(slot))
+        row = jnp.full((pcache.table.shape[1],), pcache.trash, jnp.int32)
+        return self._paged_set_table(pcache, row, jnp.int32(slot))
 
     # ------------------------------------------------------------------
     # host KV tier (models/kv_tier.py): demote/promote page spans
@@ -896,47 +897,29 @@ class Engine:
     # the PagedDecodeSlots callbacks.
     # ------------------------------------------------------------------
 
-    def extract_pages_host(self, pcache, page_ids, *, heads=None,
-                           pad_to: int = 8):
+    def extract_pages_host(self, pcache, page_ids, *, pad_to: int = 8):
         """DEMOTION d2h (also the disaggregated-serving WIRE FORMAT —
         models/disagg.py ships exactly these arrays from the prefill
         plane's staging pool to the decode pool, a transferred page
         being a demoted page with a different destination): gather the
         listed physical pages out of every
         layer's K/V pool and return them as host arrays
-        (k, v each [L, N, page, d], pool dtype — the raw bytes, so a
-        later restore is bitwise; an int8 pool appends its scale
-        planes (k, v, ks, vs) so the scales ride the same transfer).
-        The id list is trash-padded to a pad_to bucket (bounded
-        executable count; the padded reads are sliced off before
-        returning). The gather is dispatched async — the device_get
-        below is the synchronization point, i.e. the copy overlaps
-        whatever was already in flight.
-
-        heads: the kv-head index behind each page id (page groups are
-        head-ordered, so callers always know it — the scheduler's tier
-        callback passes tile(arange(Hkv))). REQUIRED on a TP-sharded
-        pool (head_groups > 1): it selects each page's owning payload
-        plane so the gathered bytes are the true ones; ignored on a
-        single-group pool."""
+        (k, v each [L, N, Hkv, page, d], pool dtype — the raw bytes of
+        every head, so a later restore is bitwise, whatever the mesh
+        the pool's heads are sharded over; an int8 pool appends its
+        scale planes (k, v, ks, vs) so the scales ride the same
+        transfer). The id list is trash-padded to a pad_to bucket
+        (bounded executable count; the padded reads are sliced off
+        before returning). The gather is dispatched async — the
+        device_get below is the synchronization point, i.e. the copy
+        overlaps whatever was already in flight."""
         import numpy as np
         ids = np.asarray(page_ids, np.int32).reshape(-1)
         n = len(ids)
-        G = pcache.head_groups
-        if G > 1 and heads is None:
-            raise ValueError(
-                "extract_pages_host on a TP-sharded pool needs the "
-                "per-page kv-head indices (heads=...) to pick each "
-                "page's owning payload plane")
         P = max(-(-n // pad_to) * pad_to, pad_to)
         padded = np.full((P,), pcache.trash, np.int32)
         padded[:n] = ids
-        owners = np.zeros((P,), np.int32)
-        if heads is not None and G > 1:
-            hkv_loc = self.traits.kv_heads // G
-            owners[:n] = np.asarray(heads, np.int32) // hkv_loc
-        out = self._gather_pages(self.model, pcache, jnp.asarray(padded),
-                                 jnp.asarray(owners))
+        out = self._gather_pages(self.model, pcache, jnp.asarray(padded))
         # one device_get over every array: the K/V (and scale) d2h
         # transfers overlap instead of serializing on the eviction
         # critical path
@@ -967,15 +950,15 @@ class Engine:
         P = max(-(-n // pad_to) * pad_to, pad_to)
         padded = np.full((P,), pcache.trash, np.int32)
         padded[:n] = ids
-        L, _, page, d = host_k.shape
-        hk = np.zeros((L, P, page, d), host_k.dtype)
-        hv = np.zeros((L, P, page, d), host_v.dtype)
+        L = host_k.shape[0]
+        hk = np.zeros((L, P) + host_k.shape[2:], host_k.dtype)
+        hv = np.zeros((L, P) + host_v.shape[2:], host_v.dtype)
         hk[:, :n] = host_k
         hv[:, :n] = host_v
         hsk = hsv = None
         if host_ks is not None:
-            hsk = np.zeros((L, P, page), host_ks.dtype)
-            hsv = np.zeros((L, P, page), host_vs.dtype)
+            hsk = np.zeros((L, P) + host_ks.shape[2:], host_ks.dtype)
+            hsv = np.zeros((L, P) + host_vs.shape[2:], host_vs.dtype)
             hsk[:, :n] = host_ks
             hsv[:, :n] = host_vs
             hsk, hsv = jnp.asarray(hsk), jnp.asarray(hsv)
@@ -1485,13 +1468,20 @@ def _mixed_verify_fn(backend, sampling, params, paged, model, cache, pos,
     return n_emit, t0n, sel_logits, cache, pos, keys
 
 
-def _pool_gather_heads(mesh, axis, pool, rows):
+def _page_rows(g):
+    """Gathered pages [n, h, page(, d)] -> each head's contiguous rows
+    [h, n*page(, d)]: what a contiguous scratch cache holds."""
+    g = jnp.swapaxes(g, 0, 1)
+    return g.reshape((g.shape[0], -1) + g.shape[3:])
+
+
+def _pool_gather_heads(mesh, axis, pool, row):
     """Head-aligned pool gather (the admit program's prefix read on
-    the TP-sharded pool): rows [Hkv, maxp] page ids -> the mapped
-    pages' bytes [Hkv, maxp*page(, d)], each rank reading its OWN
-    kv-head group's plane of the [NP, G, page(, d)] pool. Comm-free by
-    construction — the output is head-sharded exactly like the
-    contiguous scratch it fills."""
+    the TP-sharded pool): row [maxp] page ids -> the mapped pages'
+    bytes [Hkv, maxp*page(, d)], each rank reading its OWN kv heads of
+    the [NP, Hkv, page(, d)] pool. Comm-free by construction — the
+    output is head-sharded exactly like the contiguous scratch it
+    fills."""
     from jax.sharding import PartitionSpec as P
     if pool.ndim == 4:
         in_p, out_p = P(None, axis, None, None), P(axis, None, None)
@@ -1499,20 +1489,19 @@ def _pool_gather_heads(mesh, axis, pool, rows):
         in_p, out_p = P(None, axis, None), P(axis, None)
 
     @functools.partial(jax.shard_map, mesh=mesh,
-                       in_specs=(in_p, P(axis, None)), out_specs=out_p,
+                       in_specs=(in_p, P(None)), out_specs=out_p,
                        check_vma=False)
-    def f(p_loc, rows_loc):
-        g = p_loc[:, 0][rows_loc]        # [h_loc, maxp, page(, d)]
-        return g.reshape((g.shape[0], -1) + g.shape[3:])
+    def f(p_loc, row_):
+        return _page_rows(p_loc[row_])   # [maxp, h_loc, page(, d)]
 
-    return f(pool, rows)
+    return f(pool, row)
 
 
 def _pool_scatter_heads(mesh, axis, pool, dest, ri, u):
     """Head-aligned pool scatter (the admit program's suffix
     write-back): u [Hkv, S(, d)] — a head-sharded scratch slice — lands
-    at (dest [Hkv, S] page ids, ri [S] in-page rows) of each rank's
-    own plane of the [NP, G, page(, d)] pool. Trash dest ids are the
+    at (dest [S] page ids, ri [S] in-page rows) of each rank's own
+    heads of the [NP, Hkv, page(, d)] pool. Trash dest ids are the
     sanctioned sink (pad-bucket tail rows)."""
     from jax.sharding import PartitionSpec as P
     if pool.ndim == 4:
@@ -1521,12 +1510,10 @@ def _pool_scatter_heads(mesh, axis, pool, dest, ri, u):
         in_p, u_p = P(None, axis, None), P(axis, None)
 
     @functools.partial(jax.shard_map, mesh=mesh,
-                       in_specs=(in_p, P(axis, None), P(None), u_p),
+                       in_specs=(in_p, P(None), P(None), u_p),
                        out_specs=in_p, check_vma=False)
-    def f(p_loc, dest_loc, ri, u_loc):
-        p2 = p_loc[:, 0].at[dest_loc, ri[None]].set(
-            u_loc.astype(p_loc.dtype))
-        return p2[:, None]
+    def f(p_loc, dest_, ri_, u_loc):
+        return set_page_rows(p_loc, dest_, ri_, jnp.swapaxes(u_loc, 0, 1))
 
     return f(pool, dest, ri, u)
 
@@ -1547,100 +1534,110 @@ def _sp_owned_local(ids, pps, me, *, oob=None):
     return owned, loc
 
 
-def _pool_gather_sp(mesh, sp_axis, pool, rows):
+def _sp_specs(sp_axis, pool):
+    from jax.sharding import PartitionSpec as P
+    tail = (None,) * (pool.ndim - 1)
+    return P(sp_axis, *tail), P(None, *tail)
+
+
+def _pool_gather_sp(mesh, sp_axis, pool, ids):
     """Page gather on the SP-sharded pool (the admit program's prefix
-    read — kv_cache.PagedSlotCache SP SHARDING): rows [Hkv, maxp]
-    GLOBAL page ids -> the mapped pages' bytes [Hkv, maxp*page(, d)]
+    read, the demotion's d2h — kv_cache.PagedSlotCache SP SHARDING):
+    ids [n] GLOBAL page ids -> the pages' bytes [n, Hkv, page(, d)]
     REPLICATED over sp. Each chip reads the pages it owns (others
     contribute zeros) and one psum assembles the full span — traffic
     is exactly the gathered bytes, never the pool (floats sum x+0+...
     exactly, so the assembly is bitwise)."""
     from jax.sharding import PartitionSpec as P
-    if pool.ndim == 4:
-        in_p, out_p = P(sp_axis, None, None, None), P(None, None, None)
-    else:
-        in_p, out_p = P(sp_axis, None, None), P(None, None)
+    in_p, out_p = _sp_specs(sp_axis, pool)
 
     @functools.partial(jax.shard_map, mesh=mesh,
-                       in_specs=(in_p, P(None, None)), out_specs=out_p,
+                       in_specs=(in_p, P(None)), out_specs=out_p,
                        check_vma=False)
-    def f(p_loc, rows_loc):
+    def f(p_loc, ids_):
         pps = p_loc.shape[0]
         me = jax.lax.axis_index(sp_axis)
-        owned, loc = _sp_owned_local(rows_loc, pps, me)
-        g = p_loc[:, 0][loc]             # [Hkv, maxp, page(, d)]
-        mask = owned.reshape(owned.shape + (1,) * (g.ndim - 2))
+        owned, loc = _sp_owned_local(ids_, pps, me)
+        g = p_loc[loc]                   # [n, Hkv, page(, d)]
+        mask = owned.reshape(owned.shape + (1,) * (g.ndim - 1))
         g = jnp.where(mask, g, 0).astype(p_loc.dtype)
-        g = jax.lax.psum(g, sp_axis)
-        return g.reshape((g.shape[0], -1) + g.shape[3:])
+        return jax.lax.psum(g, sp_axis)
 
-    return f(pool, rows)
+    return f(pool, ids)
 
 
 def _pool_scatter_sp(mesh, sp_axis, pool, dest, ri, u):
     """Page-row scatter on the SP-sharded pool (the admit program's
     suffix write-back): u [Hkv, S(, d)] replicated rows land at
-    (dest [Hkv, S] GLOBAL page ids, ri [S] in-page rows). Each chip
+    (dest [S] GLOBAL page ids, ri [S] in-page rows). Each chip
     writes ONLY the pages it owns — non-owned (and deliberately
     out-of-range) destinations redirect past the local shard and the
     scatter drops them, so the write is comm-free. Global trash ids
     land in shard 0's local trash page, the sanctioned sink."""
     from jax.sharding import PartitionSpec as P
-    if pool.ndim == 4:
-        in_p, u_p = P(sp_axis, None, None, None), P(None, None, None)
-    else:
-        in_p, u_p = P(sp_axis, None, None), P(None, None)
+    in_p, _ = _sp_specs(sp_axis, pool)
+    u_p = P(*(None,) * u.ndim)
 
     @functools.partial(jax.shard_map, mesh=mesh,
-                       in_specs=(in_p, P(None, None), P(None), u_p),
+                       in_specs=(in_p, P(None), P(None), u_p),
                        out_specs=in_p, check_vma=False)
-    def f(p_loc, dest_loc, ri_, u_loc):
+    def f(p_loc, dest_, ri_, u_loc):
         pps = p_loc.shape[0]
         me = jax.lax.axis_index(sp_axis)
-        _, loc = _sp_owned_local(dest_loc, pps, me, oob=pps)
-        p2 = p_loc[:, 0].at[loc, ri_[None]].set(
-            u_loc.astype(p_loc.dtype))
-        return p2[:, None]
+        _, loc = _sp_owned_local(dest_, pps, me, oob=pps)
+        return set_page_rows(p_loc, loc, ri_, jnp.swapaxes(u_loc, 0, 1))
 
     return f(pool, dest, ri, u)
 
 
-def _cow_pages_sp(mesh, sp_axis, pool, cow_src, cow_dst, cow_r, page):
-    """Boundary-page copy-on-write on the SP-sharded pool: the source
-    group's valid rows [0, cow_r) copy into the destination group —
+def _pool_put_sp(mesh, sp_axis, pool, ids, h):
+    """Whole-page install on the SP-sharded pool (the promotion's
+    h2d): h [n, Hkv, page(, d)] replicated lands in pages ids [n]
+    (GLOBAL); each chip keeps the pages it owns, the rest drop."""
+    from jax.sharding import PartitionSpec as P
+    in_p, h_p = _sp_specs(sp_axis, pool)
+
+    @functools.partial(jax.shard_map, mesh=mesh,
+                       in_specs=(in_p, P(None), h_p), out_specs=in_p,
+                       check_vma=False)
+    def f(p_loc, ids_, h_):
+        pps = p_loc.shape[0]
+        me = jax.lax.axis_index(sp_axis)
+        _, loc = _sp_owned_local(ids_, pps, me, oob=pps)
+        return p_loc.at[loc].set(h_.astype(p_loc.dtype))
+
+    return f(pool, ids, h)
+
+
+def _cow_page(mesh, sp_axis, pool, cow_src, cow_dst, cow_r):
+    """Boundary-page copy-on-write: rows [0, cow_r) of page cow_src,
+    every head, copy into page cow_dst (the slot's own fresh page,
+    which then receives the request's diverging writes). cow_r == 0
+    (a page-aligned match) writes nothing. On the head-sharded pool a
+    whole-page copy stays on its chip. On the SP pool (sp_axis set)
     src and dst may live on DIFFERENT shards (the allocator rotates
-    fresh groups), so the copy is one owned-page gather (+psum) and
-    one owned-page scatter. cow_r == 0 (page-aligned match) writes
-    nothing: every destination redirects out of range."""
-    NP = pool.shape[0]
-    src = _pool_gather_sp(mesh, sp_axis, pool, cow_src[:, None])
-    # [Hkv, page(, d)] — the boundary page's bytes, replicated
-    if pool.ndim == 4:
-        src = src.reshape(cow_src.shape[0], page, -1)
-    dest = jnp.where(jnp.arange(page)[None, :] < cow_r,
-                     cow_dst[:, None], NP)        # global OOB = no-op
-    return _pool_scatter_sp(mesh, sp_axis, pool, dest,
-                            jnp.arange(page), src)
+    fresh pages), so the copy is one owned-page gather (+psum) and
+    one owned-page row scatter whose rows past cow_r redirect out of
+    range."""
+    page = pool.shape[2]
+    live = jnp.arange(page) < cow_r
+    if sp_axis is not None:
+        src = _pool_gather_sp(mesh, sp_axis, pool, cow_src[None])[0]
+        dest = jnp.where(live, cow_dst, pool.shape[0])   # OOB = no-op
+        return _pool_scatter_sp(mesh, sp_axis, pool, dest,
+                                jnp.arange(page), src)
+    mask = live.reshape((1, page) + (1,) * (pool.ndim - 3))
+    return pool.at[cow_dst].set(
+        jnp.where(mask, pool[cow_src], pool[cow_dst]))
 
 
-def _paged_install_fn(model, pcache, rows, slot, cow_src, cow_dst,
+def _paged_install_fn(model, pcache, row, slot, cow_src, cow_dst,
                       cow_r):
     """Table install + boundary-page copy-on-write for a CHUNKED paged
     admission (chunk 0): exactly the pre-forward half of
     _paged_admit_fn. The CoW must happen before ANY chunk forward reads
-    the slot's table — the boundary page's valid rows [0, cow_r) are
-    copied from the shared original into the slot's own fresh page,
-    which then receives the request's diverging writes. An int8 pool
-    copies the boundary page's scale rows alongside.
-
-    TP pool ([NP, G, page, d]): the CoW copies ALL G planes of the
-    boundary page — only the owning head's plane holds real bytes, but
-    copying the others' garbage is harmless (never read) and keeps the
-    copy a plain plane-aligned gather/scatter GSPMD keeps local.
-
-    SP pool (model.sp_axis set — the page-id space sharded over sp):
-    src and dst groups may live on different chips, so the CoW runs as
-    one owned-page gather + one owned-page scatter (_cow_pages_sp).
+    the slot's table (_cow_page). An int8 pool copies the boundary
+    page's scale rows alongside.
 
     `model` rides in ONLY for the mesh/sp_axis statics (its weights
     are dead arguments XLA prunes): a Mesh cannot live on the cache as
@@ -1649,47 +1646,24 @@ def _paged_install_fn(model, pcache, rows, slot, cow_src, cow_dst,
     cache-movement programs (install/gather/restore) take the model
     like every other serving program does."""
     import dataclasses
-    page = pcache.page
-    Hkv = rows.shape[0]
     sp_ax = getattr(model, "sp_axis", None) if pcache.sp > 1 else None
-    table = jax.lax.dynamic_update_slice(pcache.table, rows,
-                                         (slot * Hkv, 0))
-    rowmask = (jnp.arange(page) < cow_r)[None, None, :, None]
-    rowmask2 = rowmask[..., 0]
-    pk, pv, psk, psv = [], [], [], []
-    for li in range(len(pcache.pages_k)):
-        k, v = pcache.pages_k[li], pcache.pages_v[li]
-        if sp_ax is not None:
-            pk.append(_cow_pages_sp(model.mesh, sp_ax, k, cow_src,
-                                    cow_dst, cow_r, page))
-            pv.append(_cow_pages_sp(model.mesh, sp_ax, v, cow_src,
-                                    cow_dst, cow_r, page))
-        else:
-            pk.append(k.at[cow_dst].set(
-                jnp.where(rowmask, k[cow_src], k[cow_dst])))
-            pv.append(v.at[cow_dst].set(
-                jnp.where(rowmask, v[cow_src], v[cow_dst])))
-        if pcache.scales_k:
-            s_k, s_v = pcache.scales_k[li], pcache.scales_v[li]
-            if sp_ax is not None:
-                psk.append(_cow_pages_sp(model.mesh, sp_ax, s_k,
-                                         cow_src, cow_dst, cow_r, page))
-                psv.append(_cow_pages_sp(model.mesh, sp_ax, s_v,
-                                         cow_src, cow_dst, cow_r, page))
-            else:
-                psk.append(s_k.at[cow_dst].set(
-                    jnp.where(rowmask2, s_k[cow_src], s_k[cow_dst])))
-                psv.append(s_v.at[cow_dst].set(
-                    jnp.where(rowmask2, s_v[cow_src], s_v[cow_dst])))
-    return dataclasses.replace(pcache, pages_k=tuple(pk),
-                               pages_v=tuple(pv), scales_k=tuple(psk),
-                               scales_v=tuple(psv), table=table)
+    table = jax.lax.dynamic_update_slice(pcache.table, row[None],
+                                         (slot, 0))
+
+    def cow(pools):
+        return tuple(_cow_page(model.mesh, sp_ax, p, cow_src, cow_dst,
+                               cow_r) for p in pools)
+
+    return dataclasses.replace(
+        pcache, pages_k=cow(pcache.pages_k), pages_v=cow(pcache.pages_v),
+        scales_k=cow(pcache.scales_k), scales_v=cow(pcache.scales_v),
+        table=table)
 
 
-def _paged_admit_fn(model, ids, scratch, pcache, rows, slot, m, n,
+def _paged_admit_fn(model, ids, scratch, pcache, row, slot, m, n,
                     cow_src, cow_dst, cow_r, *, mode):
     """Paged admission program (one per suffix bucket): install the
-    slot's table rows, copy-on-write the partially-matched boundary
+    slot's table row, copy-on-write the partially-matched boundary
     page, gather the slot's mapped pages into the contiguous scratch,
     run the suffix forward from offset m (the prefill-from-offset —
     positions [m, n) only), and scatter the computed suffix KV back
@@ -1705,13 +1679,12 @@ def _paged_admit_fn(model, ids, scratch, pcache, rows, slot, m, n,
     derive from engine.kv_dtype), so the two branches can never be
     mismatched.
 
-    TP pool ([NP, G, page, d] head-sharded): the prefix gather and the
-    suffix scatter run HEAD-ALIGNED under shard_map
+    TP pool ([NP, Hkv, page, d] head-sharded): the prefix gather and
+    the suffix scatter run HEAD-ALIGNED under shard_map
     (_pool_gather_heads / _pool_scatter_heads) — each rank moves its
-    own kv heads' page bytes between its pool plane and its shard of
+    own kv heads' bytes between its shard of the pool and its shard of
     the (head-sharded) contiguous scratch, so the whole admission
-    stays ONE sharded program with zero cross-chip page traffic; the
-    CoW copies all planes (garbage planes are never read).
+    stays ONE sharded program with zero cross-chip page traffic.
 
     SP pool (model.sp_axis — the page-id space sharded over sp,
     kv_cache.PagedSlotCache SP SHARDING): the prefix gather assembles
@@ -1720,38 +1693,31 @@ def _paged_admit_fn(model, ids, scratch, pcache, rows, slot, m, n,
     the replicated contiguous scratch, and the suffix scatter is
     comm-free (each chip keeps only the rows of pages it owns,
     _pool_scatter_sp); the boundary CoW crosses shards as a gather +
-    scatter (the allocator rotates groups, so src and dst need not be
+    scatter (the allocator rotates pages, so src and dst need not be
     co-resident)."""
     import dataclasses
     page = pcache.page
-    Hkv, maxp = rows.shape
-    T_pool = maxp * page
-    d = pcache.pages_k[0].shape[3]
+    maxp = row.shape[0]
+    Hkv, d = pcache.kv_heads, pcache.pages_k[0].shape[3]
     mesh, axis = model.mesh, model.axis
     sp_ax = getattr(model, "sp_axis", None) if pcache.sp > 1 else None
     quant = bool(pcache.scales_k)
-    table = jax.lax.dynamic_update_slice(pcache.table, rows,
-                                         (slot * Hkv, 0))
-    rowmask = (jnp.arange(page) < cow_r)[None, None, :, None]
-    rowmask2 = rowmask[..., 0]                  # [1, 1, page] (scales)
+    table = jax.lax.dynamic_update_slice(pcache.table, row[None],
+                                         (slot, 0))
     S_pad = ids.shape[1]
     p = m + jnp.arange(S_pad)
     valid = p < n
     pi = jnp.minimum(p // page, maxp - 1)
     ri = p % page
-    dest = jnp.where(valid[None], rows[:, pi], pcache.trash)  # [Hkv, S_pad]
+    dest = jnp.where(valid, row[pi], pcache.trash)           # [S_pad]
 
-    def cow(pool, mask):
-        if sp_ax is not None:
-            return _cow_pages_sp(mesh, sp_ax, pool, cow_src, cow_dst,
-                                 cow_r, page)
-        return pool.at[cow_dst].set(
-            jnp.where(mask, pool[cow_src], pool[cow_dst]))
+    def cow(pool):
+        return _cow_page(mesh, sp_ax, pool, cow_src, cow_dst, cow_r)
 
     def gather(pool):
         if sp_ax is not None:
-            return _pool_gather_sp(mesh, sp_ax, pool, rows)
-        return _pool_gather_heads(mesh, axis, pool, rows)
+            return _page_rows(_pool_gather_sp(mesh, sp_ax, pool, row))
+        return _pool_gather_heads(mesh, axis, pool, row)
 
     def scatter(pool, u):
         if sp_ax is not None:
@@ -1763,8 +1729,8 @@ def _paged_admit_fn(model, ids, scratch, pcache, rows, slot, m, n,
     sk, sv = list(scratch.k), list(scratch.v)
     ssk, ssv = list(scratch.ks), list(scratch.vs)
     for li in range(len(pk)):
-        pk[li] = cow(pk[li], rowmask)
-        pv[li] = cow(pv[li], rowmask)
+        pk[li] = cow(pk[li])
+        pv[li] = cow(pv[li])
         kf = gather(pk[li])[None]
         vf = gather(pv[li])[None]
         sk[li] = jax.lax.dynamic_update_slice(
@@ -1772,8 +1738,8 @@ def _paged_admit_fn(model, ids, scratch, pcache, rows, slot, m, n,
         sv[li] = jax.lax.dynamic_update_slice(
             sv[li], vf.astype(sv[li].dtype), (0, 0, 0, 0))
         if quant:
-            psk[li] = cow(psk[li], rowmask2)
-            psv[li] = cow(psv[li], rowmask2)
+            psk[li] = cow(psk[li])
+            psv[li] = cow(psv[li])
             ksf = gather(psk[li])[None]
             vsf = gather(psv[li])[None]
             ssk[li] = jax.lax.dynamic_update_slice(ssk[li], ksf,
@@ -1807,14 +1773,13 @@ def _paged_admit_fn(model, ids, scratch, pcache, rows, slot, m, n,
     return logits, scratch, pcache
 
 
-def _paged_set_table_fn(pcache, rows, slot):
-    """Retire: the slot's table rows to `rows` (the trash page), and
+def _paged_set_table_fn(pcache, row, slot):
+    """Retire: the slot's table row to `row` (the trash page), and
     whatever else the cache keeps for a slot cleared (clear_slot: a
     no-op for a cache that is pages alone)."""
     import dataclasses
-    Hkv = rows.shape[0]
-    table = jax.lax.dynamic_update_slice(pcache.table, rows,
-                                         (slot * Hkv, 0))
+    table = jax.lax.dynamic_update_slice(pcache.table, row[None],
+                                         (slot, 0))
     return dataclasses.replace(pcache, table=table).clear_slot(slot)
 
 
@@ -1824,35 +1789,26 @@ def _state_admit_fn(model, ids, pcache, rows, slot, n, *, mode):
     return model.admit_slot_paged(ids, pcache, rows, slot, n, mode=mode)
 
 
-def _gather_pages_fn(model, pcache, ids, owners):
+def _gather_pages_fn(model, pcache, ids):
     """Host-tier demotion gather: the listed pages of every layer's
-    pool, stacked [L, N, page, d] (one program per id-bucket shape).
-    An int8 pool also gathers the scale planes [L, N, page] — a
-    demoted page's scales are part of its bytes.
-
-    TP pool: `owners` [N] int32 is each page's owning HEAD-GROUP plane
-    (the caller knows the kv head behind every id — page groups are
-    head-ordered); the gather selects that plane, so the returned
-    bytes are the TRUE payload whatever the mesh (take_along_axis
-    moves bytes — no arithmetic — so the d2h/h2d round trip stays
-    bitwise on sharded pools).
+    pool, stacked [L, N, Hkv, page, d] (one program per id-bucket
+    shape). An int8 pool also gathers the scale planes
+    [L, N, Hkv, page] — a demoted page's scales are part of its bytes.
+    A gather moves bytes — no arithmetic — so the d2h/h2d round trip
+    stays bitwise whatever the mesh: on the TP pool each chip supplies
+    its own heads of every page.
 
     SP pool: a demoted span's pages live on S different chips (the
-    allocator rotates groups), so ONE span is assembled from S
+    allocator rotates pages), so ONE span is assembled from S
     per-chip contributions — each chip supplies the pages it owns and
     a psum puts the span together (_pool_gather_sp's rule: x + 0 + ..
     is exact, the round trip stays bitwise)."""
     if pcache.sp > 1:
-        # the SAME owned-gather + psum program the admit path uses
-        # (_pool_gather_sp — a flat id list is a [N, 1] rows block)
         def pick(p):
-            return _pool_gather_sp(model.mesh, model.sp_axis, p,
-                                   ids[:, None])
+            return _pool_gather_sp(model.mesh, model.sp_axis, p, ids)
     else:
         def pick(p):
-            g = p[ids]                        # [N, G, page(, d)]
-            idx = owners.reshape((-1, 1) + (1,) * (g.ndim - 2))
-            return jnp.take_along_axis(g, idx, axis=1)[:, 0]
+            return p[ids]
 
     k = jnp.stack([pick(p) for p in pcache.pages_k])
     v = jnp.stack([pick(p) for p in pcache.pages_v])
@@ -1864,39 +1820,22 @@ def _gather_pages_fn(model, pcache, ids, owners):
 
 
 def _restore_pages_fn(model, pcache, ids, hk, hv, hsk=None, hsv=None):
-    """Host-tier promotion scatter: write hk/hv [L, N, page, d] into
-    the listed pages of every layer's pool (donated). Padded tail ids
-    all point at the trash page — duplicate scatter targets there are
-    fine, trash content is never read. Int8 pools restore the scale
-    planes from hsk/hsv [L, N, page] in the same program.
-
-    TP pool: the payload broadcasts into ALL G head-group planes of
-    each restored page — the owning plane gets the true bytes and the
-    others hold copies nothing ever reads (freshly allocated pages are
-    garbage until written anyway), which keeps the scatter plane-
-    aligned and comm-free instead of needing per-rank owner masks.
+    """Host-tier promotion scatter: write hk/hv [L, N, Hkv, page, d]
+    into the listed pages of every layer's pool (donated). Padded tail
+    ids all point at the trash page — duplicate scatter targets there
+    are fine, trash content is never read. Int8 pools restore the
+    scale planes from hsk/hsv [L, N, Hkv, page] in the same program.
 
     SP pool: each chip keeps only the pages it owns (non-owned ids
     redirect out of local range and drop) — a restored span scatters
     back onto its S chips comm-free, the inverse of the gather."""
     import dataclasses
-    sp_ax = model.sp_axis if pcache.sp > 1 else None
-
-    if sp_ax is not None:
-        # the SAME owned-scatter program the admit path uses
-        # (_pool_scatter_sp): a whole-page install is the row scatter
-        # with every in-page row addressed
+    if pcache.sp > 1:
         def put(p, h):
-            page = p.shape[2]
-            dest = jnp.broadcast_to(ids[:, None],
-                                    (ids.shape[0], page))
-            return _pool_scatter_sp(model.mesh, sp_ax, p, dest,
-                                    jnp.arange(page), h)
+            return _pool_put_sp(model.mesh, model.sp_axis, p, ids, h)
     else:
         def put(p, h):
-            u = jnp.broadcast_to(h[:, None],
-                                 (h.shape[0], p.shape[1]) + h.shape[1:])
-            return p.at[ids].set(u.astype(p.dtype))
+            return p.at[ids].set(h.astype(p.dtype))
 
     pk = tuple(put(p, hk[li]) for li, p in enumerate(pcache.pages_k))
     pv = tuple(put(p, hv[li]) for li, p in enumerate(pcache.pages_v))

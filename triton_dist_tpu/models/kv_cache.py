@@ -32,6 +32,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -104,14 +105,18 @@ class PagedSlotCache:
     """Multi-layer paged KV cache for the continuous-batching slot path
     (models/prefix_cache.py policy over kernels/paged_kv.py mechanics).
 
-    Per-layer physical pools pages_k/v [NP, G, page, d] (one page =
-    `page` contiguous positions of ONE (slot, kv-head) stream; G is
-    the TP head-group axis — see TP SHARDING below) behind ONE
-    shared page table [B*Hkv, max_pages]: a physical page id means the
-    same row in EVERY layer's pool, so the host allocator hands out one
-    [Hkv] page-id group per logical tile and it covers all layers.
-    That is what makes cross-request prefix sharing cheap: mapping a
-    cached prefix into a slot is a table edit, not a KV copy.
+    Per-layer physical pools pages_k/v [NP, Hkv, page, d] — one page =
+    `page` contiguous positions of ONE slot for ALL of its kv heads —
+    behind ONE shared page table [B, max_pages], one row a slot: a
+    physical page id means the same row in EVERY layer's pool, so the
+    host allocator hands out one id per logical tile and it covers all
+    heads and all layers. That is what makes cross-request prefix
+    sharing cheap: mapping a cached prefix into a slot is a table edit,
+    not a KV copy. And it is what makes the decode walk cheap
+    (kernels/paged_kv.py): one copy a page and plane moves Hkv*page*d
+    elements, 32 KiB on one chip of Qwen3-1.7B, where a page of one
+    (slot, head) moved 4 KiB and the walk was bound by the number of
+    copies it issued (PERF.md, PR 30 and PR 35).
 
     Page id `trash` (row 0 by convention, reserved by the allocator) is
     the write sink for retired/dead slots: the slot scan keeps stepping
@@ -126,7 +131,7 @@ class PagedSlotCache:
     INT8 POOL (dtype=jnp.int8 — the KV-quantization design of KIVI,
     arXiv:2402.02750, specialized to per-position symmetric scales;
     PAPERS.md): the page payload stores int8 and per-layer scale
-    planes scales_k/scales_v [NP, G, page] f32 ride ALONGSIDE it — a
+    planes scales_k/scales_v [NP, Hkv, page] f32 ride ALONGSIDE it — a
     physical page id addresses its payload AND its scales in every
     layer, so the host allocator, the radix prefix tree, the
     copy-on-write boundary copy and the host-tier d2h/h2d extract/
@@ -139,26 +144,20 @@ class PagedSlotCache:
     decode step's dominant HBM read halves and the same pool holds
     ~2x the resident pages.
 
-    TP SHARDING (the multi-chip serving layout — ROADMAP open item 1;
-    the head-axis split of the contiguous KVCache carried over to the
-    paged pool): page payloads carry a HEAD-GROUP axis G = the TP
-    mesh size, [NP, G, page, d] sharded NamedSharding(mesh, P(None,
-    axis, None, None)) — chip g's plane holds the page bytes of ITS
-    Hkv/G kv heads and nothing else ever reads or writes it. The
+    TP SHARDING (the multi-chip serving layout; the head-axis split of
+    the contiguous KVCache carried over to the paged pool): the planes
+    are sharded on their HEAD axis, NamedSharding(mesh, P(None, axis,
+    None, None)) — a chip's shard [NP, Hkv/tp, page, d] holds its own
+    kv heads of every page and nothing else, all of it real bytes. The
     page-id space is NOT split: the host allocator, refcounts, radix
     tree, CoW and LRU policy (models/prefix_cache.py) hand out the
     same ids whatever the mesh, and the replicated page table
-    resolves a (slot, head) stream to a page id exactly as on one
-    chip — the stream's kv head decides the PLANE, and that decision
-    is static per stream, so the slot attends (layers/tp_attn.py
-    _attend_paged_slots*) run under jax.shard_map with each chip
-    walking only its local shard: 1/G of the decode step's KV read
-    and attention FLOPs per chip, with the QKV/O projections riding
-    the TP comm backends (kernels/gemm_allreduce.py et al.). Planes
-    of a page outside its owning head's group hold garbage by design
-    (never read — the same argument that lets retired pages keep
-    stale bytes); the host-tier d2h gather selects the owning plane
-    per page (Engine.extract_pages_host heads=...).
+    resolves a slot's tile to a page id exactly as on one chip, so
+    the slot attends (layers/tp_attn.py _attend_paged_slots*) run
+    under jax.shard_map with each chip walking only its local shard:
+    1/tp of the decode step's KV read and attention FLOPs per chip,
+    with the QKV/O projections riding the TP comm backends
+    (kernels/gemm_allreduce.py et al.).
 
     SP SHARDING (sequence-parallel long-context serving — ROADMAP
     long-context item; the promotion of kernels/sp_flash_decode.py
@@ -170,23 +169,22 @@ class PagedSlotCache:
     a slot's max context is bounded by the WHOLE mesh's paged HBM
     instead of one chip's. The page table, allocator free lists,
     refcounts, radix tree, CoW and host-tier bookkeeping stay
-    host-side and layout-blind exactly as under the TP head-group
-    split — the allocator (kernels/paged_kv.PageAllocator shards=)
-    merely rotates fresh groups across shards so consecutive logical
-    tiles interleave chips. A decode tick runs under shard_map with
+    host-side and layout-blind exactly as under the TP head split —
+    the allocator (kernels/paged_kv.PageAllocator shards=) merely
+    rotates fresh pages across shards so consecutive logical tiles
+    interleave chips. A decode tick runs under shard_map with
     each chip walking ONLY its local pages through the split-KV
     partial kernel (kernels/paged_kv.flash_decode_paged_partial) and
     the partials merging via the cross-chip LSE combine
     (kernels/sp_flash_decode.sp_combine_partials): per-chip KV reads
     and attention FLOPs drop to ~1/S. sp composes with int8 scale
     planes (they shard alongside the payload) but not (yet) with the
-    TP head-group split — refused capability-named at Engine
-    construction."""
+    TP head split — refused capability-named at Engine construction."""
 
-    pages_k: Tuple[jax.Array, ...]   # L x [NP, G, page, d]
+    pages_k: Tuple[jax.Array, ...]   # L x [NP, Hkv, page, d]
     pages_v: Tuple[jax.Array, ...]
-    table: jax.Array                 # [B*Hkv, max_pages] int32
-    # int8 pool only: per-position dequant scales, L x [NP, G, page]
+    table: jax.Array                 # [B, max_pages] int32
+    # int8 pool only: per-position dequant scales, L x [NP, Hkv, page]
     # f32 (empty tuples for the bf16 pool — a pytree-stable "absent")
     scales_k: Tuple[jax.Array, ...] = ()
     scales_v: Tuple[jax.Array, ...] = ()
@@ -203,13 +201,12 @@ class PagedSlotCache:
                trash: int = 0,
                sp_axis: Optional[str] = None) -> "PagedSlotCache":
         maxp = -(-max_seq // page)
-        X = batch * n_kv_heads
         G = mesh.shape[axis]
         if n_kv_heads % G:
             raise ValueError(
                 f"paged pool needs n_kv_heads ({n_kv_heads}) divisible "
-                f"by the TP mesh size ({G}): each chip owns a whole "
-                f"kv-head group of the page payloads. GQA replication "
+                f"by the TP mesh size ({G}): each chip owns whole kv "
+                f"heads of the page payloads. GQA replication "
                 f"(Hq > Hkv) lives on the QUERY side and does not "
                 f"relax this — replicate KV heads in the checkpoint "
                 f"or shrink the mesh.")
@@ -219,7 +216,7 @@ class PagedSlotCache:
             if sp > 1 and G > 1:
                 raise ValueError(
                     "paged pool cannot shard pages over "
-                    f"{sp_axis!r} AND kv-head groups over {axis!r} in "
+                    f"{sp_axis!r} AND kv heads over {axis!r} in "
                     "one pool (missing capability: sp + TP hybrid "
                     "serving) — size one of the axes to 1")
             if num_pages % sp:
@@ -236,18 +233,20 @@ class PagedSlotCache:
         shd = NamedSharding(mesh, page_spec)
         mk = lambda: tuple(
             jax.device_put(
-                jnp.zeros((num_pages, G, page, head_dim), dtype), shd)
+                jnp.zeros((num_pages, n_kv_heads, page, head_dim), dtype),
+                shd)
             for _ in range(num_layers))
         sk = sv = ()
         if jnp.dtype(dtype) == jnp.int8:
             s_shd = NamedSharding(mesh, sc_spec)
             mks = lambda: tuple(
                 jax.device_put(
-                    jnp.zeros((num_pages, G, page), jnp.float32), s_shd)
+                    jnp.zeros((num_pages, n_kv_heads, page), jnp.float32),
+                    s_shd)
                 for _ in range(num_layers))
             sk, sv = mks(), mks()
         table = jax.device_put(
-            jnp.full((X, maxp), trash, jnp.int32),
+            jnp.full((batch, maxp), trash, jnp.int32),
             NamedSharding(mesh, P(None, None)))
         return PagedSlotCache(pages_k=mk(), pages_v=mk(), table=table,
                               scales_k=sk, scales_v=sv, trash=trash,
@@ -266,10 +265,18 @@ class PagedSlotCache:
         return self.pages_k[0].shape[0]
 
     @property
-    def head_groups(self) -> int:
-        """The TP head-group axis G (mesh size at creation): payload
-        plane g holds the bytes of kv-head group g's pages."""
+    def kv_heads(self) -> int:
+        """The kv heads a page holds of its slot (all of them: the
+        planes' head axis, whole across a TP mesh)."""
         return self.pages_k[0].shape[1]
+
+    @property
+    def page_copy_bytes(self) -> int:
+        """Bytes ONE copy of the decode walk moves on a chip: a page's
+        K plane for the kv heads that chip holds."""
+        shard = self.pages_k[0].sharding.shard_shape(
+            self.pages_k[0].shape)
+        return int(np.prod(shard[1:])) * self.pages_k[0].dtype.itemsize
 
     @property
     def pages_per_shard(self) -> int:
@@ -401,14 +408,13 @@ class HybridSlotCache(PagedSlotCache):
             live=self.live.at[slot].set(False))
 
     def slot_bytes(self) -> dict:
-        """Bytes ONE slot holds of each kind: a mapped page group of (a)
-        (K and V, every head), its rings (b), its planes (c); and what
-        a page group would cost in a uniform cache, where each of the
+        """Bytes ONE slot holds of each kind: a mapped page of (a) (K
+        and V, every head), its rings (b), its planes (c); and what a
+        page would cost in a uniform cache, where each of the
         `attn_layers` attention layers keeps its own."""
         nbytes = lambda t: sum(a[0].nbytes for a in t)  # noqa: E731
-        heads = self.table.shape[0] // self.live.shape[0]
-        group = 2 * heads * self.pages_k[0][0].nbytes
-        return {"page_group": group,
+        page = 2 * self.pages_k[0][0].nbytes
+        return {"page": page,
                 "window": nbytes(self.win_k) + nbytes(self.win_v),
                 "state": nbytes(self.conv) + nbytes(self.ssm),
-                "uniform_page_group": group * self.attn_layers}
+                "uniform_page": page * self.attn_layers}

@@ -6,15 +6,15 @@ used to throw away KV the next request would recompute from scratch.
 This module is the second tier of the SGLang/HiCache hierarchical-cache
 design (and the pattern Mooncake, arXiv:2407.00079, runs in production
 KV-centric serving; CachedAttention, arXiv:2403.19708, is the same idea
-for multi-turn sessions): eviction DEMOTES an unreferenced page-group
-span to pinned host memory (one d2h gather of the group's pages across
+for multi-turn sessions): eviction DEMOTES an unreferenced page
+span to pinned host memory (one d2h gather of the span's pages across
 every layer's pool) instead of dropping it, and a later prefix match on
 a host-resident path PROMOTES it back — fresh device pages are
 allocated and filled by one h2d install program before the uncached
 suffix prefill runs. Only the host tier's own LRU (bounded by
 ``host_pool_pages``) truly drops KV.
 
-`HostKVPool` is the host half: a bounded store of demoted page-group
+`HostKVPool` is the host half: a bounded store of demoted page
 payloads (per-layer K/V extracted from the device pools, kept in the
 pool dtype so the d2h -> h2d round trip is BITWISE exact) with
 second-level LRU ordering and page-denominated accounting. On a
@@ -58,16 +58,15 @@ class _HostEntry:
     """One demoted span: an opaque payload (the engine's extracted
     per-layer K/V arrays) plus the page accounting the pool needs."""
 
-    __slots__ = ("payload", "n_pages", "n_groups")
+    __slots__ = ("payload", "n_pages")
 
-    def __init__(self, payload, n_pages: int, n_groups: int):
+    def __init__(self, payload, n_pages: int):
         self.payload = payload
         self.n_pages = n_pages
-        self.n_groups = n_groups
 
 
 class HostKVPool:
-    """Bounded host-RAM store of demoted page-group payloads with LRU
+    """Bounded host-RAM store of demoted page payloads with LRU
     ordering (the capacity tier's own second-level LRU: a true drop
     happens only here). Sizes are in DEVICE PAGES so ``host_pool_pages``
     composes directly with the device pool's ``num_pages`` — the
@@ -115,7 +114,7 @@ class HostKVPool:
                 return h
         return None
 
-    def put(self, payload, *, n_pages: int, n_groups: int) -> int:
+    def put(self, payload, *, n_pages: int) -> int:
         """Store one demoted span; the caller has already made room
         (victim()/drop()). Returns the handle the tree keys its
         residency bit on."""
@@ -125,8 +124,7 @@ class HostKVPool:
                 f"{self.room} of {self.capacity}")
         h = self._next
         self._next += 1
-        self._entries[h] = _HostEntry(payload, int(n_pages),
-                                      int(n_groups))
+        self._entries[h] = _HostEntry(payload, int(n_pages))
         self.pages_resident += int(n_pages)
         self.puts += 1
         self._check()

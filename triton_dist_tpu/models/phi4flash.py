@@ -54,6 +54,7 @@ from jax.sharding import Mesh
 from triton_dist_tpu.kernels import ssm
 from triton_dist_tpu.kernels.quant import qmm
 from triton_dist_tpu.layers import TP_MLP
+from triton_dist_tpu.kernels.paged_kv import gather_pages, set_page_rows
 from triton_dist_tpu.models.utils import ServingTraits, place_replicated
 from triton_dist_tpu.runtime import auto_mesh
 
@@ -457,20 +458,18 @@ class Phi4Flash:
         # walk every reader of the pool makes
         X = B * Hp
         pos_x = jnp.repeat(pos, Hp)
-        pidx = table[jnp.arange(X), pos_x // page]
-        prow = pos_x % page
-        pk, pv = pcache.pages_k[0][:, 0], pcache.pages_v[0][:, 0]
+        pidx = table[jnp.arange(B), pos // page]
+        prow = pos % page
+        pk, pv = pcache.pages_k[0], pcache.pages_v[0]
 
         def paged_attend(q):
             if impl == "flash":
                 return flash_decode_paged(
                     q[:, None].astype(pk.dtype), pk, pv, table,
                     jnp.max(lens), scale=scale, kv_lens=lens)[:, 0]
-            T = table.shape[1] * page
             return attention_cached_ref(
-                q[:, None].astype(pk.dtype),
-                pk[table].reshape(B, Hp, T, d),
-                pv[table].reshape(B, Hp, T, d), lens, scale=scale)[:, 0]
+                q[:, None].astype(pk.dtype), gather_pages(pk, table),
+                gather_pages(pv, table), lens, scale=scale)[:, 0]
 
         x = self.embed[ids[:, 0]]
         win_k, win_v = list(pcache.win_k), list(pcache.win_v)
@@ -526,15 +525,15 @@ class Phi4Flash:
                                                   scale=scale))[:, 0]
                     else:
                         if layer.kind == "full":
-                            pk = pk.at[pidx, prow].set(
-                                k.reshape(X, d).astype(pk.dtype))
-                            pv = pv.at[pidx, prow].set(
-                                v.reshape(X, d).astype(pv.dtype))
+                            pk = set_page_rows(pk, pidx, prow,
+                                               k.reshape(B, Hp, d))
+                            pv = set_page_rows(pv, pidx, prow,
+                                               v.reshape(B, Hp, d))
                         o = paged_attend(q)
                     a = self._attn_out(layer, o, x.dtype)
             x = self._mlp(layer, x + a, mlp_mode or mode)
         pcache = dataclasses.replace(
-            pcache, pages_k=(pk[:, None],), pages_v=(pv[:, None],),
+            pcache, pages_k=(pk,), pages_v=(pv,),
             win_k=tuple(win_k), win_v=tuple(win_v), conv=tuple(conv),
             ssm=tuple(state))
         return self._logits(x), pcache
@@ -544,8 +543,8 @@ class Phi4Flash:
     def admit_slot_paged(self, ids, pcache, rows, slot, n,
                          mode: str = "flash"):
         """ids [1, P]: the prompt, zero-padded to its bucket; n: its
-        real length; rows [Hp, maxp]: the slot's table rows. Installs
-        the rows, leaves the slot's pages, rings and planes as the
+        real length; rows [maxp]: the slot's table row. Installs
+        the row, leaves the slot's pages, rings and planes as the
         prompt's last real token leaves them, and returns (logits
         [1, V] of that token, pcache)."""
         cfg = self.config
@@ -579,7 +578,7 @@ class Phi4Flash:
         x = self.embed[ids[0]]                   # [P, D], then [1, D]
         win_k, win_v = list(pcache.win_k), list(pcache.win_v)
         conv, state = list(pcache.conv), list(pcache.ssm)
-        pk, pv = pcache.pages_k[0][:, 0], pcache.pages_v[0][:, 0]
+        pk, pv = pcache.pages_k[0], pcache.pages_v[0]
         i_win = i_ssm = 0
         memory = kf = vf = None
         for layer in self.layers:
@@ -626,14 +625,12 @@ class Phi4Flash:
                         q, kf, vf = self._qkv(layer, u)
                         kf, vf = kf.astype(pk.dtype), vf.astype(pv.dtype)
                         dest = jnp.where(
-                            (p < n)[None],
-                            rows[:, jnp.minimum(p // page,
-                                                rows.shape[1] - 1)],
-                            pcache.trash)                    # [Hp, P]
-                        pk = pk.at[dest, (p % page)[None]].set(
-                            jnp.swapaxes(kf, 0, 1))
-                        pv = pv.at[dest, (p % page)[None]].set(
-                            jnp.swapaxes(vf, 0, 1))
+                            p < n,
+                            rows[jnp.minimum(p // page,
+                                             rows.shape[0] - 1)],
+                            pcache.trash)                    # [P]
+                        pk = set_page_rows(pk, dest, p % page, kf)
+                        pv = set_page_rows(pv, dest, p % page, vf)
                         x, q = row(x), row(q)
                     else:
                         q, _, _ = self._qkv(layer, u)
@@ -642,9 +639,9 @@ class Phi4Flash:
                     a = self._attn_out(layer, o, x.dtype)
             x = self._mlp(layer, x + a, mode)
         table = jax.lax.dynamic_update_slice(
-            pcache.table, rows, (slot * Hp, 0))
+            pcache.table, rows[None], (slot, 0))
         pcache = dataclasses.replace(
-            pcache, pages_k=(pk[:, None],), pages_v=(pv[:, None],),
+            pcache, pages_k=(pk,), pages_v=(pv,),
             table=table, win_k=tuple(win_k), win_v=tuple(win_v),
             conv=tuple(conv), ssm=tuple(state),
             live=pcache.live.at[slot].set(True))

@@ -13,14 +13,14 @@ that pair for the TPU serving stack:
 - `RefcountedPages`: a refcount layer over the hardened `PageAllocator`
   free list. A physical page may back many slots' page tables AND many
   tree nodes at once; it returns to the free list only at refcount
-  zero. Pages are handed out in [Hkv] GROUPS (one page per kv-head
-  stream of a logical tile) because one page id means the same row in
-  every layer's pool (PagedSlotCache) — a group is the sharing unit.
+  zero. One page id means the same row in every layer's pool, for
+  every kv head of the slot (PagedSlotCache) — a page is the sharing
+  unit.
 
 - `RadixPrefixTree`: token-granular radix tree whose nodes carry the
-  page groups backing their span. Matching a new prompt returns the
-  longest cached prefix and the groups to map read-only into the
-  slot's table; the LAST group is only partially valid when the match
+  pages backing their span. Matching a new prompt returns the
+  longest cached prefix and the pages to map read-only into the
+  slot's table; the LAST page is only partially valid when the match
   ends mid-page — the admission copy-on-writes it into a fresh page
   (the boundary page will receive the diverging request's own writes,
   which must never touch the shared original). Node splits on insert
@@ -56,7 +56,7 @@ that pair for the TPU serving stack:
 - KV FORK (parallel sampling, models/structured.py + scheduler
   `Request(n=N)`): `PagedDecodeSlots.fork` is the third consumer of
   this module's refcount/CoW machinery — a fork child RETAINS the
-  parent slot's full prompt page groups (refcount+1, mapped into its
+  parent slot's full prompt pages (refcount+1, mapped into its
   own table exactly like a tree hit) and copy-on-writes the
   partially-filled boundary page, so n decode streams share one
   prompt's physical KV. The fork records its skipped prefill through
@@ -116,15 +116,13 @@ class RefcountedPages:
     write sink for retired slots, not storage.
 
     shards > 1 (sequence-parallel serving): the allocator partitions
-    the id space per sp shard and rotates fresh groups across shards
+    the id space per sp shard and rotates fresh pages across shards
     (kernels/paged_kv.PageAllocator) — this layer stays id-blind, it
     only surfaces the per-shard accounting the telemetry and the
     per-shard zero-leak invariant read."""
 
-    def __init__(self, num_pages: int, n_kv_heads: int,
-                 shards: int = 1):
+    def __init__(self, num_pages: int, shards: int = 1):
         self._alloc = PageAllocator(num_pages, shards=shards)
-        self.n_kv_heads = n_kv_heads
         self._ref: Dict[int, int] = {}
         # shard 0 allocates first, so the trash is page 0 of shard 0
         # whatever the shard count
@@ -177,45 +175,39 @@ class RefcountedPages:
         admissions, retirements, preemptions, evictions, and faults."""
         return self._alloc.outstanding
 
-    def alloc_group(self) -> np.ndarray:
-        """One fresh writable group ([Hkv] page ids at refcount 1)."""
-        g = np.asarray(self._alloc.alloc(self.n_kv_heads), np.int32)
-        for p in g:
-            self._ref[int(p)] = 1
-        return g
+    def alloc_page(self) -> int:
+        """One fresh writable page (its id at refcount 1)."""
+        p = int(self._alloc.alloc(1)[0])
+        self._ref[p] = 1
+        return p
 
-    def retain(self, group) -> None:
-        for p in group:
-            p = int(p)
-            if p not in self._ref:
-                raise ValueError(
-                    f"retain of unreferenced page {p}: only pages live "
-                    f"from alloc_group (refcount >= 1) can gain refs — "
-                    f"a retain after the last release would resurrect "
-                    f"a page the allocator may have re-issued")
-            self._ref[p] += 1
+    def retain(self, page) -> None:
+        p = int(page)
+        if p not in self._ref:
+            raise ValueError(
+                f"retain of unreferenced page {p}: only pages live "
+                f"from alloc_page (refcount >= 1) can gain refs — "
+                f"a retain after the last release would resurrect "
+                f"a page the allocator may have re-issued")
+        self._ref[p] += 1
 
-    def release(self, group) -> None:
-        """Drop one ref per page of the group; pages at zero go back to
-        the free list (the allocator re-checks double-frees). A release
-        past zero raises BEFORE touching the pool — the silent failure
-        mode is a page freed while a radix-tree node still maps it."""
-        freed = []
-        for p in group:
-            p = int(p)
-            if p not in self._ref:
-                raise ValueError(
-                    f"refcount underflow: release of page {p} at "
-                    f"refcount 0 (already fully released, or never "
-                    f"allocated) — some holder released a group twice")
-            c = self._ref[p] - 1
-            if c:
-                self._ref[p] = c
-            else:
-                del self._ref[p]
-                freed.append(p)
-        if freed:
-            self._alloc.free(freed)
+    def release(self, page) -> None:
+        """Drop one ref of the page; at zero it goes back to the free
+        list (the allocator re-checks double-frees). A release past
+        zero raises BEFORE touching the pool — the silent failure mode
+        is a page freed while a radix-tree node still maps it."""
+        p = int(page)
+        if p not in self._ref:
+            raise ValueError(
+                f"refcount underflow: release of page {p} at "
+                f"refcount 0 (already fully released, or never "
+                f"allocated) — some holder released a page twice")
+        c = self._ref[p] - 1
+        if c:
+            self._ref[p] = c
+        else:
+            del self._ref[p]
+            self._alloc.free([p])
 
     def refcount(self, page) -> int:
         return self._ref.get(int(page), 0)
@@ -223,38 +215,38 @@ class RefcountedPages:
 
 class _Node:
     """One radix-tree edge: tokens `key` spanning absolute positions
-    [start, start + len(key)), backed by `groups` — one [Hkv] page
-    group per page index floor(start/page) .. ceil(end/page)-1. When
-    start is mid-page the first group is a page SHARED in span with the
-    parent's last group (the same physical page after a pure split, or
+    [start, start + len(key)), backed by `pages` — one page id
+    per page index floor(start/page) .. ceil(end/page)-1. When
+    start is mid-page the first page is SHARED in span with the
+    parent's last page (the same physical page after a pure split, or
     the diverging request's copy-on-write page).
 
     Residency state machine (host tier, models/kv_tier.py): `host` is
-    None for a DEVICE-resident node (groups hold device page ids) and
-    a HostKVPool handle for a HOST-resident one (groups is empty — the
+    None for a DEVICE-resident node (pages hold device page ids) and
+    a HostKVPool handle for a HOST-resident one (pages is empty — the
     span's bytes live in the host pool until promotion restores them
     into fresh device pages, or the host LRU truly drops them). Host
     nodes are opaque to insert (no descend, no split), so no DEVICE
     descendant can ever appear below one — the invariant that makes a
     host drop a clean subtree removal."""
 
-    __slots__ = ("parent", "children", "start", "key", "groups",
+    __slots__ = ("parent", "children", "start", "key", "pages",
                  "last_use", "host")
 
     def __init__(self, parent: Optional["_Node"], start: int,
-                 key: np.ndarray, groups: List[np.ndarray]):
+                 key: np.ndarray, pages: List[int]):
         self.parent = parent
         self.children: Dict[int, "_Node"] = {}
         self.start = start
         self.key = key
-        self.groups = groups
+        self.pages = pages
         self.last_use = 0
         self.host: Optional[int] = None
 
 
 class RadixPrefixTree:
     """Token-keyed radix tree over the refcounted page pool. Each node
-    holds one pool ref per group it references; matching never touches
+    holds one pool ref per page it references; matching never touches
     refcounts (callers retain what they map)."""
 
     def __init__(self, pool: RefcountedPages, page: int, *,
@@ -277,8 +269,8 @@ class RadixPrefixTree:
         # true-drop path without actually filling the host pool.
         self.host_pool = host_pool
         self.fault = fault
-        self._extract_fn = None    # groups -> payload (d2h gather)
-        self._restore_fn = None    # (payload, groups) -> None (h2d)
+        self._extract_fn = None    # pages -> payload (d2h gather)
+        self._restore_fn = None    # (payload, pages) -> None (h2d)
         # per-promote_path restore time (alloc + h2d install only —
         # NOT the victim demotions evict_until may run to make room),
         # accumulated here so PrefixCache's EMA reports what the
@@ -299,10 +291,10 @@ class RadixPrefixTree:
     # ------------------------------------------------------------------
 
     def match(self, tokens, cap: Optional[int] = None
-              ) -> Tuple[int, List[np.ndarray]]:
+              ) -> Tuple[int, List[int]]:
         """Longest cached prefix of `tokens` (≤ cap): returns
-        (m, groups) with groups covering page indices
-        0 .. ceil(m/page)-1. When m is mid-page the last group is only
+        (m, pages) with pages covering page indices
+        0 .. ceil(m/page)-1. When m is mid-page the last page is only
         partially valid — the caller must copy-on-write it before the
         slot writes anything. Touches the matched path for LRU.
 
@@ -313,20 +305,20 @@ class RadixPrefixTree:
         tokens = np.asarray(tokens, np.int32)
         node = self.root
         m = 0
-        groups: List[np.ndarray] = []
+        pages: List[int] = []
         while m < len(tokens):
             child = node.children.get(int(tokens[m]))
             if child is None or child.host is not None:
                 break
             L = _common_prefix(child.key, tokens[m:m + len(child.key)])
             if child.start % self.page:
-                # the child's first group is its own complete version
+                # the child's first page is its own complete version
                 # of the boundary page (see _Node docstring) — it
                 # overrides the parent's
-                groups.pop()
+                pages.pop()
             first_pg = child.start // self.page
             n_pg = _ceil_div(child.start + L, self.page) - first_pg
-            groups.extend(child.groups[:n_pg])
+            pages.extend(child.pages[:n_pg])
             m += L
             self._touch(child)
             if L < len(child.key):
@@ -334,17 +326,17 @@ class RadixPrefixTree:
             node = child
         if cap is not None and m > cap:
             m = cap
-            groups = groups[:_ceil_div(m, self.page)]
-        return m, groups
+            pages = pages[:_ceil_div(m, self.page)]
+        return m, pages
 
     # ------------------------------------------------------------------
     # insert
     # ------------------------------------------------------------------
 
-    def insert(self, tokens, groups_by_page: List[np.ndarray]) -> int:
+    def insert(self, tokens, pages_by_tile: List[int]) -> int:
         """Insert a finished sequence (prompt + generated): walk the
         matched path, split a node if the sequence diverges inside it,
-        and attach the unmatched suffix as a new leaf whose groups are
+        and attach the unmatched suffix as a new leaf whose pages are
         the caller's pages for that span (the tree RETAINS them — the
         caller keeps its own refs and releases them at retire). Returns
         the number of newly cached tokens."""
@@ -360,13 +352,13 @@ class RadixPrefixTree:
                 # bookkeeping, never a correctness requirement
                 return 0
             if child is None:
-                leaf_groups = [
-                    np.asarray(g, np.int32).copy()
-                    for g in groups_by_page[m // self.page:
+                leaf_pages = [
+                    int(g)
+                    for g in pages_by_tile[m // self.page:
                                             _ceil_div(len(tokens),
                                                       self.page)]]
-                leaf = _Node(node, m, tokens[m:].copy(), leaf_groups)
-                for g in leaf_groups:
+                leaf = _Node(node, m, tokens[m:].copy(), leaf_pages)
+                for g in leaf_pages:
                     self.pool.retain(g)
                 node.children[int(tokens[m])] = leaf
                 self._touch(leaf)
@@ -390,20 +382,20 @@ class RadixPrefixTree:
         s = child.start
         cut = s + L
         first_pg = s // self.page
-        head_groups = child.groups[:_ceil_div(cut, self.page) - first_pg]
-        head = _Node(child.parent, s, child.key[:L], head_groups)
+        head_pages = child.pages[:_ceil_div(cut, self.page) - first_pg]
+        head = _Node(child.parent, s, child.key[:L], head_pages)
         head.last_use = child.last_use
         child.parent.children[int(child.key[0])] = head
         tail_first = cut // self.page
-        child.groups = child.groups[tail_first - first_pg:]
+        child.pages = child.pages[tail_first - first_pg:]
         child.start = cut
         child.key = child.key[L:]
         child.parent = head
         head.children[int(child.key[0])] = child
         if cut % self.page:
-            # boundary page now appears in head.groups[-1] AND
-            # child.groups[0] (same physical page)
-            self.pool.retain(head.groups[-1])
+            # boundary page now appears in head.pages[-1] AND
+            # child.pages[0] (same physical page)
+            self.pool.retain(head.pages[-1])
         return head
 
     # ------------------------------------------------------------------
@@ -418,7 +410,7 @@ class RadixPrefixTree:
         + device refs released, node stays in the tree host-resident)
         and only falls back to a true drop when demotion is refused
         (host pool too small for the span, or a chaos fault).
-        Releasing a span's groups only drops the tree's refs; a page
+        Releasing a span's pages only drops the tree's refs; a page
         still mapped read-only by an in-flight slot stays allocated
         until that slot retires.
 
@@ -447,8 +439,8 @@ class RadixPrefixTree:
             pend = sum(1 for c in nd.children.values()
                        if subtree_dev[id(c)])
             blockers[id(nd)] = pend
-            subtree_dev[id(nd)] = bool(nd.groups) or pend > 0
-            if nd is not self.root and nd.groups and pend == 0:
+            subtree_dev[id(nd)] = bool(nd.pages) or pend > 0
+            if nd is not self.root and nd.pages and pend == 0:
                 heap.append((nd.last_use, id(nd), nd))
         heapq.heapify(heap)
         while self.pool.available < pages_needed and heap:
@@ -466,7 +458,7 @@ class RadixPrefixTree:
                 if self.tele is not None:
                     self.tele.instant("kv_evict")
             blockers[id(parent)] -= 1
-            if parent is not self.root and parent.groups \
+            if parent is not self.root and parent.pages \
                     and blockers[id(parent)] == 0:
                 heapq.heappush(heap, (parent.last_use, id(parent),
                                       parent))
@@ -481,9 +473,9 @@ class RadixPrefixTree:
         span too big for the whole host pool, everything pinned, or a
         chaos-injected host exhaustion) — the caller true-drops."""
         hp = self.host_pool
-        if hp is None or self._extract_fn is None or not nd.groups:
+        if hp is None or self._extract_fn is None or not nd.pages:
             return False
-        n_pages = sum(len(g) for g in nd.groups)
+        n_pages = len(nd.pages)
         if n_pages > hp.capacity:
             return False
         if self.fault is not None and \
@@ -497,12 +489,12 @@ class RadixPrefixTree:
             if h is None:
                 return False
             self._drop_host_subtree(self._host_nodes[h])
-        payload = self._extract_fn(nd.groups)
-        h = hp.put(payload, n_pages=n_pages, n_groups=len(nd.groups))
+        payload = self._extract_fn(nd.pages)
+        h = hp.put(payload, n_pages=n_pages)
         self._host_nodes[h] = nd
-        for g in nd.groups:
+        for g in nd.pages:
             self.pool.release(g)
-        nd.groups = []
+        nd.pages = []
         nd.host = h
         return True
 
@@ -512,9 +504,9 @@ class RadixPrefixTree:
         children are host-resident (the eligibility sweep guarantees
         the subtree holds no other device pages) and go with it —
         orphaned host spans could never be matched again."""
-        for g in nd.groups:
+        for g in nd.pages:
             self.pool.release(g)
-        nd.groups = []
+        nd.pages = []
         for c in list(nd.children.values()):
             self._drop_host_subtree(c)
         del nd.parent.children[int(nd.key[0])]
@@ -528,10 +520,10 @@ class RadixPrefixTree:
         while stack:
             x = stack.pop()
             stack.extend(x.children.values())
-            if x.groups:         # defensive: never true by invariant
-                for g in x.groups:
+            if x.pages:         # defensive: never true by invariant
+                for g in x.pages:
                     self.pool.release(g)
-                x.groups = []
+                x.pages = []
                 self.evictions += 1
             if x.host is not None:
                 self.host_pool.drop(x.host)
@@ -590,7 +582,7 @@ class RadixPrefixTree:
 
     def _promote(self, nd: _Node) -> bool:
         """Restore one host span into fresh device pages: free-list
-        headroom (evicting/demoting unpinned spans), alloc the groups,
+        headroom (evicting/demoting unpinned spans), alloc the pages,
         run the wired h2d install, and flip residency. The host entry
         is popped only after the install is dispatched — a failure
         leaves the span host-resident (and LRU-touched) for the next
@@ -598,27 +590,27 @@ class RadixPrefixTree:
         if self._restore_fn is None:
             return False
         entry = self.host_pool.get(nd.host)        # touches host LRU
-        need = entry.n_groups * self.pool.n_kv_heads
+        need = entry.n_pages
         if not self.evict_until(need):
             return False
-        groups: List[np.ndarray] = []
+        pages: List[int] = []
         t0 = time.perf_counter()
         try:
-            for _ in range(entry.n_groups):
-                groups.append(self.pool.alloc_group())
-            self._restore_fn(entry.payload, groups)
+            for _ in range(entry.n_pages):
+                pages.append(self.pool.alloc_page())
+            self._restore_fn(entry.payload, pages)
         except Exception:
             # release-before-raise (the _reserve_pages convention):
-            # groups referenced by neither the node nor any slot would
+            # pages referenced by neither the node nor any slot would
             # otherwise leak past every drain
-            for g in groups:
+            for g in pages:
                 self.pool.release(g)
             raise
         self.restore_ms_accum += (time.perf_counter() - t0) * 1e3
         self.host_pool.pop(nd.host)
         del self._host_nodes[nd.host]
         nd.host = None
-        nd.groups = groups
+        nd.pages = pages
         self.promotions += 1
         if self.tele is not None:
             self.tele.instant("kv_promote")
@@ -645,7 +637,7 @@ class PrefixCache:
     device programs, which is what makes the bitwise cache-on/off
     comparison meaningful."""
 
-    def __init__(self, num_pages: int, n_kv_heads: int, page: int, *,
+    def __init__(self, num_pages: int, page: int, *,
                  enabled: bool = True, host_pool_pages: int = 0,
                  fault=None, telemetry=None, shards: int = 1):
         """host_pool_pages > 0 attaches the host-RAM capacity tier
@@ -665,11 +657,11 @@ class PrefixCache:
         shards: the sp mesh size of a SEQUENCE-PARALLEL pool
         (kv_cache.PagedSlotCache SP SHARDING) — the allocator then
         partitions the page-id space per shard and rotates fresh
-        groups across shards, and stats() grows per-shard
+        pages across shards, and stats() grows per-shard
         `sp_pages_resident{shard=}` gauges (resident 0 on every shard
         at idle is the per-shard zero-leak invariant)."""
         from triton_dist_tpu.runtime.telemetry import Telemetry
-        self.pool = RefcountedPages(num_pages, n_kv_heads,
+        self.pool = RefcountedPages(num_pages,
                                     shards=shards)
         self.page = page
         self.enabled = enabled
@@ -699,8 +691,8 @@ class PrefixCache:
 
     def attach_host_tier(self, extract, restore) -> None:
         """Wire the device-side copy callbacks into the residency
-        machine: `extract(groups) -> payload` gathers the groups'
-        pages to host memory (demotion), `restore(payload, groups)`
+        machine: `extract(pages) -> payload` gathers their
+        bytes to host memory (demotion), `restore(payload, pages)`
         installs a payload into freshly allocated device pages
         (promotion). PagedDecodeSlots binds these to
         Engine.extract_pages_host / restore_pages_host over its own
@@ -708,12 +700,12 @@ class PrefixCache:
         self.tree._extract_fn = extract
         self.tree._restore_fn = restore
 
-    def lookup(self, prompt) -> Tuple[int, List[np.ndarray]]:
+    def lookup(self, prompt) -> Tuple[int, List[int]]:
         """Longest cached prefix for an admission (capped to n-1: the
         last prompt token is always recomputed so the slot has fresh
         next-token logits). With the host tier attached, host-resident
         spans on the path are PROMOTED first (h2d install into fresh
-        pages), so the returned groups are always device pages and the
+        pages), so the returned pages are always device pages and the
         caller's CoW/refcount flow is tier-oblivious."""
         if not self.enabled:
             return 0, []
@@ -740,10 +732,10 @@ class PrefixCache:
         self.prefill_tokens_skipped.inc(n_matched)
         self.hits.inc(int(bool(n_matched)))
 
-    def insert(self, tokens, groups_by_page) -> int:
+    def insert(self, tokens, pages_by_tile) -> int:
         if not self.enabled:
             return 0
-        new = self.tree.insert(tokens, groups_by_page)
+        new = self.tree.insert(tokens, pages_by_tile)
         self.tokens_inserted.inc(new)
         return new
 

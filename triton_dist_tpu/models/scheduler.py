@@ -168,7 +168,7 @@ Disaggregation (models/disagg.py — DistServe, 2401.09670): chunked
 prefill BOUNDS the prefill stall on live streams; `DisaggScheduler`
 (a subclass of ContinuousScheduler) REMOVES it — dedicated prefill
 workers compute admissions' KV into staging paged pools and stream
-the finished page-groups to this scheduler's decode pool over the
+the finished pages to this scheduler's decode pool over the
 p2p/DCN transfer plane, so decode polls never run a mixed tick at
 all. Streams stay bitwise identical disagg vs fused
 (tests/test_disagg.py).
@@ -1475,14 +1475,13 @@ class PagedDecodeSlots(DecodeSlots):
         self._num_pages = num_pages
         super().__init__(engine, batch, spec=spec, drafter=drafter,
                          telemetry=telemetry)
-        Hkv = engine.traits.kv_heads
         # the prefix cache publishes its counters into the SAME
         # registry, so the scheduler's stats() snapshot covers it
         # a SEQUENCE-PARALLEL pool partitions the page-id space per sp
         # shard (kv_cache.PagedSlotCache SP SHARDING): the allocator
-        # mirrors that split host-side and rotates fresh groups across
+        # mirrors that split host-side and rotates fresh pages across
         # shards so a slot's logical tiles interleave chips
-        self.prefix = PrefixCache(self.cache.num_pages, Hkv, page,
+        self.prefix = PrefixCache(self.cache.num_pages, page,
                                   enabled=prefix_cache,
                                   host_pool_pages=host_pool_pages,
                                   fault=fault, telemetry=self.tele,
@@ -1492,13 +1491,13 @@ class PagedDecodeSlots(DecodeSlots):
                                          self._tier_restore)
         # both sides reserve the same trash page (pool page 0)
         assert self.prefix.pool.trash == self.cache.trash
-        # per-slot host mirrors: mapped page groups (absolute page
+        # per-slot host mirrors: mapped pages (absolute page
         # order) and the token stream (prompt + kept generated) whose
         # KV those pages hold — the retire-time tree insert. _TokenLog:
         # amortized-O(1) appends + zero-copy views for the tree insert
         # and the preemption snapshot (the list mirrors' per-call
         # rebuilds were O(generated^2) host work over a stream)
-        self._groups: List[List[np.ndarray]] = [[] for _ in range(batch)]
+        self._pages: List[List[int]] = [[] for _ in range(batch)]
         self._tokens: List[_TokenLog] = [_TokenLog()
                                          for _ in range(batch)]
         # KV fork (parallel sampling — fork() below): per-slot flag
@@ -1513,6 +1512,14 @@ class PagedDecodeSlots(DecodeSlots):
             "boundary pages copy-on-written at fork time")
         self._g_forks = freg.gauge(
             "forks_active", "live forked decode slots")
+        # what the decode walk moves at a time (kernels/paged_kv.py):
+        # fixed by the pool's layout and the mesh, read once
+        self._page_copy_bytes = self.cache.page_copy_bytes
+        freg.gauge(
+            "kv_page_copy_bytes",
+            "bytes one K-plane copy of the paged decode walk moves on "
+            "a chip: a page's positions for the kv heads the chip holds"
+        ).set(self._page_copy_bytes)
         # a cache of several kinds of per-slot state
         # (kv_cache.HybridSlotCache) reports the bytes the live slots
         # hold of each, beside what a uniform cache would hold for them
@@ -1549,26 +1556,20 @@ class PagedDecodeSlots(DecodeSlots):
     # live paged pool, so the jitted gather/scatter sequence correctly
     # with the admission/decode programs through data dependence.
 
-    def _tier_extract(self, groups):
-        """Demotion d2h: snapshot the span's pages (all layers). An
-        int8 pool's payload carries the scale planes too ("ks"/"vs")
-        — the d2h/h2d round trip stays bitwise for both layouts,
-        including the TP-sharded pool: each group is head-ordered, so
-        the per-page kv-head indices passed here let the gather pick
-        every page's owning payload plane (Engine.extract_pages_host
-        heads contract)."""
-        ids = np.concatenate([np.asarray(g, np.int32) for g in groups])
-        Hkv = self.engine.traits.kv_heads
-        heads = np.tile(np.arange(Hkv, dtype=np.int32), len(groups))
-        out = self.engine.extract_pages_host(self.cache, ids,
-                                             heads=heads)
+    def _tier_extract(self, pages):
+        """Demotion d2h: snapshot the span's pages (all layers, every
+        head). An int8 pool's payload carries the scale planes too
+        ("ks"/"vs") — the d2h/h2d round trip stays bitwise for both
+        layouts, the TP-sharded pool included."""
+        out = self.engine.extract_pages_host(
+            self.cache, np.asarray(pages, np.int32))
         return dict(zip(("k", "v", "ks", "vs"), out))
 
-    def _tier_restore(self, payload, groups) -> None:
+    def _tier_restore(self, payload, pages) -> None:
         """Promotion h2d: install a snapshot into fresh pages."""
-        ids = np.concatenate([np.asarray(g, np.int32) for g in groups])
         self.cache = self.engine.restore_pages_host(
-            self.cache, ids, payload["k"], payload["v"],
+            self.cache, np.asarray(pages, np.int32),
+            payload["k"], payload["v"],
             payload.get("ks"), payload.get("vs"))
 
     @property
@@ -1585,17 +1586,18 @@ class PagedDecodeSlots(DecodeSlots):
         out["forks_active"] = nf
         out["fork_shared_pages"] = self._c_fork_shared.value
         out["fork_cow_breaks"] = self._c_fork_cow.value
+        out["kv_page_copy_bytes"] = self._page_copy_bytes
         out.update(self.prefix.stats())
         if self._slot_bytes:
             sb, live = self._slot_bytes, self.occupied
-            groups = sum(len(self._groups[b]) for b in live)
-            held = {"pages": groups * sb["page_group"],
+            pages = sum(len(self._pages[b]) for b in live)
+            held = {"pages": pages * sb["page"],
                     "window": len(live) * sb["window"],
                     "state": len(live) * sb["state"]}
             for kind, v in held.items():
                 self._g_cache_bytes[kind].set(v)
             self._g_uniform_bytes.set(
-                groups * sb["uniform_page_group"] + held["state"])
+                pages * sb["uniform_page"] + held["state"])
         return out
 
     def validate_admission(self, req: Request, tokens: np.ndarray
@@ -1607,7 +1609,7 @@ class PagedDecodeSlots(DecodeSlots):
           (and a zero-length prompt would leak the refs _reserve_pages
           retains when the engine refused it);
         - prompt + gen beyond slot capacity;
-        - TOTAL footprint beyond the whole pool (shared + fresh groups
+        - TOTAL footprint beyond the whole pool (shared + fresh pages
           must all coexist): reject upfront with a plain ValueError so
           the scheduler does not preempt every live slot discovering
           it (the cheap denial-of-service a repeated never-fits
@@ -1621,29 +1623,29 @@ class PagedDecodeSlots(DecodeSlots):
                 f"exceeds slot capacity {self.capacity}")
         pool = self.prefix.pool
         total = -(-(n + req.gen_len + self.margin - 1) // self.page)
-        usable = (pool.num_pages - 1) // pool.n_kv_heads
+        usable = pool.num_pages - 1
         if total > usable:
             raise ValueError(
                 f"request {req.rid!r}: worst-case footprint {total} "
-                f"page groups exceeds the whole pool ({usable} usable "
-                f"groups) — page pool exhausted for this request alone")
+                f"pages exceeds the whole pool ({usable} usable "
+                f"pages) — page pool exhausted for this request alone")
 
     def _reserve_pages(self, req: Request, tokens: np.ndarray):
         """Validation + prefix lookup + page reservation shared by the
-        monolithic and CHUNKED paged admissions. Returns (slot_groups,
+        monolithic and CHUNKED paged admissions. Returns (slot_pages,
         m, rows, cow_src, cow_dst, r, boundary) with every ref taken
         (release `boundary` after the device-side CoW ran); raises with
         everything released."""
         n = len(tokens)
         self.validate_admission(req, tokens)
         pool = self.prefix.pool
-        # total page groups the admitted slot will map (shared + fresh
+        # total pages the admitted slot will map (shared + fresh
         # must all coexist in the pool); `need` below is total - full
         total = -(-(n + req.gen_len + self.margin - 1) // self.page)
         m, shared = self.prefix.lookup(tokens)
         full, r = m // self.page, m % self.page
-        retained: List[np.ndarray] = []
-        fresh: List[np.ndarray] = []
+        retained: List[int] = []
+        fresh: List[int] = []
         try:
             # pin everything the admission program will read BEFORE
             # eviction can run
@@ -1655,27 +1657,31 @@ class PagedDecodeSlots(DecodeSlots):
                 pool.retain(boundary)
                 retained.append(boundary)
             need = total - full
-            if not self.prefix.ensure_pages(need * pool.n_kv_heads):
+            if not self.prefix.ensure_pages(need):
                 from triton_dist_tpu.models.prefix_cache import \
                     PoolExhausted
                 raise PoolExhausted(
                     f"request {req.rid!r}: page pool exhausted "
-                    f"({need} fresh groups needed, "
+                    f"({need} fresh pages needed, "
                     f"{pool.available} pages free, nothing evictable)")
-            fresh = [pool.alloc_group() for _ in range(need)]
+            fresh = [pool.alloc_page() for _ in range(need)]
         except ValueError:
             for g in fresh + retained:
                 pool.release(g)
             raise
-        slot_groups = list(shared[:full]) + fresh
-        Hkv, maxp = pool.n_kv_heads, self.cache.table.shape[1]
-        rows = np.full((Hkv, maxp), self.cache.trash, np.int32)
-        for j, g in enumerate(slot_groups):
-            rows[:, j] = g
-        trash_vec = np.full((Hkv,), self.cache.trash, np.int32)
-        cow_src = boundary if r else trash_vec
-        cow_dst = fresh[0] if r else trash_vec
-        return slot_groups, m, rows, cow_src, cow_dst, r, boundary
+        slot_pages = list(shared[:full]) + fresh
+        rows = self._table_row(slot_pages)
+        cow_src = boundary if r else self.cache.trash
+        cow_dst = fresh[0] if r else self.cache.trash
+        return slot_pages, m, rows, cow_src, cow_dst, r, boundary
+
+    def _table_row(self, slot_pages) -> np.ndarray:
+        """A slot's row of the page table: its pages in logical order,
+        trash past them."""
+        row = np.full((self.cache.table.shape[1],), self.cache.trash,
+                      np.int32)
+        row[:len(slot_pages)] = slot_pages
+        return row
 
     def admit(self, slot: int, req: Request) -> None:
         """Consult the radix tree, map the cached prefix read-only,
@@ -1684,7 +1690,7 @@ class PagedDecodeSlots(DecodeSlots):
         assert self.rids[slot] is None, f"slot {slot} is occupied"
         tokens = np.asarray(req.ids, np.int32).reshape(-1)
         n = len(tokens)
-        slot_groups, m, rows, cow_src, cow_dst, r, boundary = \
+        slot_pages, m, rows, cow_src, cow_dst, r, boundary = \
             self._reserve_pages(req, tokens)
         pool = self.prefix.pool
         row, self.cache = self.engine.admit_slot_paged(
@@ -1694,14 +1700,14 @@ class PagedDecodeSlots(DecodeSlots):
             pool.release(boundary)
         self.prefill_forwarded += n - m
         self._arm_slot(slot, req, row, n)
-        self._groups[slot] = slot_groups
+        self._pages[slot] = slot_pages
         self._tokens[slot] = _TokenLog(tokens)
         self.prefix.record(n, m)
         # insert the PROMPT pages now (not just at retire): the next
         # admission — even one in the same poll — can already share
         # them. N clients connecting at once with one system prompt is
         # the headline case, and they must not all prefill it.
-        self.prefix.insert(tokens, slot_groups[:-(-n // self.page)])
+        self.prefix.insert(tokens, slot_pages[:-(-n // self.page)])
 
     def admit_chunked(self, slot: int, req: Request) -> None:
         """Chunked paged admission: everything that must happen ONCE —
@@ -1718,13 +1724,13 @@ class PagedDecodeSlots(DecodeSlots):
         assert self.rids[slot] is None, f"slot {slot} is occupied"
         tokens = np.asarray(req.ids, np.int32).reshape(-1)
         n = len(tokens)
-        slot_groups, m, rows, cow_src, cow_dst, r, boundary = \
+        slot_pages, m, rows, cow_src, cow_dst, r, boundary = \
             self._reserve_pages(req, tokens)
         self.cache = self.engine.install_slot_paged(
             self.cache, slot, rows, cow_src, cow_dst, r)
         if boundary is not None:
             self.prefix.pool.release(boundary)
-        self._groups[slot] = slot_groups
+        self._pages[slot] = slot_pages
         self._tokens[slot] = _TokenLog(tokens[:m])
         self.prefix.record(n, m)
         self._park_prefilling(slot, req, tokens, m)
@@ -1749,57 +1755,53 @@ class PagedDecodeSlots(DecodeSlots):
             and self._pf_ids[parent] is None, \
             f"fork parent {parent} must be an ARMED slot"
         pool = self.prefix.pool
-        parent_groups = self._groups[parent]
+        parent_pages = self._pages[parent]
         # own copy: the parent's log keeps growing under the fork
         tokens = self._tokens[parent].view().copy()
         L = len(tokens)
         self.validate_admission(req, tokens)
         full, r = L // self.page, L % self.page
         total = -(-(L + req.gen_len + self.margin - 1) // self.page)
-        retained: List[np.ndarray] = []
-        fresh: List[np.ndarray] = []
+        retained: List[int] = []
+        fresh: List[int] = []
         try:
             # pin the shared prefix (and the boundary the CoW reads)
             # BEFORE eviction can run for the fresh allocations
-            for g in parent_groups[:full]:
+            for g in parent_pages[:full]:
                 pool.retain(g)
                 retained.append(g)
-            boundary = parent_groups[full] if r else None
+            boundary = parent_pages[full] if r else None
             if boundary is not None:
                 pool.retain(boundary)
                 retained.append(boundary)
             need = total - full
-            if not self.prefix.ensure_pages(need * pool.n_kv_heads):
+            if not self.prefix.ensure_pages(need):
                 from triton_dist_tpu.models.prefix_cache import \
                     PoolExhausted
                 raise PoolExhausted(
                     f"request {req.rid!r}: page pool exhausted at "
-                    f"fork ({need} fresh groups needed, "
+                    f"fork ({need} fresh pages needed, "
                     f"{pool.available} pages free, nothing evictable)")
-            fresh = [pool.alloc_group() for _ in range(need)]
+            fresh = [pool.alloc_page() for _ in range(need)]
         except ValueError:
             for g in fresh + retained:
                 pool.release(g)
             raise
-        slot_groups = list(parent_groups[:full]) + fresh
-        Hkv, maxp = pool.n_kv_heads, self.cache.table.shape[1]
-        rows = np.full((Hkv, maxp), self.cache.trash, np.int32)
-        for j, g in enumerate(slot_groups):
-            rows[:, j] = g
-        trash_vec = np.full((Hkv,), self.cache.trash, np.int32)
-        cow_src = boundary if r else trash_vec
-        cow_dst = fresh[0] if r else trash_vec
+        slot_pages = list(parent_pages[:full]) + fresh
+        cow_src = boundary if r else self.cache.trash
+        cow_dst = fresh[0] if r else self.cache.trash
         self.cache = self.engine.install_slot_paged(
-            self.cache, slot, rows, cow_src, cow_dst, r)
+            self.cache, slot, self._table_row(slot_pages), cow_src,
+            cow_dst, r)
         if boundary is not None:
             # only the CoW copy read it; the fork maps its own copy
             pool.release(boundary)
         self._arm_slot(slot, req, self.logits[parent], L)
-        self._groups[slot] = slot_groups
+        self._pages[slot] = slot_pages
         self._tokens[slot] = _TokenLog(tokens)
         self.prefix.record(L, L)      # the whole prefill was skipped
         self._is_fork[slot] = True
-        self._c_fork_shared.inc(full * Hkv)
+        self._c_fork_shared.inc(full)
         if r:
             self._c_fork_cow.inc()
 
@@ -1860,11 +1862,11 @@ class PagedDecodeSlots(DecodeSlots):
         if len(self._tokens[slot]):
             npg = -(-len(self._tokens[slot]) // self.page)
             self.prefix.insert(self._tokens[slot].view(),
-                               self._groups[slot][:npg])
-        for g in self._groups[slot]:
+                               self._pages[slot][:npg])
+        for g in self._pages[slot]:
             self.prefix.pool.release(g)
         self.cache = self.engine.retire_slot_paged(self.cache, slot)
-        self._groups[slot] = []
+        self._pages[slot] = []
         self._tokens[slot] = _TokenLog()
         self._is_fork[slot] = False
         super().retire(slot)
@@ -1914,7 +1916,7 @@ class PagedDecodeSlots(DecodeSlots):
         n = len(self._tokens[slot])
         self.prefix.insert(
             self._tokens[slot].view(),
-            self._groups[slot][:-(-n // self.page)])
+            self._pages[slot][:-(-n // self.page)])
 
 
 class ContinuousScheduler:

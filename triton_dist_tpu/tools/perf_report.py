@@ -321,14 +321,18 @@ def run_report(write_json=None, only=None):
         kv_bytes / (spec.hbm_gbps * 1e9) * 1e6)
 
     # paged decode: same KV bytes through the page-table walk (W
-    # streams per grid step); the row exists to keep the paged/contig
+    # slots per grid step); the row exists to keep the paged/contig
     # gap measured (target: within 15%)
     from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
     pg = 128 if on_tpu else 64
-    Xs, maxp = B * Hkv, T // pg
-    pk = k.reshape(Xs * maxp, pg, d)
-    pv = v.reshape(Xs * maxp, pg, d)
-    ptab = jnp.arange(Xs * maxp, dtype=jnp.int32).reshape(Xs, maxp)
+    maxp = T // pg
+
+    def paged(a):       # [B, Hkv, T, d] -> pages [B*maxp, Hkv, pg, d]
+        return a.reshape(B, Hkv, maxp, pg, d).transpose(
+            0, 2, 1, 3, 4).reshape(B * maxp, Hkv, pg, d)
+
+    pk, pv = paged(k), paged(v)
+    ptab = jnp.arange(B * maxp, dtype=jnp.int32).reshape(B, maxp)
     add("flash_decode_paged",
         lambda u: flash_decode_paged(u, pk, pv, ptab, jnp.int32(T)), q,
         kv_bytes / (spec.hbm_gbps * 1e9) * 1e6,

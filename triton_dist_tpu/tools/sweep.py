@@ -129,8 +129,8 @@ def tuned_choice(name: str, dims: Optional[Sequence[int]] = None,
     shapes (paged_kv's block_w ladder, group_gemm's _pick, flash_attn's
     _pick_bx), so a cross-bucket fallback can degrade perf but never
     correctness. Constraint-bearing dims additionally belong IN the
-    bucket key (the paged kernels lead with X=B*Hkv, which block_w must
-    divide) so exact-bucket hits are legal by construction and the
+    bucket key (the paged kernels lead with X=B*Hkv, whose slots B
+    block_w must divide) so exact-bucket hits are legal by construction and the
     re-clamp stays a fallback, not the common path."""
     from triton_dist_tpu.tools.tune import _device_tag, shape_bucket
     path = path or default_store_path()
