@@ -143,3 +143,57 @@ def test_wrapped_hooks_are_entered_while_serving(served):
     assert after["ticks_dispatched_ahead"] > before["ticks_dispatched_ahead"]
     assert set(served.lifecycle()) and all(
         ev[1] for evs in served.lifecycle().values() for ev in evs)
+
+
+# ----------------------------------------------------------------------
+# the deepseek_v3 family's adapter (benchmark/systems/deepseek_server.py)
+# on a tiny configuration of that family: the same names and handles
+# ----------------------------------------------------------------------
+
+# what a reader takes from this family's stats() beside _STATS_KEYS
+_SHARE_KEYS = ("moe_pairs_routed", "moe_pairs_held", "kv_page_copy_bytes",
+               "cache_bytes{kind=latent}", "cache_uniform_bytes")
+
+
+@pytest.fixture(scope="module")
+def served_share():
+    from benchmark.systems.deepseek_server import Served
+    from triton_dist_tpu import finalize_distributed
+    cache_dir = jax.config.jax_compilation_cache_dir
+    with open(os.path.join(_REPO, "benchmark", "testdata",
+                           "tiny-deepseek-v3.json")) as f:
+        cfg = json.load(f)
+    s = Served(cfg, 2**31 + 36, jax.devices()[:1], trace=True)
+    try:
+        yield s
+    finally:
+        s.stop()
+        assert not s.errors, s.errors
+        finalize_distributed()
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+
+
+@pytest.mark.parametrize("path", _WRAPPED + _HANDLES)
+def test_share_adapter_has_the_names_the_harness_takes(served_share, path):
+    obj, attr = _resolve(served_share.srv, path)
+    assert hasattr(obj, attr), path
+
+
+def test_share_adapter_serves_and_counts(served_share):
+    """One request through the wire; the handles read, and stats()
+    carries every key a reader of this family's cell takes by name."""
+    from benchmark.systems.deepseek_server import request
+    s = served_share
+    assert s.pool_pages() > 0 and s.weight_bytes > 0
+    assert (s.batch, s.chunk) == (4, 4)
+    before = s.stats()
+    msgs = list(request(s.host, s.port, [3, 5, 7, 11, 13], 8, 300.0))
+    assert msgs[-1].get("done") and not msgs[-1].get("error"), msgs[-1]
+    assert sum(len(m.get("token_ids") or []) for m in msgs) == 8
+    after = s.stats()
+    for key in _STATS_KEYS + _SHARE_KEYS:
+        assert key in after, key
+    routed = after["moe_pairs_routed"] - before["moe_pairs_routed"]
+    assert routed > 0 and routed % (4 * 4 * 2) == 0  # slots x k x layers
+    assert 0 <= after["moe_pairs_held"] <= after["moe_pairs_routed"]
+    assert isinstance(s.lifecycle(), dict) and s.lifecycle()

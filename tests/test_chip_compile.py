@@ -169,8 +169,63 @@ def _paired_paged_decode(q, pk, pv, table, kv_lens):
 _P4_POOL = ((P4_B * (P4_SEQ // PAGE) + 1, P4_HP, PAGE, D), BF16)
 
 
+# --- DeepSeek-V3 at its published widths, the shapes of the cell
+# deepseek-v3-ep16.longgen-saturated: 128 slots, max_seq 4096, page 16;
+# a latent row of 512 + 64 values padded to 640 lanes; 128 query heads
+# over the one latent head; 16 held experts of 7168 x 2 x 2048 / 2048 x
+# 7168; a 1,024-token admission
+DS_B, DS_H, DS_W, DS_RANK, DS_SEQ = 128, 128, 640, 512, 4096
+DS_D, DS_F, DS_E = 7168, 2048, 16
+_DS_POOL = ((DS_B * (DS_SEQ // PAGE) + 1, 1, PAGE, DS_W), BF16)
+
+
+def _latent_paged_decode(q, pool, table, kv_lens):
+    from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
+    return flash_decode_paged(q, pool, None, table, jnp.max(kv_lens),
+                              scale=0.135, kv_lens=kv_lens, v_cols=DS_RANK)
+
+
+def _expanded_prefill(q, k, v):
+    from triton_dist_tpu.kernels.flash_attn import flash_decode
+    return flash_decode(q, k, v, jnp.int32(k.shape[2]), scale=0.135)
+
+
+def _ragged_expert_rows(pairs):
+    """The local stage of an expert layer (layers/ep_moe.py
+    `expert_rows`) at `pairs` routed (token, expert) pairs: both ragged
+    grouped GEMMs, the first with its SwiGLU."""
+    def fn(x, eid, wgu, wd):
+        from triton_dist_tpu.layers.ep_moe import expert_rows
+        return expert_rows(x, jnp.arange(pairs) // 8, eid, wgu, wd)
+    return fn
+
+
+def _ragged_args(pairs):
+    return [((pairs // 8, DS_D), BF16), ((pairs,), I32),
+            ((DS_E, DS_D, 2 * DS_F), BF16), ((DS_E, DS_F, DS_D), BF16)]
+
+
 # name -> (function, [(shape, dtype), ...]); () is a traced scalar
 CASES = {
+    # the absorbed decode walk: 128 slots, one latent head, rep 128,
+    # keys 640 lanes wide, values their first 512 columns (20 KiB a copy)
+    "dsv3_latent_walk_b128": (
+        _latent_paged_decode, [((DS_B, 1, DS_H, DS_W), BF16), _DS_POOL,
+                               ((DS_B, DS_SEQ // PAGE), I32),
+                               ((DS_B,), I32)]),
+    # the expanded prefill attend: 128 heads, q/k 192 and v 128 padded
+    # to 256, the last 256 query rows of a 1,024-token prompt
+    "dsv3_expanded_prefill_q256_t1024": (
+        _expanded_prefill, [((1, 256, DS_H, 256), BF16),
+                            ((1, DS_H, 1024, 256), BF16),
+                            ((1, DS_H, 1024, 256), BF16)]),
+    # the ragged grouped GEMMs over 16 held experts: a decode tick's
+    # worst case (128 tokens x 8 pairs, ~64 of them held) and a
+    # 1,024-token admission's (8,192 pairs, ~512 held)
+    "dsv3_ragged_gmm_decode_pairs1024": (
+        _ragged_expert_rows(1024), _ragged_args(1024)),
+    "dsv3_ragged_gmm_admit_pairs8192": (
+        _ragged_expert_rows(8192), _ragged_args(8192)),
     # the admission's chunked scan over a 2,048-token prompt, and the
     # decode's one-token update of 64 slots' states
     "phi4_ssm_scan_s2048": (

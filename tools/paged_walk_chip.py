@@ -2,6 +2,7 @@
 what the CPU interpreter cannot show of it.
 
     chiprun -- python tools/paged_walk_chip.py                 # all three
+    chiprun -- python tools/paged_walk_chip.py check time gmm --shapes mla
     chiprun -- python tools/paged_walk_chip.py time --block-w 4 8 16
     JAX_PLATFORMS=cpu python tools/paged_walk_chip.py --rehearse
 
@@ -24,8 +25,16 @@ Three phases, each one JSON line per reading, `{"ok": true, ...}` last:
   (`SHAPES`): one chip's 32 slots x 8 heads x 2 rows and a TP=4 chip's
   32 x 2 x 8, lengths uniform 256..640 over 128 table columns; and
   Phi-4's 64 slots x 10 paired heads x 4 rows, lengths 2,048..3,300
-  over 256 columns. PERF.md's tables of forms, of W and of ns a copy
-  were read from this.
+  over 256 columns; and the latent walk's 128 slots x 1 latent head x
+  128 query rows (`mla`: rows of 640 lanes, values their first 512),
+  lengths 1,024..3,000. PERF.md's tables of forms, of W and of ns a
+  copy were read from this.
+- `gmm` (only when asked for): the expert layer's local stage
+  (layers/ep_moe.py `expert_rows`: both ragged grouped GEMMs) at 16
+  held experts of the published 7168 x 2 x 2048 / 2048 x 7168, for a
+  decode tick's 1,024 pair rows (~64 of them on held experts) and a
+  1,024-token admission's 8,192 (~512), against a plain einsum on the
+  rows that landed, then ms a call over 8 chained calls.
 """
 
 from __future__ import annotations
@@ -47,9 +56,11 @@ PAGE, D, CALLS = 16, 128, 28
 # draws there (the cells' contexts)
 SHAPES = {"1chip": dict(B=32, Hkv=8, Hq=16),
           "tp4": dict(B=32, Hkv=2, Hq=16),
-          "phi4": dict(B=64, Hkv=10, Hq=40)}
+          "phi4": dict(B=64, Hkv=10, Hq=40),
+          "mla": dict(B=128, Hkv=1, Hq=128, d=640, v_cols=512)}
 TIMED = {"1chip": (128, 256, 640), "tp4": (128, 256, 640),
-         "phi4": (256, 2048, 3300)}      # table columns, shortest, longest
+         "phi4": (256, 2048, 3300),      # table columns, shortest, longest
+         "mla": (256, 1024, 3000)}
 TOL = 0.03      # bf16 pools and P against float32: ~0.01 at these sizes
 
 
@@ -57,34 +68,34 @@ def _log(**fields) -> None:
     print(json.dumps(fields), flush=True)
 
 
-def _build(rng, B, Hkv, Hq, maxp, lens, S=1):
+def _build(rng, B, Hkv, Hq, maxp, lens, S=1, d=D, v_cols=None):
     """Random pools behind a shuffled table (page 0 unused): a page is
-    a slot's PAGE positions for all of its Hkv heads."""
+    a slot's PAGE positions for all of its Hkv heads. v_cols: a latent
+    pool (one plane, no V: pv is None)."""
     import jax
     import jax.numpy as jnp
     NP = B * maxp + 1
     # made on the device: Phi-4's two pools are 1.3 GB
     pk, pv = (jax.random.normal(
-        jax.random.PRNGKey(rng.randint(1 << 30)), (NP, Hkv, PAGE, D),
+        jax.random.PRNGKey(rng.randint(1 << 30)), (NP, Hkv, PAGE, d),
         jnp.bfloat16) * 0.5 for _ in range(2))
     table = jnp.asarray(
         1 + rng.permutation(NP - 1).reshape(B, maxp), jnp.int32)
-    q = jnp.asarray(rng.randn(B, S, Hq, D) * 0.5, jnp.bfloat16)
-    return q, pk, pv, table, jnp.asarray(lens, jnp.int32)
+    q = jnp.asarray(rng.randn(B, S, Hq, d) * (0.5 if d == D else 0.2),
+                    jnp.bfloat16)
+    return (q, pk, None if v_cols else pv, table,
+            jnp.asarray(lens, jnp.int32))
 
 
-def _reference(q, pk, pv, table, lens, q_lens=None):
+def _reference(q, pk, pv, table, lens, q_lens=None, v_cols=None):
     from triton_dist_tpu.kernels.flash_attn import attention_cached_ref
+    from triton_dist_tpu.kernels.paged_kv import gather_pages
     import jax.numpy as jnp
-    B, maxp = table.shape
-
-    def gather(pool):       # [B, maxp, Hkv, PAGE, D] -> [B, Hkv, T, D]
-        return pool[table].transpose(0, 2, 1, 3, 4).reshape(
-            B, pool.shape[1], maxp * PAGE, D)
-
-    k, v = gather(pk), gather(pv)
-    return attention_cached_ref(q.astype(jnp.float32), k, v, lens,
-                                q_lens=q_lens)
+    k = gather_pages(pk, table)
+    v = k if pv is None else gather_pages(pv, table)
+    out = attention_cached_ref(q.astype(jnp.float32), k, v, lens,
+                               q_lens=q_lens)
+    return out[..., :v_cols] if v_cols else out
 
 
 def check(rehearse: bool) -> None:
@@ -93,10 +104,12 @@ def check(rehearse: bool) -> None:
     from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
     rng = np.random.RandomState(30)
     for name, kw in SHAPES.items():
-        B = kw["B"]
+        B, v_cols = kw["B"], kw.get("v_cols")
         maxp = 16 if rehearse else TIMED[name][0]
         cap = maxp * PAGE
-        for windows in (False, True):
+        # a latent pool serves plain decode only (query windows ride
+        # spec decode and chunked prefill, both refused for it)
+        for windows in ((False,) if v_cols else (False, True)):
             S = 4 if windows else 1
             lens = rng.randint(cap // 8, cap // 3, size=B)
             qls = rng.randint(1, S + 1, size=B)
@@ -111,11 +124,12 @@ def check(rehearse: bool) -> None:
                                            **kw)
             ql = jnp.asarray(qls, jnp.int32) if windows else None
             out = jax.jit(lambda *a: flash_decode_paged(
-                a[0], a[1], a[2], a[3], None, kv_lens=a[4], q_lens=ql))(
-                    q, pk, pv, table, kvl)
+                a[0], a[1], a[2], a[3], None, kv_lens=a[4], q_lens=ql,
+                v_cols=v_cols))(q, pk, pv, table, kvl)
             live = lens > 0
             out = np.asarray(out, np.float32)
-            ref = np.asarray(_reference(q, pk, pv, table, kvl, ql))
+            ref = np.asarray(_reference(q, pk, pv, table, kvl, ql,
+                                        v_cols))
             if windows:     # rows past a slot's window are discarded
                 rows = np.arange(S)[None] < qls[:, None]
                 out, ref = out * rows[..., None, None], \
@@ -171,7 +185,7 @@ def mixed(rehearse: bool) -> None:
     assert agreed["mixed"] >= agreed["control"] // 2, agreed
 
 
-def _ms_per_call(args, block_w):
+def _ms_per_call(args, block_w, v_cols=None):
     import jax
     import jax.numpy as jnp
     from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
@@ -179,7 +193,9 @@ def _ms_per_call(args, block_w):
     def chain(q, pk, pv, table, lens):
         def body(qc, _):
             o = flash_decode_paged(qc, pk, pv, table, None, kv_lens=lens,
-                                   block_w=block_w)
+                                   block_w=block_w, v_cols=v_cols)
+            o = jnp.pad(o, ((0, 0),) * 3 + ((0, qc.shape[-1]
+                                             - o.shape[-1]),))
             return (qc + o * jnp.bfloat16(0.01)).astype(qc.dtype), ()
         return jax.lax.scan(body, q, None, length=CALLS)[0]
 
@@ -207,16 +223,84 @@ def timing(widths, lens) -> None:
             w = w or None           # 0 = the kernel's own pick
             if w is not None and kw["B"] % w:
                 continue
-            mn, med, first = _ms_per_call(args, w)
+            mn, med, first = _ms_per_call(args, w, kw.get("v_cols"))
             _log(phase="time", shape=name, block_w=w, lens=[lo, hi],
                  ms_min=round(mn, 4), ms_med=round(med, 4),
                  first_call_s=round(first, 1))
 
 
+def gmm(rehearse: bool) -> None:
+    """The expert layer's local stage alone (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    from triton_dist_tpu.layers.ep_moe import expert_rows
+    E, Dm, F, K = (4, 128, 128, 4) if rehearse else (16, 7168, 2048, 8)
+    ks = jax.random.split(jax.random.PRNGKey(36), 6)
+    dt = jnp.float32 if rehearse else jnp.bfloat16
+    wgu = (jax.random.normal(ks[0], (E, Dm, 2 * F), jnp.float32)
+           * Dm ** -0.5).astype(dt)
+    wd = (jax.random.normal(ks[1], (E, F, Dm), jnp.float32)
+          * F ** -0.5).astype(dt)
+    for label, T in (("decode", 16 if rehearse else 128),
+                     ("admit", 64 if rehearse else 1024)):
+        x = jax.random.normal(ks[2], (T, Dm), jnp.float32).astype(dt)
+        R = T * K
+        # a pair lands on a held expert with probability 1/16
+        eid = jnp.where(jax.random.uniform(ks[3], (R,)) < 1 / 16,
+                        jax.random.randint(ks[4], (R,), 0, E), E)
+        src = jnp.arange(R) // K
+        f = jax.jit(lambda x, eid, a, b: expert_rows(x, src, eid, a, b))
+        y = np.asarray(f(x, eid, wgu, wd), np.float32)
+        eid_h = np.asarray(eid)
+        held = eid_h < E
+        rows = np.nonzero(held)[0]
+        want = np.zeros((len(rows), Dm), np.float32)
+        for e in range(E):          # an expert at a time: 117 MB in f32
+            at = np.nonzero(eid_h[rows] == e)[0]
+            if not len(at):
+                continue
+            xe = x[np.asarray(src)[rows[at]]].astype(jnp.float32)
+            g, u = jnp.split(xe @ wgu[e].astype(jnp.float32), 2, axis=-1)
+            h = (g * jax.nn.sigmoid(g) * u).astype(dt).astype(jnp.float32)
+            want[at] = np.asarray(h @ wd[e].astype(jnp.float32))
+        err = float(np.abs(y[rows] - np.asarray(want)).max())
+        zero = bool((y[~held] == 0).all())
+        tol = 1e-4 if rehearse else 0.06
+        _log(phase="gmm_check", shape=label, pair_rows=R,
+             pairs_held=int(held.sum()), max_err=err, tol=tol,
+             other_rows_zero=zero)
+        assert err <= tol and zero, (label, err)
+        if rehearse:
+            continue
+
+        def chain(x, eid, a, b):
+            def body(xc, _):
+                y = expert_rows(xc, src, eid, a, b)
+                return (xc + y.reshape(T, K, Dm).sum(1)
+                        * jnp.bfloat16(0.01)).astype(xc.dtype), ()
+            return jax.lax.scan(body, x, None, length=8)[0]
+
+        fc = jax.jit(chain)
+        fc(x, eid, wgu, wd).block_until_ready()
+        ts = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            fc(x, eid, wgu, wd).block_until_ready()
+            ts.append(time.perf_counter() - t0)
+        _log(phase="gmm_time", shape=label, pair_rows=R,
+             pairs_held=int(held.sum()), ms_min=round(min(ts) / 8 * 1e3, 4),
+             ms_med=round(float(np.median(ts)) / 8 * 1e3, 4),
+             weights_ms_at_819=round(
+                 1e3 * (wgu.nbytes + wd.nbytes) / 819e9, 4))
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("phases", nargs="*", default=["check", "mixed", "time"],
-                    help="of check, mixed, time (default: all)")
+                    help="of check, mixed, time, gmm (default: the "
+                         "first three)")
+    ap.add_argument("--shapes", nargs="*", default=None,
+                    help="of " + ", ".join(SHAPES) + " (default: all)")
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes for the CPU interpreter; no timing")
     ap.add_argument("--block-w", type=int, nargs="*", default=None,
@@ -231,6 +315,9 @@ def main() -> None:
     _log(device=str(dev), kind=dev.device_kind)
     if not args.rehearse:
         assert dev.platform == "tpu", f"not a TPU: {dev}"
+    for name in list(SHAPES):
+        if args.shapes and name not in args.shapes:
+            del SHAPES[name]
     if args.rehearse:
         for kw in SHAPES.values():
             kw["B"] = 20
@@ -240,6 +327,8 @@ def main() -> None:
         mixed(args.rehearse)
     if "time" in args.phases and not args.rehearse:
         timing(args.block_w, args.lens)
+    if "gmm" in args.phases:
+        gmm(args.rehearse)
     _log(ok=True, device={"platform": dev.platform, "kind": dev.device_kind})
 
 

@@ -93,6 +93,35 @@ def route(router_logits, k: int, *, norm_topk: bool = True):
     return w, idx.astype(jnp.int32)
 
 
+def route_noaux_tc(x, w_router, e_bias, k: int, *, n_group: int,
+                   topk_group: int, routed_scaling_factor: float):
+    """Grouped sigmoid routing without an auxiliary loss (DeepSeek-V3's
+    `noaux_tc`), in float32 whatever x's dtype, as published: scores
+    sigmoid(x W_r) [T, E]; SELECTION reads the scores plus the learned
+    bias `e_bias` [E]: a group's score is the sum of its two largest
+    biased scores, the `topk_group` best of `n_group` groups stay in
+    (the others' biased scores set to 0.0), top-k of what is left;
+    WEIGHTS read the unbiased scores of the chosen experts, normalised
+    to 1 and scaled. Returns (weights [T, k] f32, expert_idx [T, k]
+    int32) over all E experts the router has columns for."""
+    with jax.named_scope("moe_route"):
+        sc = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), w_router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        sb = sc + e_bias.astype(jnp.float32)
+        T, E = sb.shape
+        grouped = sb.reshape(T, n_group, E // n_group)
+        gscore = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, gidx = jax.lax.top_k(gscore, topk_group)
+        keep = jnp.any(jnp.arange(n_group) == gidx[..., None], axis=-2)
+        masked = jnp.where(keep[..., None], grouped, 0.0).reshape(T, E)
+        _, idx = jax.lax.top_k(masked, k)
+        w = jnp.take_along_axis(sc, idx, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) \
+            * routed_scaling_factor
+        return w, idx.astype(jnp.int32)
+
+
 @dataclasses.dataclass
 class DispatchPlan:
     """Source-side record of where each (token, k) entry was placed, so

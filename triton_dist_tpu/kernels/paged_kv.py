@@ -74,7 +74,8 @@ def _block_pages(page: int) -> int:
 
 
 def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
-                  quant: bool, partial: bool, s_ref, *refs):
+                  quant: bool, partial: bool, v_cols: Optional[int],
+                  s_ref, *refs):
     """Grid (B // W,): one step walks W slots — W*H (slot, kv-head)
     streams, H the heads of the pool it is handed — through THEIR OWN
     pages, C pages at a time (module docstring). refs = q
@@ -120,10 +121,19 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
     UNNORMALIZED accumulator plus the (m, l) softmax stats instead of
     the normalized output. Tiles a chip does not own are a bitwise
     no-op of its accumulator, so the n per-chip partials LSE-combine to
-    exactly the full softmax."""
-    q_ref, lens_ref, k_hbm, v_hbm = refs[:4]
-    rest = refs[4:]
-    pools = [k_hbm, v_hbm]
+    exactly the full softmax.
+
+    v_cols (a LATENT pool — kv_cache.LatentSlotCache, layers/mla_attn.py:
+    one plane a layer, no V pool): a page row is [c_kv | k_pe | pad], the
+    keys are the whole row and the values its first v_cols columns, so
+    K and V are read from the SAME block of the buffer: one copy a page,
+    QK over d, PV over v_cols, the output v_cols wide. With one latent
+    head and rep = every query head, a block is an MXU-shaped
+    [rep, d] x [d, C*page] and [rep, C*page] x [C*page, v_cols]."""
+    q_ref, lens_ref = refs[:2]
+    n_payload = 1 if v_cols is not None else 2
+    pools = list(refs[2:2 + n_payload])
+    rest = refs[2 + n_payload:]
     if quant:
         pools += rest[:2]
         rest = rest[2:]
@@ -134,7 +144,8 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
         o_ref = rest[0]
         rest = rest[1:]
     *bufs, sem = rest
-    kbuf, vbuf, *scale_bufs = bufs
+    kbuf = bufs[0]
+    scale_bufs = bufs[n_payload:]
     x = pl.program_id(0)
     B = pl.num_programs(0) * W      # slots of the call
     WH, rows, d = q_ref.shape
@@ -175,7 +186,7 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
 
                 def copies():
                     for n, (pool, buf) in enumerate(zip(pools, bufs)):
-                        dst = (buf.at[half, j, :, at] if n < 2
+                        dst = (buf.at[half, j, :, at] if n < n_payload
                                else buf.at[half, j, :, 0, at])
                         act(pltpu.make_async_copy(pool.at[pid], dst,
                                                   sem.at[half]))
@@ -239,7 +250,8 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
         alpha = jnp.exp(m - m_new)
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         l = l * alpha + jnp.sum(p, -1, keepdims=True)
-        v = vbuf[half].reshape(WH, CP, d)
+        v = (k[:, :, :v_cols] if v_cols is not None
+             else bufs[1][half].reshape(WH, CP, d))
         if quant:
             # V's scale folds into p (diag(sv) V == V rows scaled); the
             # convert to the compute dtype happens in VMEM
@@ -254,7 +266,7 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
         0, nblk, block,
         (jnp.full((WH, rows, 1), -1e30, jnp.float32),
          jnp.zeros((WH, rows, 1), jnp.float32),
-         jnp.zeros((WH, rows, d), jnp.float32)))
+         jnp.zeros((WH, rows, v_cols or d), jnp.float32)))
     if partial:
         # the SP partial contract: unnormalized accumulator + softmax
         # stats, combined across chips by lse_combine
@@ -269,8 +281,13 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
 def flash_decode_paged(q, pages_k, pages_v, page_table, kv_len, *,
                        scale: Optional[float] = None, kv_lens=None,
                        q_lens=None, k_scale=None, v_scale=None,
-                       block_w: Optional[int] = None):
+                       block_w: Optional[int] = None,
+                       v_cols: Optional[int] = None):
     """Cached GQA decode attention through a page table.
+
+    pages_v=None with v_cols: a LATENT pool (one plane; the values are
+    the first v_cols columns of the key rows): returns [B, S, Hq,
+    v_cols].
 
     q: [B, S, Hq, d] (S == 1 unless q_lens is given); pages_k/v:
     [NP, Hkv, page, d] (Hkv = the kv heads this pool holds of every
@@ -308,7 +325,8 @@ def flash_decode_paged(q, pages_k, pages_v, page_table, kv_len, *,
     return _flash_decode_paged_call(
         q, pages_k, pages_v, page_table, kv_len, scale=scale,
         kv_lens=kv_lens, q_lens=q_lens, k_scale=k_scale,
-        v_scale=v_scale, tile_owned=None, block_w=block_w)
+        v_scale=v_scale, tile_owned=None, block_w=block_w,
+        v_cols=v_cols)
 
 
 def flash_decode_paged_partial(q, pages_k, pages_v, page_table, *,
@@ -344,11 +362,13 @@ def flash_decode_paged_partial(q, pages_k, pages_v, page_table, *,
         tune_name="flash_decode_paged_partial")
 
 
-def _slot_block(tune_name, dims, B, H, block_w):
+def _slot_block(tune_name, dims, B, H, block_w, streams: int = 8):
     """W: slots per grid step. Resolution: explicit block_w >
     contextual profile > tune cache (tools/sweep) > the largest W
-    dividing B with W*H <= 8 streams a step (1 slot on a chip that
-    holds 8 or more kv heads of every slot, 4 where it holds 2). W only
+    dividing B with W*H <= `streams` streams a step (1 slot on a chip
+    that holds 8 or more kv heads of every slot, 4 where it holds 2; a
+    latent walk, whose one stream a slot carries every query head,
+    asks for 2). W only
     regroups slots across grid steps and never changes a stream's
     result. Strictness splits by provenance: an indivisible W that was
     pinned explicitly or installed in the contextual profile is a loud
@@ -374,16 +394,23 @@ def _slot_block(tune_name, dims, B, H, block_w):
         W = None
     if W is None:
         W = next(w for w in (8, 4, 2, 1)
-                 if B % w == 0 and (w * H <= 8 or w == 1))
+                 if B % w == 0 and (w * H <= streams or w == 1))
     return int(W)
 
 
 def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
                              scale, kv_lens, q_lens, k_scale, v_scale,
-                             tile_owned, block_w=None,
+                             tile_owned, block_w=None, v_cols=None,
                              tune_name="flash_decode_paged"):
     B, S, Hq, d = q.shape
     partial = tile_owned is not None
+    latent = v_cols is not None
+    assert latent == (pages_v is None), \
+        "a latent pool has no V plane: pages_v=None with v_cols"
+    assert not latent or (k_scale is None and not partial), \
+        "the latent walk serves the bf16 pool on one chip"
+    if latent:
+        tune_name = "flash_decode_paged_latent"
     if q_lens is not None:
         assert kv_lens is not None, "q_lens rides on per-slot kv_lens"
     elif not partial:
@@ -404,7 +431,8 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
     qx = (q.reshape(B, S, H, rep, d)
            .transpose(0, 2, 1, 3, 4)
            .reshape(X, rows, d))
-    W = _slot_block(tune_name, (X, B * Hq, NP * page), B, H, block_w)
+    W = _slot_block(tune_name, (X, B * Hq, NP * page), B, H, block_w,
+                    streams=2 if latent else 8)
     WH = W * H
     CP = _block_pages(page) * page
     # every slot carries its own (kv length, query length): a launch
@@ -429,12 +457,12 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
         return pl.BlockSpec((WH,) + tail, lambda x, s_ref: (x, 0, 0))
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [per_step(rows, d), per_step(1, 2), hbm, hbm]
+    payload = [pages_k] if latent else [pages_k, pages_v]
+    dv = v_cols if latent else d
+    in_specs = [per_step(rows, d), per_step(1, 2)] + [hbm] * len(payload)
     args = [qx, jnp.repeat(jnp.stack([lens_b, qlens_b], 1), H,
-                           axis=0).reshape(X, 1, 2),
-            pages_k, pages_v]
-    scratch = [pltpu.VMEM((2, W, H, CP, d), pages_k.dtype),
-               pltpu.VMEM((2, W, H, CP, d), pages_v.dtype)]
+                           axis=0).reshape(X, 1, 2)] + payload
+    scratch = [pltpu.VMEM((2, W, H, CP, d), p.dtype) for p in payload]
     if quant:
         in_specs += [hbm, hbm]
         args += [k_scale, v_scale]
@@ -448,17 +476,17 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
         own = jnp.pad(own, ((0, 0), (0, L - maxp * page)))
         in_specs.append(per_step(1, L))
         args.append(jnp.repeat(own, H, axis=0).reshape(X, 1, L))
-        out_specs = (per_step(rows, d), per_step(rows, 1),
+        out_specs = (per_step(rows, dv), per_step(rows, 1),
                      per_step(rows, 1))
         out_shape = (jax.ShapeDtypeStruct((X, rows, d), jnp.float32),
                      jax.ShapeDtypeStruct((X, rows, 1), jnp.float32),
                      jax.ShapeDtypeStruct((X, rows, 1), jnp.float32))
     else:
-        out_specs = per_step(rows, d)
-        out_shape = jax.ShapeDtypeStruct((X, rows, d), q.dtype)
+        out_specs = per_step(rows, dv)
+        out_shape = jax.ShapeDtypeStruct((X, rows, dv), q.dtype)
     out = pl.pallas_call(
         functools.partial(_paged_kernel, float(scale), rep, page, W,
-                          maxp, quant, partial),
+                          maxp, quant, partial, v_cols),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B // W,),

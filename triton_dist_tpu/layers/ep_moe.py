@@ -31,10 +31,38 @@ from triton_dist_tpu.kernels.ep_a2a import (combine_a2a, combine_from_slots,
                                             group_by_expert, pack_rows_int8,
                                             plan_dispatch,
                                             plan_dispatch_valid, route,
+                                            route_noaux_tc,
                                             unpack_rows_int8)
-from triton_dist_tpu.kernels.group_gemm import grouped_gemm
+from triton_dist_tpu.kernels.group_gemm import (group_rows_ragged,
+                                                grouped_gemm,
+                                                ragged_block_m,
+                                                ragged_grouped_gemm)
 from triton_dist_tpu.kernels.swiglu import swiglu_ref
 from triton_dist_tpu.runtime import next_collective_id
+
+
+def expert_rows(x, src_row, eid, w_gate_up, w_down):
+    """The LOCAL STAGE of an expert owner, the same code for a rank of
+    `fwd_ep` (its received slots) and for a stated share on one chip
+    (`fwd_share`: its own tokens' routed pairs): group the work rows
+    that landed on held experts (kernels/group_gemm.py
+    `group_rows_ragged`: sorted by expert, each group padded to the row
+    tile), ragged grouped GEMM + SwiGLU, ragged grouped GEMM, back to
+    work-row order.
+
+    x [N, D]; src_row [R]: the row of x each work row reads; eid [R]:
+    its LOCAL expert, `w_gate_up.shape[0]` for a row that landed on no
+    held expert. Returns [R, D], zero for those rows. No capacity:
+    nothing is dropped, and the GEMMs' work follows the rows that
+    landed, not R."""
+    E = w_gate_up.shape[0]
+    R = eid.shape[0]
+    g = group_rows_ragged(eid, E, ragged_block_m(R))
+    xs = jnp.where((g.src >= 0)[:, None],
+                   x[src_row[jnp.maximum(g.src, 0)]], 0).astype(x.dtype)
+    h = ragged_grouped_gemm(xs, w_gate_up.astype(x.dtype), g, swiglu=True)
+    y = ragged_grouped_gemm(h, w_down.astype(x.dtype), g)
+    return jnp.where((eid < E)[:, None], y[g.dest], 0).astype(x.dtype)
 
 
 @jax.tree_util.register_dataclass
@@ -46,6 +74,12 @@ class EP_MoE:
     w_gate_up: [E, D, 2I] sharded P(ep, None, None) — E/n experts per
                device, full intermediate (packed [gate | up]).
     w_down:    [E, I, D] sharded P(ep, None, None).
+
+    A STATED SHARE (`held = (first, count)`): the layer is one chip of
+    a wider expert-parallel deployment. w_router keeps all E columns,
+    w_gate_up / w_down hold experts first .. first + count - 1 only,
+    and `fwd_share` adds what those add: no dispatch, no combine, and
+    nothing standing in for the absent chips.
     """
 
     w_router: jax.Array
@@ -68,18 +102,47 @@ class EP_MoE:
     # (one int8 rounding per direction), like the reference's fp8 wire.
     payload_int8: bool = dataclasses.field(
         default=False, metadata=dict(static=True))
+    # (first, count) of the routed experts this layer holds, of the
+    # w_router.shape[1] the router ranks; None = all of them, split
+    # over the mesh
+    held: Optional[tuple] = dataclasses.field(
+        default=None, metadata=dict(static=True))
+    # grouped sigmoid routing (kernels/ep_a2a.py route_noaux_tc):
+    # (n_group, topk_group, routed_scaling_factor); None = softmax top-k
+    noaux: Optional[tuple] = dataclasses.field(
+        default=None, metadata=dict(static=True))
+    # `noaux` routing only: the selection bias [E] f32 (a leaf)
+    e_bias: Optional[jax.Array] = None
 
     @staticmethod
     def init(w_router, w_gate, w_up, w_down, *, mesh: Mesh,
              axis: str = "tp", top_k: int,
              capacity_factor: float = 2.0,
              slice_axis: Optional[str] = None,
-             payload_int8: bool = False) -> "EP_MoE":
+             payload_int8: bool = False, held: Optional[tuple] = None,
+             e_bias=None, noaux: Optional[tuple] = None) -> "EP_MoE":
         import numpy as np
         E = np.shape(w_gate)[0]      # no device transfer for the check
         n_ep = mesh.shape[axis] * (mesh.shape[slice_axis]
                                    if slice_axis else 1)
-        if E % n_ep:
+        if (e_bias is None) != (noaux is None):
+            raise ValueError("noaux routing takes its selection bias "
+                             "and its (n_group, topk_group, scale) "
+                             "together")
+        if held is not None:
+            first, count = held
+            E_all = np.shape(w_router)[1]
+            if n_ep != 1 or slice_axis:
+                raise ValueError(
+                    f"a stated share held={held} is ONE chip's part of "
+                    f"a wider deployment; the mesh axis {axis!r} has "
+                    f"size {n_ep} (missing capability: a share split "
+                    f"again over a mesh)")
+            if E != count or first < 0 or first + count > E_all:
+                raise ValueError(
+                    f"held={held}: {E} expert panels given for a share "
+                    f"of {count} of the router's {E_all}")
+        elif E % n_ep:
             raise ValueError(
                 f"EP_MoE needs the expert count ({E}) divisible by the "
                 f"expert-parallel axis size ({n_ep}, mesh axis "
@@ -97,7 +160,46 @@ class EP_MoE:
         return EP_MoE(w_router=jnp.asarray(w_router), w_gate_up=packed,
                       w_down=w_down, mesh=mesh, axis=axis, top_k=top_k,
                       capacity_factor=capacity_factor,
-                      slice_axis=slice_axis, payload_int8=payload_int8)
+                      slice_axis=slice_axis, payload_int8=payload_int8,
+                      held=held, noaux=noaux,
+                      e_bias=(None if e_bias is None
+                              else jnp.asarray(e_bias, jnp.float32)))
+
+    def _route(self, x):
+        """(weights [T, k] f32, expert numbers [T, k]) over all the
+        experts the router ranks."""
+        if self.noaux is None:
+            return route(x @ self.w_router.astype(x.dtype), self.top_k)
+        n_group, topk_group, scale = self.noaux
+        return route_noaux_tc(x, self.w_router, self.e_bias, self.top_k,
+                              n_group=n_group, topk_group=topk_group,
+                              routed_scaling_factor=scale)
+
+    def fwd_share(self, x, return_stats: bool = False):
+        """x [T, D] on the one chip that holds `held`: route over all E
+        experts, add w_i Expert_i(x) for the chosen experts held here.
+        DROPLESS by construction (`expert_rows` has no capacity).
+
+        return_stats=True also returns {"dropped": 0, "expert_tokens":
+        [count] the pairs each held expert got, "pairs_routed": T * k,
+        "pairs_held": those that landed here}."""
+        first, count = self.held
+        T, k = x.shape[0], self.top_k
+        topk_w, topk_idx = self._route(x)
+        local = topk_idx - first
+        eid = jnp.where((local >= 0) & (local < count), local,
+                        count).reshape(-1)
+        y = expert_rows(x, jnp.arange(T * k) // k, eid, self.w_gate_up,
+                        self.w_down)
+        y = jnp.sum(y.reshape(T, k, -1).astype(jnp.float32)
+                    * topk_w[..., None], axis=1).astype(x.dtype)
+        if not return_stats:
+            return y
+        counts = expert_token_counts(eid[:, None], count + 1)[:count]
+        return y, {"dropped": jnp.zeros((), jnp.int32),
+                   "expert_tokens": counts,
+                   "pairs_routed": jnp.int32(T * k),
+                   "pairs_held": jnp.sum(counts)}
 
     @property
     def num_experts(self) -> int:
@@ -185,7 +287,35 @@ class EP_MoE:
                                          collective_id=cid)
                 comb = functools.partial(combine_a2a, n=n, axis=axis,
                                          collective_id=cid)
+        def local_capacity(recv_x, recv_meta, wgu_loc, wd_loc):
+            # the differentiable local stage (a custom-VJP `gemm`):
+            # capacity-padded [E, C, D] batches
+            x_e, inv_slot, r_drop = group_by_expert(recv_x, recv_meta,
+                                                    epr, e_cap)
+            h = gemm(x_e, wgu_loc.astype(x_e.dtype))
+            h = swiglu_ref(h)
+            y_e = gemm(h, wd_loc.astype(x_e.dtype))
+            y_flat = y_e.reshape(epr * e_cap, -1)
+            gathered = jnp.take(y_flat,
+                                jnp.minimum(inv_slot, epr * e_cap - 1),
+                                axis=0)
+            return gathered * (inv_slot < epr * e_cap)[:, None].astype(
+                gathered.dtype), r_drop
+
+        def local_ragged(recv_x, recv_meta, wgu_loc, wd_loc):
+            # serving: the stage `fwd_share` runs, over the received
+            # slots; an arrival is never dropped here
+            eid = jnp.where(recv_meta[:, 1] > 0, recv_meta[:, 0], epr)
+            return expert_rows(recv_x, jnp.arange(recv_x.shape[0]), eid,
+                               wgu_loc, wd_loc), jnp.zeros((), jnp.int32)
+
+        # 'dropless' on the capacity stage means every expert sized for
+        # every arrival: the ragged stage gives the same guarantee for
+        # the work of what arrived. A float factor keeps its counted
+        # drops, the training path its differentiable grouped GEMM.
+        ragged = gemm is None and self.capacity_factor == "dropless"
         gemm = gemm or grouped_gemm
+        local = local_ragged if ragged else local_capacity
 
         @functools.partial(
             jax.shard_map, mesh=self.mesh,
@@ -199,17 +329,7 @@ class EP_MoE:
             send_x, send_meta = fill_send_buffers(x_loc, topk_idx, plan,
                                                   n, epr, cap)
             recv_x, recv_meta = disp(send_x, send_meta)
-            x_e, inv_slot, r_drop = group_by_expert(recv_x, recv_meta,
-                                                    epr, e_cap)
-            h = gemm(x_e, wgu_loc.astype(x_e.dtype))
-            h = swiglu_ref(h)
-            y_e = gemm(h, wd_loc.astype(x_e.dtype))
-            y_flat = y_e.reshape(epr * e_cap, -1)
-            gathered = jnp.take(y_flat,
-                                jnp.minimum(inv_slot, epr * e_cap - 1),
-                                axis=0)
-            y_slots = gathered * (inv_slot < epr * e_cap)[:, None].astype(
-                gathered.dtype)
+            y_slots, r_drop = local(recv_x, recv_meta, wgu_loc, wd_loc)
             y_back = comb(y_slots)
             y = combine_from_slots(y_back, plan, topk_w, t_loc)
             loud = (warn_drops and self.capacity_factor != "dropless")

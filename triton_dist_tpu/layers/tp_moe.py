@@ -91,7 +91,11 @@ class TP_MoE:
         """Static per-expert capacity (reference analog: the max_M-sized
         symmetric workspaces). capacity_factor='dropless' uses the
         provable worst case (all routed entries on one expert) — never
-        drops, at the memory price of the bound."""
+        drops, at the memory price of the bound AND its work: the
+        [E, C, D] grouped GEMM computes E x M x top_k rows whatever was
+        routed. The dropless stage whose work follows the routed pairs
+        is the ragged one (layers/ep_moe.py `expert_rows`,
+        kernels/group_gemm.py `ragged_grouped_gemm`)."""
         if self.capacity_factor == "dropless":
             # rounded up to whole 8-row tiles (kernel slab slices must
             # stay sublane-aligned on real TPUs)

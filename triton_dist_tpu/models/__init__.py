@@ -6,6 +6,8 @@ Qwen3-MoE -> Qwen3MoE)."""
 from triton_dist_tpu.models.config import (ModelConfig, qwen3_30b_a3b,  # noqa: F401
                                            qwen3_32b, tiny_qwen3,
                                            tiny_qwen3_moe)
+from triton_dist_tpu.models.deepseek import (DeepSeekConfig,  # noqa: F401
+                                             DeepSeekV3, tiny_deepseek)
 from triton_dist_tpu.models.dense import DenseLLM  # noqa: F401
 from triton_dist_tpu.models.disagg import (DCNTransport,  # noqa: F401
                                            DisaggScheduler,
@@ -13,7 +15,12 @@ from triton_dist_tpu.models.disagg import (DCNTransport,  # noqa: F401
                                            KVHandoff, PrefillWorker,
                                            PrefillWorkerDied)
 from triton_dist_tpu.models.engine import Engine  # noqa: F401
-from triton_dist_tpu.models.kv_cache import KVCache, PagedSlotCache  # noqa: F401
+from triton_dist_tpu.models.kv_cache import (HybridSlotCache,  # noqa: F401
+                                             KVCache, LatentSlotCache,
+                                             PagedSlotCache)
+from triton_dist_tpu.models.phi4flash import (Phi4Flash,  # noqa: F401
+                                              Phi4FlashConfig,
+                                              tiny_phi4flash)
 from triton_dist_tpu.models.prefix_cache import (PoolExhausted,  # noqa: F401
                                                  PrefixCache)
 from triton_dist_tpu.models.scheduler import (ContinuousScheduler,  # noqa: F401

@@ -66,11 +66,14 @@ class Engine:
         if backend not in BACKENDS:
             raise ValueError(f"unknown backend {backend!r}; this engine "
                              f"serves {BACKENDS}")
-        self.moe_family = bool(getattr(model.config, "is_moe", False))
+        self.moe_family = _is_moe(model)
         # what the model says of itself (models/utils.ServingTraits):
         # the ONE place the engine learns the pool's heads and
         # whether slots hold state beside pages
         self.traits = model.serving_traits()
+        # what a slot of this model has that the engine's own paged
+        # programs cannot move (None for K and V pages alone)
+        self._own = self.traits.slot_state or self.traits.own_pool
         # a slot of such a model is pages PLUS state that pages cannot
         # express: what moves or rebuilds pages alone is refused, here
         # and wherever an option is taken, through refuse_slot_state
@@ -78,12 +81,12 @@ class Engine:
             self.refuse_slot_state(
                 f"backend={backend!r}",
                 f"TP comm-kernel projections over "
-                f"{self.traits.slot_state}; its layers run "
+                f"{self._own}; its layers run "
                 f"single-chip on 'flash' or the 'xla' oracle")
         if kv_dtype is not None:
             self.refuse_slot_state(
                 f"kv_dtype={jnp.dtype(kv_dtype)}",
-                f"int8 pool beside {self.traits.slot_state}")
+                f"int8 pool for {self._own}")
         # SEQUENCE-PARALLEL serving (long-context — the sp-sharded
         # paged pool, kv_cache.PagedSlotCache SP SHARDING): capability
         # gates live HERE, at construction, naming what is missing —
@@ -256,19 +259,26 @@ class Engine:
 
     def refuse_slot_state(self, option: str, capability: str) -> None:
         """Refuse `option` for a model whose slots hold state beside
-        their pages (ServingTraits.slot_state): the option moves,
-        shares or rebuilds a slot from pages alone, and `capability`
-        names what would have to exist for it. A no-op for a model
-        whose whole context is its pages."""
+        their pages (ServingTraits.slot_state) or whose pool only
+        its own programs read (ServingTraits.own_pool): the option
+        moves, shares or rebuilds a slot through the engine's K/V page
+        programs, and `capability` names what would have to exist for
+        it. A no-op for a model whose whole context is K and V pages."""
         if self.traits.slot_state:
             raise ValueError(
                 f"{option}: {type(self.model).__name__} slots hold "
                 f"{self.traits.slot_state} beside their pages (missing "
                 f"capability: {capability})")
+        if self.traits.own_pool:
+            raise ValueError(
+                f"{option}: {type(self.model).__name__} keeps "
+                f"{self.traits.own_pool}, which the engine's K/V page "
+                f"programs do not move (missing capability: "
+                f"{capability})")
 
     def _contiguous_only(self, what: str) -> None:
         self.refuse_slot_state(
-            what, f"contiguous cache over {self.traits.slot_state}; "
+            what, f"contiguous cache over {self._own}; "
                   f"serve with ContinuousScheduler(paged=True) / "
                   f"TokenServer(paged=True)")
 
@@ -733,8 +743,8 @@ class Engine:
         if not hasattr(self.model, "forward_tokens_slots_paged"):
             raise ValueError(
                 f"{type(self.model).__name__} has no paged slot decode "
-                "path (DenseLLM, Qwen3MoE and Phi4Flash carry the "
-                "serving surface)")
+                "path (DenseLLM, Qwen3MoE, Phi4Flash and DeepSeekV3 "
+                "carry the serving surface)")
         if for_ticks:
             # a pool that will DRIVE decode/verify/mixed ticks feeds
             # its batch rows to the row-sharded EP dispatch; staging
@@ -813,14 +823,14 @@ class Engine:
         P = -(-s // pad_to) * pad_to
         padded = jnp.zeros((1, P), jnp.int32).at[0, :s].set(ids[m:])
         self._c_prefills.inc()
-        if self.traits.slot_state:
-            # the model's own admission program: pages, rings and
-            # planes of the slot in one pass, no scratch, no prefix
+        if self._own:
+            # the model's own admission program: pages (and rings and
+            # planes) of the slot in one pass, no scratch, no prefix
             if m:
                 raise ValueError(
-                    f"kv_start={m}: a cached prefix holds no "
-                    f"{self.traits.slot_state} (missing capability: "
-                    f"prefix reuse over {self.traits.slot_state})")
+                    f"kv_start={m}: {type(self.model).__name__} admits "
+                    f"a whole prompt through its own program (missing "
+                    f"capability: prefix reuse over {self._own})")
             logits, pcache = self._state_admit(
                 self.model, padded, pcache, jnp.asarray(rows, jnp.int32),
                 jnp.int32(slot), jnp.int32(n))
@@ -1126,7 +1136,7 @@ def _is_moe(model) -> bool:
     config is static pytree metadata, so this never retraces a given
     model inconsistently."""
     return bool(getattr(model.config, "is_moe", False)) \
-        and hasattr(model, "forward_tokens_slots")
+        and hasattr(model, "_zero_load")
 
 
 def _slot_scan_decode_fn(backend, model, logits0, cache, pos, active,

@@ -329,6 +329,65 @@ def uniform_paged_cache(model, batch: int, max_seq: int, *, page: int,
         dtype=dtype or cfg.jax_dtype, sp_axis=sp_axis)
 
 
+# lanes a latent row is padded to: the chip's HBM tiles are 128 lanes
+# wide, so a 576-wide row takes 640 there whether the plane says so or
+# not; saying so keeps every copy, slice and dot of the walk aligned
+LATENT_LANES = 128
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class LatentSlotCache(PagedSlotCache):
+    """The paged pool of a latent-attention model (layers/mla_attn.py;
+    models/deepseek.py): ONE plane a layer, `pages_k[l]` [NP, 1, page,
+    width], a position's row [c_kv (rank) | k_pe (rope) | zero pad] in
+    the layer's compute dtype, and NO V plane (`pages_v` is empty): the
+    decode walk reads its values out of the same rows
+    (kernels/paged_kv.py, `v_cols`). Pages, the table, the allocator,
+    retire-to-trash and `clear_slot` are PagedSlotCache's own; a slot's
+    whole context is its pages.
+
+    `row_bytes` is what a position costs as published (rank + rope
+    values), `page_copy_bytes` what the walk's one copy a page moves
+    (the padded row)."""
+
+    rank: int = dataclasses.field(default=0, metadata=dict(static=True))
+    rope: int = dataclasses.field(default=0, metadata=dict(static=True))
+    # what the same positions would take as expanded per-head K and V
+    # (heads x (qk + v) values): `cache_uniform_bytes`
+    expanded_row_values: int = dataclasses.field(
+        default=0, metadata=dict(static=True))
+
+    @staticmethod
+    def create_latent(num_layers: int, batch: int, max_seq: int, *,
+                      rank: int, rope: int, expanded_row_values: int,
+                      page: int, num_pages: int, mesh: Mesh,
+                      dtype=jnp.bfloat16) -> "LatentSlotCache":
+        width = -(-(rank + rope) // LATENT_LANES) * LATENT_LANES
+        maxp = -(-max_seq // page)
+        rep = NamedSharding(mesh, P())
+        planes = tuple(
+            jax.device_put(jnp.zeros((num_pages, 1, page, width), dtype),
+                           rep) for _ in range(num_layers))
+        table = jax.device_put(jnp.zeros((batch, maxp), jnp.int32), rep)
+        return LatentSlotCache(pages_k=planes, pages_v=(), table=table,
+                               rank=rank, rope=rope,
+                               expanded_row_values=expanded_row_values)
+
+    @property
+    def row_bytes(self) -> int:
+        return (self.rank + self.rope) * self.pages_k[0].dtype.itemsize
+
+    def slot_bytes(self) -> dict:
+        """Bytes a mapped page holds over all layers, as published
+        (kind "latent"), beside what expanded K and V would."""
+        L, item = len(self.pages_k), self.pages_k[0].dtype.itemsize
+        return {"page_kind": "latent",
+                "page": L * self.page * self.row_bytes,
+                "uniform_page": L * self.page * self.expanded_row_values
+                * item}
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class HybridSlotCache(PagedSlotCache):

@@ -497,13 +497,24 @@ class DecodeSlots:
             # have left an unlanded stats entry — start aligned
             engine._moe_pending.clear()
             reg = self.tele.registry
-            E = engine.model.config.num_experts
+            mcfg = engine.model.config
+            E = mcfg.num_experts
             self._moe_tokens_cum = np.zeros((E,), np.int64)
+            # a model that holds a stated share of a wider expert set
+            # names its experts by their published numbers
             self._c_expert = [
                 reg.counter("expert_tokens",
                             "routed entries per expert (compute load)",
                             labels={"expert": str(e)})
-                for e in range(E)]
+                for e in getattr(mcfg, "expert_ids", range(E))]
+            self._c_pairs_routed = reg.counter(
+                "moe_pairs_routed",
+                "(token, expert) pairs the router chose, over every "
+                "expert it ranks, all layers")
+            self._c_pairs_held = reg.counter(
+                "moe_pairs_held",
+                "those of moe_pairs_routed that landed on experts this "
+                "server holds (all of them unless it holds a share)")
             self._c_moe_drops = reg.counter(
                 "moe_capacity_drops",
                 "routed entries lost to expert capacity (0 under "
@@ -815,7 +826,14 @@ class DecodeSlots:
         serving metrics (driver thread only — the same thread that
         lands ticks)."""
         load = np.asarray(load, np.int64)
-        counts, dropped = load[:-1], int(load[-1])
+        E = len(self._c_expert)
+        counts, dropped = load[:E], int(load[E])
+        # [.., pairs routed, pairs held] where the model holds a share;
+        # a model that holds every expert routes what it holds
+        routed, held = (load[E + 1:E + 3] if len(load) > E + 1
+                        else (counts.sum() + dropped,) * 2)
+        self._c_pairs_routed.inc(int(routed))
+        self._c_pairs_held.inc(int(held))
         for e in np.nonzero(counts)[0]:
             self._c_expert[int(e)].inc(int(counts[e]))
         if dropped:
@@ -1526,11 +1544,16 @@ class PagedDecodeSlots(DecodeSlots):
         self._slot_bytes = (self.cache.slot_bytes()
                             if hasattr(self.cache, "slot_bytes") else None)
         if self._slot_bytes:
+            sb = self._slot_bytes
+            # a mapped page's bytes go under the cache's own name for
+            # them; what a slot holds beside its pages, by kind
+            self._page_kind = sb.get("page_kind", "pages")
             self._g_cache_bytes = {
                 kind: freg.gauge(
                     "cache_bytes", "bytes the live slots hold, by kind "
                     "of state", labels={"kind": kind})
-                for kind in ("pages", "window", "state")}
+                for kind in (self._page_kind,) + tuple(
+                    k for k in ("window", "state") if k in sb)}
             self._g_uniform_bytes = freg.gauge(
                 "cache_uniform_bytes",
                 "bytes a uniform cache (every attention layer its own "
@@ -1591,13 +1614,14 @@ class PagedDecodeSlots(DecodeSlots):
         if self._slot_bytes:
             sb, live = self._slot_bytes, self.occupied
             pages = sum(len(self._pages[b]) for b in live)
-            held = {"pages": pages * sb["page"],
-                    "window": len(live) * sb["window"],
-                    "state": len(live) * sb["state"]}
+            held = {self._page_kind: pages * sb["page"]}
+            for kind in ("window", "state"):
+                if kind in sb:
+                    held[kind] = len(live) * sb[kind]
             for kind, v in held.items():
                 self._g_cache_bytes[kind].set(v)
             self._g_uniform_bytes.set(
-                pages * sb["uniform_page"] + held["state"])
+                pages * sb["uniform_page"] + held.get("state", 0))
         return out
 
     def validate_admission(self, req: Request, tokens: np.ndarray

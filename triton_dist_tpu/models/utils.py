@@ -29,9 +29,17 @@ class ServingTraits:
     pages and that pages cannot express (recurrent state, a window
     ring) — None for a model whose whole context is its pages. A model
     with slot_state admits through its own `admit_slot_paged`, and
-    every option that rebuilds a slot from pages alone is refused."""
+    every option that rebuilds a slot from pages alone is refused;
+    own_pool: the name of a paged pool whose layout only the model's
+    own programs read and write (a latent pool: one plane, no V) —
+    None for K and V planes, which the Engine's admission, copy-on-
+    write, gather and restore programs move. A slot of such a model IS
+    its pages, but until those programs learn the layout it admits
+    through its own `admit_slot_paged` too, and the same options are
+    refused, by the pool's name."""
     kv_heads: int
     slot_state: str | None = None
+    own_pool: str | None = None
 
 
 def place_replicated(tree, mesh):
