@@ -38,9 +38,16 @@ _STATS_KEYS = ("device_wait_s_by_kind", "host_phase_s", "tokens_emitted",
                "ticks_dispatched_ahead", "serve_loop_iterations",
                "prompt_tokens", "prefill_tokens_skipped", "admissions",
                "hits", "preemptions")
-# the wrapped hooks a dispatch-ahead serve loop enters while it serves
+# the wrapped hooks a dispatch-ahead serve loop enters while it serves.
+# `_sock.accept` is the ACCEPTOR thread's call since PR 37: it reads
+# `srv._sock` anew every pass, so it picks up the proxy that annotate()
+# puts there on a running server (if it did not, `bench:accept_wait`
+# would leave `breakdown.idle_gaps` in silence). NOT entered since then:
+# `_stop.wait` (the `bench:idle_sleep` mark): an idle loop sleeps on its
+# inbox's wake event; the name stays, `stop()` and `kill()` set the
+# event. Nor `sched.slots.step_chunk` under dispatch-ahead (PR 32).
 _ENTERED = ("sched.poll", "sched._admit", "sched.slots._fetch", "_emit",
-            "_probe_disconnects")
+            "_probe_disconnects", "_sock.accept")
 
 
 def _resolve(root, path):

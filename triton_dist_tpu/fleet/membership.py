@@ -91,8 +91,9 @@ class InprocReplica:
         """Abrupt death: every live client socket is slammed (their
         streams end at EOF with NO done message — exactly what a
         crashed process looks like from the wire) and the serve loop
-        stops. The listener closes via serve_forever's own teardown,
-        so probes start failing within one accept timeout."""
+        stops. The listener closes via serve_forever's own teardown
+        (which the loop reaches within one idle sleep and which also
+        ends its acceptor thread), so probes are refused from then on."""
         srv = self.server
         srv._stop.set()
         for cs in list(srv._conns.values()):

@@ -97,7 +97,7 @@ inter-token floor. Mechanics:
   poll). Two registry counters say how the mechanism fares:
   ``ticks_dispatched_ahead`` (a spec=0 tick dispatched with the
   previous tick's retires, the server's wire writes and its next
-  accept() still to run under it; over the engine's dispatches of
+  intake still to run under it; over the engine's dispatches of
   the same ticks, the share that engaged) and ``pipeline_drains``
   (every collapse: preemption, cancel, an in-flight deadline, a
   PoolExhausted admission, a grammar tick).
@@ -2134,7 +2134,7 @@ class ContinuousScheduler:
             "host_ms_per_poll",
             "EMA of the wall time from one tick's dispatch to the next "
             "minus the device wait between them: scheduling, drafting, "
-            "admission, the serve loop's accept() wait and socket "
+            "admission, the serve loop's intake and socket "
             "writes, and any compile an admission meets")
         # TP topology + live throughput (multi-chip serving — ROADMAP
         # open item 1): ONE scheduler drives the whole TP mesh, so
@@ -2164,7 +2164,7 @@ class ContinuousScheduler:
         self._c_ahead = reg.counter(
             "ticks_dispatched_ahead",
             "ticks dispatched before the previous tick's retires, the "
-            "wire writes and the next accept() (overlap, spec=0)")
+            "wire writes and the next intake (overlap, spec=0)")
         self._c_drains = reg.counter(
             "pipeline_drains",
             "overlap pipeline collapses: preemption, cancel, an "

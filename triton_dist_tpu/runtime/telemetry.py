@@ -488,7 +488,12 @@ class _Req:
 # The host path's phases: name -> the name of its annotation in a
 # profiler trace. `serve:` phases are TokenServer.serve_forever's (the
 # root `loop` and its children), `sched:` the scheduler's (`sched_poll`
-# is one ContinuousScheduler.poll() and the parent of the rest). The
+# is one ContinuousScheduler.poll() and the parent of the rest).
+# `accept_wait` is the loop's INTAKE of the requests that its reader
+# threads parsed since the last iteration (it waits for nothing; the
+# acceptor thread that blocks in accept() opens no phase, or the phases
+# would no longer partition the serve thread's wall time); `idle_sleep`
+# is the loop's only wait of its own, on the inbox's wake event. The
 # set is FIXED: Telemetry seeds a total per phase at construction, so
 # cross-thread stats() readers never see the dict resize, and a phase
 # not listed here is a KeyError at its first use.

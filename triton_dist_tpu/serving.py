@@ -70,11 +70,20 @@ not, every iteration of serve_forever is a `serve:loop` phase over
 `serve:accept_wait` / `poll` / `wire_write` / `probe` / `idle_sleep`
 (Telemetry.phase): self-time totals in stats()["host_phase_s"], and
 annotations that a jax.profiler session attached to the live server
-shows beside the device's operations.
+shows beside the device's operations. `accept_wait` is the loop's
+INTAKE: it waits for nothing there. An acceptor thread owns the
+listening socket's accept(), a reader thread per connection parses the
+request line and puts it into the loop's inbox, and the loop takes in
+what arrived since its last iteration (rid, `_conns` entry, submit)
+before it polls; neither thread opens a phase, so the phases still
+partition the serve thread's wall time. A loop with nothing to do
+sleeps on the inbox's wake event (`serve_idle_wakeups` counts the
+sleeps a request cut short), never on a timer with a chunk in flight.
 """
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import random
@@ -147,9 +156,10 @@ class TokenServer:
     rows, one jitted slot scan per chunk. A freed slot is refilled
     from the connection queue between chunks while the other clients'
     streams keep flowing. Still single-threaded ON THE MODEL: socket
-    threads only parse requests and write replies; every jax dispatch
-    happens on the serve_forever thread (concurrency is batching, not
-    model threads — the discipline the old one-request loop had, kept)."""
+    threads only accept, parse requests and write refusals; every jax
+    dispatch, every rid and every `_conns` entry is the serve_forever
+    thread's (concurrency is batching, not model threads — the
+    discipline the old one-request loop had, kept)."""
 
     def __init__(self, engine, tokenizer, *, batch: int,
                  host: str = "127.0.0.1", port: int = 0,
@@ -209,7 +219,7 @@ class TokenServer:
         module docstring): the driver dispatches the next device tick
         before it reads back the previous one, so this server's host
         work a poll — admissions, drafting, the socket writes, the
-        disconnect probes and the next accept() wait — runs while the
+        disconnect probes and the inbox's intake — runs while the
         device computes instead of serializing with it. Token streams
         are bitwise those of the synchronous loop; the watchdog and
         deadline checks sit at landed-tick boundaries (a dispatch
@@ -324,14 +334,29 @@ class TokenServer:
         self.host, self.port = self._sock.getsockname()
         self._stop = threading.Event()
         # iterations of serve_forever's loop: over them, the loop's
-        # time outside accept() (host_phase_s of every phase but
-        # accept_wait and idle_sleep) is the mean gap for which a
-        # connecting client sits unseen in the kernel's backlog
-        self._c_iterations = self.sched.tele.registry.counter(
+        # time outside its intake (host_phase_s of every phase but
+        # accept_wait and idle_sleep) is the mean gap between two
+        # intakes, which a parsed request sits out in the inbox
+        reg = self.sched.tele.registry
+        self._c_iterations = reg.counter(
             "serve_loop_iterations", "iterations of the serve loop")
-        self._next_rid = 0
-        self._conns: dict = {}          # rid -> _ClientStream
-        self._lock = threading.Lock()   # guards scheduler submit + _conns
+        self._c_idle_wakeups = reg.counter(
+            "serve_idle_wakeups",
+            "sleeps of the serve loop that a request in its inbox cut "
+            "short (of host_phase_n{phase=idle_sleep} sleeps in all)")
+        # reader threads -> serve loop: parsed requests in arrival
+        # order. _wake is set by every put and by stop(); _inbox_lock
+        # only orders a put against the loop's teardown (_closed), the
+        # loop itself pops without it
+        self._inbox: collections.deque = collections.deque()
+        self._inbox_lock = threading.Lock()
+        self._closed = False
+        self._wake = threading.Event()
+        self._accept_thread: Optional[threading.Thread] = None
+        self._next_rid = 0              # serve thread only
+        self._conns: dict = {}          # rid -> _ClientStream, likewise
+        # guards poll() against cross-thread stats() and cancel
+        self._lock = threading.Lock()
         # optional Prometheus /metrics listener (daemon thread; dies
         # with stop()). metrics_port=0 binds an ephemeral port.
         self.metrics_port: Optional[int] = None
@@ -348,9 +373,25 @@ class TokenServer:
             threading.Thread(target=self._serve_metrics,
                              daemon=True).start()
 
+    class _Arrival:
+        """A parsed request on its way from its reader thread to the
+        serve loop, and the loop's verdict on its way back: `accepted`
+        is submit()'s answer (None where the loop ended first), `hint`
+        the retry_after_ms of a refusal."""
+
+        __slots__ = ("req", "conn", "fh", "accepted", "hint", "ready")
+
+        def __init__(self, req, conn, fh):
+            self.req = req
+            self.conn = conn
+            self.fh = fh
+            self.accepted: Optional[bool] = None
+            self.hint = 0
+            self.ready = threading.Event()
+
     class _ClientStream:
         """Per-connection state: the socket + reply file handle + token
-        count. Owned by the model loop after admission; the reader
+        count. Owned by the model loop from the intake on; the reader
         thread only hands it over."""
 
         def __init__(self, conn, fh):
@@ -397,12 +438,14 @@ class TokenServer:
                 accepted_at: Optional[float] = None) -> None:
         """Connection thread: parse ONE request line (capped at
         _MAX_LINE bytes — a garbage firehose cannot balloon this
-        thread), enqueue it for the model loop, leave the socket open
-        for streaming replies. Every refusal — malformed JSON,
-        over-capacity prompt, oversized line, full queue — is answered
-        with a structured line before the close. accepted_at: the
-        monotonic stamp of accept()'s return, the first event of the
-        request's traced lifecycle."""
+        thread), put it into the model loop's inbox, wait for the
+        loop's verdict, leave the socket open for streaming replies.
+        Every refusal — malformed JSON, over-capacity prompt, oversized
+        line, full queue — is answered with a structured line before
+        the close, and from HERE (_refuse may block for a second; the
+        loop only says yes or no). accepted_at: the monotonic stamp of
+        accept()'s return, the first event of the request's traced
+        lifecycle."""
         import sys
         from triton_dist_tpu.models.scheduler import Request
         try:
@@ -519,33 +562,30 @@ class TokenServer:
                              f"capacity {slot_cap - 1}"})
                 return
             gen_len = max(1, min(gen_len, cap))
-            with self._lock:
-                rid = self._next_rid
-                self._next_rid += 1
-                accepted = self.sched.submit(Request(
-                    rid=rid, ids=np.asarray(ids, np.int32),
-                    gen_len=gen_len, seed=seed, n=n, grammar=gspec,
-                    deadline_ms=deadline_ms, slo=slo,
-                    accepted_at=accepted_at))
-                if accepted:
-                    cs = self._ClientStream(conn, f)
-                    cs.n_left = n
-                    if n > 1:
-                        # the scheduler fans rid out into kid rids
-                        # (rid, 0)..(rid, n-1); every fork streams to
-                        # this ONE connection and the done message
-                        # fans back in once all n finish
-                        for k in range(n):
-                            self._conns[(rid, k)] = cs
-                    else:
-                        self._conns[rid] = cs
+            # the loop gives the rid: the inbox's order is the order of
+            # the waiting line
+            arrival = self._Arrival(Request(
+                rid=None, ids=np.asarray(ids, np.int32),
+                gen_len=gen_len, seed=seed, n=n, grammar=gspec,
+                deadline_ms=deadline_ms, slo=slo,
+                accepted_at=accepted_at), conn, f)
+            with self._inbox_lock:
+                if not self._closed:
+                    self._inbox.append(arrival)
+                    self._wake.set()
                 else:
-                    hint = self._retry_after_ms()
-            if not accepted:
+                    arrival.ready.set()
+            arrival.ready.wait()
+            if arrival.accepted is None:
+                self._refuse(conn, f, {
+                    "done": True, "n_tokens": 0,
+                    "error": "server stopped before the request "
+                             "was taken in"})
+            elif not arrival.accepted:
                 # backpressure, not an unbounded queue: tell the client
                 # WHEN to come back instead of buffering it forever
                 self._refuse(conn, f, {"busy": True,
-                                       "retry_after_ms": hint})
+                                       "retry_after_ms": arrival.hint})
         except OSError as e:
             print(f"[TokenServer] bad request: {type(e).__name__}: {e}",
                   file=sys.stderr)
@@ -737,19 +777,82 @@ class TokenServer:
                 pass
         return True
 
+    def _acceptor(self) -> None:
+        """The thread that owns accept(): it blocks there (a time-out
+        of its own, so `_stop` is seen) and hands every connection,
+        stamped, to a reader thread (daemonic and short-lived: one
+        request line each, no tracking needed). `self._sock` is read
+        anew every pass: a caller may wrap the listener of a RUNNING
+        server (benchmark/systems/token_server.py::annotate). It opens
+        no phase: `host_phase_s` partitions the serve thread's time.
+        Ends with the loop, whose teardown closes the listener."""
+        while not self._stop.is_set():
+            try:
+                conn, _ = self._sock.accept()
+            except socket.timeout:
+                continue
+            except OSError:
+                return
+            threading.Thread(target=self._reader,
+                             args=(conn, time.monotonic()),
+                             daemon=True).start()
+
+    def _intake(self) -> int:
+        """Serve thread: take in what the readers parsed since the
+        last intake, in arrival order: a rid, the scheduler's verdict,
+        and for an accepted request its `_conns` entry, which so exists
+        before any poll can emit for it. Returns how many it took."""
+        self._wake.clear()      # before the drain: a put during it
+        taken = 0               # is either drained or wakes the loop
+        while self._inbox:
+            arrival = self._inbox.popleft()
+            req = arrival.req
+            req.rid = rid = self._next_rid
+            self._next_rid += 1
+            arrival.accepted = self.sched.submit(req)
+            if arrival.accepted:
+                cs = self._ClientStream(arrival.conn, arrival.fh)
+                cs.n_left = req.n
+                if req.n > 1:
+                    # the scheduler fans rid out into kid rids
+                    # (rid, 0)..(rid, n-1); every fork streams to
+                    # this ONE connection and the done message
+                    # fans back in once all n finish
+                    for k in range(req.n):
+                        self._conns[(rid, k)] = cs
+                else:
+                    self._conns[rid] = cs
+            else:
+                arrival.hint = self._retry_after_ms()
+            arrival.ready.set()
+            taken += 1
+        return taken
+
     def serve_forever(self, max_requests: Optional[int] = None) -> None:
-        """Model loop: accept connections (handing each to a reader
-        thread), then run the scheduler — admit, one chunk, stream each
-        slot's tokens to its client. max_requests counts COMPLETED
-        requests (so a test can serve N concurrent clients and exit).
-        A watchdogged chunk that hangs (watchdog_s) ends the loop with
-        a structured HANG error to every live client — the process is
-        poisoned (runtime/stress.py::watchdog contract), and a visible
-        verdict beats a silent freeze."""
+        """Model loop: take in the requests that arrived (an acceptor
+        thread hands each connection to a reader thread, which parses
+        it into the inbox), then run the scheduler — admit, one chunk,
+        stream each slot's tokens to its client. The loop never waits
+        on a timer for connections: with a chunk in flight it goes
+        from the intake straight to the poll, whose `land` waits for
+        the device; with nothing in flight, or after an iteration that
+        took in, landed, emitted and finished nothing (a disaggregated
+        prefill still out, a queued request that cannot be admitted
+        yet), it sleeps on the inbox's wake event, bounded so `_stop`
+        is seen. max_requests counts COMPLETED requests (so a test can
+        serve N concurrent clients and exit). A watchdogged chunk that
+        hangs (watchdog_s) ends the loop with a structured HANG error
+        to every live client — the process is poisoned
+        (runtime/stress.py::watchdog contract), and a visible verdict
+        beats a silent freeze."""
         from triton_dist_tpu.runtime.stress import HangError
         done_count = 0
         tele = self.sched.tele
-        self._sock.settimeout(0.02)
+        slots = self.sched.slots
+        self._sock.settimeout(0.25)
+        self._accept_thread = threading.Thread(
+            target=self._acceptor, daemon=True, name="serve-acceptor")
+        self._accept_thread.start()
         try:
             while not self._stop.is_set():
                 self._c_iterations.inc()
@@ -757,21 +860,10 @@ class TokenServer:
                 # names is its own self time, so the phases' totals
                 # partition this thread's wall time
                 with tele.phase("loop"):
-                    # drain the accept queue without blocking the
-                    # decode loop (reader threads are daemonic and
-                    # short-lived: one request line each, no tracking
-                    # needed)
                     with tele.phase("accept_wait"):
-                        while True:
-                            try:
-                                conn, _ = self._sock.accept()
-                            except socket.timeout:
-                                break
-                            threading.Thread(
-                                target=self._reader,
-                                args=(conn, time.monotonic()),
-                                daemon=True).start()
+                        took = self._intake()
                     t0 = time.monotonic()
+                    waited = slots.device_wait_s
                     try:
                         with tele.phase("poll"), self._lock:
                             out, finished = self.sched.poll()
@@ -803,13 +895,34 @@ class TokenServer:
                     if max_requests is not None \
                             and done_count >= max_requests:
                         break
-                    if self.sched.idle:
-                        # nothing in flight: sleep on accept instead
-                        # of spinning the poll loop
+                    idle = self.sched.idle
+                    moved = took or out or finished or dead \
+                        or slots.device_wait_s != waited
+                    if (idle or not moved) and not self._inbox:
+                        # nothing in flight, or nothing that this
+                        # iteration could move (the poll waited for
+                        # no device result either): sleep until a
+                        # request arrives instead of spinning
                         with tele.phase("idle_sleep"):
-                            self._stop.wait(0.05)
+                            self._wake.wait(0.05 if idle else 0.02)
+                            if self._inbox:
+                                self._c_idle_wakeups.inc()
         finally:
-            self._sock.close()
+            # the listener first, so that probes and new clients are
+            # refused at once; shutdown() wakes the acceptor's accept()
+            for end in (lambda: self._sock.shutdown(socket.SHUT_RDWR),
+                        self._sock.close):
+                try:
+                    end()
+                except OSError:
+                    pass
+            self._accept_thread.join(timeout=2.0)
+            with self._inbox_lock:
+                self._closed = True
+                unserved = list(self._inbox)
+                self._inbox.clear()
+            for arrival in unserved:    # its reader tells the client
+                arrival.ready.set()
             for rid in list(self._conns):
                 self._finish(rid)
             # TDTPU_TRACE contract: dump the poll-loop timeline +
@@ -824,6 +937,7 @@ class TokenServer:
 
     def stop(self) -> None:
         self._stop.set()
+        self._wake.set()        # a sleeping loop sees _stop now
         # disaggregated mode: stop the prefill worker threads too
         close = getattr(self.sched, "close", None)
         if close is not None:
