@@ -204,3 +204,65 @@ def test_share_adapter_serves_and_counts(served_share):
     assert routed > 0 and routed % (4 * 4 * 2) == 0  # slots x k x layers
     assert 0 <= after["moe_pairs_held"] <= after["moe_pairs_routed"]
     assert isinstance(s.lifecycle(), dict) and s.lifecycle()
+
+
+# ----------------------------------------------------------------------
+# the keye_vl2 family's adapter (benchmark/systems/keye_server.py) on a
+# tiny configuration of that family: what the `.longdoc` metric files
+# (benchmark/metrics/*.longdoc.json) take from stats() by name
+# ----------------------------------------------------------------------
+
+_SPARSE_KEYS = ("sa_positions_in_context", "sa_positions_attended",
+                "cache_bytes{kind=pages}", "cache_bytes{kind=index}",
+                "cache_uniform_bytes", "moe_pairs_routed",
+                "moe_pairs_held", "kv_page_copy_bytes",
+                "moe_experts_touched", "moe_experts_offered")
+
+
+@pytest.fixture(scope="module")
+def served_sparse():
+    from benchmark.systems.keye_server import Served, request
+    from triton_dist_tpu import finalize_distributed
+    cache_dir = jax.config.jax_compilation_cache_dir
+    with open(os.path.join(_REPO, "benchmark", "testdata",
+                           "tiny-keye-vl2.json")) as f:
+        cfg = json.load(f)
+    s = Served(cfg, 2**31 + 39, jax.devices()[:1], trace=True)
+    try:
+        before = s.stats()
+        # 24 + 8 positions: past the tiny indexer's topk of 16
+        msgs = list(request(s.host, s.port, list(range(3, 27)), 8, 300.0))
+        assert msgs[-1].get("done") and not msgs[-1].get("error"), msgs[-1]
+        yield s, before, s.stats()
+    finally:
+        s.stop()
+        assert not s.errors, s.errors
+        finalize_distributed()
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+
+
+@pytest.mark.parametrize("key", _STATS_KEYS + _SPARSE_KEYS)
+def test_sparse_adapter_stats_carry_the_key_a_reader_takes(served_sparse,
+                                                           key):
+    _, _, after = served_sparse
+    assert key in after, key
+
+
+def test_sparse_adapter_counts_what_the_metric_files_divide(served_sparse):
+    """`sa.attended_share_pct.longdoc` and `moe.held_pair_share_pct
+    .longdoc` are ratios of these counters' deltas: both numerators
+    move, and stay under their denominators."""
+    s, before, after = served_sparse
+    assert s.pool_pages() > 0 and (s.batch, s.chunk) == (4, 4)
+    d = lambda k: after[k] - before.get(k, 0)  # noqa: E731
+    assert 0 < d("sa_positions_attended") < d("sa_positions_in_context")
+    assert 0 <= d("moe_pairs_held") < d("moe_pairs_routed")
+    assert d("moe_pairs_routed") % (4 * 4 * 2) == 0  # slots x k x layers
+    assert 0 < d("moe_experts_touched") <= d("moe_experts_offered")
+    for name in os.listdir(os.path.join(_REPO, "benchmark", "metrics")):
+        if not name.endswith(".longdoc.json"):
+            continue
+        with open(os.path.join(_REPO, "benchmark", "metrics", name)) as f:
+            args = json.load(f).get("args", {})
+        for k in ("numerator", "denominator"):
+            assert args.get(k, "tokens_emitted") in after, (name, k)

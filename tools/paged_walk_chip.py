@@ -35,6 +35,19 @@ Three phases, each one JSON line per reading, `{"ok": true, ...}` last:
   decode tick's 1,024 pair rows (~64 of them on held experts) and a
   1,024-token admission's 8,192 (~512), against a plain einsum on the
   rows that landed, then ms a call over 8 chained calls.
+- `sa` (only when asked for): learned sparse attention at Keye-VL-2.0's
+  published widths and the cell's shapes (32 slots, contexts 16,384 to
+  20,400 of max_seq 20,480, 2,048 selected): the decode step's three
+  pieces (the index walk of the pool's index plane, the selection, the
+  paged walk under it: K and V in one plane of 8 head rows) checked
+  against float32 with a short and an empty slot in the batch, then ms
+  a call each over 6 chained calls (a step's six layers) beside the
+  least time of their bytes; and one layer's 16,384-token admission
+  (`SA_Attn.prefill`: index scores, selection and attention, 256 query
+  rows at a time), ms a call. Beside them, what the layout and the
+  selection were chosen against: the same walk over TWO planes (K and
+  V pages apart, two copies a page) under the same set, and the same
+  set by `jax.lax.top_k` and a scatter to the mask.
 """
 
 from __future__ import annotations
@@ -294,11 +307,241 @@ def gmm(rehearse: bool) -> None:
                  1e3 * (wgu.nbytes + wd.nbytes) / 819e9, 4))
 
 
+def sa(rehearse: bool) -> None:
+    """Learned sparse attention's kernels alone (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    from triton_dist_tpu.kernels.paged_kv import (flash_decode_paged,
+                                                  gather_pages,
+                                                  index_scores_paged)
+    from triton_dist_tpu.kernels.sparse_attn import (index_scores_ref,
+                                                     select_topk)
+    from triton_dist_tpu.layers.sparse_attn import SA_Attn
+    B, Hq, Hkv, Hi, di, topk = (4, 8, 2, 4, 16, 32) if rehearse else (
+        32, 32, 4, 16, 64, 2048)
+    max_seq, lo, hi = (256, 100, 250) if rehearse else (20480, 16384, 20400)
+    P_ = 128 if rehearse else 16384
+    dt = jnp.float32 if rehearse else jnp.bfloat16
+    maxp = max_seq // PAGE
+    NP = B * maxp + 1
+    rng = np.random.RandomState(39)
+    ks = jax.random.split(jax.random.PRNGKey(39), 8)
+    kv = (jax.random.normal(ks[0], (NP, 2 * Hkv, PAGE, D), jnp.float32)
+          * 0.5).astype(dt)
+    ix = jnp.pad((jax.random.normal(ks[1], (NP, 1, PAGE, di), jnp.float32)
+                  ).astype(dt), ((0, 0),) * 3 + ((0, 128 - di),))
+    table = jnp.asarray(1 + rng.permutation(NP - 1).reshape(B, maxp),
+                        jnp.int32)
+    lens = rng.randint(lo, hi + 1, size=B)
+    lens[1], lens[2] = topk // 2, 0         # nothing to choose; empty
+    lens = jnp.asarray(lens, jnp.int32)
+    q = (jax.random.normal(ks[2], (B, 1, Hq, D), jnp.float32) * 0.5
+         ).astype(dt)
+    qi = jnp.pad(jax.random.normal(ks[3], (B, Hi, di), jnp.float32
+                                   ).astype(dt),
+                 ((0, 0), (0, 0), (0, 128 - di)))
+    w = jax.random.normal(ks[4], (B, Hi), jnp.float32)
+    scale = di ** -0.5 * Hi ** -0.5
+
+    score = jax.jit(lambda qi, w, ix, t, n: index_scores_paged(
+        qi, w, ix, t, n, scale=scale))
+    choose = jax.jit(lambda sc, n: select_topk(
+        sc, jnp.arange(sc.shape[1])[None] < n[:, None], topk))
+    walk = jax.jit(lambda q, kv, t, n, sel: flash_decode_paged(
+        q, kv, None, t, None, kv_lens=n, fused=True, sel=sel))
+    sc = score(qi, w, ix, table, lens)
+    sel = choose(sc, lens)
+    out = walk(q, kv, table, lens, sel)
+    # against float32, a slot at a time (a slot's rows are 84 MB)
+    live = np.asarray(lens) > 0
+    worst = {"index": 0.0, "walk": 0.0}
+    sets_equal = True
+    for b in range(B):
+        n = int(lens[b])
+        if not n:
+            continue
+        keys = gather_pages(ix[:, :, :, :di], table[b:b + 1])[0, 0, :n]
+        ref = index_scores_ref(qi[b:b + 1, :, :di].astype(jnp.float32),
+                               w[b:b + 1], keys.astype(jnp.float32),
+                               scale=scale)[0]
+        got = sc[b, :n]
+        worst["index"] = max(worst["index"],
+                             float(jnp.abs(got - ref).max()))
+        # the selection of the kernel's own scores, by a sort
+        want = np.zeros((n,), bool)
+        want[np.argsort(-np.asarray(got), kind="stable")[:topk]] = True
+        sets_equal &= bool((np.asarray(sel[b, :n]) == want).all()) \
+            and not bool(np.asarray(sel[b, n:]).any())
+        rows = gather_pages(kv, table[b:b + 1])[0, :, :n].astype(
+            jnp.float32)
+        qg = q[b, 0].reshape(Hkv, Hq // Hkv, D).astype(jnp.float32)
+        s_ = jnp.einsum("hrd,htd->hrt", qg, rows[:Hkv]) * D ** -0.5
+        p = jax.nn.softmax(jnp.where(sel[b, :n][None, None], s_,
+                                     -jnp.inf), -1)
+        o = jnp.einsum("hrt,htd->hrd", p, rows[Hkv:]).reshape(Hq, D)
+        worst["walk"] = max(worst["walk"], float(jnp.abs(
+            out[b, 0].astype(jnp.float32) - o).max()))
+    empty_zero = bool((np.asarray(out, np.float32)[~live] == 0).all())
+    _log(phase="sa_check", slots=B, lens=[lo, hi], topk=topk,
+         index_max_err=worst["index"], walk_max_err=worst["walk"],
+         sets_equal=sets_equal, empty_rows_zero=empty_zero, tol=TOL)
+    assert worst["index"] <= TOL and worst["walk"] <= TOL and sets_equal \
+        and empty_zero, worst
+
+    # one layer's admission: the scan of 256-row blocks
+    attn = SA_Attn.init(
+        *[(jax.random.normal(k, sh, jnp.float32) * sh[0] ** -0.5
+           ).astype(dt) for k, sh in zip(jax.random.split(ks[5], 4), (
+               (256, Hq * D), (256, Hkv * D), (256, Hkv * D),
+               (Hq * D, 256)))],
+        jnp.ones((D,), dt), jnp.ones((D,), dt),
+        *[(jax.random.normal(k, sh, jnp.float32) * 256 ** -0.5).astype(dt)
+          for k, sh in zip(jax.random.split(ks[6], 3), (
+              (256, Hi * di), (256, di), (256, Hi)))],
+        n_heads=Hq, n_kv_heads=Hkv, head_dim=D, idx_heads=Hi, idx_dim=di,
+        topk=topk)
+    u = jax.random.normal(ks[7], (P_, 256), jnp.float32).astype(dt)
+    pos = jnp.arange(P_)
+    ang = lambda d: (pos[:, None] * (1e7 ** (  # noqa: E731
+        -jnp.arange(0, d, 2) / d))[None]).astype(jnp.float32)
+    rope = (jnp.cos(ang(D)), jnp.sin(ang(D)))
+    rope_i = (jnp.cos(ang(di)), jnp.sin(ang(di)))
+    pids = table[0][:P_ // PAGE]
+    outs = {}
+    for impl in ("flash",) + (("ref",) if rehearse else ()):
+        f = jax.jit(lambda u, kv, ix, impl=impl: attn.prefill(
+            u, rope, rope_i, kv, ix, pids, impl=impl)[0])
+        t0 = time.perf_counter()
+        outs[impl] = f(u, kv, ix).block_until_ready()
+        first = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        f(u, kv, ix).block_until_ready()
+        _log(phase="sa_prefill", impl=impl, tokens=P_,
+             ms=round(1e3 * (time.perf_counter() - t0), 3),
+             first_call_s=round(first, 1))
+    if rehearse:
+        err = float(jnp.abs(outs["flash"] - outs["ref"]).max())
+        _log(phase="sa_prefill_check", max_err=err)
+        assert err < 1e-4, err
+
+    def ms(fn, *args):
+        """ms a call over 6 chained calls (the first argument is fed
+        back, perturbed by the result)."""
+        def chain(*a):
+            def body(x, _):
+                y = fn(x, *a[1:])
+                # (rows past a slot's end are whatever was there)
+                y = jnp.sum(jnp.where(jnp.isfinite(y), y, 0).astype(
+                    jnp.float32))
+                return (x + (y * 1e-9).astype(x.dtype)), ()
+            return jax.lax.scan(body, a[0], None, length=6)[0]
+        f = jax.jit(chain)
+        f(*args).block_until_ready()
+        ts = []
+        for _ in range(1 if rehearse else 5):
+            t0 = time.perf_counter()
+            f(*args).block_until_ready()
+            ts.append(time.perf_counter() - t0)
+        return round(min(ts) / 6 * 1e3, 4), round(
+            float(np.median(ts)) / 6 * 1e3, 4)
+
+    # the admission's three pieces, one 256-row block against 16,384
+    # keys (the scan runs 64 of them a layer)
+    from triton_dist_tpu.kernels.sparse_attn import (index_scores,
+                                                     selected_attention)
+    M = min(256, P_)
+    qb = (jax.random.normal(ks[2], (M, Hq, D), jnp.float32) * 0.5
+          ).astype(dt)
+    qib = jax.random.normal(ks[3], (M, Hi, di), jnp.float32).astype(dt)
+    wb = jax.random.normal(ks[4], (M, Hi), jnp.float32)
+    kib = jax.random.normal(ks[5], (P_, di), jnp.float32).astype(dt)
+    kT, vT = (jax.random.normal(k_, (Hkv, P_, D), jnp.float32) * 0.5
+              for k_ in jax.random.split(ks[6], 2))
+    kT, vT = kT.astype(dt), vT.astype(dt)
+    c1 = jnp.int32(P_)
+    causal = jnp.arange(P_)[None] <= (P_ - M + jnp.arange(M))[:, None]
+    scb = index_scores(qib, wb, kib, c1, scale=scale)
+    selb = select_topk(scb, causal, topk)
+    for name, t in (
+            ("index_block", ms(lambda qi, w, k: index_scores(
+                qi, w, k, c1, scale=scale), qib, wb, kib)),
+            ("select_block", ms(lambda sc: select_topk(
+                sc, causal, topk), scb)),
+            ("attend_block", ms(lambda q, k, v, sel: selected_attention(
+                q, k, v, sel, c1, scale=D ** -0.5), qb, kT, vT, selb))):
+        _log(phase="sa_time", kernel=name, ms_min=t[0], ms_med=t[1],
+             rows=M, keys=P_)
+
+    def top_k_mask(sc, valid):
+        """The same set by `jax.lax.top_k` and a scatter to the mask
+        (the plain reference's way), for timing beside `select_topk`."""
+        _, idx = jax.lax.top_k(jnp.where(valid, sc, -jnp.inf),
+                               min(topk, sc.shape[1]))
+        return jnp.zeros(sc.shape, bool).at[
+            jnp.arange(sc.shape[0])[:, None], idx].set(True) & valid
+
+    live_cols = jnp.arange(sc.shape[1])[None] < lens[:, None]
+    assert bool((top_k_mask(sc, live_cols) == sel).all())
+    for name, t in (
+            ("lax_top_k_block", ms(lambda sc: top_k_mask(sc, causal), scb)),
+            ("lax_top_k", ms(lambda sc, n: top_k_mask(
+                sc, jnp.arange(sc.shape[1])[None] < n[:, None]), sc, lens))):
+        _log(phase="sa_time", kernel=name, ms_min=t[0], ms_med=t[1],
+             shape=list(scb.shape if "block" in name else sc.shape))
+
+    # the same walk over TWO planes (PagedSlotCache's layout: K and V
+    # pages of Hkv heads each, two copies a page) under the same set
+    pk, pv = kv[:, :Hkv], kv[:, Hkv:]
+    two = jax.jit(lambda q, pk, pv, t, n, sel: flash_decode_paged(
+        q, pk, pv, t, None, kv_lens=n, sel=sel))(q, pk, pv, table, lens,
+                                                 sel)
+    err2 = float(jnp.abs(two[np.asarray(live)].astype(jnp.float32)
+                         - out[np.asarray(live)].astype(jnp.float32)).max())
+    _log(phase="sa_check", two_plane_vs_fused_max_err=err2)
+    assert err2 <= TOL, err2
+
+    ctx = float(np.asarray(lens).sum())
+    att = float(np.minimum(np.asarray(lens), topk).sum())
+    for bw in (None,) + (() if rehearse else (4, 8)):
+        try:
+            t = ms(lambda q, pk, pv, t, n, sel, bw=bw: flash_decode_paged(
+                q, pk, pv, t, None, kv_lens=n, sel=sel, block_w=bw),
+                q, pk, pv, table, lens, sel)
+        except Exception as e:     # a W the chip's VMEM refuses
+            _log(phase="sa_time", kernel=f"two_plane_walk_w{bw}",
+                 refused=repr(e)[:300])
+            continue
+        _log(phase="sa_time", kernel=f"two_plane_walk_w{bw or 'pick'}",
+             ms_min=t[0], ms_med=t[1], positions_in_context=ctx,
+             positions_attended=att)
+    for name, t, least in (
+            ("index_walk", ms(lambda qi, w, ix, t, n: index_scores_paged(
+                qi, w, ix, t, n, scale=scale), qi, w, ix, table, lens),
+             ctx * di * 2),
+            ("select", ms(lambda sc, n: select_topk(
+                sc, jnp.arange(sc.shape[1])[None] < n[:, None], topk),
+                sc, lens), 0.0),
+            ("selected_walk", ms(lambda q, kv, t, n, sel: flash_decode_paged(
+                q, kv, None, t, None, kv_lens=n, fused=True, sel=sel),
+                q, kv, table, lens, sel), att * 2 * Hkv * D * 2)) + tuple(
+            (f"selected_walk_w{bw}", ms(
+                lambda q, kv, t, n, sel, bw=bw: flash_decode_paged(
+                    q, kv, None, t, None, kv_lens=n, fused=True, sel=sel,
+                    block_w=bw), q, kv, table, lens, sel),
+             att * 2 * Hkv * D * 2) for bw in (1, 4) if not rehearse):
+        _log(phase="sa_time", kernel=name, ms_min=t[0], ms_med=t[1],
+             positions_in_context=ctx, positions_attended=att,
+             least_ms_at_819=round(1e3 * least / 819e9, 4),
+             whole_context_ms_at_819=round(
+                 1e3 * ctx * 2 * Hkv * D * 2 / 819e9, 4)
+             if name == "selected_walk" else None)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("phases", nargs="*", default=["check", "mixed", "time"],
-                    help="of check, mixed, time, gmm (default: the "
-                         "first three)")
+                    help="of check, mixed, time, gmm, sa (default: "
+                         "the first three)")
     ap.add_argument("--shapes", nargs="*", default=None,
                     help="of " + ", ".join(SHAPES) + " (default: all)")
     ap.add_argument("--rehearse", action="store_true",
@@ -329,6 +572,8 @@ def main() -> None:
         timing(args.block_w, args.lens)
     if "gmm" in args.phases:
         gmm(args.rehearse)
+    if "sa" in args.phases:
+        sa(args.rehearse)
     _log(ok=True, device={"platform": dev.platform, "kind": dev.device_kind})
 
 

@@ -86,11 +86,12 @@ def route(router_logits, k: int, *, norm_topk: bool = True):
     """Softmax -> top-k -> (optionally) renormalize (Qwen3-MoE routing,
     reference models/qwen_moe.py). Returns (weights [T, k] f32,
     expert_idx [T, k] int32)."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    w, idx = jax.lax.top_k(probs, k)
-    if norm_topk:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return w, idx.astype(jnp.int32)
+    with jax.named_scope("moe_route"):
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+        w, idx = jax.lax.top_k(probs, k)
+        if norm_topk:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return w, idx.astype(jnp.int32)
 
 
 def route_noaux_tc(x, w_router, e_bias, k: int, *, n_group: int,

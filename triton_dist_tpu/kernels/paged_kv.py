@@ -75,7 +75,7 @@ def _block_pages(page: int) -> int:
 
 def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
                   quant: bool, partial: bool, v_cols: Optional[int],
-                  s_ref, *refs):
+                  fused: bool, selected: bool, s_ref, *refs):
     """Grid (B // W,): one step walks W slots — W*H (slot, kv-head)
     streams, H the heads of the pool it is handed — through THEIR OWN
     pages, C pages at a time (module docstring). refs = q
@@ -129,9 +129,19 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
     K and V are read from the SAME block of the buffer: one copy a page,
     QK over d, PV over v_cols, the output v_cols wide. With one latent
     head and rep = every query head, a block is an MXU-shaped
-    [rep, d] x [d, C*page] and [rep, C*page] x [C*page, v_cols]."""
+    [rep, d] x [d, C*page] and [rep, C*page] x [C*page, v_cols].
+
+    fused (kv_cache.IndexedSlotCache: K and V in ONE plane, a page
+    [2H, page, d] holding the slot's H key heads and then its H value
+    heads): one copy a page fetches both, and a stream's keys and
+    values are two head rows of the same block of the buffer.
+
+    selected (learned sparse attention, layers/sparse_attn.py): `sel`
+    [W*H, 1, L] int32 marks, per position, what the slot's indexer
+    chose; a position it did not choose is masked like one past the
+    slot's end. The walk still fetches every page of the context."""
     q_ref, lens_ref = refs[:2]
-    n_payload = 1 if v_cols is not None else 2
+    n_payload = 1 if (v_cols is not None or fused) else 2
     pools = list(refs[2:2 + n_payload])
     rest = refs[2 + n_payload:]
     if quant:
@@ -141,6 +151,8 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
         own_ref, o_ref, m_ref, l_ref = rest[:4]
         rest = rest[4:]
     else:
+        if selected:
+            sel_ref, rest = rest[0], rest[1:]
         o_ref = rest[0]
         rest = rest[1:]
     *bufs, sem = rest
@@ -234,8 +246,13 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
         if partial:
             mask = mask & (own_ref[
                 :, :, pl.ds(pl.multiple_of(pos, CP), CP)] != 0)
+        if selected:
+            mask = mask & (sel_ref[
+                :, :, pl.ds(pl.multiple_of(pos, CP), CP)] != 0)
         # a slot's heads lie side by side in the buffer: its streams
-        k = kbuf[half].reshape(WH, CP, d)
+        H = WH // W
+        k = (kbuf[half, :, :H] if fused else kbuf[half]).reshape(
+            WH, CP, d)
         if quant:
             k = k.astype(q.dtype)
         s = jax.lax.dot_general(
@@ -250,8 +267,12 @@ def _paged_kernel(scale: float, rep: int, page: int, W: int, maxp: int,
         alpha = jnp.exp(m - m_new)
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         l = l * alpha + jnp.sum(p, -1, keepdims=True)
-        v = (k[:, :, :v_cols] if v_cols is not None
-             else bufs[1][half].reshape(WH, CP, d))
+        if v_cols is not None:
+            v = k[:, :, :v_cols]
+        elif fused:
+            v = kbuf[half, :, H:].reshape(WH, CP, d)
+        else:
+            v = bufs[1][half].reshape(WH, CP, d)
         if quant:
             # V's scale folds into p (diag(sv) V == V rows scaled); the
             # convert to the compute dtype happens in VMEM
@@ -282,8 +303,15 @@ def flash_decode_paged(q, pages_k, pages_v, page_table, kv_len, *,
                        scale: Optional[float] = None, kv_lens=None,
                        q_lens=None, k_scale=None, v_scale=None,
                        block_w: Optional[int] = None,
-                       v_cols: Optional[int] = None):
+                       v_cols: Optional[int] = None,
+                       fused: bool = False, sel=None):
     """Cached GQA decode attention through a page table.
+
+    pages_v=None with fused: K and V share ONE plane [NP, 2 Hkv, page,
+    d], a page's first Hkv head rows the keys and its last Hkv the
+    values (kv_cache.IndexedSlotCache). sel [B, L] (L >= the table's
+    positions, a multiple of the softmax tile): nonzero where slot b
+    attends position s; needs kv_lens.
 
     pages_v=None with v_cols: a LATENT pool (one plane; the values are
     the first v_cols columns of the key rows): returns [B, S, Hq,
@@ -326,7 +354,7 @@ def flash_decode_paged(q, pages_k, pages_v, page_table, kv_len, *,
         q, pages_k, pages_v, page_table, kv_len, scale=scale,
         kv_lens=kv_lens, q_lens=q_lens, k_scale=k_scale,
         v_scale=v_scale, tile_owned=None, block_w=block_w,
-        v_cols=v_cols)
+        v_cols=v_cols, fused=fused, sel=sel)
 
 
 def flash_decode_paged_partial(q, pages_k, pages_v, page_table, *,
@@ -368,7 +396,9 @@ def _slot_block(tune_name, dims, B, H, block_w, streams: int = 8):
     dividing B with W*H <= `streams` streams a step (1 slot on a chip
     that holds 8 or more kv heads of every slot, 4 where it holds 2; a
     latent walk, whose one stream a slot carries every query head,
-    asks for 2). W only
+    asks for 2; a fused K/V plane, one copy a page for both, for 16:
+    4 slots of 4 heads read 1.85 ms a call where 2 read 2.02 and 1
+    2.62, PERF.md PR 39). W only
     regroups slots across grid steps and never changes a stream's
     result. Strictness splits by provenance: an indivisible W that was
     pinned explicitly or installed in the contextual profile is a loud
@@ -401,14 +431,20 @@ def _slot_block(tune_name, dims, B, H, block_w, streams: int = 8):
 def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
                              scale, kv_lens, q_lens, k_scale, v_scale,
                              tile_owned, block_w=None, v_cols=None,
+                             fused=False, sel=None,
                              tune_name="flash_decode_paged"):
     B, S, Hq, d = q.shape
     partial = tile_owned is not None
     latent = v_cols is not None
-    assert latent == (pages_v is None), \
-        "a latent pool has no V plane: pages_v=None with v_cols"
-    assert not latent or (k_scale is None and not partial), \
-        "the latent walk serves the bf16 pool on one chip"
+    one_plane = latent or fused
+    assert not (latent and fused)
+    assert one_plane == (pages_v is None), \
+        "one plane holds keys and values: pages_v=None with v_cols " \
+        "(a latent pool) or fused (K and V heads in one page)"
+    assert not one_plane or (k_scale is None and not partial), \
+        "the one-plane walks serve the bf16 pool on one chip"
+    assert sel is None or (kv_lens is not None and not partial), \
+        "a selection rides on per-slot kv_lens"
     if latent:
         tune_name = "flash_decode_paged_latent"
     if q_lens is not None:
@@ -420,7 +456,8 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
     quant = k_scale is not None
     assert (k_scale is None) == (v_scale is None), \
         "int8 pool carries BOTH scale planes"
-    NP, H, page, _ = pages_k.shape
+    NP, Hp, page, _ = pages_k.shape
+    H = Hp // 2 if fused else Hp    # (slot, kv-head) streams a slot
     assert page_table.shape[0] == B, "the table has one row a slot"
     maxp = page_table.shape[1]
     X = B * H
@@ -432,7 +469,7 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
            .transpose(0, 2, 1, 3, 4)
            .reshape(X, rows, d))
     W = _slot_block(tune_name, (X, B * Hq, NP * page), B, H, block_w,
-                    streams=2 if latent else 8)
+                    streams=2 if latent else 16 if fused else 8)
     WH = W * H
     CP = _block_pages(page) * page
     # every slot carries its own (kv length, query length): a launch
@@ -457,12 +494,12 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
         return pl.BlockSpec((WH,) + tail, lambda x, s_ref: (x, 0, 0))
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    payload = [pages_k] if latent else [pages_k, pages_v]
+    payload = [pages_k] if one_plane else [pages_k, pages_v]
     dv = v_cols if latent else d
     in_specs = [per_step(rows, d), per_step(1, 2)] + [hbm] * len(payload)
     args = [qx, jnp.repeat(jnp.stack([lens_b, qlens_b], 1), H,
                            axis=0).reshape(X, 1, 2)] + payload
-    scratch = [pltpu.VMEM((2, W, H, CP, d), p.dtype) for p in payload]
+    scratch = [pltpu.VMEM((2, W, Hp, CP, d), p.dtype) for p in payload]
     if quant:
         in_specs += [hbm, hbm]
         args += [k_scale, v_scale]
@@ -482,11 +519,19 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
                      jax.ShapeDtypeStruct((X, rows, 1), jnp.float32),
                      jax.ShapeDtypeStruct((X, rows, 1), jnp.float32))
     else:
+        if sel is not None:
+            # the slot's row once for each of its streams, like `own`
+            L = sel.shape[1]
+            assert L % CP == 0 and L >= maxp * page, (L, CP, maxp, page)
+            in_specs.append(per_step(1, L))
+            args.append(jnp.repeat(jnp.asarray(sel, jnp.int32), H,
+                                   axis=0).reshape(X, 1, L))
         out_specs = per_step(rows, dv)
         out_shape = jax.ShapeDtypeStruct((X, rows, dv), q.dtype)
     out = pl.pallas_call(
         functools.partial(_paged_kernel, float(scale), rep, page, W,
-                          maxp, quant, partial, v_cols),
+                          maxp, quant, partial, v_cols, fused,
+                          sel is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B // W,),
@@ -513,6 +558,107 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
         acc, m, l = out
         return unfold(acc), unfold(m[..., 0]), unfold(l[..., 0])
     return unfold(out)
+
+
+# Positions per block of the index walk: its pages carry 128 B of key a
+# position where a K/V page carries 2 KiB, so a block takes four times
+# the walk's positions to give the loop's fixed cost something to hide
+# behind (the copies are bound by their number either way: PERF.md).
+_INDEX_TILE = 512
+
+
+def _index_kernel(scale: float, page: int, maxp: int, s_ref, q_ref, w_ref,
+                  pool, o_ref, buf, sem):
+    """Grid (B,): one step scores ONE slot's whole context against its
+    indexer queries. q [1, Hi, d]; w [1, Hi, 1] f32; pool [NP, 1, page,
+    d] in HBM; o [1, 1, L] f32; buf [2, C*page, d]. s_ref holds the B
+    lengths, then the page table row by row (the paged walk's layout).
+
+    score(s) = scale * sum_j w_j relu(q_j . k_s), in float32. The walk
+    is the paged attention walk's (`_paged_kernel`): blocks of C pages,
+    the next block's copies started before this block's wait, the tail
+    clamped to the slot's last page so that no copy needs a branch, at
+    least one block so that every copy started is waited for. Blocks
+    past the slot's end are not written: the caller masks by length."""
+    b = pl.program_id(0)
+    B = pl.num_programs(0)
+    C = max(1, _INDEX_TILE // page)
+    CP = C * page
+    n_pages = (s_ref[b] + (page - 1)) // page
+    nblk = jnp.maximum((n_pages + (C - 1)) // C, 1)
+    last = jnp.maximum(n_pages - 1, 0)
+    row0 = B + b * maxp
+
+    def start(i, half):
+        for c in range(C):
+            pid = s_ref[row0 + jnp.minimum(i * C + c, last)]
+            pltpu.make_async_copy(
+                pool.at[pid, 0], buf.at[half, pl.ds(c * page, page)],
+                sem.at[half]).start()
+
+    start(0, 0)
+    q = q_ref[0]                                     # [Hi, d]
+    w = w_ref[0]                                     # [Hi, 1]
+
+    def block(i, carry):
+        half = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < nblk)
+        def _ahead():
+            start(i + 1, 1 - half)
+
+        # every copy of the block signals one semaphore by its bytes
+        pltpu.make_async_copy(buf.at[half], buf.at[half],
+                              sem.at[half]).wait()
+        s = jax.lax.dot_general(
+            q, buf[half], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [Hi, CP]
+        o_ref[0, :, pl.ds(pl.multiple_of(i * CP, CP), CP)] = jnp.sum(
+            jnp.maximum(s, 0.0) * w, axis=0, keepdims=True) * scale
+        return carry
+
+    jax.lax.fori_loop(0, nblk, block, 0)
+
+
+def index_scores_paged(qi, w, pages_i, page_table, kv_lens, *,
+                       scale: float):
+    """The indexer's scores of every cached position of every slot
+    (learned sparse attention, layers/sparse_attn.py), through the page
+    table: qi [B, Hi, d] (the pool's dtype), w [B, Hi] float32, pages_i
+    [NP, 1, page, d] (kv_cache.IndexedSlotCache's index plane: ONE key
+    head a position), page_table [B, maxp], kv_lens [B]. Returns
+    [B, L] float32, L the table's positions rounded up to the walk's
+    block; score[b, s] = scale * sum_j w[b, j] relu(qi[b, j] .
+    key[b, s]) for s < kv_lens[b], anything past it."""
+    B, Hi, d = qi.shape
+    NP, one, page, dk = pages_i.shape
+    assert one == 1 and dk == d, (pages_i.shape, qi.shape)
+    maxp = page_table.shape[1]
+    CP = max(1, _INDEX_TILE // page) * page
+    L = -(-maxp * page // CP) * CP
+    scalars = jnp.concatenate([jnp.asarray(kv_lens, jnp.int32),
+                               page_table.astype(jnp.int32).reshape(-1)])
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, float(scale), page, maxp),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, Hi, d), lambda b, s_ref: (b, 0, 0)),
+                pl.BlockSpec((1, Hi, 1), lambda b, s_ref: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, 1, L), lambda b, s_ref: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, CP, d), pages_i.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, 1, L), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret_mode(),
+        name="sa_index_paged",
+    )(scalars, qi.astype(pages_i.dtype),
+      jnp.asarray(w, jnp.float32)[..., None], pages_i)
+    return out[:, 0]
 
 
 def set_page_rows(pool, pidx, r, u):

@@ -35,12 +35,30 @@ def precompute_rope(head_dim: int, max_seq: int, theta: float = 1e6):
             jnp.asarray(np.sin(freqs), dtype=jnp.float32))
 
 
-def apply_rope(x, cos, sin, positions):
+def rope_rows(cos, sin, positions, sections=()):
+    """The tables' rows (c, s) [T, D/2] at `positions`: [T] int32, one
+    position a token, or [3, T] (time, height, width: multi-section
+    rotary, the D/2 frequency pairs split by `sections`, e.g.
+    (16, 24, 24): pairs 0-15 take their angle from component 0, 16-39
+    from component 1, 40-63 from component 2; a text token has all
+    three equal and reads what the one-position form reads)."""
+    positions = jnp.asarray(positions)
+    if positions.ndim == 1:
+        return cos[positions], sin[positions]
+    assert positions.shape[0] == len(sections) \
+        and sum(sections) == cos.shape[-1], (positions.shape, sections)
+    comp = np.repeat(np.arange(len(sections)), sections)     # [D/2]
+    at = positions[comp].T                                   # [T, D/2]
+    pair = jnp.arange(cos.shape[-1])
+    return cos[at, pair], sin[at, pair]
+
+
+def apply_rope(x, cos, sin, positions, sections=()):
     """Rotate half-pairs: x [..., S, H, D]; cos/sin [max_seq, D/2];
-    positions [S] (ref: tp_attn.py:165 applies the same rotation on the
-    gathered QKV)."""
-    c = cos[positions][:, None, :]  # [S, 1, D/2]
-    s = sin[positions][:, None, :]
+    positions [S], or [3, S] with `sections` (`rope_rows`) (ref:
+    tp_attn.py:165 applies the same rotation on the gathered QKV)."""
+    c, s = rope_rows(cos, sin, positions, sections)
+    c, s = c[:, None, :], s[:, None, :]  # [S, 1, D/2]
     x1, x2 = jnp.split(x, 2, axis=-1)
     dt = x.dtype
     x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)

@@ -3,9 +3,9 @@ SURVEY.md §2.4). `AutoLLM` dispatches by model name/config the way the
 reference does (models/__init__.py:33-59: Qwen3 -> DenseLLM,
 Qwen3-MoE -> Qwen3MoE)."""
 
-from triton_dist_tpu.models.config import (ModelConfig, qwen3_30b_a3b,  # noqa: F401
-                                           qwen3_32b, tiny_qwen3,
-                                           tiny_qwen3_moe)
+from triton_dist_tpu.models.config import (ModelConfig, SAConfig,  # noqa: F401
+                                           qwen3_30b_a3b, qwen3_32b,
+                                           tiny_qwen3, tiny_qwen3_moe)
 from triton_dist_tpu.models.deepseek import (DeepSeekConfig,  # noqa: F401
                                              DeepSeekV3, tiny_deepseek)
 from triton_dist_tpu.models.dense import DenseLLM  # noqa: F401
@@ -16,7 +16,8 @@ from triton_dist_tpu.models.disagg import (DCNTransport,  # noqa: F401
                                            PrefillWorkerDied)
 from triton_dist_tpu.models.engine import Engine  # noqa: F401
 from triton_dist_tpu.models.kv_cache import (HybridSlotCache,  # noqa: F401
-                                             KVCache, LatentSlotCache,
+                                             IndexedSlotCache, KVCache,
+                                             LatentSlotCache,
                                              PagedSlotCache)
 from triton_dist_tpu.models.phi4flash import (Phi4Flash,  # noqa: F401
                                               Phi4FlashConfig,

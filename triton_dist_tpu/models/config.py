@@ -7,8 +7,20 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+from typing import Optional
 
 import jax.numpy as jnp
+
+
+@dataclasses.dataclass(frozen=True)
+class SAConfig:
+    """Learned sparse attention (`sa_config` of a HF config: the
+    DeepSeek sparse-attention indexer; layers/sparse_attn.py). One
+    index key head a position; the published `q_chunk_size` /
+    `kv_chunk_size` tile the score computation and change no result."""
+    indexer_num_heads: int = 16
+    indexer_head_dim: int = 64
+    topk: int = 2048
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,10 +44,28 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     moe_intermediate_size: int = 0
     dtype: str = "bfloat16"
+    # learned sparse attention over the cached positions; None = every
+    # query attends its whole context (models/qwen_moe.py)
+    sa_config: Optional[SAConfig] = None
+    # multi-section rotary: how many of the head's frequency pairs read
+    # each component of a (time, height, width) position; () = one
+    # position a token
+    mrope_section: tuple = ()
+    # a STATED SHARE of an expert-parallel deployment: (first, count) of
+    # the `num_experts` the router ranks are held here (layers/ep_moe.py
+    # `held`); None = all of them
+    held_experts: Optional[tuple] = None
 
     @property
     def jax_dtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def expert_ids(self) -> range:
+        """The published numbers of the experts whose load this server
+        reports (`expert_tokens{expert=}`): the held ones."""
+        first, count = self.held_experts or (0, self.num_experts)
+        return range(first, first + count)
 
     @property
     def is_moe(self) -> bool:

@@ -390,6 +390,64 @@ class LatentSlotCache(PagedSlotCache):
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
+class IndexedSlotCache(PagedSlotCache):
+    """The paged pool of a model with learned sparse attention
+    (layers/sparse_attn.py; models/qwen_moe.py with `sa_config`): THREE
+    planes of a position under ONE page table.
+
+    `pages_k[l]` [NP, 2 Hkv, page, d] holds K AND V: a page's first Hkv
+    head rows are the slot's keys, its last Hkv its values, so the
+    decode walk fetches both with one copy a page (the walk is bound by
+    the copies it issues, PERF.md PR 35) and the append writes both
+    with one scatter; `pages_v` is empty, as a latent pool's.
+    `pages_i[l]` [NP, 1, page, lanes] is the indexer's cache: ONE key
+    head a position, `index_dim` values padded to the chip's 128-lane
+    HBM tile (a 64-wide plane takes 128 lanes there whether the shape
+    says so or not). A page id means the same 16 positions in all three.
+    Pages, the table, the allocator, retire-to-trash and `clear_slot`
+    are PagedSlotCache's own; a slot's whole context is its pages."""
+
+    pages_i: Tuple[jax.Array, ...] = ()
+    index_dim: int = dataclasses.field(default=0,
+                                       metadata=dict(static=True))
+
+    @staticmethod
+    def create_indexed(num_layers: int, batch: int, max_seq: int, *,
+                       n_kv_heads: int, head_dim: int, index_dim: int,
+                       page: int, num_pages: int, mesh: Mesh,
+                       dtype=jnp.bfloat16) -> "IndexedSlotCache":
+        lanes = -(-index_dim // LATENT_LANES) * LATENT_LANES
+        maxp = -(-max_seq // page)
+        rep = NamedSharding(mesh, P())
+
+        def planes(shape):
+            return tuple(jax.device_put(jnp.zeros(shape, dtype), rep)
+                         for _ in range(num_layers))
+
+        table = jax.device_put(jnp.zeros((batch, maxp), jnp.int32), rep)
+        return IndexedSlotCache(
+            pages_k=planes((num_pages, 2 * n_kv_heads, page, head_dim)),
+            pages_v=(), table=table,
+            pages_i=planes((num_pages, 1, page, lanes)),
+            index_dim=index_dim)
+
+    @property
+    def kv_heads(self) -> int:
+        return self.pages_k[0].shape[1] // 2
+
+    def slot_bytes(self) -> dict:
+        """Bytes a mapped page holds over all layers: its K and V rows
+        (kind "pages") and its index keys as published (kind "index":
+        `index_dim` values a position, whatever the plane pads them
+        to). A uniform cache is the K and V rows alone."""
+        L, item = len(self.pages_k), self.pages_k[0].dtype.itemsize
+        kv = L * int(np.prod(self.pages_k[0].shape[1:])) * item
+        return {"page": kv, "uniform_page": kv,
+                "index": L * self.page * self.index_dim * item}
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
 class HybridSlotCache(PagedSlotCache):
     """One slot cache for a model whose layers keep THREE kinds of
     per-slot state (models/phi4flash.py; ROADMAP Queue 2 A.3 / A.5):

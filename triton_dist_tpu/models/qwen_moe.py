@@ -37,6 +37,19 @@ engine/scheduler surface as `expert_tokens{expert=...}` gauges,
 `moe_capacity_drops` and `expert_load_imbalance` — the loud half of
 dropless-or-loud, observable.
 
+LEARNED SPARSE ATTENTION (`config.sa_config`, Keye-VL-2.0's language
+model: this stack at its own numbers plus an indexer): the layers'
+attention is `SA_Attn` (layers/sparse_attn.py), every query attending
+the `topk` cached positions its indexer scores highest; the pool holds
+K and V in one plane and the indexer's keys in another under one page
+table (kv_cache.IndexedSlotCache), which only this model's programs
+read and write (`ServingTraits.own_pool`: it admits through
+`admit_slot_paged` below, and whatever would move a slot through the
+Engine's K/V page programs is refused by name); the experts are a
+STATED SHARE (`config.held_experts`, `EP_MoE.fwd_share`): the chip
+routes over all `num_experts` and adds what its own add. One chip,
+paged serving only; `sa_config=None` is the stack as it was.
+
 EP+TP HYBRID MESH: `moe_axis` names the mesh axis the experts shard
 over (default: the attention `axis`). On a 2-D mesh like
 make_mesh((2, 4), ("expert", "tp")), attention KV head-groups split on
@@ -58,6 +71,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from triton_dist_tpu.layers import TP_Attn, precompute_rope, rms_norm
 from triton_dist_tpu.layers.ep_moe import EP_MoE
+from triton_dist_tpu.layers.sparse_attn import SA_Attn
 from triton_dist_tpu.layers.tp_moe import TP_MoE
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.kv_cache import KVCache
@@ -68,7 +82,7 @@ from triton_dist_tpu.runtime import auto_mesh
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class MoELayer:
-    attn: TP_Attn
+    attn: TP_Attn | SA_Attn
     moe: TP_MoE | EP_MoE
     ln_attn: jax.Array
     ln_mlp: jax.Array
@@ -94,6 +108,10 @@ class Qwen3MoE:
     # pre-hybrid caller builds).
     moe_axis: str = dataclasses.field(default=None,
                                       metadata=dict(static=True))
+    # sparse attention only: the indexer's rotary tables [max_seq,
+    # indexer_head_dim / 2]
+    cos_i: jax.Array = None
+    sin_i: jax.Array = None
 
     @property
     def ep_axis(self) -> str:
@@ -129,6 +147,24 @@ class Qwen3MoE:
             s = scale if scale is not None else (shape[-2] ** -0.5)
             return jax.random.normal(next(kit), shape,
                                      dtype=dt) * jnp.asarray(s, dtype=dt)
+
+        if cfg.sa_config is not None:
+            sa = cfg.sa_config
+            Hi, di = sa.indexer_num_heads, sa.indexer_head_dim
+            Eh = len(cfg.expert_ids)
+            one = lambda n: jnp.ones((n,), dt)  # noqa: E731
+            layers = [Qwen3MoE.make_sa_layer(cfg, dict(
+                wq=w(D, Hq * hd), wk=w(D, Hkv * hd), wv=w(D, Hkv * hd),
+                wo=w(Hq * hd, D), q_norm=one(hd), k_norm=one(hd),
+                w_qi=w(D, Hi * di), w_ki=w(D, di), w_w=w(D, Hi),
+                w_router=w(D, E, scale=0.02), we_gate=w(Eh, D, I),
+                we_up=w(Eh, D, I), we_down=w(Eh, I, D), ln_attn=one(D),
+                ln_mlp=one(D)), mesh, axis)
+                for _ in range(cfg.num_layers)]
+            head = {"embed": w(cfg.vocab_size, D, scale=0.02),
+                    "final_norm": one(D),
+                    "lm_head": w(D, cfg.vocab_size, scale=0.02)}
+            return Qwen3MoE.build_sa(cfg, head, layers, mesh, axis)
 
         moe_cls = TP_MoE if moe_impl == "tp" else EP_MoE
         layers = []
@@ -245,7 +281,11 @@ class Qwen3MoE:
 
     def _zero_load(self):
         """Fresh routing-load accumulator: [expert_tokens[0..E-1],
-        capacity_dropped] — the serving tick's telemetry payload."""
+        capacity_dropped] — the serving tick's telemetry payload. A
+        stated share with sparse attention reports its held experts'
+        counts and six entries more (`_sa_ffn`)."""
+        if self.config.sa_config is not None:
+            return jnp.zeros((len(self.config.expert_ids) + 7,), jnp.int32)
         return jnp.zeros((self.config.num_experts + 1,), jnp.int32)
 
     def _moe_ffn(self, layer, h, moe_mode, load):
@@ -363,6 +403,9 @@ class Qwen3MoE:
         pos [B] int32; pcache: PagedSlotCache."""
         B, S = ids.shape
         assert S == 1, "slot decode feeds one token per slot"
+        if self.config.sa_config is not None:
+            return self._sa_decode(ids, pcache, pos, mode,
+                                   return_moe_stats)
         attn_mode, moe_mode = self._moe_modes(mode)
         load = self._zero_load() if return_moe_stats else None
         x = self.embed[ids].reshape(B, self.config.hidden_size)
@@ -472,8 +515,153 @@ class Qwen3MoE:
                               dtype=dtype or cfg.jax_dtype)
 
     def serving_traits(self):
-        return ServingTraits(kv_heads=self.config.num_kv_heads)
+        return ServingTraits(
+            kv_heads=self.config.num_kv_heads,
+            own_pool=(None if self.config.sa_config is None
+                      else "K/V and index-key planes"))
 
     def make_paged_cache(self, batch: int, max_seq: int, **kw):
+        if self.config.sa_config is not None:
+            from triton_dist_tpu.models.kv_cache import IndexedSlotCache
+            cfg = self.config
+            return IndexedSlotCache.create_indexed(
+                cfg.num_layers, batch, max_seq,
+                n_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
+                index_dim=cfg.sa_config.indexer_head_dim, page=kw["page"],
+                num_pages=kw["num_pages"], mesh=self.mesh,
+                dtype=kw.get("dtype") or cfg.jax_dtype)
         from triton_dist_tpu.models.kv_cache import uniform_paged_cache
         return uniform_paged_cache(self, batch, max_seq, **kw)
+
+    # ------------------------------------------------------------------
+    # learned sparse attention (config.sa_config): construction, the
+    # decode tick and the admission over the three-plane pool
+    # ------------------------------------------------------------------
+
+    @staticmethod
+    def make_sa_layer(cfg: ModelConfig, w: dict, mesh: Mesh,
+                      axis: str = "tp") -> MoELayer:
+        """One layer from a dict of plain arrays under the reference's
+        names (benchmark/reference/keye_vl2.py `_layer_weights`); its
+        `we_*` hold the HELD experts only."""
+        mesh = _one_chip(mesh, axis)
+        sa = cfg.sa_config
+        attn = SA_Attn.init(
+            w["wq"], w["wk"], w["wv"], w["wo"], w["q_norm"], w["k_norm"],
+            w["w_qi"], w["w_ki"], w["w_w"], n_heads=cfg.num_heads,
+            n_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
+            idx_heads=sa.indexer_num_heads, idx_dim=sa.indexer_head_dim,
+            topk=sa.topk, sections=cfg.mrope_section,
+            eps=cfg.rms_norm_eps)
+        ids = cfg.expert_ids
+        moe = EP_MoE.init(
+            w["w_router"], w["we_gate"], w["we_up"], w["we_down"],
+            mesh=mesh, axis=axis, top_k=cfg.num_experts_per_tok,
+            capacity_factor="dropless", held=(ids[0], len(ids)))
+        return MoELayer(attn=attn, moe=moe, ln_attn=w["ln_attn"],
+                        ln_mlp=w["ln_mlp"])
+
+    @staticmethod
+    def build_sa(cfg: ModelConfig, head: dict, layers, mesh: Mesh,
+                 axis: str = "tp") -> "Qwen3MoE":
+        """head: {"embed", "final_norm", "lm_head"}; layers from
+        `make_sa_layer`. One chip: the mesh's `axis` has size 1."""
+        mesh = _one_chip(mesh, axis)
+        cos, sin = precompute_rope(cfg.head_dim,
+                                   cfg.max_position_embeddings,
+                                   cfg.rope_theta)
+        cos_i, sin_i = precompute_rope(cfg.sa_config.indexer_head_dim,
+                                       cfg.max_position_embeddings,
+                                       cfg.rope_theta)
+        model = Qwen3MoE(
+            embed=head["embed"], layers=tuple(layers),
+            final_norm=head["final_norm"], lm_head=head["lm_head"],
+            cos=cos, sin=sin, config=cfg, mesh=mesh, axis=axis,
+            moe_impl="ep", cos_i=cos_i, sin_i=sin_i)
+        return place_replicated(model, mesh)
+
+    def _sa_ffn(self, layer, x, load=None, context=None, attended=None):
+        """x + the held experts' part of FFN(RMSNorm(x)); `load`
+        accumulates [expert_tokens of the held experts, dropped (0),
+        pairs routed, pairs held, positions in context, positions
+        attended, held experts with a pair, held experts] when the
+        caller asked for it."""
+        h = rms_norm(x, layer.ln_mlp, self.config.rms_norm_eps)
+        y, st = layer.moe.fwd_share(h, return_stats=True)
+        if load is not None:
+            load = load + jnp.concatenate([
+                st["expert_tokens"], st["dropped"].reshape(1),
+                st["pairs_routed"].reshape(1), st["pairs_held"].reshape(1),
+                context.reshape(1), attended.reshape(1),
+                jnp.sum(st["expert_tokens"] > 0).reshape(1),
+                jnp.full((1,), st["expert_tokens"].shape[0])
+            ]).astype(jnp.int32)
+        return x + y.astype(x.dtype), load
+
+    def _sa_logits(self, x):
+        x = rms_norm(x, self.final_norm, self.config.rms_norm_eps)
+        return jnp.dot(x, self.lm_head, preferred_element_type=jnp.float32)
+
+    def _sa_decode(self, ids, pcache, pos, mode, return_moe_stats):
+        impl = "ref" if mode == "xla" else "flash"
+        pos = jnp.asarray(pos, jnp.int32)
+        load = self._zero_load() if return_moe_stats else None
+        x = self.embed[ids[:, 0]]
+        # the tables' rows once, for every layer
+        rope = self.layers[0].attn.rope_of(self.cos, self.sin, self.cos_i,
+                                           self.sin_i, pos)
+        kv, ix = list(pcache.pages_k), list(pcache.pages_i)
+        for li, layer in enumerate(self.layers):
+            u = rms_norm(x, layer.ln_attn, self.config.rms_norm_eps)
+            a, kv[li], ix[li], n_att = layer.attn.decode(
+                u, *rope, kv[li], ix[li], pcache.table, pos, impl=impl)
+            x, load = self._sa_ffn(layer, x + a, load, jnp.sum(pos + 1),
+                                   jnp.sum(n_att))
+        pcache = dataclasses.replace(pcache, pages_k=tuple(kv),
+                                     pages_i=tuple(ix))
+        if return_moe_stats:
+            return self._sa_logits(x), pcache, load
+        return self._sa_logits(x), pcache
+
+    def admit_slot_paged(self, ids, pcache, rows, slot, n,
+                         mode: str = "flash"):
+        """Sparse attention only (the Engine's own admission serves the
+        other stacks). ids [1, P]: the prompt, zero-padded to its
+        bucket; n: its real length; rows [maxp]: the slot's table row.
+        Installs the row, writes the prompt's K/V rows and index keys to
+        the slot's pages and returns (logits [1, V] of its last token,
+        pcache)."""
+        impl = "ref" if mode == "xla" else "flash"
+        page = pcache.page
+        P_ = ids.shape[1]
+        npg = -(-P_ // page)
+        # the prompt's pages; the trash page for one wholly past its end
+        pids = jnp.where(jnp.arange(npg) * page < n, rows[:npg],
+                         pcache.trash)
+        x = self.embed[ids[0]]
+        rope = self.layers[0].attn.rope_of(self.cos, self.sin, self.cos_i,
+                                           self.sin_i, jnp.arange(P_))
+        kv, ix = list(pcache.pages_k), list(pcache.pages_i)
+        for li, layer in enumerate(self.layers):
+            u = rms_norm(x, layer.ln_attn, self.config.rms_norm_eps)
+            a, kv[li], ix[li] = layer.attn.prefill(
+                u, *rope, kv[li], ix[li], pids, impl=impl)
+            x, _ = self._sa_ffn(layer, x + a)
+        table = jax.lax.dynamic_update_slice(pcache.table, rows[None],
+                                             (slot, 0))
+        pcache = dataclasses.replace(pcache, pages_k=tuple(kv),
+                                     pages_i=tuple(ix), table=table)
+        last = jax.lax.dynamic_slice_in_dim(x, n - 1, 1, 0)
+        return self._sa_logits(last), pcache
+
+
+def _one_chip(mesh: Mesh, axis: str) -> Mesh:
+    mesh = auto_mesh(mesh)
+    if mesh.shape[axis] != 1:
+        raise ValueError(
+            f"Qwen3MoE with sa_config serves one chip's share (mesh axis "
+            f"{axis!r} has size {mesh.shape[axis]}); missing capability: "
+            "tensor-parallel sparse attention (the indexer's one key "
+            "head has nothing to split) and the expert exchange across "
+            "a mesh")
+    return mesh

@@ -498,15 +498,15 @@ class DecodeSlots:
             engine._moe_pending.clear()
             reg = self.tele.registry
             mcfg = engine.model.config
-            E = mcfg.num_experts
-            self._moe_tokens_cum = np.zeros((E,), np.int64)
             # a model that holds a stated share of a wider expert set
             # names its experts by their published numbers
             self._c_expert = [
                 reg.counter("expert_tokens",
                             "routed entries per expert (compute load)",
                             labels={"expert": str(e)})
-                for e in getattr(mcfg, "expert_ids", range(E))]
+                for e in mcfg.expert_ids]
+            self._moe_tokens_cum = np.zeros((len(self._c_expert),),
+                                            np.int64)
             self._c_pairs_routed = reg.counter(
                 "moe_pairs_routed",
                 "(token, expert) pairs the router chose, over every "
@@ -522,6 +522,26 @@ class DecodeSlots:
             self._g_moe_imb = reg.gauge(
                 "expert_load_imbalance",
                 "max/mean of cumulative per-expert routed load")
+            if getattr(mcfg, "sa_config", None) is not None:
+                # learned sparse attention: two more entries of the same
+                # vector (models/qwen_moe.py `_zero_load`)
+                self._c_sa_context = reg.counter(
+                    "sa_positions_in_context",
+                    "cached positions the decode steps' indexers "
+                    "scored, over slots and layers")
+                self._c_sa_attended = reg.counter(
+                    "sa_positions_attended",
+                    "those of sa_positions_in_context the steps "
+                    "attended: the positions the walk's mask let "
+                    "through, a slot and layer")
+                self._c_experts_touched = reg.counter(
+                    "moe_experts_touched",
+                    "held experts that a decode step's pairs reached "
+                    "(whose weights the grouped GEMM read), over steps "
+                    "and layers")
+                self._c_experts_offered = reg.counter(
+                    "moe_experts_offered",
+                    "held experts, over the same steps and layers")
         self.spec = int(spec)
         if self.spec:
             from triton_dist_tpu.models.spec_decode import NgramDrafter
@@ -834,6 +854,11 @@ class DecodeSlots:
                         else (counts.sum() + dropped,) * 2)
         self._c_pairs_routed.inc(int(routed))
         self._c_pairs_held.inc(int(held))
+        if len(load) > E + 3:
+            self._c_sa_context.inc(int(load[E + 3]))
+            self._c_sa_attended.inc(int(load[E + 4]))
+            self._c_experts_touched.inc(int(load[E + 5]))
+            self._c_experts_offered.inc(int(load[E + 6]))
         for e in np.nonzero(counts)[0]:
             self._c_expert[int(e)].inc(int(counts[e]))
         if dropped:
@@ -1553,7 +1578,7 @@ class PagedDecodeSlots(DecodeSlots):
                     "cache_bytes", "bytes the live slots hold, by kind "
                     "of state", labels={"kind": kind})
                 for kind in (self._page_kind,) + tuple(
-                    k for k in ("window", "state") if k in sb)}
+                    k for k in ("index", "window", "state") if k in sb)}
             self._g_uniform_bytes = freg.gauge(
                 "cache_uniform_bytes",
                 "bytes a uniform cache (every attention layer its own "
@@ -1615,6 +1640,8 @@ class PagedDecodeSlots(DecodeSlots):
             sb, live = self._slot_bytes, self.occupied
             pages = sum(len(self._pages[b]) for b in live)
             held = {self._page_kind: pages * sb["page"]}
+            if "index" in sb:           # a second plane of the same pages
+                held["index"] = pages * sb["index"]
             for kind in ("window", "state"):
                 if kind in sb:
                     held[kind] = len(live) * sb[kind]

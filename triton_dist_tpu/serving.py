@@ -95,7 +95,11 @@ from typing import Iterator, Optional
 import numpy as np
 
 # longest accepted request line: a protocol message is a few hundred
-# bytes; anything bigger is a firehose and gets a structured refusal
+# bytes beside its prompt; anything bigger is a firehose and gets a
+# structured refusal. A server whose slots hold long contexts takes the
+# prompts that fill them: its cap is the larger of this and 16 bytes a
+# position of the engine's max_seq (a token id in decimal with its
+# separator is at most 7)
 _MAX_LINE = 65536
 
 
@@ -295,6 +299,8 @@ class TokenServer:
         from triton_dist_tpu.models.disagg import DisaggScheduler
         from triton_dist_tpu.models.scheduler import ContinuousScheduler
         self.engine = engine
+        self._max_line = max(_MAX_LINE,
+                             16 * int(getattr(engine, "max_seq", 0)))
         self.tok = tokenizer
         self.batch = batch
         self.chunk = chunk
@@ -437,7 +443,7 @@ class TokenServer:
     def _reader(self, conn: socket.socket,
                 accepted_at: Optional[float] = None) -> None:
         """Connection thread: parse ONE request line (capped at
-        _MAX_LINE bytes — a garbage firehose cannot balloon this
+        `_max_line` bytes — a garbage firehose cannot balloon this
         thread), put it into the model loop's inbox, wait for the
         loop's verdict, leave the socket open for streaming replies.
         Every refusal — malformed JSON, over-capacity prompt, oversized
@@ -452,7 +458,7 @@ class TokenServer:
             conn.settimeout(60.0)   # a silent client cannot hold a slot
             f = conn.makefile("rw")
             try:
-                line = f.readline(_MAX_LINE + 1)
+                line = f.readline(self._max_line + 1)
             except UnicodeDecodeError:
                 # the reply side of the text-mode file is independent
                 # of the poisoned read side — refuse, don't hang the
@@ -466,10 +472,12 @@ class TokenServer:
                 return
             # readline's cap counts decoded CHARACTERS; the contract is
             # BYTES (multi-byte UTF-8 would otherwise stretch it 4x)
-            if len(line) > _MAX_LINE or len(line.encode()) > _MAX_LINE:
+            if len(line) > self._max_line \
+                    or len(line.encode()) > self._max_line:
                 self._refuse(conn, f, {
                     "done": True, "n_tokens": 0,
-                    "error": f"request line exceeds {_MAX_LINE} bytes"})
+                    "error": f"request line exceeds {self._max_line} "
+                             f"bytes"})
                 return
             try:
                 req = json.loads(line)
