@@ -207,18 +207,19 @@ def _ragged_args(pairs):
 
 # --- Keye-VL-2.0's language model at its published widths (learned
 # sparse attention: 32 query heads of 128 over 4 KV heads, K and V in
-# one plane of 8 head rows a page, an index plane of one 64-wide key
-# padded to 128 lanes, 16 indexer heads, topk 2,048; the cell's batch
-# 32, max_seq 20,480, page 16; a 16,384-token admission)
+# one plane of 8 head rows a page, a per-slot index plane of one 64-wide
+# key, two positions a 128-lane row, 16 indexer heads, topk 2,048; the
+# cell's batch 32, max_seq 20,480, page 16; a 16,384-token admission)
 KY_B, KY_HQ, KY_HKV, KY_HI, KY_DI, KY_SEQ, KY_P = 32, 32, 4, 16, 64, 20480, 16384
 KY_MAXP = KY_SEQ // PAGE
 _KY_KV = ((KY_B * KY_MAXP + 1, 2 * KY_HKV, PAGE, D), BF16)
-_KY_IX = ((KY_B * KY_MAXP + 1, 1, PAGE, 128), BF16)
 
 
-def _sa_index_walk(qi, w, pool, table, kv_lens):
-    from triton_dist_tpu.kernels.paged_kv import index_scores_paged
-    return index_scores_paged(qi, w, pool, table, kv_lens, scale=0.03125)
+def _sa_index(qi, w, keys, kv_lens):
+    from triton_dist_tpu.kernels.sparse_attn import index_scores
+    out = index_scores(qi, w, keys, kv_lens, scale=0.03125)
+    assert out.shape == qi.shape[:2] + (2 * keys.shape[1],), out.shape
+    return out
 
 
 def _sa_select(scores, kv_lens):
@@ -233,11 +234,6 @@ def _sa_decode_walk(q, pool, table, kv_lens, sel):
                               kv_lens=kv_lens, fused=True, sel=sel)
 
 
-def _sa_index_prefill(qi, w, ki, n):
-    from triton_dist_tpu.kernels.sparse_attn import index_scores
-    return index_scores(qi, w, ki, n, scale=0.03125)
-
-
 def _sa_prefill_attend(q, k, v, n, sel):
     from triton_dist_tpu.kernels.sparse_attn import selected_attention
     return selected_attention(q, k, v, sel, n, scale=D ** -0.5)
@@ -245,10 +241,11 @@ def _sa_prefill_attend(q, k, v, n, sel):
 
 # name -> (function, [(shape, dtype), ...]); () is a traced scalar
 CASES = {
-    # the decode step's index walk: 32 slots of 1,280 index pages
+    # the decode step's index scores: 32 slots' one row each over the
+    # per-slot plane, ten blocks of 2,048 positions a slot: [32, 20480]
     "keye_index_walk_b32": (
-        _sa_index_walk, [((KY_B, KY_HI, 128), BF16), ((KY_B, KY_HI), F32),
-                         _KY_IX, ((KY_B, KY_MAXP), I32), ((KY_B,), I32)]),
+        _sa_index, [((KY_B, 1, KY_HI, KY_DI), BF16), ((KY_B, 1, KY_HI), F32),
+                    ((KY_B, KY_SEQ // 2, 128), BF16), ((KY_B,), I32)]),
     # its selection: 2,048 of up to 20,480 scores a slot (XLA, no kernel:
     # compiled with the walk it feeds)
     # the paged walk under the selection: K and V in one page of 8 head
@@ -259,11 +256,11 @@ CASES = {
         [((KY_B, 1, KY_HQ, D), BF16), _KY_KV, ((KY_B, KY_MAXP), I32),
          ((KY_B,), I32), ((KY_B, KY_SEQ), F32)]),
     # the admission: 256 query rows' index scores against the prompt's
-    # 16,384 index keys, and their attention under the selection
+    # 16,384 packed index keys (the same kernel, one "slot"), and their
+    # attention under the selection
     "keye_index_prefill_q256_t16384": (
-        _sa_index_prefill, [((256, KY_HI, KY_DI), BF16),
-                            ((256, KY_HI), F32), ((KY_P, KY_DI), BF16),
-                            ((), I32)]),
+        _sa_index, [((1, 256, KY_HI, KY_DI), BF16), ((1, 256, KY_HI), F32),
+                    ((1, KY_P // 2, 128), BF16), ((1,), I32)]),
     "keye_selected_prefill_q256_t16384": (
         _sa_prefill_attend, [((256, KY_HQ, D), BF16),
                              ((KY_HKV, KY_P, D), BF16),

@@ -101,8 +101,9 @@ def test_the_small_model_is_a_share_with_an_indexer(model):
 
 @pytest.mark.parametrize("backend,n0", [("xla", 10), ("xla", 37),
                                         ("flash", 10), ("flash", 37)])
-def test_prefill_then_decode_through_the_three_plane_pool(model, ids, want,
-                                                          backend, n0):
+def test_prefill_then_decode_through_the_pool_and_the_plane(model, ids,
+                                                            want, backend,
+                                                            n0):
     """Admission of an n0-token prompt into slot 1, then decode to 60
     positions beside an empty slot: every step's logits are the
     reference's full forward's. n0 = 10 admits with everything selected
@@ -146,14 +147,16 @@ def test_prefill_then_decode_through_the_three_plane_pool(model, ids, want,
 # (b) the two attends are one attention, and their sets the reference's
 # ----------------------------------------------------------------------
 
-def _layer_inputs(model, P_, key=3):
+def _layer_inputs(model, P_, key=3, batch=1, max_seq=MAX_SEQ):
     from triton_dist_tpu.models.kv_cache import IndexedSlotCache
     u = jax.random.normal(jax.random.key(key), (P_, CFG["hidden_size"]),
                           jnp.float32)
+    maxp = max_seq // PAGE
     pc = IndexedSlotCache.create_indexed(
-        1, 1, MAX_SEQ, n_kv_heads=2, head_dim=32, index_dim=16, page=PAGE,
-        num_pages=40, mesh=model.mesh, dtype=jnp.float32)
-    return u, pc, jnp.asarray(_rows(0, MAX_SEQ // PAGE))
+        1, batch, max_seq, n_kv_heads=2, head_dim=32, index_dim=16,
+        page=PAGE, num_pages=batch * maxp + 8, mesh=model.mesh,
+        dtype=jnp.float32)
+    return u, pc, jnp.asarray(_rows(0, maxp))
 
 
 @pytest.mark.parametrize("impl", ["ref", "flash"])
@@ -171,7 +174,7 @@ def test_decode_form_equals_prefill_form_and_the_references_sets(model,
                                 model.sin_i, p)
     whole, kv, ix, sets = attn.prefill(
         u, rope, rope_i, pc.pages_k[0], pc.pages_i[0], rows[:P_ // PAGE],
-        impl=impl, return_sets=True)
+        0, impl=impl, return_sets=True)
     w = ref.layer_weights_fn(CFG)(ref.layer_key(SEED, 0))
     want_out, want_sets = ref.attention(CFG, u, w)
     np.testing.assert_array_equal(np.asarray(sets), np.asarray(want_sets))
@@ -237,6 +240,123 @@ def test_select_topk_is_top_k_with_ties_to_the_lower_position():
 
 
 # ----------------------------------------------------------------------
+# (b') the index plane and the one kernel that scores it
+# ----------------------------------------------------------------------
+
+_BLOCK = 256        # the kernel's block in these tests (2,048 as served)
+
+
+def _index_case(values, key, S, M, Hi, d, P_):
+    """Queries, weights and keys of P_ positions a slot. "integers":
+    small whole numbers, a weight a power of two: every product and sum
+    is exact in float32 whatever their order (and would not be in
+    bfloat16: a dot reaches 16 x 49), so the kernel equals the oracle to
+    0.0 by what it computes and not by how XLA's CPU backend happens to
+    order or fuse a float sum, which is all that "normals" can differ
+    by (a few ulps of a score: 2e-6, relative and absolute)."""
+    ks = jax.random.split(jax.random.key(key), 3)
+    if values == "integers":
+        draw = lambda k, sh: jax.random.randint(  # noqa: E731
+            k, sh, -7, 8).astype(jnp.float32)
+        w = 2.0 ** jax.random.randint(ks[2], (S, M, Hi), -2, 3)
+        w = w * jnp.where(jnp.arange(Hi) % 3 == 1, -1.0, 1.0)
+    else:
+        draw = lambda k, sh: jax.random.normal(k, sh, jnp.float32)  # noqa
+        w = jax.random.normal(ks[2], (S, M, Hi), jnp.float32)
+    return (draw(ks[0], (S, M, Hi, d)), w, draw(ks[1], (S, P_, d)),
+            0.0 if values == "integers" else 2e-6)
+
+
+@pytest.mark.parametrize("d", [16, 64])
+@pytest.mark.parametrize("values", ["integers", "normals"])
+def test_index_scores_of_ragged_slots_in_one_call(monkeypatch, values, d):
+    """The decode step's shape: one query row a slot over the plane, the
+    slots' lengths ragged in ONE call: an empty slot, one key, one
+    short of a block's edge, the edge, one past it, a length that ends
+    in a block's first half, the full plane."""
+    from triton_dist_tpu.kernels import sparse_attn as sa
+    monkeypatch.setattr(sa, "INDEX_BLOCK", _BLOCK)
+    lens = [0, 1, _BLOCK - 1, _BLOCK, _BLOCK + 1, 2 * _BLOCK + 100,
+            4 * _BLOCK]
+    S, Hi, L = len(lens), 4, 4 * _BLOCK
+    qi, w, keys, tol = _index_case(values, 5, S, 1, Hi, d, L)
+    rows, lanes = sa.index_plane_shape(L, d)
+    assert (rows, lanes, sa.index_block(rows)) == (L // 2, 128, _BLOCK)
+    plane = jax.vmap(lambda k: sa.pack_index_keys(k, rows, lanes))(keys)
+    assert plane.shape == (S, rows, lanes)
+    got = np.asarray(jax.jit(lambda *a: sa.index_scores(*a, scale=0.125))(
+        qi, w, plane, jnp.asarray(lens, jnp.int32)))
+    assert got.shape == (S, 1, L)
+    for s_, n in enumerate(lens):
+        want = np.asarray(sa.index_scores_ref(qi[s_], w[s_], keys[s_, :n],
+                                              scale=0.125))
+        np.testing.assert_allclose(got[s_, :, :n], want, rtol=tol, atol=tol,
+                                   err_msg=f"slot {s_}, {n} keys")
+
+
+@pytest.mark.parametrize("kv_len", [_BLOCK, 2 * _BLOCK + 88, 3 * _BLOCK])
+@pytest.mark.parametrize("values", ["integers", "normals"])
+def test_index_scores_of_an_admission_block(monkeypatch, values, kv_len):
+    """The admission's shape: ONE "slot" of 256 query rows over a
+    prompt's packed keys, of which `kv_len` count: whole blocks, and a
+    last block that ends in its first half."""
+    from triton_dist_tpu.kernels import sparse_attn as sa
+    monkeypatch.setattr(sa, "INDEX_BLOCK", _BLOCK)
+    M, Hi, d, P_ = 256, 4, 16, 3 * _BLOCK - 40
+    qi, w, keys, tol = _index_case(values, 6, 1, M, Hi, d, P_)
+    kp = sa.pack_index_keys(keys[0], 4 * _BLOCK, 128)
+    assert kp.shape == (3 * _BLOCK // 2, 128)
+    got = np.asarray(jax.jit(lambda *a: sa.index_scores(*a, scale=0.125))(
+        qi, w, kp[None], jnp.asarray([kv_len], jnp.int32)))
+    n = min(kv_len, P_)
+    want = np.asarray(sa.index_scores_ref(qi[0], w[0], keys[0, :n],
+                                          scale=0.125))
+    np.testing.assert_allclose(got[0, :, :n], want, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("impl", ["ref", "flash"])
+def test_index_plane_round_trip(model, monkeypatch, impl):
+    """An admission's write into slot 1's run, then appends for three
+    slots at once: slot 0 from its first row, slot 1 across the packed
+    row's two halves (positions 120..131: 128 is where a block's second
+    half starts), slot 2 across a block's edge (250..261). Read back
+    position for position, every key is the one written, and what
+    nobody wrote is zero."""
+    from triton_dist_tpu.kernels import sparse_attn as sa
+    monkeypatch.setattr(sa, "INDEX_BLOCK", _BLOCK)
+    attn = model.layers[0].attn
+    B, P_, steps, max_seq = 3, 120, 12, 600
+    u, pc, rows = _layer_inputs(model, P_, key=12, batch=B, max_seq=max_seq)
+    assert pc.pages_i[0].shape == (B, 3 * _BLOCK // 2, 128)
+    maxp = max_seq // PAGE
+    table = jnp.asarray(np.stack([_rows(b, maxp) for b in range(B)]))
+    tables = lambda p: attn.rope_of(model.cos, model.sin,  # noqa: E731
+                                    model.cos_i, model.sin_i, p)
+    rope, rope_i = tables(jnp.arange(P_))
+    _, kv, ix = attn.prefill(u, rope, rope_i, pc.pages_k[0], pc.pages_i[0],
+                             table[1, :P_ // PAGE], 1, impl=impl)
+    want = np.zeros((B, 3 * _BLOCK, 16), np.float32)
+    want[1, :P_] = np.asarray(attn.project(u, rope, rope_i)[3])
+
+    @jax.jit
+    def step(a, u1, kv, ix, t):
+        r, ri = tables(t)
+        out = a.decode(u1, r, ri, kv, ix, table, t, impl=impl)
+        return out[1], out[2], a.project(u1, r, ri)[3]
+
+    start = np.array([0, P_, 250], np.int32)
+    for t in range(steps):
+        u1 = jax.random.normal(jax.random.key(100 + t),
+                               (B, CFG["hidden_size"]), jnp.float32)
+        kv, ix, ki = step(attn, u1, kv, ix, jnp.asarray(start + t))
+        want[np.arange(B), start + t] = np.asarray(ki)
+    got = np.asarray(sa.unpack_index_keys(ix, 16))
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+    # the lanes a 16-wide key does not fill stay zero
+    assert not np.asarray(ix).reshape(B, -1, 2, 64)[..., 16:].any()
+
+
+# ----------------------------------------------------------------------
 # (c) the three-section rotary on unequal components
 # ----------------------------------------------------------------------
 
@@ -281,7 +401,7 @@ def test_attention_on_multimodal_positions_matches_the_reference(model,
                                 model.sin_i, jnp.asarray(pos))
     got, _, _, sets = attn.prefill(
         u, rope, rope_i, pc.pages_k[0], pc.pages_i[0], rows[:P_ // PAGE],
-        impl=impl, return_sets=True)
+        0, impl=impl, return_sets=True)
     w = ref.layer_weights_fn(CFG)(ref.layer_key(SEED, 1))
     want_out, want_sets = ref.attention(CFG, u, w, positions=pos)
     np.testing.assert_array_equal(np.asarray(sets), np.asarray(want_sets))

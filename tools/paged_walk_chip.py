@@ -38,16 +38,20 @@ Three phases, each one JSON line per reading, `{"ok": true, ...}` last:
 - `sa` (only when asked for): learned sparse attention at Keye-VL-2.0's
   published widths and the cell's shapes (32 slots, contexts 16,384 to
   20,400 of max_seq 20,480, 2,048 selected): the decode step's three
-  pieces (the index walk of the pool's index plane, the selection, the
-  paged walk under it: K and V in one plane of 8 head rows) checked
+  pieces (the index scores of the per-slot index plane, the selection,
+  the paged walk under it: K and V in one plane of 8 head rows) checked
   against float32 with a short and an empty slot in the batch, then ms
   a call each over 6 chained calls (a step's six layers) beside the
   least time of their bytes; and one layer's 16,384-token admission
   (`SA_Attn.prefill`: index scores, selection and attention, 256 query
-  rows at a time), ms a call. Beside them, what the layout and the
-  selection were chosen against: the same walk over TWO planes (K and
-  V pages apart, two copies a page) under the same set, and the same
-  set by `jax.lax.top_k` and a scatter to the mask.
+  rows at a time), ms a call. Beside them, what the layouts and the
+  selection were chosen against: the index scores over keys PADDED to
+  128 lanes (256 B a position where the packed plane holds the
+  published 128) and at other blocks than the kernel's 2,048
+  positions, the plane's append packed (a row read, merged and written)
+  and as a plain row write, the same walk over TWO planes (K and V
+  pages apart, two copies a page) under the same set, and the same set
+  by `jax.lax.top_k` and a scatter to the mask.
 """
 
 from __future__ import annotations
@@ -311,10 +315,14 @@ def sa(rehearse: bool) -> None:
     """Learned sparse attention's kernels alone (module docstring)."""
     import jax
     import jax.numpy as jnp
+    from triton_dist_tpu.kernels import sparse_attn
     from triton_dist_tpu.kernels.paged_kv import (flash_decode_paged,
-                                                  gather_pages,
-                                                  index_scores_paged)
-    from triton_dist_tpu.kernels.sparse_attn import (index_scores_ref,
+                                                  gather_pages)
+    from triton_dist_tpu.kernels.sparse_attn import (append_index_keys,
+                                                     index_plane_shape,
+                                                     index_scores,
+                                                     index_scores_ref,
+                                                     pack_index_keys,
                                                      select_topk)
     from triton_dist_tpu.layers.sparse_attn import SA_Attn
     B, Hq, Hkv, Hi, di, topk = (4, 8, 2, 4, 16, 32) if rehearse else (
@@ -328,8 +336,13 @@ def sa(rehearse: bool) -> None:
     ks = jax.random.split(jax.random.PRNGKey(39), 8)
     kv = (jax.random.normal(ks[0], (NP, 2 * Hkv, PAGE, D), jnp.float32)
           * 0.5).astype(dt)
-    ix = jnp.pad((jax.random.normal(ks[1], (NP, 1, PAGE, di), jnp.float32)
-                  ).astype(dt), ((0, 0),) * 3 + ((0, 128 - di),))
+    # the index plane: every slot's keys in one run, two a row
+    rows_i, lanes = index_plane_shape(max_seq, di)
+    keys = jax.random.normal(ks[1], (B, 2 * rows_i, di), jnp.float32
+                             ).astype(dt)
+    pack = jax.jit(jax.vmap(lambda k: pack_index_keys(
+        k, *index_plane_shape(max_seq, k.shape[-1]))))
+    ix = pack(keys)
     table = jnp.asarray(1 + rng.permutation(NP - 1).reshape(B, maxp),
                         jnp.int32)
     lens = rng.randint(lo, hi + 1, size=B)
@@ -337,19 +350,20 @@ def sa(rehearse: bool) -> None:
     lens = jnp.asarray(lens, jnp.int32)
     q = (jax.random.normal(ks[2], (B, 1, Hq, D), jnp.float32) * 0.5
          ).astype(dt)
-    qi = jnp.pad(jax.random.normal(ks[3], (B, Hi, di), jnp.float32
-                                   ).astype(dt),
-                 ((0, 0), (0, 0), (0, 128 - di)))
+    qi = jax.random.normal(ks[3], (B, Hi, di), jnp.float32).astype(dt)
     w = jax.random.normal(ks[4], (B, Hi), jnp.float32)
     scale = di ** -0.5 * Hi ** -0.5
 
-    score = jax.jit(lambda qi, w, ix, t, n: index_scores_paged(
-        qi, w, ix, t, n, scale=scale))
+    def slot_scores(qi, w, ix, n):
+        return index_scores(qi[:, None], w[:, None], ix, n,
+                            scale=scale)[:, 0]
+
+    score = jax.jit(slot_scores)
     choose = jax.jit(lambda sc, n: select_topk(
         sc, jnp.arange(sc.shape[1])[None] < n[:, None], topk))
     walk = jax.jit(lambda q, kv, t, n, sel: flash_decode_paged(
         q, kv, None, t, None, kv_lens=n, fused=True, sel=sel))
-    sc = score(qi, w, ix, table, lens)
+    sc = score(qi, w, ix, lens)
     sel = choose(sc, lens)
     out = walk(q, kv, table, lens, sel)
     # against float32, a slot at a time (a slot's rows are 84 MB)
@@ -360,9 +374,8 @@ def sa(rehearse: bool) -> None:
         n = int(lens[b])
         if not n:
             continue
-        keys = gather_pages(ix[:, :, :, :di], table[b:b + 1])[0, 0, :n]
-        ref = index_scores_ref(qi[b:b + 1, :, :di].astype(jnp.float32),
-                               w[b:b + 1], keys.astype(jnp.float32),
+        ref = index_scores_ref(qi[b:b + 1].astype(jnp.float32),
+                               w[b:b + 1], keys[b, :n].astype(jnp.float32),
                                scale=scale)[0]
         got = sc[b, :n]
         worst["index"] = max(worst["index"],
@@ -410,7 +423,7 @@ def sa(rehearse: bool) -> None:
     outs = {}
     for impl in ("flash",) + (("ref",) if rehearse else ()):
         f = jax.jit(lambda u, kv, ix, impl=impl: attn.prefill(
-            u, rope, rope_i, kv, ix, pids, impl=impl)[0])
+            u, rope, rope_i, kv, ix, pids, 0, impl=impl)[0])
         t0 = time.perf_counter()
         outs[impl] = f(u, kv, ix).block_until_ready()
         first = time.perf_counter() - t0
@@ -447,24 +460,29 @@ def sa(rehearse: bool) -> None:
 
     # the admission's three pieces, one 256-row block against 16,384
     # keys (the scan runs 64 of them a layer)
-    from triton_dist_tpu.kernels.sparse_attn import (index_scores,
-                                                     selected_attention)
+    from triton_dist_tpu.kernels.sparse_attn import selected_attention
     M = min(256, P_)
     qb = (jax.random.normal(ks[2], (M, Hq, D), jnp.float32) * 0.5
           ).astype(dt)
     qib = jax.random.normal(ks[3], (M, Hi, di), jnp.float32).astype(dt)
     wb = jax.random.normal(ks[4], (M, Hi), jnp.float32)
-    kib = jax.random.normal(ks[5], (P_, di), jnp.float32).astype(dt)
+    kib = pack_index_keys(
+        jax.random.normal(ks[5], (P_, di), jnp.float32).astype(dt),
+        rows_i, lanes)[None]
     kT, vT = (jax.random.normal(k_, (Hkv, P_, D), jnp.float32) * 0.5
               for k_ in jax.random.split(ks[6], 2))
     kT, vT = kT.astype(dt), vT.astype(dt)
     c1 = jnp.int32(P_)
     causal = jnp.arange(P_)[None] <= (P_ - M + jnp.arange(M))[:, None]
-    scb = index_scores(qib, wb, kib, c1, scale=scale)
+
+    def block_scores(qi, w, k):
+        return index_scores(qi[None], w[None], k, c1[None],
+                            scale=scale)[0, :, :P_]
+
+    scb = block_scores(qib, wb, kib)
     selb = select_topk(scb, causal, topk)
     for name, t in (
-            ("index_block", ms(lambda qi, w, k: index_scores(
-                qi, w, k, c1, scale=scale), qib, wb, kib)),
+            ("index_block", ms(block_scores, qib, wb, kib)),
             ("select_block", ms(lambda sc: select_topk(
                 sc, causal, topk), scb)),
             ("attend_block", ms(lambda q, k, v, sel: selected_attention(
@@ -514,10 +532,51 @@ def sa(rehearse: bool) -> None:
         _log(phase="sa_time", kernel=f"two_plane_walk_w{bw or 'pick'}",
              ms_min=t[0], ms_med=t[1], positions_in_context=ctx,
              positions_attended=att)
-    for name, t, least in (
-            ("index_walk", ms(lambda qi, w, ix, t, n: index_scores_paged(
-                qi, w, ix, t, n, scale=scale), qi, w, ix, table, lens),
-             ctx * di * 2),
+    # the index scores: the plane as served, then (the record of why
+    # it is packed, and why its block is what it is) the key PADDED to
+    # 128 lanes, a row of two still: 256 B a position; and other blocks
+    # over the same bytes (random keys: any layout is as good)
+    k128 = pack(jnp.pad(keys, ((0, 0), (0, 0), (0, 128 - di))))
+    q128 = jnp.pad(qi, ((0, 0), (0, 0), (0, 128 - di)))
+    assert bool((score(q128, w, k128, lens) == sc)[live_cols].all())
+    index_times = [("index_walk", ms(slot_scores, qi, w, ix, lens)),
+                   ("index_walk_padded", ms(slot_scores, q128, w, k128,
+                                            lens))]
+    served = sparse_attn.INDEX_BLOCK
+    for blk in () if rehearse else (1024, 4096):
+        sparse_attn.INDEX_BLOCK = blk
+        try:
+            index_times.append((f"index_walk_block{blk}", ms(
+                slot_scores, qi, w, ix, lens)))
+        finally:
+            sparse_attn.INDEX_BLOCK = served
+
+    def per_append(fn, plane):
+        """ms an append over 24 chained ones, the plane carried."""
+        ki = keys[:, 0]
+
+        def chain(plane, pos):
+            return jax.lax.scan(
+                lambda c, _: ((fn(c[0], ki, c[1]), c[1] + 1), ()),
+                (plane, pos), None, length=24)[0][0]
+        f = jax.jit(chain, donate_argnums=0)
+        plane = f(plane, lens).block_until_ready()
+        ts = []
+        for _ in range(1 if rehearse else 5):
+            t0 = time.perf_counter()
+            plane = f(plane, lens).block_until_ready()
+            ts.append(time.perf_counter() - t0)
+        return round(min(ts) / 24 * 1e3, 5)
+
+    for name, t in (
+            ("append_packed", per_append(append_index_keys, ix + 0)),
+            ("append_row", per_append(
+                lambda plane, ki, pos: plane.at[jnp.arange(B), pos].set(
+                    jnp.pad(ki, ((0, 0), (0, 128 - di)))),
+                jnp.zeros((B, max_seq, 128), dt)))):
+        _log(phase="sa_time", kernel=name, ms_min=t, slots=B)
+    for name, t, least in tuple(
+            (name, t, ctx * di * 2) for name, t in index_times) + (
             ("select", ms(lambda sc, n: select_topk(
                 sc, jnp.arange(sc.shape[1])[None] < n[:, None], topk),
                 sc, lens), 0.0),

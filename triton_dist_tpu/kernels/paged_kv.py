@@ -560,107 +560,6 @@ def _flash_decode_paged_call(q, pages_k, pages_v, page_table, kv_len, *,
     return unfold(out)
 
 
-# Positions per block of the index walk: its pages carry 128 B of key a
-# position where a K/V page carries 2 KiB, so a block takes four times
-# the walk's positions to give the loop's fixed cost something to hide
-# behind (the copies are bound by their number either way: PERF.md).
-_INDEX_TILE = 512
-
-
-def _index_kernel(scale: float, page: int, maxp: int, s_ref, q_ref, w_ref,
-                  pool, o_ref, buf, sem):
-    """Grid (B,): one step scores ONE slot's whole context against its
-    indexer queries. q [1, Hi, d]; w [1, Hi, 1] f32; pool [NP, 1, page,
-    d] in HBM; o [1, 1, L] f32; buf [2, C*page, d]. s_ref holds the B
-    lengths, then the page table row by row (the paged walk's layout).
-
-    score(s) = scale * sum_j w_j relu(q_j . k_s), in float32. The walk
-    is the paged attention walk's (`_paged_kernel`): blocks of C pages,
-    the next block's copies started before this block's wait, the tail
-    clamped to the slot's last page so that no copy needs a branch, at
-    least one block so that every copy started is waited for. Blocks
-    past the slot's end are not written: the caller masks by length."""
-    b = pl.program_id(0)
-    B = pl.num_programs(0)
-    C = max(1, _INDEX_TILE // page)
-    CP = C * page
-    n_pages = (s_ref[b] + (page - 1)) // page
-    nblk = jnp.maximum((n_pages + (C - 1)) // C, 1)
-    last = jnp.maximum(n_pages - 1, 0)
-    row0 = B + b * maxp
-
-    def start(i, half):
-        for c in range(C):
-            pid = s_ref[row0 + jnp.minimum(i * C + c, last)]
-            pltpu.make_async_copy(
-                pool.at[pid, 0], buf.at[half, pl.ds(c * page, page)],
-                sem.at[half]).start()
-
-    start(0, 0)
-    q = q_ref[0]                                     # [Hi, d]
-    w = w_ref[0]                                     # [Hi, 1]
-
-    def block(i, carry):
-        half = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < nblk)
-        def _ahead():
-            start(i + 1, 1 - half)
-
-        # every copy of the block signals one semaphore by its bytes
-        pltpu.make_async_copy(buf.at[half], buf.at[half],
-                              sem.at[half]).wait()
-        s = jax.lax.dot_general(
-            q, buf[half], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [Hi, CP]
-        o_ref[0, :, pl.ds(pl.multiple_of(i * CP, CP), CP)] = jnp.sum(
-            jnp.maximum(s, 0.0) * w, axis=0, keepdims=True) * scale
-        return carry
-
-    jax.lax.fori_loop(0, nblk, block, 0)
-
-
-def index_scores_paged(qi, w, pages_i, page_table, kv_lens, *,
-                       scale: float):
-    """The indexer's scores of every cached position of every slot
-    (learned sparse attention, layers/sparse_attn.py), through the page
-    table: qi [B, Hi, d] (the pool's dtype), w [B, Hi] float32, pages_i
-    [NP, 1, page, d] (kv_cache.IndexedSlotCache's index plane: ONE key
-    head a position), page_table [B, maxp], kv_lens [B]. Returns
-    [B, L] float32, L the table's positions rounded up to the walk's
-    block; score[b, s] = scale * sum_j w[b, j] relu(qi[b, j] .
-    key[b, s]) for s < kv_lens[b], anything past it."""
-    B, Hi, d = qi.shape
-    NP, one, page, dk = pages_i.shape
-    assert one == 1 and dk == d, (pages_i.shape, qi.shape)
-    maxp = page_table.shape[1]
-    CP = max(1, _INDEX_TILE // page) * page
-    L = -(-maxp * page // CP) * CP
-    scalars = jnp.concatenate([jnp.asarray(kv_lens, jnp.int32),
-                               page_table.astype(jnp.int32).reshape(-1)])
-    out = pl.pallas_call(
-        functools.partial(_index_kernel, float(scale), page, maxp),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, Hi, d), lambda b, s_ref: (b, 0, 0)),
-                pl.BlockSpec((1, Hi, 1), lambda b, s_ref: (b, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, 1, L), lambda b, s_ref: (b, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((2, CP, d), pages_i.dtype),
-                            pltpu.SemaphoreType.DMA((2,))],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, 1, L), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=interpret_mode(),
-        name="sa_index_paged",
-    )(scalars, qi.astype(pages_i.dtype),
-      jnp.asarray(w, jnp.float32)[..., None], pages_i)
-    return out[:, 0]
-
-
 def set_page_rows(pool, pidx, r, u):
     """Write rows into a paged pool plane [NP, h, page(, d)]
     (kv_cache.PagedSlotCache): u [..., h(, d)] lands at in-page row

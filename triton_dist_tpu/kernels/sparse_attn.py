@@ -1,13 +1,29 @@
 """Learned sparse attention (the DeepSeek sparse-attention indexer, as
-Keye-VL-2.0's `sa_config` sizes it): the two pieces no paged walk has.
+Keye-VL-2.0's `sa_config` sizes it): the pieces no paged walk has.
 
     I[t, s] = scale * sum_j w[t, j] relu(qI[t, j] . kI[s])     (float32)
     S_t     = the `k` positions s <= t with the largest I[t, s]
 
-`index_scores` computes I for a block of query rows against CONTIGUOUS
-keys (a prompt being admitted); the decode step's keys lie in the paged
-pool's index plane and are scored by kernels/paged_kv.py
-`index_scores_paged`. `selected_attention` is the admission's attention
+The index keys are no part of the paged pool: a slot's keys lie in ONE
+RUN of its own plane (kv_cache.IndexedSlotCache `pages_i`, [slots, L /
+2, lanes]), addressed by (slot, position), two positions a row: within
+each block of T positions (`index_block`), row r holds position r in
+its first half of the lanes and position r + T / 2 in its second
+(`pack_index_keys`, `append_index_keys`, `unpack_index_keys`). A block of
+the plane is one contiguous copy of thousands of positions (256 KiB at
+T = 2,048 and 64-wide bf16 keys: the published 128 B a position), where
+a 16-position page was 4 KiB and the walk was bound by the NUMBER of
+its copies (PERF.md, PR 39 and PR 40).
+
+`index_scores` computes I for every slot's query rows against its run
+of keys: ONE kernel for both attends, the grid over (slots, blocks),
+BlockSpec-pipelined, each slot's length scalar-prefetched. The decode
+step calls it with every slot's one row over the plane; the admission
+with one "slot" of 256 query rows over the prompt's fresh keys, packed
+as the plane holds them (the same array is what the admission writes to
+the slot's rows). The queries are padded [q | 0] and [0 | q]: a row of
+two keys dotted with them gives each key's own score, and the zeros add
+exact zeros. `selected_attention` is the admission's attention
 under the sets: cached attention over contiguous K and V with an
 ADDITIVE mask a (query, key) pair (0 where chosen, -inf where not; the
 causal frontier is inside it), one mask for every head.
@@ -29,59 +45,159 @@ from jax.experimental.pallas import tpu as pltpu
 
 from triton_dist_tpu.runtime import interpret_mode
 
-_BT = 512          # key positions per grid step of `index_scores`
+# Positions per block of an index plane, and per grid step of
+# `index_scores`: 256 KiB of 64-wide bf16 keys a copy. A plane shorter
+# than this is one block.
+INDEX_BLOCK = 2048
+_HALF_LANES = 64   # a key's half of a row, in lanes: 128-lane rows
+_ROWS_STEP = 512   # keys per product of an admission's query rows
 _BITS = 2          # bits of the threshold settled per pass over a row
 
 
-def _index_scores_kernel(scale: float, len_ref, q_ref, w_ref, k_ref,
-                         o_ref):
-    """Grid (T / bt,): q [Hi, M, d], w [Hi, M, 1] f32, k [bt, d] ->
-    o [M, bt] f32. A tile wholly past `len_ref[0]` keys is skipped (its
+def index_plane_shape(max_seq: int, index_dim: int):
+    """(rows, lanes) of one slot's run of an index plane that holds
+    `max_seq` positions: `max_seq` rounded up to the kernel's block (to
+    256, one block, where it is shorter than INDEX_BLOCK), two positions
+    a row, a key in half a row."""
+    L = -(-max_seq // 256) * 256
+    if L > INDEX_BLOCK:
+        L = -(-max_seq // INDEX_BLOCK) * INDEX_BLOCK
+    return L // 2, 2 * -(-index_dim // _HALF_LANES) * _HALF_LANES
+
+
+def index_block(rows: int) -> int:
+    """T, the positions of a block of a plane of `rows` rows a slot."""
+    return min(INDEX_BLOCK, 2 * rows)
+
+
+def pack_index_keys(ki, rows: int, lanes: int):
+    """ki [P, d]: the keys of positions 0 .. P-1 -> [P' / 2, lanes], the
+    first rows of a slot's run in a plane of `rows` rows a slot (P' = P
+    rounded up to the plane's block; zeros past P and in the lanes a
+    key does not fill)."""
+    P_, d = ki.shape
+    block = index_block(rows)
+    h, w = block // 2, lanes // 2
+    nb = -(-P_ // block)
+    ki = jnp.pad(ki, ((0, nb * block - P_), (0, w - d)))
+    return ki.reshape(nb, 2, h, w).swapaxes(1, 2).reshape(nb * h, lanes)
+
+
+def append_index_keys(plane, ki, pos):
+    """ki [B, d], pos [B]: slot b's key of position pos[b] into its half
+    of its row of the plane [B, L / 2, lanes]. The row's other half is
+    another position's: the row is read, merged and written back, the
+    two leading dims indexed (the form that updates in place in the
+    served scan: kernels/paged_kv.py `set_page_rows`)."""
+    B, R, lanes = plane.shape
+    h, w = index_block(R) // 2, lanes // 2
+    off = pos % (2 * h)
+    slot, row = jnp.arange(B), pos // (2 * h) * h + off % h
+    new = jnp.pad(ki.astype(plane.dtype), ((0, 0), (0, w - ki.shape[1])))
+    mine = (jnp.arange(lanes) // w)[None] == (off // h)[:, None]
+    return plane.at[slot, row].set(
+        jnp.where(mine, jnp.tile(new, 2), plane[slot, row]))
+
+
+def unpack_index_keys(plane, d: int):
+    """The oracle's read of a plane [..., L / 2, lanes]: the keys by
+    position, [..., L, d]."""
+    lead, (R, lanes) = plane.shape[:-2], plane.shape[-2:]
+    h = index_block(R) // 2
+    k = plane.reshape(lead + (R // h, h, 2, lanes // 2))
+    return jnp.swapaxes(k, -3, -2).reshape(lead + (2 * R, lanes // 2))[
+        ..., :d]
+
+
+def _index_kernel(scale: float, len_ref, q_ref, w_ref, k_ref, o_ref):
+    """Grid (slots, L / T). q [1, 2 Hi M, lanes]: a slot's M query rows
+    of Hi heads, rows in (half, head, row) order, half 0 the queries as
+    [q | 0] and half 1 as [0 | q]; w [1, Hi M, 1] f32; k [1, T / 2,
+    lanes]: a block of the slot's run -> o [1, M, T] f32, the block's
+    first T / 2 positions then its second. A block wholly past the
+    slot's `len_ref` keys is skipped (the caller's index map asks for
+    the slot's last block again, so nothing is fetched either, and its
     output is whatever was there: the caller masks by length)."""
-    bt = k_ref.shape[0]
+    h = k_ref.shape[1]
+    M = o_ref.shape[1]
+    Hi = w_ref.shape[1] // M
+    left = len_ref[pl.program_id(0)] - pl.program_id(1) * 2 * h
+    dot = functools.partial(
+        jax.lax.dot_general,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-    @pl.when(pl.program_id(0) * bt < len_ref[0])
-    def _():
-        k = k_ref[...]
-        acc = jnp.zeros(o_ref.shape, jnp.float32)
-        for j in range(q_ref.shape[0]):
-            s = jax.lax.dot_general(
-                q_ref[j], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [M, bt]
-            acc = acc + jnp.maximum(s, 0.0) * w_ref[j]
-        o_ref[...] = acc * scale
+    if M == 1:
+        # a decode row: both halves' heads are the rows of ONE product
+        @pl.when(left > 0)
+        def _():
+            s = jnp.maximum(dot(q_ref[0], k_ref[0]), 0.0)    # [2 Hi, h]
+            for half in range(2):
+                o_ref[0, :, half * h:(half + 1) * h] = jnp.sum(
+                    s[half * Hi:(half + 1) * Hi] * w_ref[0], axis=0,
+                    keepdims=True) * scale
+        return
+
+    # an admission's rows: a head at a time over _ROWS_STEP keys, each
+    # step skipped alone once it lies past the keys that count
+    step = min(h, _ROWS_STEP)
+    for p0 in range(0, 2 * h, step):
+        @pl.when(left > p0)
+        def _(p0=p0):
+            k = k_ref[0, p0 % h:p0 % h + step]
+            acc = jnp.zeros((M, step), jnp.float32)
+            for j in range(Hi):
+                r0 = (p0 // h * Hi + j) * M
+                acc = acc + jnp.maximum(
+                    dot(q_ref[0, r0:r0 + M], k), 0.0
+                ) * w_ref[0, j * M:(j + 1) * M]
+            o_ref[0, :, p0:p0 + step] = acc * scale
 
 
-def index_scores(qi, w, ki, kv_len, *, scale: float):
-    """qi [M, Hi, d] and ki [T, d] (one dtype), w [M, Hi] float32,
-    kv_len: traced scalar, the keys that count. Returns [M, T'] float32
-    (T' = T rounded up to the key tile): score[t, s] for s < kv_len,
-    anything past it. No causal mask: the caller's selection has it."""
-    M, Hi, d = qi.shape
-    T = ki.shape[0]
-    bt = min(_BT, T)
-    Tp = -(-T // bt) * bt
-    if Tp != T:
-        ki = jnp.pad(ki, ((0, Tp - T), (0, 0)))
+def index_scores(qi, w, keys, kv_lens, *, scale: float):
+    """qi [S, M, Hi, d] (the keys' dtype), w [S, M, Hi] float32, keys
+    [S, L / 2, lanes]: each slot's run of an index plane (or a prompt's
+    `pack_index_keys`), kv_lens [S]: the keys that count of each.
+    Returns [S, M, L] float32: score[s, m, p] = scale * sum_j w[s, m, j]
+    relu(qi[s, m, j] . key[s, p]) for p < kv_lens[s], anything past the
+    slot's last block with keys. No causal mask: the caller's selection
+    has it."""
+    S, M, Hi, d = qi.shape
+    _, R, lanes = keys.shape
+    T = index_block(R)
+    h = T // 2
+    # rows (half, head, query row): [q | 0] then [0 | q]
+    qt = jnp.swapaxes(qi, 1, 2).astype(keys.dtype)
+    q2 = jnp.stack([jnp.pad(qt, ((0, 0),) * 3 + ((off, lanes - off - d),))
+                    for off in (0, lanes // 2)], 1)
+    wt = jnp.swapaxes(jnp.asarray(w, jnp.float32), 1, 2)
+
+    def blk(s, t, n):
+        # past the slot's last block with keys that block is asked for
+        # again: the pipeline elides the copy, and the write-back
+        return jnp.minimum(t, jnp.maximum((n[s] + T - 1) // T - 1, 0))
+
     return pl.pallas_call(
-        functools.partial(_index_scores_kernel, float(scale)),
+        functools.partial(_index_kernel, float(scale)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(Tp // bt,),
+            grid=(S, R // h),
             in_specs=[
-                pl.BlockSpec((Hi, M, d), lambda t, n: (0, 0, 0)),
-                pl.BlockSpec((Hi, M, 1), lambda t, n: (0, 0, 0)),
-                pl.BlockSpec((bt, d), lambda t, n: (t, 0))],
-            out_specs=pl.BlockSpec((M, bt), lambda t, n: (0, t)),
+                pl.BlockSpec((1, 2 * Hi * M, lanes),
+                             lambda s, t, n: (s, 0, 0)),
+                pl.BlockSpec((1, Hi * M, 1), lambda s, t, n: (s, 0, 0)),
+                pl.BlockSpec((1, h, lanes),
+                             lambda s, t, n: (s, blk(s, t, n), 0))],
+            out_specs=pl.BlockSpec((1, M, T),
+                                   lambda s, t, n: (s, 0, blk(s, t, n))),
         ),
-        out_shape=jax.ShapeDtypeStruct((M, Tp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((S, M, 2 * R), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret_mode(),
         name="sa_index",
-    )(jnp.asarray(kv_len, jnp.int32).reshape(1),
-      jnp.swapaxes(qi, 0, 1),
-      jnp.swapaxes(jnp.asarray(w, jnp.float32), 0, 1)[..., None], ki)
+    )(jnp.asarray(kv_lens, jnp.int32).reshape(S),
+      q2.reshape(S, 2 * Hi * M, lanes), wt.reshape(S, Hi * M, 1), keys)
 
 
 def index_scores_ref(qi, w, ki, *, scale: float):

@@ -17,23 +17,26 @@ indexer's rotary runs over all di dims at the first component. Both
 pair dim i with dim i + half (half-split).
 
 One class, TWO attends (tests/test_keye_vl2.py ties them on the same
-rows), and three planes of the paged pool (kv_cache.IndexedSlotCache):
+rows), over kv_cache.IndexedSlotCache: two planes of a position under
+the page table (K and V, in one array) and one plane a SLOT beside them
+(the index keys, a slot's in one run: kernels/sparse_attn.py has the
+layout). Both attends score through the one kernel, `index_scores`.
 
 - `prefill`: a whole prompt, 256 query rows at a time: the rows'
-  scores against the prompt's index keys (kernels/sparse_attn.py
-  `index_scores`), their sets (`select_topk`), and attention over the
-  prompt's own K and V under those sets (`selected_attention`: the
-  mask is one add a score tile). The [k | v] rows and the index keys
-  go to the pool a page at a time.
-- `decode`: one token a slot: its row and index key are appended, the
-  index plane of the slot's whole context is scored through the page
-  table (kernels/paged_kv.py `index_scores_paged`), the set is chosen,
-  and the paged walk attends under it (`flash_decode_paged`, `fused`,
-  `sel`). The walk reads every page of the context and masks what was
-  not chosen: a selected position costs its whole page's copy either
-  way at 2,048 of ~18,000 positions (a page of 16 holds a chosen
-  position with probability 0.85), and a copy a POSITION is bound by
-  its issue, not its bytes (PERF.md, PR 39).
+  scores against the prompt's index keys, their sets (`select_topk`),
+  and attention over the prompt's own K and V under those sets
+  (`selected_attention`: the mask is one add a score tile). The
+  [k | v] rows go to the pool a page at a time, the packed index keys
+  to the slot's rows of the index plane in one update.
+- `decode`: one token a slot: its row is appended to its page and its
+  index key to the slot's run, the runs of all slots are scored, the
+  set is chosen, and the paged walk attends under it
+  (`flash_decode_paged`, `fused`, `sel`). The walk reads every page of
+  the context and masks what was not chosen: a selected position costs
+  its whole page's copy either way at 2,048 of ~18,000 positions (a
+  page of 16 holds a chosen position with probability 0.85), so it is
+  bound by the whole context's BYTES; a copy a POSITION would be bound
+  by its issue instead (PERF.md, PR 39 and PR 40).
 
 Single chip: the mesh axis must have size 1 (the model refuses a wider
 one by name).
@@ -48,14 +51,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from triton_dist_tpu.kernels.paged_kv import (flash_decode_paged,
-                                              gather_pages,
-                                              index_scores_paged,
-                                              set_page_rows)
+                                              gather_pages, set_page_rows)
 from triton_dist_tpu.kernels.quant import qmm
-from triton_dist_tpu.kernels.sparse_attn import (index_scores,
+from triton_dist_tpu.kernels.sparse_attn import (append_index_keys,
+                                                 index_scores,
                                                  index_scores_ref,
+                                                 pack_index_keys,
                                                  select_topk,
-                                                 selected_attention)
+                                                 selected_attention,
+                                                 unpack_index_keys)
 from triton_dist_tpu.layers.common import rms_norm, rope_rows
 
 _PREFILL_Q = 256      # query rows per attention call of a prefill
@@ -135,28 +139,25 @@ class SA_Attn:
         return (rope_rows(cos, sin, positions, self.sections),
                 (cos_i[first], sin_i[first]))
 
-    def _key_rows(self, ki, lanes: int):
-        """ki [M, di] -> the index plane's rows [M, 1, lanes]."""
-        pad = jnp.zeros((ki.shape[0], lanes - self.idx_dim), ki.dtype)
-        return jnp.concatenate([ki, pad], axis=-1)[:, None, :]
-
     def _out(self, o):
         return qmm(o.reshape(o.shape[0], -1).astype(self.w_o.dtype),
                    self.w_o)
 
     # -- prefill: one prompt --------------------------------------------
 
-    def prefill(self, u, rope, rope_i, kv_pool, idx_pool, page_ids, *,
-                impl: str, return_sets: bool = False):
+    def prefill(self, u, rope, rope_i, kv_pool, idx_pool, page_ids, slot,
+                *, impl: str, return_sets: bool = False):
         """u [P, D]: a prompt at positions 0 .. P-1 (its bucket: rows
         past the prompt's end are padding); rope / rope_i as `rope_of`
         gives them; page_ids [ceil(P / page)]: the page of each 16
-        positions, the trash page for a page wholly past the prompt.
-        Writes the rows and the index keys, a PAGE at a time (a prompt
-        starts at a page's first row; what the padding leaves in the
-        last page's tail lies past the slot's length until decode
-        overwrites it), and returns (attention output [P, D], kv_pool,
-        idx_pool[, the selection [P, P] bool])."""
+        positions, the trash page for a page wholly past the prompt;
+        slot: traced scalar, whose run of the index plane the prompt's
+        keys open. Writes the [k | v] rows a PAGE at a time and the
+        index keys a BLOCK at a time (a prompt starts at a page's, and
+        the run's, first row; what the padding leaves behind the prompt
+        lies past the slot's length until decode overwrites it), and
+        returns (attention output [P, D], kv_pool, idx_pool[, the
+        selection [P, P] bool])."""
         P_ = u.shape[0]
         page = kv_pool.shape[2]
         Hkv, d = self.n_kv_heads, self.head_dim
@@ -168,8 +169,12 @@ class SA_Attn:
             return rows.reshape((n, page) + rows.shape[1:]).swapaxes(1, 2)
 
         kv_pool = kv_pool.at[page_ids].set(paged(kv).astype(kv_pool.dtype))
-        idx_pool = idx_pool.at[page_ids].set(paged(self._key_rows(
-            ki, idx_pool.shape[-1])).astype(idx_pool.dtype))
+        # the prompt's keys as the plane holds them: what the slot's
+        # run opens with, and what the blocks below score against
+        kp = pack_index_keys(ki.astype(idx_pool.dtype),
+                             *idx_pool.shape[1:])
+        idx_pool = jax.lax.dynamic_update_slice(idx_pool, kp[None],
+                                                (slot, 0, 0))
         t = jnp.arange(P_)
         if impl == "ref":
             with jax.named_scope("sa_index"):
@@ -196,7 +201,6 @@ class SA_Attn:
         padq = lambda a: jnp.pad(a, ((0, Pp - P_),) + ((0, 0),) * (  # noqa
             a.ndim - 1))
         kt = jnp.swapaxes(padq(kv), 0, 1)               # [2 Hkv, Pp, d]
-        kiP = padq(ki)
         col = jnp.arange(Pp)
 
         def block(_, xs):
@@ -206,10 +210,10 @@ class SA_Attn:
 
             def choose():
                 with jax.named_scope("sa_index"):
-                    sc = index_scores(qib, wb, kiP, c1,
-                                      scale=self.index_scale)
+                    sc = index_scores(qib[None], wb[None], kp[None],
+                                      c1[None], scale=self.index_scale)
                 with jax.named_scope("sa_topk"):
-                    return select_topk(sc[:, :Pp], causal, self.topk)
+                    return select_topk(sc[0, :, :Pp], causal, self.topk)
 
             # a block whose rows all see topk keys or fewer attends
             # every one of them: nothing to score, nothing to choose
@@ -245,23 +249,17 @@ class SA_Attn:
         q, kv, qi, ki, w = self.project(u, rope, rope_i)
         pidx = table[jnp.arange(B), pos // page]
         kv_pool = set_page_rows(kv_pool, pidx, pos % page, kv)
-        lanes = idx_pool.shape[-1]
-        idx_pool = set_page_rows(idx_pool, pidx, pos % page,
-                                 self._key_rows(ki, lanes))
+        idx_pool = append_index_keys(idx_pool, ki, pos)
         lens = pos + 1
-        if impl == "ref":
-            with jax.named_scope("sa_index"):
-                keys = gather_pages(idx_pool, table)[:, 0, :, :self.idx_dim]
+        with jax.named_scope("sa_index"):
+            if impl == "ref":
+                keys = unpack_index_keys(idx_pool, self.idx_dim)
                 sc = jax.vmap(lambda a, b, c: index_scores_ref(
                     a[None], b[None], c, scale=self.index_scale)[0])(
                         qi, w, keys)
-        else:
-            with jax.named_scope("sa_index"):
-                qp = jnp.concatenate(
-                    [qi, jnp.zeros((B, self.idx_heads,
-                                    lanes - self.idx_dim), qi.dtype)], -1)
-                sc = index_scores_paged(qp, w, idx_pool, table, lens,
-                                        scale=self.index_scale)
+            else:
+                sc = index_scores(qi[:, None], w[:, None], idx_pool, lens,
+                                  scale=self.index_scale)[:, 0]
         with jax.named_scope("sa_topk"):
             sel = select_topk(
                 sc, jnp.arange(sc.shape[1])[None] < lens[:, None],

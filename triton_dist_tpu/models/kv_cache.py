@@ -35,6 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from triton_dist_tpu.kernels.sparse_attn import index_plane_shape
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
@@ -391,21 +393,31 @@ class LatentSlotCache(PagedSlotCache):
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class IndexedSlotCache(PagedSlotCache):
-    """The paged pool of a model with learned sparse attention
-    (layers/sparse_attn.py; models/qwen_moe.py with `sa_config`): THREE
-    planes of a position under ONE page table.
+    """The slot cache of a model with learned sparse attention
+    (layers/sparse_attn.py; models/qwen_moe.py with `sa_config`): TWO
+    planes of a position under the page table and ONE plane a slot
+    beside them.
 
     `pages_k[l]` [NP, 2 Hkv, page, d] holds K AND V: a page's first Hkv
     head rows are the slot's keys, its last Hkv its values, so the
     decode walk fetches both with one copy a page (the walk is bound by
     the copies it issues, PERF.md PR 35) and the append writes both
-    with one scatter; `pages_v` is empty, as a latent pool's.
-    `pages_i[l]` [NP, 1, page, lanes] is the indexer's cache: ONE key
-    head a position, `index_dim` values padded to the chip's 128-lane
-    HBM tile (a 64-wide plane takes 128 lanes there whether the shape
-    says so or not). A page id means the same 16 positions in all three.
-    Pages, the table, the allocator, retire-to-trash and `clear_slot`
-    are PagedSlotCache's own; a slot's whole context is its pages."""
+    with one scatter; `pages_v` is empty, as a latent pool's. Pages, the
+    table, the allocator, retire-to-trash and `clear_slot` are
+    PagedSlotCache's own.
+    `pages_i[l]` [B, L / 2, lanes] is the indexer's cache, per-slot
+    state beside the pages as HybridSlotCache's rings are: ONE key head
+    a position, a slot's keys in one run addressed by (slot, position),
+    two positions a row (kernels/sparse_attn.py has the layout; L is
+    `max_seq` rounded up to the kernel's block), so that the indexer
+    reads a slot's context in blocks of thousands of positions and not
+    a 16-position page at a time. At `index_dim` 64 in bfloat16 a row
+    is the chip's 128-lane tile and a position the published 128 B.
+    The model shares no index keys between slots (prefix reuse, forks,
+    the host tier and disaggregation are refused by name), so no table
+    stands before the plane; a slot's rows need no clearing either: a
+    later occupant's admission overwrites what its length lets it
+    read."""
 
     pages_i: Tuple[jax.Array, ...] = ()
     index_dim: int = dataclasses.field(default=0,
@@ -416,7 +428,6 @@ class IndexedSlotCache(PagedSlotCache):
                        n_kv_heads: int, head_dim: int, index_dim: int,
                        page: int, num_pages: int, mesh: Mesh,
                        dtype=jnp.bfloat16) -> "IndexedSlotCache":
-        lanes = -(-index_dim // LATENT_LANES) * LATENT_LANES
         maxp = -(-max_seq // page)
         rep = NamedSharding(mesh, P())
 
@@ -428,7 +439,8 @@ class IndexedSlotCache(PagedSlotCache):
         return IndexedSlotCache(
             pages_k=planes((num_pages, 2 * n_kv_heads, page, head_dim)),
             pages_v=(), table=table,
-            pages_i=planes((num_pages, 1, page, lanes)),
+            pages_i=planes((batch,) + index_plane_shape(max_seq,
+                                                        index_dim)),
             index_dim=index_dim)
 
     @property
@@ -437,9 +449,12 @@ class IndexedSlotCache(PagedSlotCache):
 
     def slot_bytes(self) -> dict:
         """Bytes a mapped page holds over all layers: its K and V rows
-        (kind "pages") and its index keys as published (kind "index":
-        `index_dim` values a position, whatever the plane pads them
-        to). A uniform cache is the K and V rows alone."""
+        (kind "pages") and, kind "index", the index keys of ITS
+        positions as published (`index_dim` values a position): the
+        index plane is per-slot state, but the gauge goes on counting
+        the positions the live slots have mapped, not the plane's
+        `max_seq` rows a slot. A uniform cache is the K and V rows
+        alone."""
         L, item = len(self.pages_k), self.pages_k[0].dtype.itemsize
         kv = L * int(np.prod(self.pages_k[0].shape[1:])) * item
         return {"page": kv, "uniform_page": kv,

@@ -40,10 +40,10 @@ dropless-or-loud, observable.
 LEARNED SPARSE ATTENTION (`config.sa_config`, Keye-VL-2.0's language
 model: this stack at its own numbers plus an indexer): the layers'
 attention is `SA_Attn` (layers/sparse_attn.py), every query attending
-the `topk` cached positions its indexer scores highest; the pool holds
-K and V in one plane and the indexer's keys in another under one page
-table (kv_cache.IndexedSlotCache), which only this model's programs
-read and write (`ServingTraits.own_pool`: it admits through
+the `topk` cached positions its indexer scores highest; the cache holds
+K and V in one plane under the page table and the indexer's keys in a
+per-slot plane beside it (kv_cache.IndexedSlotCache), which only this
+model's programs read and write (`ServingTraits.own_pool`: it admits through
 `admit_slot_paged` below, and whatever would move a slot through the
 Engine's K/V page programs is refused by name); the experts are a
 STATED SHARE (`config.held_experts`, `EP_MoE.fwd_share`): the chip
@@ -535,7 +535,8 @@ class Qwen3MoE:
 
     # ------------------------------------------------------------------
     # learned sparse attention (config.sa_config): construction, the
-    # decode tick and the admission over the three-plane pool
+    # decode tick and the admission over the K/V pool and the per-slot
+    # index plane
     # ------------------------------------------------------------------
 
     @staticmethod
@@ -628,8 +629,9 @@ class Qwen3MoE:
         """Sparse attention only (the Engine's own admission serves the
         other stacks). ids [1, P]: the prompt, zero-padded to its
         bucket; n: its real length; rows [maxp]: the slot's table row.
-        Installs the row, writes the prompt's K/V rows and index keys to
-        the slot's pages and returns (logits [1, V] of its last token,
+        Installs the row, writes the prompt's K/V rows to the slot's
+        pages and its index keys to the slot's run of the index plane,
+        and returns (logits [1, V] of its last token,
         pcache)."""
         impl = "ref" if mode == "xla" else "flash"
         page = pcache.page
@@ -645,7 +647,7 @@ class Qwen3MoE:
         for li, layer in enumerate(self.layers):
             u = rms_norm(x, layer.ln_attn, self.config.rms_norm_eps)
             a, kv[li], ix[li] = layer.attn.prefill(
-                u, *rope, kv[li], ix[li], pids, impl=impl)
+                u, *rope, kv[li], ix[li], pids, slot, impl=impl)
             x, _ = self._sa_ffn(layer, x + a)
         table = jax.lax.dynamic_update_slice(pcache.table, rows[None],
                                              (slot, 0))
