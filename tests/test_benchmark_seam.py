@@ -37,7 +37,14 @@ _STATS_KEYS = ("device_wait_s_by_kind", "host_phase_s", "tokens_emitted",
                "engine_decode_dispatches", "engine_prefill_dispatches",
                "ticks_dispatched_ahead", "serve_loop_iterations",
                "prompt_tokens", "prefill_tokens_skipped", "admissions",
-               "hits", "preemptions")
+               "hits", "preemptions",
+               # readers/compile_seconds.py sums the series of this
+               # form; `eager` is the role every process has
+               "program_compile_s{program=eager,stage=trace}",
+               "program_compile_s{program=eager,stage=lower}",
+               "program_compile_s{program=eager,stage=backend}",
+               "program_compile_n{program=eager,stage=backend}",
+               "program_compile_n{program=eager,stage=cache_load}")
 # the wrapped hooks a dispatch-ahead serve loop enters while it serves.
 # `_sock.accept` is the ACCEPTOR thread's call since PR 37: it reads
 # `srv._sock` anew every pass, so it picks up the proxy that annotate()
@@ -150,6 +157,59 @@ def test_wrapped_hooks_are_entered_while_serving(served):
     assert after["ticks_dispatched_ahead"] > before["ticks_dispatched_ahead"]
     assert set(served.lifecycle()) and all(
         ev[1] for evs in served.lifecycle().values() for ev in evs)
+
+
+def _compile_metric(name, stats0, stats1):
+    """`benchmark/metrics/<name>.json` through its reader, over a
+    capture that holds the two snapshots and nothing else."""
+    import importlib
+    import types
+    with open(os.path.join(_REPO, "benchmark", "metrics",
+                           name + ".json")) as f:
+        m = json.load(f)
+    assert m["reader"] == "compile_seconds", name
+    reader = importlib.import_module("benchmark.readers." + m["reader"])
+    cap = types.SimpleNamespace(stats0=stats0, stats1=stats1)
+    return reader.read(cap, **m["args"])
+
+
+def test_compile_seconds_reads_the_fixtures_own_stats(served):
+    """The six `setup.*` / `serve.compile_in_window_ms.*` metric files
+    over the adapter's own `stats()`: at set-up each reads a number
+    (the tiny server's build traced, lowered and compiled something,
+    under an engine role too once a request has run), a window in
+    which nothing compiles reads 0.0 and not None, and a program that
+    keeps no such counters reads None."""
+    from benchmark.systems.token_server import request
+    msgs = list(request(served.host, served.port, [2, 3, 5, 7, 11], 4,
+                        300.0))
+    assert msgs[-1].get("done") and not msgs[-1].get("error"), msgs[-1]
+    st = served.stats()
+    at_setup = {name: _compile_metric(name, st, st) for name in (
+        "setup.trace_s", "setup.lower_s", "setup.backend_s",
+        "setup.cache_hit_pct", "setup.programs_n")}
+    print("compile_seconds at set-up:", at_setup)
+    for name in ("setup.trace_s", "setup.lower_s", "setup.backend_s"):
+        assert at_setup[name] > 0.0, name
+    assert 0.0 <= at_setup["setup.cache_hit_pct"] <= 100.0
+    # the admission, the decode scan, the table reset at least
+    assert at_setup["setup.programs_n"] >= 3
+    assert at_setup["setup.programs_n"] == sum(
+        v for k, v in st.items()
+        if k.startswith("program_compile_n{") and "stage=backend" in k
+        and "program=eager" not in k)
+    for name in ("serve.compile_in_window_ms.sat",
+                 "serve.compile_in_window_ms.steady"):
+        assert _compile_metric(name, st, dict(st)) == 0.0
+        grown = dict(st)
+        grown["program_compile_s{program=paged_admit,stage=backend}"] = \
+            st.get("program_compile_s{program=paged_admit,stage=backend}",
+                   0.0) + 0.25
+        assert _compile_metric(name, st, grown) == pytest.approx(250.0)
+    older = {k: v for k, v in st.items()
+             if not k.startswith("program_compile_")}
+    for name in list(at_setup) + ["serve.compile_in_window_ms.sat"]:
+        assert _compile_metric(name, older, older) is None, name
 
 
 # ----------------------------------------------------------------------

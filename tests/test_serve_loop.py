@@ -251,8 +251,13 @@ def test_overflow_is_answered_busy_through_the_inbox(tiny):
     gate = _HeldPoll(srv)
     long_ = [("first occupant", 40), ("2nd occupant", 40)]
     with _serving(srv):
-        held = [_raw(srv, p, g) for p, g in long_]
-        first = [json.loads(f.readline()) for _, f in held]
+        # one occupant at a time: two parsed before the loop's first
+        # intake (a server thread slow to start, on a loaded machine)
+        # would meet max_queue=1 themselves
+        held, first = [], []
+        for p, g in long_:
+            held.append(_raw(srv, p, g))
+            first.append(json.loads(held[-1][1].readline()))
         assert all(m.get("token_ids") for m in first), first
         gate.hold()
         sq, fq = _raw(srv, "queued behind them", 6)

@@ -26,7 +26,8 @@ import pytest
 from triton_dist_tpu.models import (AutoLLM, ContinuousScheduler, Engine,
                                     Request)
 from triton_dist_tpu.models.config import tiny_qwen3
-from triton_dist_tpu.runtime.telemetry import (Counter, Gauge, Histogram,
+from triton_dist_tpu.runtime.telemetry import (COMPILE_STAGES, Counter,
+                                               Gauge, Histogram,
                                                MetricsRegistry, Telemetry,
                                                prometheus_text)
 
@@ -485,6 +486,11 @@ def test_token_server_telemetry_surfacing(tmp_path, monkeypatch):
     assert reply["done"] is True
     assert reply["stats"]["ttft_ms"]["count"] == 3
     assert reply["stats"]["requests_retired"] == 3
+    # the compile accounting, flat as host_phase_s is
+    for key in ("program_compile_s", "program_compile_n"):
+        assert set(st[key]) == set(reply["stats"][key])
+        assert {"eager/" + stage for stage in
+                ("trace", "lower", "backend", "cache_load")} <= set(st[key])
 
     # Prometheus text exposition over the metrics listener
     with socket.create_connection(("127.0.0.1", srv.metrics_port),
@@ -501,8 +507,13 @@ def test_token_server_telemetry_surfacing(tmp_path, monkeypatch):
     text = body.decode()
     assert 'tdtpu_ttft_ms_bucket{le="+Inf"} 3' in text
     assert "tdtpu_requests_retired 3" in text
-    # the process-global registry rides along (Engine dispatch mix)
+    # the process-global registry rides along (Engine dispatch mix,
+    # the compile accounting)
     assert "tdtpu_engine_prefill_dispatches" in text
+    assert 'tdtpu_program_compile_s{program="eager",stage="backend"}' \
+        in text
+    assert 'tdtpu_program_compile_n{program="eager",stage="cache_load"}' \
+        in text
 
     srv.stop()
     th.join(timeout=60)
@@ -522,6 +533,8 @@ def test_token_server_telemetry_surfacing(tmp_path, monkeypatch):
             and "first_token" in kinds and kinds[-1] == "retired"
         assert req["ttft_ms"] is not None
     assert dump["metrics"]["ttft_ms"]["count"] == 3
+    assert "program_compile_n{program=eager,stage=backend}" \
+        in dump["metrics"]
 
     # ... and tools/trace_view.py can summarize it
     import importlib.util
@@ -533,6 +546,7 @@ def test_token_server_telemetry_surfacing(tmp_path, monkeypatch):
     spec.loader.exec_module(tv)
     text = tv.summarize(dump, top_k=3)
     assert "poll" in text and "ttft" in text.lower()
+    assert "eager" in tv.analyze(dump)["programs"]
 
 
 # ----------------------------------------------------------------------
@@ -673,3 +687,335 @@ def test_served_streams_and_programs_same_trace_on_off(served_pair):
         == served_pair["gen_lens"]
     assert not served_pair["compiled"], (
         f"the traced server compiled {served_pair['compiled']}")
+
+
+# ----------------------------------------------------------------------
+# compile accounting: jax's own trace / lower / backend events as
+# program_compile_s / _n per role of the program dispatched, and as
+# compile:<role> spans on a traced ring
+# ----------------------------------------------------------------------
+
+def _compile_series():
+    """{(role, stage): (seconds, events)} of the default registry."""
+    from triton_dist_tpu.runtime.telemetry import \
+        install_compile_accounting
+    secs, n = install_compile_accounting().totals()
+    return {tuple(k.split("/")): (secs[k], n[k]) for k in secs}
+
+
+def _grown(before, after):
+    """The series that moved: {(role, stage): (seconds, events)}."""
+    out = {}
+    for key, (s1, n1) in after.items():
+        s0, n0 = before.get(key, (0.0, 0))
+        if (s1, n1) != (s0, n0):
+            out[key] = (s1 - s0, n1 - n0)
+    return out
+
+
+def _prompt(cfg, length, seed):
+    rng = np.random.RandomState(seed)
+    return rng.randint(0, cfg.vocab_size, size=(length,)).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def cold_engine():
+    """An engine whose shapes no other test of this file has compiled
+    (max_seq 40), and a traced and an untraced scheduler over it, both
+    alive while the first request compiles through the traced one:
+    per request what grew in the compile series, the wall seconds of
+    the call, and the names jax logged as compiled."""
+    import time
+
+    cfg = tiny_qwen3(mesh.shape["tp"])
+    eng = Engine(AutoLLM.from_config(cfg, mesh), max_seq=40,
+                 backend="xla")
+    kw = dict(batch=2, chunk=4, paged=True, page=8, prefix_cache=False)
+    on = ContinuousScheduler(eng, trace=True, **kw)
+    off = ContinuousScheduler(eng, trace=False, **kw)
+
+    handler = _CompileCounter()
+    logger = logging.getLogger("jax._src.interpreters.pxla")
+    logger.addHandler(handler)
+    prev = jax.config.jax_log_compiles
+    jax.config.update("jax_log_compiles", True)
+    runs = []
+    try:
+        # two prompts of 5 ids (one suffix bucket), then one of 13
+        for rid, length in enumerate((5, 5, 13)):
+            before, seen = _compile_series(), len(handler.names)
+            t0 = time.time()
+            got = on.run([Request(rid=rid, ids=_prompt(cfg, length, rid),
+                                  gen_len=6, seed=rid)])
+            runs.append(dict(grown=_grown(before, _compile_series()),
+                             wall=time.time() - t0,
+                             names=handler.names[seen:],
+                             tokens=len(got[rid])))
+    finally:
+        jax.config.update("jax_log_compiles", prev)
+        logger.removeHandler(handler)
+    return dict(cfg=cfg, eng=eng, on=on, off=off, runs=runs)
+
+
+def test_first_request_compiles_under_its_programs_roles(cold_engine):
+    """The admission and the decode scan each traced, lowered and
+    compiled under their own role; no stage took longer than the call,
+    nor did all of them together (they partition the compile time)."""
+    run = cold_engine["runs"][0]
+    assert run["tokens"] == 6
+    for role in ("paged_admit", "paged_slot_scan"):
+        for stage in ("trace", "lower", "backend"):
+            secs, n = run["grown"][(role, stage)]
+            assert 0.0 < secs < run["wall"], (role, stage, secs)
+            assert n >= 1
+    assert sum(s for (_, stage), (s, _) in run["grown"].items()
+               if stage != "cache_load") < run["wall"]
+    # the eager pad and the slot's arming compile outside any program
+    assert run["grown"][("eager", "backend")][1] >= 1
+
+
+def test_same_shape_again_compiles_nothing(cold_engine):
+    """A second prompt of the same length: nothing lowered, compiled or
+    loaded under any role. (On the CPU an interpreter callback keeps
+    the admission off jit's C++ fast path, so each dispatch re-enters
+    jax's tracing cache: one trace event of microseconds, no more.)"""
+    grown = cold_engine["runs"][1]["grown"]
+    assert not [k for k in grown if k[1] != "trace"], grown
+    assert set(grown) <= {("paged_admit", "trace")}, grown
+    assert grown.get(("paged_admit", "trace"), (0.0, 0))[0] < 0.05
+    assert not cold_engine["runs"][1]["names"]
+
+
+def test_new_prompt_length_compiles_its_admission_only(cold_engine):
+    """13 ids pad to another suffix bucket: one more admission program
+    and the eager pad's tiny ones; the decode scan is the one it was."""
+    grown = cold_engine["runs"][2]["grown"]
+    assert grown[("paged_admit", "backend")][1] == 1
+    assert grown[("paged_admit", "lower")][0] > 0.0
+    assert grown[("eager", "backend")][1] >= 1
+    assert not [k for k in grown if k[0] == "paged_slot_scan"], grown
+
+
+def test_nested_jit_counts_the_union_not_the_sum():
+    """A program that calls a jitted helper twenty times: jax fires the
+    helper's trace events inside the outer one. The role's trace
+    seconds stay under the call's wall time and under the plain sum of
+    the events; the stages together stay under the wall time too."""
+    import time
+
+    import jax.monitoring
+    import jax.numpy as jnp
+
+    from triton_dist_tpu.runtime.telemetry import register_program_roles
+
+    @jax.jit
+    def helper(x, k):
+        return jnp.tanh(x * k) + jnp.roll(x, 1)
+
+    def _probe_nested_fn(x):
+        for i in range(20):
+            x = helper(x[: x.shape[0] - 1], float(i))   # a shape a call
+        return x
+
+    raw = []
+
+    def listen(event, secs, **kw):
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            raw.append(secs)
+
+    prog = jax.jit(_probe_nested_fn)
+    register_program_roles({"probe_nested": prog})
+    x = jnp.ones((64,))
+    before = _compile_series()
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        t0 = time.time()
+        jax.block_until_ready(prog(x))
+        wall = time.time() - t0
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    grown = _grown(before, _compile_series())
+    trace_s, trace_n = grown[("probe_nested", "trace")]
+    assert len(raw) > 20 and trace_n == 1
+    assert 0.0 < trace_s < wall
+    assert trace_s < sum(raw)
+    assert {k[0] for k in grown} == {"probe_nested"}
+    assert sum(s for s, _ in grown.values()) < wall
+    assert grown[("probe_nested", "backend")][1] == 1
+
+
+def test_engine_programs_are_the_plain_jits_under_the_names_jax_logs(
+        cold_engine):
+    """What `tick.admit_ms.steady`'s `^jit__unknown` rests on: nothing
+    stands between the engine and its jitted partials (no wrapper: the
+    role is read off jax's own trace event), so jax logs every engine
+    program the first request compiled as it logs any jitted
+    functools.partial, `<unknown>`."""
+    import functools
+
+    import jax.numpy as jnp
+
+    def body(k, x):
+        return x * k + 1
+
+    x7 = jnp.ones((7,))
+    handler = _CompileCounter()
+    logger = logging.getLogger("jax._src.interpreters.pxla")
+    logger.addHandler(handler)
+    prev = jax.config.jax_log_compiles
+    jax.config.update("jax_log_compiles", True)
+    try:
+        jax.jit(functools.partial(body, 3))(x7)
+    finally:
+        jax.config.update("jax_log_compiles", prev)
+        logger.removeHandler(handler)
+    assert len(handler.names) == 1 and "unknown" in handler.names[0]
+    # the admission and the decode scan, at least
+    first = cold_engine["runs"][0]["names"]
+    assert first.count(handler.names[0]) >= 2, first
+    eng = cold_engine["eng"]
+    for prog in (eng._paged_admit, eng._paged_slot_scan,
+                 eng._paged_set_table):
+        assert type(prog) is type(jax.jit(body)), type(prog)
+    assert eng._paged_admit._cache_size() >= 2       # two suffix buckets
+    assert eng._paged_slot_scan.__wrapped__.func.__name__ \
+        == "_paged_slot_scan_decode_fn"
+
+
+def test_every_engine_program_has_a_role_under_the_name_jax_traces():
+    """The accounting knows a program by the name jax puts in its
+    trace event: `traced_name` agrees with jax's own `fun_name` for
+    every program of the engine's set, each name leads to the role of
+    a program over that function (the contiguous and the paged mixed
+    ticks share one), and a jitted function nobody registered is
+    `eager`."""
+    import jax.numpy as jnp
+    from jax._src import util as jax_util
+
+    from triton_dist_tpu.models.engine import _jit_programs
+    from triton_dist_tpu.runtime import telemetry as T
+
+    progs = _jit_programs("xla", "greedy", (1.0, 50, 0.9), "xla")
+    by_name = {}
+    for role, prog in progs.items():
+        name = T.traced_name(prog)
+        assert name == jax_util.fun_name(prog.__wrapped__), role
+        by_name.setdefault(name, []).append(role)
+        assert T._ROLES[name] in by_name[name]
+    shared = {n: r for n, r in by_name.items() if len(r) > 1}
+    assert shared == {
+        "_mixed_step_fn": ["slot_mixed", "paged_slot_mixed"],
+        "_mixed_verify_fn": ["slot_mixed_verify",
+                             "paged_slot_mixed_verify"]}, shared
+    assert T._ROLES["_mixed_step_fn"] == "slot_mixed"
+
+    def _probe_nobodys_fn(x):
+        return x * 5
+
+    x = jnp.ones((17,))
+    before = _compile_series()
+    jax.jit(_probe_nobodys_fn)(x)
+    grown = _grown(before, _compile_series())
+    assert {k[0] for k in grown} == {"eager"}, grown
+    assert grown[("eager", "backend")][1] == 1
+
+
+def test_compile_spans_on_the_traced_ring_only(cold_engine):
+    """trace=True: the first request's compiles are `compile:<role>`
+    spans (stage and seconds in args) inside a poll span of the ring;
+    the untraced scheduler, alive all the while, kept an empty ring."""
+    events = cold_engine["on"].tele.export()["traceEvents"]
+    polls = [(e["ts"], e["ts"] + e["dur"]) for e in events
+             if e.get("ph") == "X" and e.get("name") == "poll"]
+    spans = [e for e in events
+             if e.get("ph") == "X"
+             and str(e.get("name", "")).startswith("compile:")]
+    by_role = {}
+    for e in spans:
+        assert e["tid"] == 0 and e["args"]["stage"] in COMPILE_STAGES
+        assert e["args"]["seconds"] == pytest.approx(e["dur"] / 1e6,
+                                                     abs=1e-5)
+        by_role.setdefault(e["name"], set()).add(e["args"]["stage"])
+        # (the ring of a live traced bundle also takes what the process
+        # compiles outside its polls: eager ops, other tests' probes)
+        if e["name"] in ("compile:paged_admit",
+                         "compile:paged_slot_scan"):
+            assert any(s - 1e3 <= e["ts"]
+                       and e["ts"] + e["dur"] <= t + 1e3
+                       for s, t in polls), e
+    for role in ("compile:paged_admit", "compile:paged_slot_scan"):
+        assert {"trace", "lower", "backend"} <= by_role[role]
+    assert "compile:eager" in by_role
+    off = cold_engine["off"].tele.export()["traceEvents"]
+    assert not [e for e in off if e.get("ph") != "M"]
+
+
+def test_dispatch_from_a_second_thread_has_its_own_role():
+    """Roles are per thread: two threads inside their programs' traces
+    at the same moment (a barrier in the traced bodies) are each
+    counted under their own role, and the thread that started them is
+    still `eager`."""
+    import jax.numpy as jnp
+
+    from triton_dist_tpu.runtime.telemetry import (dispatching_role,
+                                                   register_program_roles)
+
+    barrier = threading.Barrier(2, timeout=120)
+    seen = {}
+
+    def _probe_thread_a_fn(x):
+        seen["probe_thread_a"] = dispatching_role()     # at trace time
+        barrier.wait()                                  # both traces open
+        return x * 2
+
+    def _probe_thread_b_fn(x):
+        seen["probe_thread_b"] = dispatching_role()
+        barrier.wait()
+        return x * 3
+
+    progs = {"probe_thread_a": jax.jit(_probe_thread_a_fn),
+             "probe_thread_b": jax.jit(_probe_thread_b_fn)}
+    register_program_roles(progs)
+    out = {}
+
+    def work(role, n):
+        out[role] = np.asarray(progs[role](jnp.ones((n,))))
+
+    before = _compile_series()
+    threads = [threading.Thread(target=work, args=(role, 11 + i))
+               for i, role in enumerate(progs)]
+    for t in threads:
+        t.start()
+    assert dispatching_role() == "eager"
+    for t in threads:
+        t.join(timeout=300)
+        assert not t.is_alive()
+    grown = _grown(before, _compile_series())
+    assert seen == {role: role for role in progs}
+    for role in progs:
+        assert grown[(role, "trace")][1] == 1
+        assert grown[(role, "backend")][1] == 1
+        assert grown[(role, "trace")][0] > 0.0
+    assert out["probe_thread_a"].shape == (11,) \
+        and out["probe_thread_b"][0] == 3.0
+
+
+def test_host_ms_per_poll_leaves_the_compiles_out():
+    """A scheduler whose first request compiles everything: the gauge
+    is the host's own milliseconds a poll, not the seconds jax
+    compiled between two dispatches (program_compile_s has those)."""
+    from triton_dist_tpu.runtime.telemetry import thread_compile_seconds
+
+    cfg = tiny_qwen3(mesh.shape["tp"])
+    eng = Engine(AutoLLM.from_config(cfg, mesh), max_seq=48,
+                 backend="xla")
+    sched = ContinuousScheduler(eng, batch=2, chunk=2, paged=True,
+                                page=8, prefix_cache=False)
+    c0 = thread_compile_seconds()
+    sched.run([Request(rid=0, ids=_prompt(cfg, 6, 0), gen_len=4,
+                       seed=0)])
+    compiled_ms = 1e3 * (thread_compile_seconds() - c0)
+    assert compiled_ms > 100.0           # the scan and the admission
+    # an EMA with weight 0.2 on the newest poll: one poll that held
+    # the compiles would alone read a fifth of them
+    assert 0.0 < sched.stats()["host_ms_per_poll"] < 0.1 * compiled_ms

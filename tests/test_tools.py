@@ -172,6 +172,72 @@ def test_trace_view_cli(tmp_path):
     assert "ttft_ms: n=2" in text
 
 
+def test_trace_view_programs_table(tmp_path):
+    """The programs table: per role the compile accounting's totals
+    from the dump's metrics (programs compiled, cache hits, trace /
+    lower / backend seconds) and the `compile:<role>` spans that lie
+    inside a poll span, in the text report and in --json."""
+    import subprocess
+    import sys
+
+    def span(name, ts, dur, **args):
+        return {"name": name, "ph": "X", "pid": 0, "tid": 0, "ts": ts,
+                "dur": dur, "args": args}
+
+    key = "program_compile_{}{{program={},stage={}}}".format
+    dump = {
+        "traceEvents": [
+            span("poll", 0, 1000, seq=1),
+            span("admit", 50, 800),
+            span("compile:paged_admit", 100, 300, stage="trace",
+                 seconds=0.0003),
+            span("compile:paged_admit", 400, 400, stage="backend",
+                 seconds=0.0004),
+            # between two polls: the warm-up's, not the loop's
+            span("compile:eager", 1200, 100, stage="backend",
+                 seconds=0.0001),
+            span("poll", 1500, 500, seq=2),
+        ],
+        "requests": {},
+        "metrics": {
+            key("s", "paged_admit", "trace"): 12.5,
+            key("s", "paged_admit", "lower"): 3.25,
+            key("s", "paged_admit", "backend"): 20.0,
+            key("s", "paged_admit", "cache_load"): 1.5,
+            key("n", "paged_admit", "trace"): 3,
+            key("n", "paged_admit", "backend"): 3,
+            key("n", "paged_admit", "cache_load"): 2,
+            key("s", "eager", "backend"): 0.75,
+            key("n", "eager", "backend"): 40,
+            "host_phase_s{phase=admit}": 1.0,
+        },
+    }
+    path = tmp_path / "trace.json"
+    path.write_text(json.dumps(dump))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    tool = os.path.join(repo, "tools", "trace_view.py")
+    out = subprocess.run([sys.executable, tool, str(path), "--json"],
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    programs = json.loads(out.stdout)["programs"]
+    assert programs == {
+        "paged_admit": {"compiled": 3, "cache_hits": 2, "trace_s": 12.5,
+                        "lower_s": 3.25, "backend_s": 20.0,
+                        "in_poll_n": 2, "in_poll_ms": 0.7},
+        "eager": {"compiled": 40, "cache_hits": 0, "trace_s": 0.0,
+                  "lower_s": 0.0, "backend_s": 0.75, "in_poll_n": 0,
+                  "in_poll_ms": 0.0}}
+    out = subprocess.run([sys.executable, tool, str(path)],
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    rows = [ln.split() for ln in out.stdout.splitlines()
+            if ln.startswith("  paged_admit") or ln.startswith("  eager")]
+    # most compile seconds first; compiled / hits, then the seconds
+    assert [r[0] for r in rows] == ["paged_admit", "eager"]
+    assert rows[0][1:7] == ["3", "/", "2", "12.500", "3.250", "20.000"]
+    assert rows[0][-2:] == ["2", "(0.700ms)"]
+
+
 def test_kernel_context_tune_cold_and_warm(cache_path, monkeypatch):
     """The wired path (VERDICT r2 #7): create_ag_gemm_context(tune=True)
     cold-tunes over the block space and caches; a second creation with

@@ -18,7 +18,12 @@ This CLI reads one dump and prints:
   metrics snapshot,
 - the fleet HA event line (replica_death / breaker_open /
   breaker_close / router_failover instants) so a resteer or failover
-  is visible in the terminal report, not only in perfetto.
+  is visible in the terminal report, not only in perfetto,
+- a PROGRAMS table from the compile accounting (runtime/telemetry.py
+  `program_compile_s` / `program_compile_n` in the embedded metrics):
+  per engine program role the programs compiled, persistent-cache
+  hits, trace / lower / backend seconds, and the `compile:<role>`
+  spans that fell INSIDE a poll — a compile the warm-up did not cover.
 
 Usage: python tools/trace_view.py /path/to/trace.json [--top 5]
        python tools/trace_view.py /path/to/trace.json --json
@@ -29,8 +34,13 @@ so tests and notebooks can reuse the analysis and formatting.
 """
 
 import argparse
+import bisect
 import json
+import re
 import sys
+
+_COMPILE_KEY = re.compile(
+    r"^program_compile_(s|n)\{program=([^,}]+),stage=([^,}]+)\}$")
 
 
 def _fmt_ms(us: float) -> str:
@@ -177,6 +187,43 @@ def analyze(dump: dict, top_k: int = 5) -> dict:
         })
 
     metrics = dump.get("metrics", {})
+    # programs: the compile accounting's totals per role (process-wide,
+    # so a server's dump also holds what ran before it was built) and
+    # this ring's compile spans that lie inside one of its polls
+    programs = out["programs"] = {}
+
+    def program(role):
+        return programs.setdefault(role, {
+            "compiled": 0, "cache_hits": 0, "trace_s": 0.0,
+            "lower_s": 0.0, "backend_s": 0.0, "in_poll_n": 0,
+            "in_poll_ms": 0.0})
+
+    for key, v in metrics.items():
+        m = _COMPILE_KEY.match(key)
+        if not m:
+            continue
+        what, role, stage = m.groups()
+        row = program(role)
+        if what == "s" and stage != "cache_load":
+            row[stage + "_s"] = round(v, 6)
+        elif what == "n" and stage == "backend":
+            row["compiled"] = int(v)
+        elif what == "n" and stage == "cache_load":
+            row["cache_hits"] = int(v)
+    poll_ivals = sorted((e["ts"], e["ts"] + e["dur"]) for e in polls)
+    poll_starts = [s for s, _ in poll_ivals]
+    for e in spans:
+        name = e.get("name", "")
+        if e.get("tid") != 0 or not name.startswith("compile:"):
+            continue
+        # polls do not overlap: the one that began last before the span
+        i = bisect.bisect_right(poll_starts, e["ts"]) - 1
+        if i >= 0 and e["ts"] + e["dur"] <= poll_ivals[i][1]:
+            row = program(name[len("compile:"):])
+            row["in_poll_n"] += 1
+            row["in_poll_ms"] = round(row["in_poll_ms"]
+                                      + e["dur"] / 1e3, 3)
+
     for key, m in metrics.items():
         base = key.split("{", 1)[0]
         if base in ("ttft_ms", "inter_token_ms", "poll_ms",
@@ -256,6 +303,20 @@ def summarize(dump: dict, top_k: int = 5) -> str:
                 f"{r['tokens']:>6d} "
                 f"{'-' if ttft is None else format(ttft, '9.3f')} "
                 f"{'-' if tr is None else format(tr, '11.3f')}")
+
+    if a["programs"]:
+        out.append("programs (compiled / cache hits; trace, lower, "
+                   "backend s; compiles inside a poll):")
+        for role, p in sorted(a["programs"].items(),
+                              key=lambda kv: -(kv[1]["trace_s"]
+                                               + kv[1]["lower_s"]
+                                               + kv[1]["backend_s"])):
+            out.append(
+                f"  {role:<24s} {p['compiled']:>4d} / "
+                f"{p['cache_hits']:<4d} {p['trace_s']:8.3f} "
+                f"{p['lower_s']:8.3f} {p['backend_s']:8.3f}  "
+                f"in polls: {p['in_poll_n']} "
+                f"({p['in_poll_ms']:.3f}ms)")
 
     for key, m in a["metrics"].items():
         out.append(f"{key}: n={m['count']} p50={m['p50']} "

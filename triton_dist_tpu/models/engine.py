@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from triton_dist_tpu.kernels.paged_kv import set_page_rows
-from triton_dist_tpu.runtime.telemetry import default_registry
+from triton_dist_tpu.runtime.telemetry import (
+    default_registry, install_compile_accounting, register_program_roles)
 
 # every string Engine(backend=) serves: the module docstring's table
 BACKENDS = ("xla", "flash", "dist", "ar", "gemm_ar", "ep", "ep_flash")
@@ -146,6 +147,9 @@ class Engine:
         # own registry. Cached Counter handles: inc() on the dispatch
         # path is one int add, no registry lock.
         _reg = default_registry()
+        # ... and, beside them, jax's own trace / lower / compile
+        # seconds by the role of the program dispatched
+        install_compile_accounting()
         self._c_prefills = _reg.counter(
             "engine_prefill_dispatches", "prefill/admit forwards")
         self._c_decode = _reg.counter(
@@ -1090,6 +1094,11 @@ def _jit_programs(backend: str, sampling: str, pkey: tuple,
     P["paged_install"] = jax.jit(_paged_install_fn, donate_argnums=(1,))
     P["gather_pages"] = jax.jit(_gather_pages_fn)
     P["restore_pages"] = jax.jit(_restore_pages_fn, donate_argnums=(1,))
+    # each known by its role to the compile accounting (runtime/
+    # telemetry.py): what a dispatch traces, lowers and compiles is
+    # counted under this name. The jitted callables are untouched: they
+    # stay the partials they are, so a trace still shows jit__unknown
+    register_program_roles(P)
     return P
 
 

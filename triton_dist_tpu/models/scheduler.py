@@ -207,7 +207,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from triton_dist_tpu.runtime.telemetry import Telemetry, \
-    UNTAGGED_PRIORITY, trace_env_enabled
+    UNTAGGED_PRIORITY, thread_compile_seconds, trace_env_enabled
 from triton_dist_tpu.models.structured import NO_FORCED, \
     constrained_draft, window_masks
 
@@ -2129,8 +2129,9 @@ class ContinuousScheduler:
         self._carry_done: List[object] = []
         # host_ms_per_poll gauge: dispatch-to-dispatch wall time minus
         # the device wait accumulated in between (DecodeSlots._fetch)
+        # and what this thread's dispatches compiled
         self._host_ms_ema: Optional[float] = None
-        self._last_mark: Optional[Tuple[float, float]] = None
+        self._last_mark: Optional[Tuple[float, float, float]] = None
         self._queue: deque = deque()
         # guards _queue/_deadline against cross-thread submit()/cancel()
         # racing the driver thread's poll() (the class contract allows
@@ -2160,9 +2161,9 @@ class ContinuousScheduler:
         self._g_host_ms = reg.gauge(
             "host_ms_per_poll",
             "EMA of the wall time from one tick's dispatch to the next "
-            "minus the device wait between them: scheduling, drafting, "
-            "admission, the serve loop's intake and socket "
-            "writes, and any compile an admission meets")
+            "minus the device wait and the compile seconds "
+            "(program_compile_s) between them: scheduling, drafting, "
+            "admission, the serve loop's intake and socket writes")
         # TP topology + live throughput (multi-chip serving — ROADMAP
         # open item 1): ONE scheduler drives the whole TP mesh, so
         # multi-chip runs must report both aggregate and per-chip
@@ -2406,21 +2407,25 @@ class ContinuousScheduler:
     def _mark_dispatch(self) -> None:
         """Stamp a device-step dispatch: host_ms_per_poll is the time
         since the previous stamp minus the device wait accrued in
-        between (DecodeSlots._fetch) — i.e. what the HOST spent
+        between (DecodeSlots._fetch) and the seconds jax compiled for
+        this thread's dispatches (a shape the warm-up did not meet:
+        program_compile_s has them) — i.e. what the HOST spent
         scheduling, drafting, streaming and admitting per poll,
         whether or not the device was busy under it."""
         now = time.monotonic()
         wait = self.slots.device_wait_s
+        comp = thread_compile_seconds()
         if self._last_mark is not None:
-            t0, w0 = self._last_mark
-            host_ms = max(0.0, ((now - t0) - (wait - w0)) * 1e3)
+            t0, w0, c0 = self._last_mark
+            host_ms = max(0.0, ((now - t0) - (wait - w0)
+                                - (comp - c0)) * 1e3)
             self._host_ms_ema = host_ms if self._host_ms_ema is None \
                 else 0.8 * self._host_ms_ema + 0.2 * host_ms
             self._g_host_ms.set(self._host_ms_ema)   # registry mirror
             # serving time base for the live tok/s gauges (stats()):
             # dispatch-to-dispatch wall while occupied, idle excluded
             self._busy_s += now - t0
-        self._last_mark = (now, wait)
+        self._last_mark = (now, wait, comp)
 
     @property
     def idle(self) -> bool:

@@ -28,6 +28,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from triton_dist_tpu.runtime.telemetry import install_compile_accounting
+
 _CONTEXT: Optional["DistContext"] = None
 
 # Default logical axis order: outermost (slowest, DCN-friendly) first,
@@ -146,6 +148,8 @@ def initialize_distributed(mesh_shape: Optional[dict] = None,
     global _CONTEXT
     _maybe_init_multihost()
     place_compile_cache()
+    # before the caller builds weights: their compiles are counted too
+    install_compile_accounting()
     mesh = make_mesh(mesh_shape, devices)
     _CONTEXT = DistContext(mesh=mesh, axes=tuple(mesh.axis_names), seed=seed)
     return _CONTEXT

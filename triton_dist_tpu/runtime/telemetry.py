@@ -89,10 +89,31 @@ its observability substrate:
   (DecodeSlots.device_wait_by_kind: decode/verify/mixed/admit, plus
   the disagg plane's prefill/transfer buckets).
 
-ALWAYS ON: the registry, the derived latency histograms and the host
+- COMPILE ACCOUNTING (always on, process-wide): every jitted program
+  of the engine has a ROLE (`paged_admit`, `paged_slot_scan`, ...),
+  registered under the name of the function it traces
+  (`register_program_roles`, from engine._jit_programs); whatever else
+  is dispatched is role `eager`. `install_compile_accounting()`
+  registers ONE set of jax.monitoring listeners that turn jax's own
+  compile events into `program_compile_s{program=<role>,stage=trace|
+  lower|backend|cache_load}` and `program_compile_n{...}` of the
+  default registry. A dispatch that compiles begins with a trace event
+  that names its function, so the role is read off jax's own event
+  and NOTHING stands on the dispatch path: no wrapper, no frame, no
+  write a call (a wrapper round each program read 9-10 s more set-up
+  in one cell on the chip, PERF.md PR 41). Seconds are an event's SELF
+  time, its span less the events nested in it: a nested jit's trace
+  lies inside its caller's, a lowering re-traces inside its own event,
+  and none of it is added twice. With tracing on each counted event is
+  a `compile:<role>` span on the Chrome ring's host track, inside the
+  phase that met it.
+
+ALWAYS ON: the registry, the derived latency histograms, the host
 phases (per phase two clock reads, two counter adds and one
 TraceAnnotation, which costs under a microsecond while no profiler
-session is open). `trace=True` ADDS the request event lists and the
+session is open) and the compile accounting (its listeners run only
+when jax compiles).
+`trace=True` ADDS the request event lists and the
 Chrome ring; every entry point of those early-outs on `self.trace`.
 Either way this module runs no device computation (its one jax call
 is the profiler's annotation), so token streams stay BITWISE
@@ -106,12 +127,14 @@ that path on exit); summarize dumps with `tools/trace_view.py`
 
 from __future__ import annotations
 
+import functools
 import json
 import math
 import os
 import re
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -335,6 +358,225 @@ def default_registry() -> MetricsRegistry:
     in each scheduler's own registry (`sched.tele.registry`) so two
     schedulers never alias each other's stats."""
     return _DEFAULT
+
+
+# ----------------------------------------------------------------------
+# compile accounting: which program traced, lowered and compiled, and
+# for how long (module docstring)
+# ----------------------------------------------------------------------
+
+# the role of whatever is not a registered program: the admission's
+# eager pad, the scheduler's .at[slot].set scatters, pool allocation, a
+# caller's own weight builders
+EAGER = "eager"
+
+# jax's compile events by the stage they time. The first three come
+# with start and end (jax._src.dispatch.log_elapsed_time) and NEST: a
+# jitted function that calls a jitted helper fires the helper's trace
+# event inside its own, a lowering re-traces the jnp helpers it meets
+# inside its lower event, an op run eagerly while tracing compiles
+# inside the trace event. `backend` times the compiler OR the
+# persistent cache's load, whichever ran; a load also fires the cache's
+# own retrieval event, a duration only, inside it.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_SPAN_STAGES = {
+    _TRACE_EVENT: "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+COMPILE_STAGES = ("trace", "lower", "backend", "cache_load")
+
+# {name of a traced function: the role of the program that traces it}
+_ROLES: Dict[str, str] = {}
+
+class _ThreadCompiles(threading.local):
+    """What the listeners keep per thread."""
+
+    role = EAGER        # of the dispatch whose compile events arrive
+    compile_s = 0.0     # seconds inside any compile event so far
+
+    def __init__(self):
+        # one entry per compile event begun and not ended: the seconds
+        # its children have covered
+        self.open: List[float] = []
+
+
+_DISPATCH = _ThreadCompiles()
+
+# the Telemetry bundles built with trace=True that are still alive:
+# each gets the compile spans on its ring
+_TRACED: "weakref.WeakSet[Telemetry]" = weakref.WeakSet()
+_TRACED_LOCK = threading.Lock()
+
+
+def traced_name(fn) -> Optional[str]:
+    """The name jax gives a jitted callable's function in its trace
+    event (jax._src.util.fun_name): its `__name__`, through any
+    functools.partial."""
+    fn = getattr(fn, "__wrapped__", fn)
+    while (isinstance(fn, functools.partial)
+           and getattr(fn, "__name__", None) is None):
+        fn = fn.func
+    return getattr(fn, "__name__", None)
+
+
+def register_program_roles(programs: Dict[str, object]) -> None:
+    """{role: jitted program}: whatever jax traces, lowers or compiles
+    for a dispatch of one of these is counted under its role. Two
+    programs over one function (the contiguous and the paged form of a
+    mixed tick) share the role registered first."""
+    for role, fn in programs.items():
+        name = traced_name(fn)
+        if name is not None:
+            _ROLES.setdefault(name, role)
+
+
+def dispatching_role() -> str:
+    """The role of the dispatch whose compile events the calling
+    thread is in (or was last in)."""
+    return _DISPATCH.role
+
+
+def thread_compile_seconds() -> float:
+    """Seconds the calling thread has spent inside jax's trace, lower
+    and backend-compile events so far (their union): the scheduler
+    takes what a poll's dispatches compiled out of host_ms_per_poll."""
+    return _DISPATCH.compile_s
+
+
+class CompileAccounting:
+    """jax's compile events as counters of `registry`, by the role of
+    the dispatch they belong to. jax calls a listener on the thread
+    that compiles, and a dispatch that compiles anything begins with a
+    trace event that names the function traced (even where jax finds
+    the trace in its cache): an event with nothing open round it sets
+    the thread's role from that name, and the lower and backend events
+    of the same dispatch follow it.
+
+    SECONDS are an event's SELF time: its span less what the events
+    nested in it covered, whatever their stage (jax records a scalar
+    under the event's name when it begins, so the open events of a
+    thread are a stack). A stage's seconds are then the union of its
+    events less what other stages did inside them, never their sum,
+    and the three stages partition the thread's compile time.
+    COUNTS (and the ring's `compile:<role>` spans) are of the events
+    with nothing open round them, and of every backend event (the
+    programs that reached the compiler or the cache): a helper's trace
+    inside its caller's, or a lowering's re-traces, are not counted
+    again."""
+
+    def __init__(self, registry: MetricsRegistry):
+        self.registry = registry
+        self._lock = threading.Lock()
+        self._series: Dict[tuple, tuple] = {}
+        for stage in COMPILE_STAGES:    # `eager` is always there
+            self._pair(EAGER, stage)
+
+    def _pair(self, role: str, stage: str) -> tuple:
+        pair = self._series.get((role, stage))
+        if pair is None:
+            lb = {"program": role, "stage": stage}
+            r = self.registry
+            pair = (r.counter("program_compile_s", "seconds jax spent "
+                              "tracing, lowering or compiling (backend: "
+                              "the compiler or the persistent cache's "
+                              "load; cache_load: that load alone) for "
+                              "the dispatches of an engine program",
+                              labels=lb),
+                    r.counter("program_compile_n", "how many times: "
+                              "backend counts the programs that "
+                              "reached the compiler or the cache, "
+                              "cache_load the hits", labels=lb))
+            with self._lock:
+                self._series[(role, stage)] = pair
+        return pair
+
+    def _on_begin(self, event: str, value, **kw) -> None:
+        if event in _SPAN_STAGES:
+            tls = _DISPATCH
+            if not tls.open and event == _TRACE_EVENT:
+                tls.role = _ROLES.get(kw.get("fun_name"), EAGER)
+            tls.open.append(0.0)
+
+    def _on_span(self, event: str, start: float, end: float,
+                 **kw) -> None:
+        stage = _SPAN_STAGES.get(event)
+        if stage is None:
+            return
+        tls = _DISPATCH
+        open_ = tls.open
+        dur = end - start
+        # (a jax that records no scalar at an event's start leaves the
+        # stack empty: every event then reads as outermost, and takes
+        # its role here)
+        inner = open_.pop() if open_ else 0.0
+        if open_:
+            open_[-1] += dur
+        else:
+            tls.compile_s += dur
+            if event == _TRACE_EVENT:
+                tls.role = _ROLES.get(kw.get("fun_name"), EAGER)
+        # jax stamps these on the wall clock, the ring runs on the
+        # monotonic one
+        t1 = time.monotonic() - (time.time() - end)
+        self._add(stage, max(dur - inner, 0.0),
+                  (t1 - dur, t1) if not open_ or stage == "backend"
+                  else None)
+
+    def _on_duration(self, event: str, secs: float, **kw) -> None:
+        if event == _CACHE_LOAD_EVENT:
+            t1 = time.monotonic()
+            self._add("cache_load", secs, (t1 - secs, t1))
+
+    def _add(self, stage: str, secs: float, counted) -> None:
+        """`secs` more of `stage` for the thread's role;
+        `counted`: the event's (t0, t1) on the monotonic clock where
+        it counts as one more, None for a nested one."""
+        role = dispatching_role()
+        seconds, n = self._pair(role, stage)
+        with self._lock:
+            seconds.inc(secs)
+            if counted:
+                n.inc()
+        if not counted:
+            return
+        with _TRACED_LOCK:
+            traced = list(_TRACED)
+        t0, t1 = counted
+        for tele in traced:
+            tele.span("compile:" + role, t0, t1, tid=0,
+                      args={"stage": stage,
+                            "seconds": round(t1 - t0, 6)})
+
+    def totals(self) -> tuple:
+        """({"<role>/<stage>": seconds}, {"<role>/<stage>": events}),
+        the flat form TokenServer.stats() carries."""
+        with self._lock:
+            series = list(self._series.items())
+        return ({f"{r}/{st}": s.value for (r, st), (s, _) in series},
+                {f"{r}/{st}": n.value for (r, st), (_, n) in series})
+
+
+_ACCOUNTING: Optional[CompileAccounting] = None
+_ACCOUNTING_LOCK = threading.Lock()
+
+
+def install_compile_accounting() -> CompileAccounting:
+    """The process's one CompileAccounting, over default_registry():
+    built, and its listeners registered with jax.monitoring, at the
+    first call (initialize_distributed() and Engine() both call, so a
+    caller's weight builders are counted too); the same object after."""
+    global _ACCOUNTING
+    with _ACCOUNTING_LOCK:
+        if _ACCOUNTING is None:
+            import jax.monitoring as mon
+            acc = CompileAccounting(default_registry())
+            mon.register_scalar_listener(acc._on_begin)
+            mon.register_event_time_span_listener(acc._on_span)
+            mon.register_event_duration_secs_listener(acc._on_duration)
+            _ACCOUNTING = acc
+        return _ACCOUNTING
 
 
 _NAME_RE = re.compile(r"[^a-zA-Z0-9_:]")
@@ -661,6 +903,9 @@ class Telemetry:
         self._tracks: Dict[str, int] = {"host phases": 0,
                                         "device occupancy": 1}
         self._next_tid = 2
+        if self.trace:
+            with _TRACED_LOCK:      # compile spans land on its ring
+                _TRACED.add(self)
 
     # ------------------------------------------------------------------
     # request lifecycle (histograms always; event ring when tracing)
@@ -934,8 +1179,15 @@ class Telemetry:
                                       "tokens": rec.n,
                                       "ttft_ms": ttft,
                                       "events": list(rec.ev)}
+        metrics = self.registry.snapshot()
+        if self.registry is not _DEFAULT:
+            # the process-wide compile series beside the scheduler's
+            # own: tools/trace_view.py's programs table reads them
+            for k, v in _DEFAULT.snapshot().items():
+                if k.startswith("program_compile_"):
+                    metrics.setdefault(k, v)
         return {"traceEvents": events, "displayTimeUnit": "ms",
-                "requests": reqs, "metrics": self.registry.snapshot()}
+                "requests": reqs, "metrics": metrics}
 
     def dump(self, path: str) -> None:
         """Write the export to `path` (the TDTPU_TRACE contract)."""

@@ -712,7 +712,11 @@ class TokenServer:
         tokens_per_step — spec=K mode), the resilience counters
         (queue_depth, preemptions, deadline_expired, busy_rejections,
         "hang" verdict once a watchdogged chunk missed its deadline),
-        and the live ttft_ms / inter_token_ms / poll_ms histograms.
+        the live ttft_ms / inter_token_ms / poll_ms histograms, and
+        the process's compile accounting (program_compile_s / _n:
+        trace, lower, backend and cache-load seconds and events per
+        engine program; a compile inside the serving loop shows as
+        program_compile_n rising after warm-up).
 
         The scheduler already returns a DEEP single-point-in-time
         registry snapshot (runtime/telemetry.py) — every container
@@ -720,8 +724,14 @@ class TokenServer:
         cross-thread readers (this server's reader threads, the
         /metrics listener, test hammers) can iterate and serialize it
         while the driver keeps polling."""
+        from triton_dist_tpu.runtime.telemetry import \
+            install_compile_accounting
         with self._lock:
             st = self.sched.stats()
+        # the process-wide compile accounting, flat as host_phase_s is:
+        # {"<program role>/<stage>": seconds} and events
+        st["program_compile_s"], st["program_compile_n"] = \
+            install_compile_accounting().totals()
         if self.replica_id is not None:
             st["replica_id"] = self.replica_id
         return st
