@@ -48,7 +48,7 @@ _LONG_FILES = (
     "test_sp_attention.py", "test_telemetry.py", "test_sp_serving.py",
     "test_stress.py", "test_moe_reduce_rs.py", "test_paged_kv.py",
     "test_tp_serving.py", "test_chip_compile.py", "test_prefix_cache.py",
-    "test_flash_attn.py")
+    "test_vocab_parallel_head.py", "test_flash_attn.py")
 
 
 def pytest_collection_modifyitems(config, items):

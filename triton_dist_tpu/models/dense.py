@@ -21,7 +21,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from triton_dist_tpu.layers import TP_Attn, TP_MLP, precompute_rope, rms_norm
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.kv_cache import KVCache
-from triton_dist_tpu.models.utils import ServingTraits, place_replicated
+from triton_dist_tpu.models.utils import (ServingTraits, place_replicated,
+                                         split_last_axis)
 from triton_dist_tpu.runtime import auto_mesh
 
 
@@ -37,10 +38,21 @@ class DenseLayer:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class DenseLLM:
+    """PLACEMENT over `mesh`: the TP layers split their own projections
+    over `axis`; the LM head's VOCABULARY columns are split over the
+    same axis wherever it has more than one chip and divides the
+    vocabulary (`vocab_axis`), so a decode step reads V/n columns of
+    the head a chip and not all of it; everything else (embedding,
+    norms, rope tables) is replicated. `place_replicated` places all of
+    it, at the end of every constructor. Downstream of a split head
+    the logits [.., V] stay split over the vocabulary (`_head` pins
+    them): the engine's carry, the grammar mask and the greedy pick
+    all work on a chip's own columns, and only a token id a slot
+    crosses chips."""
     embed: jax.Array            # [V, D]
     layers: Tuple[DenseLayer, ...]
     final_norm: jax.Array       # [D]
-    lm_head: jax.Array          # [D, V]
+    lm_head: jax.Array          # [D, V]; columns over vocab_axis
     cos: jax.Array
     sin: jax.Array
     config: ModelConfig = dataclasses.field(metadata=dict(static=True))
@@ -63,6 +75,24 @@ class DenseLLM:
     def sp_size(self) -> int:
         """Sequence-parallel mesh size (1 = no page sharding)."""
         return self.mesh.shape[self.sp_axis] if self.sp_axis else 1
+
+    @property
+    def vocab_axis(self) -> Optional[str]:
+        """The mesh axis the LM head's vocabulary columns (and the
+        logits after it) are split over: the TP axis where it has more
+        than one chip and divides the vocabulary, else None (a head
+        replicated, as on one chip)."""
+        n = self.mesh.shape[self.axis]
+        return self.axis if n > 1 and self.config.vocab_size % n == 0 \
+            else None
+
+    def split_leaves(self) -> dict:
+        """{field: mesh axis} of the leaves that are NOT replicated
+        over the mesh but split along their LAST dimension (what
+        place_replicated asks a model): the head's [D, V], and both
+        leaves of its int8 form, q [D, V] and s [V]."""
+        ax = self.vocab_axis
+        return {"lm_head": ax} if ax else {}
 
     # ------------------------------------------------------------------
     # construction
@@ -192,8 +222,10 @@ class DenseLLM:
                                         w_gate_up=q8(ly.mlp.w_gate_up),
                                         w_down=q8(ly.mlp.w_down)))
             for ly in self.layers)
-        return dataclasses.replace(self, layers=layers,
-                                   lm_head=q8(self.lm_head))
+        # the int8 head's leaves are placed as the bf16 head was
+        return place_replicated(
+            dataclasses.replace(self, layers=layers,
+                                lm_head=q8(self.lm_head)), self.mesh)
 
     # ------------------------------------------------------------------
     # forward
@@ -234,14 +266,7 @@ class DenseLLM:
         xr = x.reshape(B, S, -1)
         last = xr[:, -1] if last_pos is None else jnp.take(
             xr, last_pos, axis=1)
-        # bf16 x bf16 -> f32 on the MXU; casting the [D, V] weight to f32
-        # would materialize (and re-read) gigabytes per decode step.
-        # lm_head may be int8-quantized (the single biggest weight read
-        # of a decode step) — qmm dequants after the dot.
-        from triton_dist_tpu.kernels.quant import qmm
-        logits = qmm(last, self.lm_head,
-                     preferred_element_type=jnp.float32)
-        return logits, cache
+        return self._head(last), cache
 
     def forward_tokens_slots(self, ids, cache: KVCache, pos,
                              mode: str = "dist",
@@ -268,9 +293,7 @@ class DenseLLM:
         x = rms_norm(x, self.final_norm, self.config.rms_norm_eps)
         if mode == "dist":
             x = self._gather_rows(x)
-        from triton_dist_tpu.kernels.quant import qmm
-        logits = qmm(x, self.lm_head, preferred_element_type=jnp.float32)
-        return logits, cache
+        return self._head(x), cache
 
     def forward_tokens_slots_verify(self, ids, cache: KVCache, pos,
                                     q_lens, mode: str = "dist",
@@ -298,9 +321,7 @@ class DenseLLM:
         x = rms_norm(x, self.final_norm, self.config.rms_norm_eps)
         if mode == "dist":
             x = self._gather_rows(x)
-        from triton_dist_tpu.kernels.quant import qmm
-        logits = qmm(x, self.lm_head, preferred_element_type=jnp.float32)
-        return logits.reshape(B, S, -1), cache
+        return self._head(x).reshape(B, S, -1), cache
 
     def forward_tokens_slots_paged_verify(self, ids, pcache, pos, q_lens,
                                           mode: str = "flash",
@@ -331,9 +352,7 @@ class DenseLLM:
         x = rms_norm(x, self.final_norm, self.config.rms_norm_eps)
         if mode == "dist":
             x = self._gather_rows(x)
-        from triton_dist_tpu.kernels.quant import qmm
-        logits = qmm(x, self.lm_head, preferred_element_type=jnp.float32)
-        return logits.reshape(B, S, -1), pcache
+        return self._head(x).reshape(B, S, -1), pcache
 
     def forward_tokens_slots_paged(self, ids, pcache, pos,
                                    mode: str = "flash",
@@ -369,9 +388,7 @@ class DenseLLM:
         x = rms_norm(x, self.final_norm, self.config.rms_norm_eps)
         if mode == "dist":
             x = self._gather_rows(x)
-        from triton_dist_tpu.kernels.quant import qmm
-        logits = qmm(x, self.lm_head, preferred_element_type=jnp.float32)
-        return logits, pcache
+        return self._head(x), pcache
 
     def forward_train(self, ids, mode: str = "train"):
         """Training forward (no KV cache): full-causal attention over
@@ -403,6 +420,20 @@ class DenseLLM:
         logits = jnp.dot(x, self.lm_head,
                          preferred_element_type=jnp.float32)
         return logits.reshape(B, S, -1)
+
+    def _head(self, x):
+        """Replicated rows [M, D] -> logits [M, V] f32, split over
+        vocab_axis like the head: a local [M, D] x [D, V/n] product a
+        chip, no collective. bf16 x bf16 -> f32 on the MXU; casting the
+        [D, V] weight to f32 would materialize (and re-read) gigabytes
+        per decode step. lm_head may be int8-quantized (the single
+        biggest weight read of a decode step) — qmm dequants after the
+        dot. The placement is PINNED, not left to propagation: the
+        scheduler builds its carry to it (Engine.logits_sharding), and
+        a tick that returned any other would compile the next again."""
+        from triton_dist_tpu.kernels.quant import qmm
+        logits = qmm(x, self.lm_head, preferred_element_type=jnp.float32)
+        return split_last_axis(logits, self.mesh, self.vocab_axis)
 
     def _gather_rows(self, x):
         """Row-sharded [M, D] -> replicated (the LM-head prologue)."""

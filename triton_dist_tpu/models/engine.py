@@ -125,6 +125,15 @@ class Engine:
                     f"moe_impl={model.moe_impl!r} — TP-MoE serves its "
                     "grouped-GEMM dispatch on 'flash' (or 'dist' for "
                     "the comm-kernel attention)")
+        # where the model splits its LM head's vocabulary over a mesh
+        # axis (DenseLLM.vocab_axis), every [B, V] logits array of the
+        # slot programs is split the same way: the carry a scheduler
+        # builds and re-arms is placed to logits_sharding, which is
+        # what the model's head pins a tick's logits to
+        from triton_dist_tpu.models.utils import last_axis_sharding
+        vax = getattr(model, "vocab_axis", None)
+        self.lm_head_shards = int(model.mesh.shape[vax]) if vax else 1
+        self.logits_sharding = last_axis_sharding(model.mesh, 2, vax)
         # An expert-SHARDED model feeds row-sharded token batches to
         # the EP FFN (the a2a dispatch on the ep backends, the
         # all-gather oracle on the rest): every forward's row count
@@ -1427,7 +1436,8 @@ def _mixed_step_fn(backend, sampling, params, paged, model, logits0,
     the carry logits only — sel_logits stay RAW (a prefill row's
     arming logits must be the unconstrained model output; the grammar
     mask applies at every SELECTION from them, never to the carry)."""
-    from triton_dist_tpu.models.utils import sample_top_k, sample_top_p
+    from triton_dist_tpu.models.utils import (sample_top_k, sample_top_p,
+                                              split_last_axis)
     B, S = tokens.shape
     sel0 = logits0 if mask is None else \
         jnp.where(mask, logits0, -jnp.inf)
@@ -1452,7 +1462,10 @@ def _mixed_step_fn(backend, sampling, params, paged, model, logits0,
     logits_all, cache, cap, load = _verify_forward(
         backend, paged, model, cache, pos, toks, q_lens)
     sel = jnp.maximum(q_lens - 1, 0)
-    sel_logits = logits_all[jnp.arange(B), sel]            # [B, V]
+    # [B, V], placed as the scheduler's carry is (logits_sharding)
+    sel_logits = split_last_axis(logits_all[jnp.arange(B), sel],
+                                 model.mesh,
+                                 getattr(model, "vocab_axis", None))
     adv = jnp.where(prefilling, q_lens, active.astype(jnp.int32))
     pos = jnp.minimum(pos + adv, cap - 1)
     if load is not None:
@@ -1471,6 +1484,7 @@ def _mixed_verify_fn(backend, sampling, params, paged, model, cache, pos,
     per-row last-valid-position logits (the arming logits when a final
     chunk lands). mask [B, S, V]: acceptance only — sel_logits stay
     RAW (see _mixed_step_fn)."""
+    from triton_dist_tpu.models.utils import split_last_axis
     B, S = tokens.shape
     logits_all, cache, cap, load = _verify_forward(
         backend, paged, model, cache, pos, tokens, q_lens)
@@ -1481,7 +1495,10 @@ def _mixed_verify_fn(backend, sampling, params, paged, model, cache, pos,
         keys)
     pos = jnp.minimum(pos + jnp.where(prefilling, q_lens, 0), cap - 1)
     sel = jnp.maximum(q_lens - 1, 0)
-    sel_logits = logits_all[jnp.arange(B), sel]            # [B, V]
+    # [B, V], placed as the scheduler's carry is (logits_sharding)
+    sel_logits = split_last_axis(logits_all[jnp.arange(B), sel],
+                                 model.mesh,
+                                 getattr(model, "vocab_axis", None))
     if load is not None:
         return n_emit, t0n, sel_logits, cache, pos, keys, load
     return n_emit, t0n, sel_logits, cache, pos, keys

@@ -162,7 +162,10 @@ admission, preemption, the radix tree, deadlines and the overlap
 pipeline mutate page TABLES and masks, never payloads, so the same
 scheduler code serves TP=1 and TP=8 with bitwise-identical streams
 (tests/test_tp_serving.py). stats() reports tp_size plus aggregate
-AND per-chip tok/s.
+AND per-chip tok/s, and lm_head_shards: a dense model's LM head is
+split over the vocabulary on the TP axis (models/dense.py), the
+logits carry of DecodeSlots with it, so a chip reads a quarter of the
+head at TP=4 (tests/test_vocab_parallel_head.py).
 
 Disaggregation (models/disagg.py — DistServe, 2401.09670): chunked
 prefill BOUNDS the prefill stall on live streams; `DisaggScheduler`
@@ -400,14 +403,18 @@ class DecodeSlots:
         self.tele = telemetry if telemetry is not None else Telemetry()
         V = engine.model.config.vocab_size
         self.cache = self._make_cache()
-        # carried state starts out placed over the model's mesh: what a
-        # tick returns carries the mesh in its type, and a first tick
-        # fed bare host-made arrays would be compiled again for the
-        # second
+        # carried state starts out placed as a tick returns it: a
+        # program is compiled for the placement of its operands, so a
+        # first tick fed bare host-made arrays, or logits replicated
+        # where the model's head leaves them split over the vocabulary
+        # (Engine.logits_sharding), would be compiled again for the
+        # second, inside the serving window. _arm_slot keeps the
+        # logits there.
         from jax.sharding import NamedSharding, PartitionSpec
-        self.logits, self.pos, self.active = jax.device_put(
-            (jnp.zeros((batch, V), jnp.float32),
-             jnp.zeros((batch,), jnp.int32), jnp.zeros((batch,), bool)),
+        self.logits = jax.device_put(jnp.zeros((batch, V), jnp.float32),
+                                     engine.logits_sharding)
+        self.pos, self.active = jax.device_put(
+            (jnp.zeros((batch,), jnp.int32), jnp.zeros((batch,), bool)),
             NamedSharding(engine.model.mesh, PartitionSpec()))
         self.keys = (None if engine.sampling == "greedy"
                      else jax.random.split(jax.random.key(0), batch))
@@ -637,7 +644,13 @@ class DecodeSlots:
             self._grammar[slot] = gs
         else:
             self._grammar[slot] = None
+        # the row arrives split like the carry (the admission's head
+        # pins it), so the eager set keeps the carry's placement; were
+        # it ever to return another, the next tick would compile again
+        # inside the window: fail here instead, on the host
         self.logits = self.logits.at[slot].set(row_logits)
+        assert self.logits.sharding == self.engine.logits_sharding, \
+            self.logits.sharding
         self.pos = self.pos.at[slot].set(n)
         self.active = self.active.at[slot].set(True)
         if self.keys is not None:
@@ -2177,6 +2190,12 @@ class ContinuousScheduler:
             engine.model.mesh.shape[engine.model.axis])
         reg.gauge("tp_size",
                   "TP mesh size this scheduler drives").set(self.tp_size)
+        # whether the vocabulary-parallel head engaged: the chips the
+        # LM head's columns (and the logits carry) are split over
+        reg.gauge("lm_head_shards",
+                  "chips the LM head's vocabulary columns are split "
+                  "over (1 = every chip reads the whole head)").set(
+            engine.lm_head_shards)
         # sequence-parallel topology (long-context serving): the sp
         # mesh size the paged pool's page-id space shards over —
         # per-chip KV reads and attention FLOPs drop to ~1/sp_size and

@@ -43,23 +43,60 @@ class ServingTraits:
 
 
 def place_replicated(tree, mesh):
-    """Commit every array of `tree` that is not yet placed over `mesh`
-    to it, replicated. The TP layers shard their own projections; what
-    a constructor makes beside them (embedding, LM head, norms, rope
-    tables) is otherwise an uncommitted array on the first device —
-    one chip holds it all, and every program call copies it out again
-    to the others. (Under a trace, as in jax.eval_shape, there is
-    nothing to place.)"""
-    from jax.sharding import NamedSharding, PartitionSpec
+    """Place `tree` over `mesh`: the one placement point at a model's
+    boundary (every constructor, and a caller that builds a model from
+    its own weights, ends with it). Every array not yet placed over
+    the mesh is committed to it REPLICATED: the TP layers shard their
+    own projections; what a constructor makes beside them (embedding,
+    norms, rope tables) is otherwise an uncommitted array on the first
+    device — one chip holds it all, and every program call copies it
+    out again to the others. The leaves a model names in
+    `split_leaves()` ({field: mesh axis}; DenseLLM's LM head) are
+    instead SPLIT along their last dimension over that axis, also
+    where they arrive replicated over the mesh: each chip keeps its
+    own columns, nothing moves between chips, and the replicated copy
+    is freed with the caller's last reference to it. (The name is from
+    before a leaf was split, and stays because callers import it.
+    Under a trace, as in jax.eval_shape, there is nothing to place.)"""
+    import dataclasses
 
-    def put(x):
-        if isinstance(x, jax.Array) and \
-                not isinstance(x, jax.core.Tracer) and \
-                getattr(x.sharding, "mesh", None) != mesh:
-            return jax.device_put(x, NamedSharding(mesh, PartitionSpec()))
+    def put(x, axis=None):
+        if not isinstance(x, jax.Array) or isinstance(x, jax.core.Tracer):
+            return x
+        if axis is not None or getattr(x.sharding, "mesh", None) != mesh:
+            return jax.device_put(x, last_axis_sharding(mesh, x.ndim, axis))
         return x
 
+    split = getattr(tree, "split_leaves", dict)()
+    if split:
+        tree = dataclasses.replace(tree, **{
+            field: jax.tree.map(lambda x, ax=ax: put(x, ax),
+                                getattr(tree, field))
+            for field, ax in split.items()})
     return jax.tree.map(put, tree)
+
+
+def last_axis_sharding(mesh, ndim: int, axis):
+    """An ndim-array over `mesh` with its LAST dimension split over
+    mesh axis `axis` and every other replicated (None: all of it
+    replicated, under the empty spec a program's replicated results
+    carry: P(None, None) is another sharding to jit's cache)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    if axis is None:
+        return NamedSharding(mesh, PartitionSpec())
+    return NamedSharding(mesh, PartitionSpec(*[None] * (ndim - 1), axis))
+
+
+def split_last_axis(x, mesh, axis):
+    """Inside a program: pin x's LAST dimension split over mesh axis
+    `axis`, every other replicated (None: x as it is). What keeps
+    [.., V] logits on the columns of a vocabulary-split LM head
+    (DenseLLM.vocab_axis) by statement and not by what the partitioner
+    happens to propagate."""
+    if axis is None:
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, last_axis_sharding(mesh, x.ndim, axis))
 
 
 def sample_greedy(logits):
