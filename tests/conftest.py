@@ -44,7 +44,7 @@ _LONG_FILES = (
     "test_serving.py", "test_e2e_inference.py", "test_moe_e2e.py",
     "test_moe_layers.py", "test_scheduler.py", "test_overlap.py",
     "test_phi4flash.py", "test_resilience.py", "test_chunked_prefill.py",
-    "test_deepseek_v3.py", "test_keye_vl2.py",
+    "test_deepseek_v3.py", "test_keye_vl2.py", "test_afmoe.py",
     "test_sp_attention.py", "test_telemetry.py", "test_sp_serving.py",
     "test_stress.py", "test_moe_reduce_rs.py", "test_paged_kv.py",
     "test_tp_serving.py", "test_chip_compile.py", "test_prefix_cache.py",

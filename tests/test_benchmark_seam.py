@@ -326,3 +326,73 @@ def test_sparse_adapter_counts_what_the_metric_files_divide(served_sparse):
             args = json.load(f).get("args", {})
         for k in ("numerator", "denominator"):
             assert args.get(k, "tokens_emitted") in after, (name, k)
+
+
+# ----------------------------------------------------------------------
+# the afmoe family's adapter (benchmark/systems/afmoe_server.py) on a
+# tiny configuration of that family: what the `.agent` metric files
+# (benchmark/metrics/*.agent.json) take from stats() by name
+# ----------------------------------------------------------------------
+
+_RING_KEYS = ("attn_kv_positions{kind=window}",
+              "attn_kv_positions{kind=full}", "cache_bytes{kind=pages}",
+              "cache_bytes{kind=window}", "cache_uniform_bytes",
+              "moe_pairs_routed", "moe_pairs_held", "kv_page_copy_bytes",
+              "moe_experts_touched", "moe_experts_offered")
+
+
+@pytest.fixture(scope="module")
+def served_rings():
+    from benchmark.systems.afmoe_server import Served, request
+    from triton_dist_tpu import finalize_distributed
+    cache_dir = jax.config.jax_compilation_cache_dir
+    with open(os.path.join(_REPO, "benchmark", "testdata",
+                           "tiny-afmoe.json")) as f:
+        cfg = json.load(f)
+    s = Served(cfg, 2**31 + 43, jax.devices()[:1], trace=True)
+    try:
+        before = s.stats()
+        # 24 + 8 positions: three and four times the tiny window of 8
+        msgs = list(request(s.host, s.port, list(range(3, 27)), 8, 300.0))
+        assert msgs[-1].get("done") and not msgs[-1].get("error"), msgs[-1]
+        yield s, before, s.stats()
+    finally:
+        s.stop()
+        assert not s.errors, s.errors
+        finalize_distributed()
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+
+
+@pytest.mark.parametrize("key", _STATS_KEYS + _RING_KEYS)
+def test_ring_adapter_stats_carry_the_key_a_reader_takes(served_rings, key):
+    _, _, after = served_rings
+    assert key in after, key
+
+
+def test_ring_adapter_counts_what_the_metric_files_divide(served_rings):
+    """`attn.window_read_share_pct.agent`, `moe.held_pair_share_pct
+    .agent` and `moe.experts_touched_pct.agent` are ratios of these
+    counters' deltas: the numerators move, and stay under their
+    denominators; every series a `.agent` metric file names is in
+    stats()."""
+    s, before, after = served_rings
+    assert s.pool_pages() > 0 and (s.batch, s.chunk) == (4, 4)
+    d = lambda k: after[k] - before.get(k, 0)  # noqa: E731
+    win, full = (d("attn_kv_positions{kind=window}"),
+                 d("attn_kv_positions{kind=full}"))
+    # the request's slot reads 8 rows of each of six rings a step and
+    # 25-32 positions of each of two full layers
+    assert 0 < win and 0 < full and win % 6 == 0 and full % 2 == 0
+    assert 0 <= d("moe_pairs_held") < d("moe_pairs_routed")
+    assert d("moe_pairs_routed") % (4 * 4 * 7) == 0  # slots x k x layers
+    assert 0 < d("moe_experts_touched") <= d("moe_experts_offered")
+    assert "cache_bytes{kind=state}" not in after
+    for name in os.listdir(os.path.join(_REPO, "benchmark", "metrics")):
+        if not name.endswith(".agent.json"):
+            continue
+        with open(os.path.join(_REPO, "benchmark", "metrics", name)) as f:
+            args = json.load(f).get("args", {})
+        for k in ("numerator", "denominator"):
+            assert args.get(k, "tokens_emitted") in after, (name, k)
+        for k in args.get("numerators", []) + args.get("denominators", []):
+            assert k in after, (name, k)

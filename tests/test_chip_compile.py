@@ -239,8 +239,64 @@ def _sa_prefill_attend(q, k, v, n, sel):
     return selected_attention(q, k, v, sel, n, scale=D ** -0.5)
 
 
+# --- Trinity-Mini (afmoe) at its published widths, the shapes of the
+# cell trinity-mini-ep8.agent-saturated: 64 slots, max_seq 12,288, page
+# 16; 32 query heads of 128 over 4 KV heads; a ring of 2,048 rows a slot
+# and window layer; K and V in one plane of 8 head rows a page in the
+# full layers; 16 held experts of 2048 x 2 x 1024 / 1024 x 2048; an
+# 8,192-token admission, 256 query rows a scanned block
+AF_B, AF_HQ, AF_HKV, AF_W, AF_SEQ, AF_P = 64, 32, 4, 2048, 12288, 8192
+AF_D, AF_F, AF_E = 2048, 1024, 16
+_AF_RING = ((AF_B, AF_HKV, AF_W, D), BF16)
+_AF_KV = ((AF_B * (AF_SEQ // PAGE) + 1, 2 * AF_HKV, PAGE, D), BF16)
+
+
+def _afmoe_fused_walk(q, pool, table, kv_lens):
+    from triton_dist_tpu.kernels.paged_kv import flash_decode_paged
+    return flash_decode_paged(q, pool, None, table, jnp.max(kv_lens),
+                              kv_lens=kv_lens, fused=True)
+
+
+def _afmoe_prefill(window):
+    def fn(q, k, v):
+        from triton_dist_tpu.layers.gated_attn import prefill_attention
+        return prefill_attention(q, k, v, window=window, scale=D ** -0.5,
+                                 impl="flash", scope="x")
+    return fn
+
+
+def _afmoe_ragged(pairs):
+    def fn(x, eid, wgu, wd):
+        from triton_dist_tpu.layers.ep_moe import expert_rows
+        return expert_rows(x, jnp.arange(pairs) // 8, eid, wgu, wd)
+    return fn, [((pairs // 8, AF_D), BF16), ((pairs,), I32),
+                ((AF_E, AF_D, 2 * AF_F), BF16), ((AF_E, AF_F, AF_D), BF16)]
+
+
+_AF_PROMPT = [((AF_P, AF_HQ, D), BF16), ((AF_P, AF_HKV, D), BF16),
+              ((AF_P, AF_HKV, D), BF16)]
+
 # name -> (function, [(shape, dtype), ...]); () is a traced scalar
 CASES = {
+    # the window layers' walk of decode: every slot's ring of 2,048
+    # rotated rows, rep 8
+    "afmoe_ring_decode_b64": (
+        _ring_decode, [((AF_B, 1, AF_HQ, D), BF16), _AF_RING, _AF_RING,
+                       ((AF_B,), I32)]),
+    # the full layers' walk: K and V in one page of 8 head rows (32 KiB a
+    # copy), 768 table columns, no selection
+    "afmoe_fused_paged_decode_b64": (
+        _afmoe_fused_walk, [((AF_B, 1, AF_HQ, D), BF16), _AF_KV,
+                            ((AF_B, AF_SEQ // PAGE), I32), ((AF_B,), I32)]),
+    # an 8,192-token admission's attention, the whole scanned program:
+    # a window layer's blocks over the 2,304 keys they can see, a full
+    # layer's over the prompt up to their last row
+    "afmoe_window_prefill_t8192": (_afmoe_prefill(AF_W), _AF_PROMPT),
+    "afmoe_full_prefill_t8192": (_afmoe_prefill(0), _AF_PROMPT),
+    # the ragged grouped GEMMs over 16 held experts: a decode tick's 512
+    # pairs (~64 of them held) and an admission's 65,536 (~8,192)
+    "afmoe_ragged_gmm_decode_pairs512": _afmoe_ragged(512),
+    "afmoe_ragged_gmm_admit_pairs65536": _afmoe_ragged(65536),
     # the decode step's index scores: 32 slots' one row each over the
     # per-slot plane, ten blocks of 2,048 positions a slot: [32, 20480]
     "keye_index_walk_b32": (

@@ -583,6 +583,21 @@ def set_page_rows(pool, pidx, r, u):
         u.astype(pool.dtype)).reshape(pool.shape)
 
 
+def set_prompt_pages(pool, page_ids, rows):
+    """Write a whole prompt a PAGE at a time into a pool plane
+    [NP, h, page, d]: rows [P, h, d] at positions 0 .. P-1 (a prompt
+    starts at a page's first row), page_ids [ceil(P / page)] the page of
+    each `page` positions (the trash page for one wholly past the
+    prompt's end). What the padding leaves behind the prompt in its
+    last page lies past the slot's length until decode overwrites
+    it."""
+    n, page, P_ = page_ids.shape[0], pool.shape[2], rows.shape[0]
+    rows = jnp.pad(rows, ((0, n * page - P_), (0, 0), (0, 0)))
+    return pool.at[page_ids].set(
+        rows.reshape((n, page) + rows.shape[1:]).swapaxes(1, 2)
+        .astype(pool.dtype))
+
+
 def gather_pages(pool, table):
     """The oracle's read of a paged pool plane: [NP, h, page, d]
     through table [B, maxp] -> the slots' contiguous [B, h, maxp*page,

@@ -53,6 +53,17 @@ def rope_rows(cos, sin, positions, sections=()):
     return cos[at, pair], sin[at, pair]
 
 
+def rotate_rows(x, c, s):
+    """x [M, H, d] (or [M, d]) rotated by the rows' own angles c, s
+    [M, d / 2] (`rope_rows`), dim i paired with dim i + d / 2; float32
+    inside, x's dtype out."""
+    if x.ndim == 3:
+        c, s = c[:, None], s[:, None]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
+                           axis=-1).astype(x.dtype)
+
+
 def apply_rope(x, cos, sin, positions, sections=()):
     """Rotate half-pairs: x [..., S, H, D]; cos/sin [max_seq, D/2];
     positions [S], or [3, S] with `sections` (`rope_rows`) (ref:

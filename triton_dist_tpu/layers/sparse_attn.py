@@ -51,7 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from triton_dist_tpu.kernels.paged_kv import (flash_decode_paged,
-                                              gather_pages, set_page_rows)
+                                              gather_pages, set_page_rows,
+                                              set_prompt_pages)
 from triton_dist_tpu.kernels.quant import qmm
 from triton_dist_tpu.kernels.sparse_attn import (append_index_keys,
                                                  index_scores,
@@ -60,18 +61,10 @@ from triton_dist_tpu.kernels.sparse_attn import (append_index_keys,
                                                  select_topk,
                                                  selected_attention,
                                                  unpack_index_keys)
-from triton_dist_tpu.layers.common import rms_norm, rope_rows
+from triton_dist_tpu.layers.common import (rms_norm, rope_rows,
+                                            rotate_rows as _rope)
 
 _PREFILL_Q = 256      # query rows per attention call of a prefill
-
-
-def _rope(x, c, s):
-    """x [M, H, d] (or [M, d]); c, s [M, d / 2]; half-split."""
-    if x.ndim == 3:
-        c, s = c[:, None], s[:, None]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
-                           axis=-1).astype(x.dtype)
 
 
 @jax.tree_util.register_dataclass
@@ -159,16 +152,9 @@ class SA_Attn:
         returns (attention output [P, D], kv_pool, idx_pool[, the
         selection [P, P] bool])."""
         P_ = u.shape[0]
-        page = kv_pool.shape[2]
         Hkv, d = self.n_kv_heads, self.head_dim
         q, kv, qi, ki, w = self.project(u, rope, rope_i)
-
-        def paged(rows):        # [P, h, x] -> [pages, h, page, x]
-            n = page_ids.shape[0]
-            rows = jnp.pad(rows, ((0, n * page - P_), (0, 0), (0, 0)))
-            return rows.reshape((n, page) + rows.shape[1:]).swapaxes(1, 2)
-
-        kv_pool = kv_pool.at[page_ids].set(paged(kv).astype(kv_pool.dtype))
+        kv_pool = set_prompt_pages(kv_pool, page_ids, kv)
         # the prompt's keys as the plane holds them: what the slot's
         # run opens with, and what the blocks below score against
         kp = pack_index_keys(ki.astype(idx_pool.dtype),

@@ -3,6 +3,8 @@ SURVEY.md §2.4). `AutoLLM` dispatches by model name/config the way the
 reference does (models/__init__.py:33-59: Qwen3 -> DenseLLM,
 Qwen3-MoE -> Qwen3MoE)."""
 
+from triton_dist_tpu.models.afmoe import (Afmoe, AfmoeConfig,  # noqa: F401
+                                          tiny_afmoe)
 from triton_dist_tpu.models.config import (ModelConfig, SAConfig,  # noqa: F401
                                            qwen3_30b_a3b, qwen3_32b,
                                            tiny_qwen3, tiny_qwen3_moe)

@@ -11,6 +11,8 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from triton_dist_tpu.models.utils import EXPERTS_TOUCHED_COUNTERS
+
 
 @dataclasses.dataclass(frozen=True)
 class SAConfig:
@@ -70,6 +72,23 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def load_counters(self) -> tuple:
+        """(name, help, labels) of the counters the scheduler folds the
+        entries of a tick's routing-load vector into that follow
+        [.., dropped, pairs routed, pairs held], in the order of the
+        model's `_zero_load` (models/qwen_moe.py `_sa_ffn`)."""
+        if self.sa_config is None:
+            return ()
+        return (
+            ("sa_positions_in_context",
+             "cached positions the decode steps' indexers scored, over "
+             "slots and layers", None),
+            ("sa_positions_attended",
+             "those of sa_positions_in_context the steps attended: the "
+             "positions the walk's mask let through, a slot and layer",
+             None)) + EXPERTS_TOUCHED_COUNTERS
 
     @staticmethod
     def from_hf_config(path_or_dict) -> "ModelConfig":

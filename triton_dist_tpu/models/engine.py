@@ -756,8 +756,8 @@ class Engine:
         if not hasattr(self.model, "forward_tokens_slots_paged"):
             raise ValueError(
                 f"{type(self.model).__name__} has no paged slot decode "
-                "path (DenseLLM, Qwen3MoE, Phi4Flash and DeepSeekV3 "
-                "carry the serving surface)")
+                "path (DenseLLM, Qwen3MoE, Phi4Flash, DeepSeekV3 and "
+                "Afmoe carry the serving surface)")
         if for_ticks:
             # a pool that will DRIVE decode/verify/mixed ticks feeds
             # its batch rows to the row-sharded EP dispatch; staging

@@ -464,24 +464,34 @@ class IndexedSlotCache(PagedSlotCache):
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class HybridSlotCache(PagedSlotCache):
-    """One slot cache for a model whose layers keep THREE kinds of
-    per-slot state (models/phi4flash.py; ROADMAP Queue 2 A.3 / A.5):
+    """One slot cache for a model whose layers keep up to THREE kinds of
+    per-slot state (models/phi4flash.py, models/afmoe.py):
 
-    (a) pages behind the shared table, inherited from PagedSlotCache —
-        here ONE pool (`pages_k[0]`), the full-attention layer's, which
-        that layer and every cross-attention layer read. Allocator,
-        table install, retire-to-trash: all the paged path's own.
+    (a) pages behind the shared table, inherited from PagedSlotCache:
+        `paged_layers` pools, `pages_k[i]` the i-th paged layer's.
+        Phi-4-mini-flash has ONE (its full-attention layer's, which
+        that layer and every cross-attention layer read, K and V in
+        two planes); Trinity has one for every full-attention layer
+        (`fused`: K and V in ONE plane [NP, 2 heads, page, d], a
+        page's first `heads` rows the keys, its last the values, so
+        the decode walk fetches both with one copy a page, as
+        IndexedSlotCache; `pages_v` is then empty). Allocator, table
+        install, retire-to-trash: all the paged path's own.
     (b) `win_k` / `win_v`: one RING of `window` positions per slot and
         window-attention layer, [B, heads, window, d]. Position t lives
         in row t % window, so a slot's bytes are bounded by the window
-        whatever max_seq is. The model has no positional encoding, so
-        attention is indifferent to the order of the rows: a decode
-        step attends the first min(t + 1, window) rows of the ring as
-        an ordinary cached attention (the choice between a ring and
-        pages freed behind the window: the ring needs no allocator
-        traffic per 16 tokens and no window start in the paged walk).
+        whatever max_seq is. What a ring holds is FINAL: a model with
+        positions (afmoe: rotary on the window layers) rotates a key at
+        its absolute position BEFORE it writes it, a model without
+        (phi4flash) writes it as projected; either way attention is
+        indifferent to the order of the rows, and a decode step attends
+        the first min(t + 1, window) rows of the ring as an ordinary
+        cached attention (the choice between a ring and pages freed
+        behind the window: the ring needs no allocator traffic per 16
+        tokens and no window start in the paged walk).
     (c) `conv` [B, d_conv - 1, E] and `ssm` [B, d_state, E] float32
-        planes per state-space layer: fixed size, not pageable.
+        planes per state-space layer: fixed size, not pageable; none
+        (`state_layers` 0) in a model of attention layers only.
 
     `live` [B] marks the slots that hold an admitted request: a decode
     step advances (c) for live slots only and leaves every other slot's
@@ -504,10 +514,12 @@ class HybridSlotCache(PagedSlotCache):
                       state_layers: int, d_inner: int, d_state: int,
                       d_conv: int, attn_layers: int, page: int,
                       num_pages: int, mesh: Mesh, axis: str = "tp",
-                      dtype=jnp.bfloat16) -> "HybridSlotCache":
-        base = PagedSlotCache.create(1, batch, max_seq, heads, head_dim,
-                                     page=page, num_pages=num_pages,
-                                     mesh=mesh, axis=axis, dtype=dtype)
+                      dtype=jnp.bfloat16, paged_layers: int = 1,
+                      fused: bool = False) -> "HybridSlotCache":
+        base = PagedSlotCache.create(
+            paged_layers, batch, max_seq, heads * (2 if fused else 1),
+            head_dim, page=page, num_pages=num_pages, mesh=mesh,
+            axis=axis, dtype=dtype)
         rep = NamedSharding(mesh, P())
 
         def planes(n, shape, dt):
@@ -516,8 +528,8 @@ class HybridSlotCache(PagedSlotCache):
 
         ring = (batch, heads, window, head_dim)
         return HybridSlotCache(
-            pages_k=base.pages_k, pages_v=base.pages_v, table=base.table,
-            trash=base.trash,
+            pages_k=base.pages_k, pages_v=() if fused else base.pages_v,
+            table=base.table, trash=base.trash,
             win_k=planes(window_layers, ring, dtype),
             win_v=planes(window_layers, ring, dtype),
             conv=planes(state_layers, (batch, d_conv - 1, d_inner),
@@ -526,6 +538,10 @@ class HybridSlotCache(PagedSlotCache):
                        jnp.float32),
             live=jax.device_put(jnp.zeros((batch,), bool), rep),
             attn_layers=attn_layers)
+
+    @property
+    def kv_heads(self) -> int:
+        return self.pages_k[0].shape[1] // (1 if self.pages_v else 2)
 
     def clear_slot(self, slot) -> "HybridSlotCache":
         """Retire: zero the slot's recurrent planes and mark it dead.
@@ -541,12 +557,16 @@ class HybridSlotCache(PagedSlotCache):
 
     def slot_bytes(self) -> dict:
         """Bytes ONE slot holds of each kind: a mapped page of (a) (K
-        and V, every head), its rings (b), its planes (c); and what a
-        page would cost in a uniform cache, where each of the
-        `attn_layers` attention layers keeps its own."""
+        and V, every head, every paged layer), its rings (b), its
+        planes (c, left out where the model has none); and what a page
+        would cost in a uniform cache, where each of the `attn_layers`
+        attention layers keeps its own."""
         nbytes = lambda t: sum(a[0].nbytes for a in t)  # noqa: E731
-        page = 2 * self.pages_k[0][0].nbytes
-        return {"page": page,
-                "window": nbytes(self.win_k) + nbytes(self.win_v),
-                "state": nbytes(self.conv) + nbytes(self.ssm),
-                "uniform_page": page * self.attn_layers}
+        page = nbytes(self.pages_k) + nbytes(self.pages_v)
+        out = {"page": page,
+               "window": nbytes(self.win_k) + nbytes(self.win_v),
+               "uniform_page": page // len(self.pages_k)
+               * self.attn_layers}
+        if self.conv:
+            out["state"] = nbytes(self.conv) + nbytes(self.ssm)
+        return out

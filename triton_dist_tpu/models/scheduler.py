@@ -529,26 +529,13 @@ class DecodeSlots:
             self._g_moe_imb = reg.gauge(
                 "expert_load_imbalance",
                 "max/mean of cumulative per-expert routed load")
-            if getattr(mcfg, "sa_config", None) is not None:
-                # learned sparse attention: two more entries of the same
-                # vector (models/qwen_moe.py `_zero_load`)
-                self._c_sa_context = reg.counter(
-                    "sa_positions_in_context",
-                    "cached positions the decode steps' indexers "
-                    "scored, over slots and layers")
-                self._c_sa_attended = reg.counter(
-                    "sa_positions_attended",
-                    "those of sa_positions_in_context the steps "
-                    "attended: the positions the walk's mask let "
-                    "through, a slot and layer")
-                self._c_experts_touched = reg.counter(
-                    "moe_experts_touched",
-                    "held experts that a decode step's pairs reached "
-                    "(whose weights the grouped GEMM read), over steps "
-                    "and layers")
-                self._c_experts_offered = reg.counter(
-                    "moe_experts_offered",
-                    "held experts, over the same steps and layers")
+            # what a share's vector holds behind [.., dropped, pairs
+            # routed, pairs held]: the model's config names the
+            # counters, in the order of its `_zero_load`
+            self._c_load_extra = [
+                reg.counter(name, text, labels=labels)
+                for name, text, labels in getattr(mcfg, "load_counters",
+                                                  ())]
         self.spec = int(spec)
         if self.spec:
             from triton_dist_tpu.models.spec_decode import NgramDrafter
@@ -867,11 +854,8 @@ class DecodeSlots:
                         else (counts.sum() + dropped,) * 2)
         self._c_pairs_routed.inc(int(routed))
         self._c_pairs_held.inc(int(held))
-        if len(load) > E + 3:
-            self._c_sa_context.inc(int(load[E + 3]))
-            self._c_sa_attended.inc(int(load[E + 4]))
-            self._c_experts_touched.inc(int(load[E + 5]))
-            self._c_experts_offered.inc(int(load[E + 6]))
+        for c, v in zip(self._c_load_extra, load[E + 3:]):
+            c.inc(int(v))
         for e in np.nonzero(counts)[0]:
             self._c_expert[int(e)].inc(int(counts[e]))
         if dropped:

@@ -42,6 +42,16 @@ class ServingTraits:
     own_pool: str | None = None
 
 
+# (name, help, labels) of the two entries a share's routing-load vector
+# ends with where the model counts them (a config's `load_counters`)
+EXPERTS_TOUCHED_COUNTERS = (
+    ("moe_experts_touched",
+     "held experts that a decode step's pairs reached (whose weights "
+     "the grouped GEMM read), over steps and layers", None),
+    ("moe_experts_offered",
+     "held experts, over the same steps and layers", None))
+
+
 def place_replicated(tree, mesh):
     """Place `tree` over `mesh`: the one placement point at a model's
     boundary (every constructor, and a caller that builds a model from
