@@ -427,6 +427,58 @@ def test_kernel_compiles_for_v5e(name, one_chip, for_chip):
         f"{name}: no Mosaic kernel in the compiled program")
 
 
+@pytest.mark.parametrize("hkv", [8, 2], ids=["1chip", "tp4"])
+def test_kv_append_updates_the_pool_in_place(hkv, one_chip, for_chip):
+    """The decode scan's append (append_page_rows) then walk, over
+    DONATED planes, at a cell's pool (_paged_cell): the compiled
+    program holds no copy of a plane, as [NP, hkv, page, d] or as the
+    scatter's [NP*hkv, page, d] view — what the alias must give and
+    interpret mode cannot show. The append is its own `kv_append`
+    custom call, which neither the walk's roofline pattern nor the
+    fused GEMM + all-reduce's picks up (benchmark/metrics)."""
+    import json
+    import re
+    from triton_dist_tpu.kernels.paged_kv import (append_page_rows,
+                                                  flash_decode_paged)
+    q, pool, _, table, lens = _paged_cell(hkv)
+    b, maxp = table[0]
+    (np_, _, page, d), _ = pool
+
+    def scan(pk, pv, q, k, v, tbl, pos):
+        def step(c, x):
+            pk, pv = append_page_rows(*c, tbl[jnp.arange(b), x // page],
+                                      x % page, k, v)
+            return (pk, pv), flash_decode_paged(q, pk, pv, tbl,
+                                                jnp.max(x + 1),
+                                                kv_lens=x + 1)
+        return jax.lax.scan(step, (pk, pv), pos + jnp.arange(4)[:, None])
+
+    shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in
+              (pool, pool, q, ((b, hkv, d), BF16), ((b, hkv, d), BF16),
+               table, lens)]
+    text = jax.jit(scan, donate_argnums=(0, 1)).lower(
+        *shapes).compile().as_text()
+    planes = (f"[{np_},{hkv},{page},{d}]", f"[{np_ * hkv},{page},{d}]")
+    ops = [line.strip() for line in text.splitlines()]
+    copies = [o for o in ops if re.search(r" copy(-start)?\(", o)
+              and any(p in o.split(" copy")[0] for p in planes)]
+    assert not copies, copies[:2]
+    appends = [o for o in ops if o.startswith("%kv_append")]
+    assert len(appends) == 1 and "custom-call(" in appends[0]
+    # both planes pinned to HBM (memory color 0): a TP=4 plane fits
+    # VMEM, and left to XLA the whole scan moved planes there
+    assert '"output_memory_colors":["0","0"]' in appends[0]
+
+    def pattern(metric):
+        with open(os.path.join(os.path.dirname(__file__), "..",
+                               "benchmark", "metrics",
+                               f"{metric}.json")) as f:
+            return re.compile(json.load(f)["args"]["patterns"][0])
+    for metric in ("decode_attn_roofline.sat", "tp_gemm_ar_roofline.sat"):
+        assert not pattern(metric).search(appends[0]), metric
+    assert any(pattern("decode_attn_roofline.sat").search(o) for o in ops)
+
+
 def test_int8_pool_compiles_at_page_128_only(one_chip, for_chip):
     """The int8 pool's scale planes are [NP, Hkv, page] f32 and the
     walk copies one page's rows of them: at page 16, as served, that is

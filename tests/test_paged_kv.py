@@ -628,6 +628,72 @@ def test_paged_walk_bitwise_whatever_w_and_neighbours(Hkv, rep):
         assert _bits(again) == _bits(base), f"block_w={w}"
 
 
+# ---------------------------------------------------------------------
+# the decode append of a two-plane pool: one aliased launch a layer
+# (append_page_rows) in place of a scatter a plane
+# ---------------------------------------------------------------------
+
+@pytest.mark.parametrize("tp,hkv", [(1, 8), (4, 2)], ids=["1chip", "tp4"])
+def test_kv_append_equals_the_scatter(tp, hkv):
+    """At the cells' page layouts (8 heads a page on one chip of
+    Qwen3-1.7B, 2 on a TP=4 chip; page 16, 32 slots) the kernel's
+    planes are set_page_rows's bitwise on every page but the trash
+    page 0: rows at 0 (a fresh page) and at 15, the live slots on
+    distinct pages, three retired ones on the trash page. Then one
+    decode step of TP_Attn._attend_paged_slots on `tp` chips: the
+    flash path (the kernel, the walk) against the "ref" path (the
+    scatter, gather + oracle)."""
+    from triton_dist_tpu.kernels.paged_kv import (append_page_rows,
+                                                  set_page_rows)
+    from triton_dist_tpu.layers import TP_Attn, precompute_rope
+    if len(jax.devices()) < tp:
+        pytest.skip(f"needs >= {tp} devices")
+    rng = np.random.RandomState(44)
+    B, NP = 32, 40
+    pk, pv = (jnp.asarray(rng.randn(NP, hkv, _PAGE, _D), jnp.bfloat16)
+              for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(B, hkv, _D), jnp.bfloat16)
+            for _ in range(2))
+    pidx = np.concatenate([1 + rng.permutation(NP - 1)[:B - 3], [0] * 3])
+    r = rng.randint(0, _PAGE, B)
+    r[:4], r[-3:] = [0, 0, 15, 15], [0, 7, 15]
+    got = jax.jit(append_page_rows)(pk, pv, jnp.asarray(pidx, jnp.int32),
+                                    jnp.asarray(r, jnp.int32), k, v)
+    for plane, new, out in zip((pk, pv), (k, v), got):
+        want = set_page_rows(plane, jnp.asarray(pidx), jnp.asarray(r), new)
+        assert _bits(out[1:]) == _bits(want[1:])
+        # a row of the trash page holds what it held or what a retired
+        # slot wrote there
+        for i in range(_PAGE):
+            keep = [plane[0, :, i]] + [new[j] for j in range(B - 3, B)
+                                       if r[j] == i]
+            assert any(_bits(out[0, :, i]) == _bits(x) for x in keep)
+
+    mesh = jax.make_mesh((tp,), ("tp",))
+    Hkv, Hq, hd, D, T = hkv * tp, 2 * hkv * tp, _D, 64, 4 * _PAGE
+    Bs = 4
+    w = lambda *s: rng.randn(*s).astype(np.float32) * 0.1
+    attn = TP_Attn.init(w(D, Hq * hd), w(D, Hkv * hd), w(D, Hkv * hd),
+                        w(Hq * hd, D), mesh=mesh, n_heads=Hq,
+                        n_kv_heads=Hkv, head_dim=hd)
+    cos, sin = precompute_rope(hd, T)
+    NPs = 1 + Bs * (T // _PAGE)
+    pool = [jnp.asarray(rng.randn(NPs, Hkv, _PAGE, hd), jnp.float32)
+            for _ in range(2)]
+    table = jnp.asarray(1 + rng.permutation(NPs - 1).reshape(Bs, -1),
+                        jnp.int32)
+    pos = jnp.asarray([0, 15, 16, 40], jnp.int32)
+    qkv = jnp.asarray(rng.randn(Bs, (Hq + 2 * Hkv) * hd), jnp.float32)
+    step = jax.jit(lambda impl: attn._attend_paged_slots(
+        qkv, cos, sin, Bs, tuple(pool), table, pos, impl),
+        static_argnums=0)
+    (o, kv), (o_ref, kv_ref) = step("flash"), step("ref")
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               atol=2e-4, rtol=2e-4)
+    for a, b in zip(kv, kv_ref):
+        assert _bits(a) == _bits(b)
+
+
 def test_allocator_hands_out_one_id_a_tile_and_conserves_them():
     """A slot of n positions takes ceil(n / page) ids, whatever its
     head count (a page holds them all), no id twice, and every id comes

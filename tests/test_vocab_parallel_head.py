@@ -277,16 +277,21 @@ def test_scan_compiles_once_and_a_warm_burst_compiles_nothing(n):
         np.testing.assert_array_equal(again[r.rid], out[r.rid])
 
 
-@pytest.mark.parametrize("n,shards", [(_TP, _TP), (1, 1)])
-def test_lm_head_shards_gauge(n, shards):
+@pytest.mark.parametrize("n,shards,kw,append", [
+    (_TP, _TP, {}, 1), (1, 1, {}, 1), (1, 1, {"kv_dtype": "int8"}, 0)])
+def test_path_gauges(n, shards, kw, append):
+    """lm_head_shards, and kv_append_kernel: whether the decode append
+    to the K/V pages is the one-launch kernel (the int8 pool scatters)."""
     from triton_dist_tpu.runtime.telemetry import prometheus_text
-    sched = ContinuousScheduler(_engine(n), batch=2, paged=True, chunk=2,
-                                page=8)
+    sched = ContinuousScheduler(_engine(n, **kw), batch=2, paged=True,
+                                chunk=2, page=8)
     st = sched.stats()
     assert st["lm_head_shards"] == shards
     assert st["tp_size"] == n
-    assert f"tdtpu_lm_head_shards {shards:g}\n" in \
-        prometheus_text(sched.slots.tele.registry) + "\n"
+    assert st["kv_append_kernel"] == append
+    text = prometheus_text(sched.slots.tele.registry) + "\n"
+    assert f"tdtpu_lm_head_shards {shards:g}\n" in text
+    assert f"tdtpu_kv_append_kernel {append:g}\n" in text
 
 
 @pytest.mark.parametrize("mode", ["greedy_with_grammar", "sampled"])

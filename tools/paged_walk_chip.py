@@ -4,6 +4,7 @@ what the CPU interpreter cannot show of it.
     chiprun -- python tools/paged_walk_chip.py                 # all three
     chiprun -- python tools/paged_walk_chip.py check time gmm --shapes mla
     chiprun -- python tools/paged_walk_chip.py time --block-w 4 8 16
+    chiprun -- python tools/paged_walk_chip.py append --shapes 1chip tp4
     JAX_PLATFORMS=cpu python tools/paged_walk_chip.py --rehearse
 
 Three phases, each one JSON line per reading, `{"ok": true, ...}` last:
@@ -52,6 +53,10 @@ Three phases, each one JSON line per reading, `{"ok": true, ...}` last:
   and as a plain row write, the same walk over TWO planes (K and V
   pages apart, two copies a page) under the same set, and the same set
   by `jax.lax.top_k` and a scatter to the mask.
+- `append` (only when asked for): the decode append of the two-plane
+  pool (`append_page_rows`, one launch for K and V) against two
+  scatters (`set_page_rows`) at the served pools, bitwise but the trash
+  page, then us a call over 28 chained calls on donated planes.
 """
 
 from __future__ import annotations
@@ -596,10 +601,67 @@ def sa(rehearse: bool) -> None:
              if name == "selected_walk" else None)
 
 
+def append(rehearse: bool) -> None:
+    """The decode append of the two-plane pool (append_page_rows, one
+    aliased launch for K and V) against set_page_rows on each plane, at
+    the served pools: bitwise on every page but the trash page 0, with
+    three retired slots on it, rows at 0 and 15; then ms a call over
+    28 chained calls on donated planes (a step's layers), each form."""
+    import jax
+    import jax.numpy as jnp
+    from triton_dist_tpu.kernels.paged_kv import (append_page_rows,
+                                                  set_page_rows)
+
+    def scatter(pk, pv, pidx, r, k, v):
+        return (set_page_rows(pk, pidx, r, k), set_page_rows(pv, pidx, r, v))
+
+    rng = np.random.RandomState(44)
+    for name in ("1chip", "tp4"):
+        if name not in SHAPES:
+            continue
+        B, Hkv = SHAPES[name]["B"], SHAPES[name]["Hkv"]
+        NP = B * (8 if rehearse else TIMED[name][0]) + 1
+        pk, pv = (jax.random.normal(jax.random.PRNGKey(i), (NP, Hkv, PAGE,
+                                    D), jnp.bfloat16) for i in range(2))
+        k, v = (jnp.asarray(rng.randn(B, Hkv, D), jnp.bfloat16)
+                for _ in range(2))
+        pidx = np.concatenate([1 + rng.permutation(NP - 1)[:B - 3], [0] * 3])
+        r = rng.randint(0, PAGE, B)
+        r[:4] = [0, 0, 15, 15]
+        pidx, r = jnp.asarray(pidx, jnp.int32), jnp.asarray(r, jnp.int32)
+        got = jax.jit(append_page_rows)(pk, pv, pidx, r, k, v)
+        want = jax.jit(scatter)(pk, pv, pidx, r, k, v)
+        same = [bool(jnp.array_equal(a[1:], b[1:]))
+                for a, b in zip(got, want)]
+        _log(phase="append", shape=name, bitwise=same)
+        assert all(same), name
+        if rehearse:
+            continue
+        for form, fn in (("kv_append", append_page_rows),
+                         ("scatter", scatter)):
+            def chain(pk, pv, pidx, r, k, v, fn=fn):
+                def body(c, i):
+                    return fn(*c, pidx, (r + i) % PAGE, k + i.astype(k.dtype),
+                              v), ()
+                return jax.lax.scan(body, (pk, pv), jnp.arange(CALLS))[0]
+            f = jax.jit(chain, donate_argnums=(0, 1))
+            pk, pv = f(pk, pv, pidx, r, k, v)
+            jax.block_until_ready(pk)
+            ts = []
+            for _ in range(5):
+                t0 = time.perf_counter()
+                pk, pv = f(pk, pv, pidx, r, k, v)
+                jax.block_until_ready(pk)
+                ts.append(time.perf_counter() - t0)
+            _log(phase="append_time", shape=name, form=form,
+                 us_min=round(min(ts) / CALLS * 1e6, 2),
+                 us_med=round(float(np.median(ts)) / CALLS * 1e6, 2))
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("phases", nargs="*", default=["check", "mixed", "time"],
-                    help="of check, mixed, time, gmm, sa (default: "
+                    help="of check, mixed, time, gmm, sa, append (default: "
                          "the first three)")
     ap.add_argument("--shapes", nargs="*", default=None,
                     help="of " + ", ".join(SHAPES) + " (default: all)")
@@ -633,6 +695,8 @@ def main() -> None:
         gmm(args.rehearse)
     if "sa" in args.phases:
         sa(args.rehearse)
+    if "append" in args.phases:
+        append(args.rehearse)
     _log(ok=True, device={"platform": dev.platform, "kind": dev.device_kind})
 
 

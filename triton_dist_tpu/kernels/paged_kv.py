@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -575,12 +576,103 @@ def set_page_rows(pool, pidx, r, u):
     35). A single index over [NP*h*page(, d)] read 1.43 there, and a
     head axis between the two indexed ones 90.7 us an append against
     52.0 at Phi-4's shape (models/phi4flash.py's rings met the
-    same)."""
+    same). The decode step of the two-plane pool on a kernel backend
+    writes through append_page_rows instead (PR 44)."""
     NP, h = pool.shape[:2]
     rows = pidx[..., None] * h + jnp.arange(h)           # [..., h]
     flat = pool.reshape((NP * h,) + pool.shape[2:])
     return flat.at[rows, r[..., None]].set(
         u.astype(pool.dtype)).reshape(pool.shape)
+
+
+def _append_kernel(B: int, pidx_ref, r_ref, k_ref, v_ref, pk_in, pv_in,
+                   pk_ref, pv_ref, kbuf, vbuf, sem):
+    """One launch, every slot: read each slot's page of both planes
+    into VMEM, overlay its row, write the pages back. refs: the new
+    rows [B, H, 1, d] in VMEM, the planes [NP, H, page, d] in HBM (the
+    outputs alias them), the page buffers [B, H, page, d], one DMA
+    semaphore a plane."""
+    del pk_in, pv_in     # the same buffers as pk_ref, pv_ref (aliased)
+    planes = ((pk_ref, kbuf, k_ref), (pv_ref, vbuf, v_ref))
+
+    def copy_all(read: bool):
+        def body(b, c):
+            for n, (pool, buf, _) in enumerate(planes):
+                src, dst = pool.at[pidx_ref[b]], buf.at[b]
+                if not read:
+                    src, dst = dst, src
+                pltpu.make_async_copy(src, dst, sem.at[n]).start()
+            return c
+
+        jax.lax.fori_loop(0, B, body, 0)
+        # every copy of a plane signals its semaphore by its bytes: one
+        # wait a plane, for all B pages
+        for n, (_, buf, _) in enumerate(planes):
+            pltpu.make_async_copy(buf, buf, sem.at[n]).wait()
+
+    copy_all(read=True)
+    row = jax.lax.broadcasted_iota(jnp.int32, kbuf.shape[1:], 1)
+
+    def overlay(b, c):
+        for _, buf, new in planes:
+            buf[b] = jnp.where(row == r_ref[b], new[b], buf[b])
+        return c
+
+    jax.lax.fori_loop(0, B, overlay, 0)
+    copy_all(read=False)
+
+
+def append_page_rows(pages_k, pages_v, pidx, r, k, v):
+    """The decode append of a two-plane pool [NP, H, page, d], in place:
+    k, v [B, H, d] land at in-page row r [B] of every head of page
+    pidx [B] — set_page_rows on both planes, as ONE aliased Pallas
+    launch in place of two XLA scatters of B*H rows (which run their
+    rows one after another, ~75 ns a row on a v5e; PERF.md, PR 44).
+
+    Each slot's whole page ([H, page, d]: 32 KiB at 8 heads of page 16)
+    comes into VMEM, takes its row there and goes back: a page of 16
+    bf16 rows is one native tile a head, so every copy is tile-aligned,
+    where a single row at a traced sublane offset is not (kv_update).
+    Every read is started before any is waited for, and every write
+    after the last overlay. Every pidx must be a page of the pool (a
+    decode table's entries are); a page two slots share (retired slots
+    on the trash page) holds the last writer's row alone."""
+    B, H, d = k.shape
+    full = pl.BlockSpec((B, H, 1, d), lambda i, p, r: (0, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_append_kernel, B),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[full, full, hbm, hbm],
+            out_specs=(hbm, hbm),
+            scratch_shapes=[pltpu.VMEM((B,) + pages_k.shape[1:],
+                                       pages_k.dtype),
+                            pltpu.VMEM((B,) + pages_v.shape[1:],
+                                       pages_v.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        # the planes stay in HBM (and, aliased, the inputs with them):
+        # left to XLA, a TP=4 chip's 33.6 MB plane fits VMEM, and the
+        # 16-layer scan moved 1.5 GB of whole planes into it a step to
+        # rewrite 256 KiB of pages a plane (compile, PR 44)
+        out_shape=(pltpu.HBM(pages_k.shape, pages_k.dtype),
+                   pltpu.HBM(pages_v.shape, pages_v.dtype)),
+        input_output_aliases={4: 0, 5: 1},
+        # what the launch moves: XLA places the next weights' prefetch
+        # into VMEM by its cost model, and costed as nothing this call
+        # hid none, so half the down projections read HBM (PERF.md, PR
+        # 44)
+        cost_estimate=pl.CostEstimate(
+            flops=0, transcendentals=0,
+            bytes_accessed=4 * B * math.prod(pages_k.shape[1:])
+            * pages_k.dtype.itemsize),
+        name="kv_append",
+        interpret=interpret_mode(),
+    )(jnp.asarray(pidx, jnp.int32), jnp.asarray(r, jnp.int32),
+      k.astype(pages_k.dtype)[:, :, None],
+      v.astype(pages_v.dtype)[:, :, None], pages_k, pages_v)
 
 
 def set_prompt_pages(pool, page_ids, rows):

@@ -26,7 +26,8 @@ from triton_dist_tpu.kernels import (ag_gemm, all_reduce,
                                      create_gemm_ar_context,
                                      create_gemm_rs_context, gemm_allreduce,
                                      gemm_rs)
-from triton_dist_tpu.kernels.paged_kv import gather_pages, set_page_rows
+from triton_dist_tpu.kernels.paged_kv import (append_page_rows, gather_pages,
+                                           set_page_rows)
 from triton_dist_tpu.layers.common import (apply_rope, apply_rope_slots,
                                            rms_norm, shard_cols_packed)
 
@@ -731,6 +732,10 @@ class TP_Attn:
                 cv = set_page_rows(cv, pidx, r, v8)
                 sk = set_page_rows(sk, pidx, r, k_s)
                 sv = set_page_rows(sv, pidx, r, v_s)
+            elif impl == "flash":
+                # both planes in one aliased launch, not two scatters
+                ck, cv = append_page_rows(ck, cv, pidx, r, k, v)
+                sk = sv = None
             else:
                 ck = set_page_rows(ck, pidx, r, k)
                 cv = set_page_rows(cv, pidx, r, v)

@@ -133,6 +133,14 @@ class Engine:
         from triton_dist_tpu.models.utils import last_axis_sharding
         vax = getattr(model, "vocab_axis", None)
         self.lm_head_shards = int(model.mesh.shape[vax]) if vax else 1
+        # whether a decode step appends to the K and V pages as ONE
+        # kv_append launch a layer (kernels/paged_kv.append_page_rows,
+        # layers/tp_attn.py _attend_paged_slots): the pages the engine
+        # moves itself, on a kernel backend; the int8 pool, the
+        # sp-sharded pool and the xla oracle scatter
+        self.kv_append_kernel = int(
+            self._own is None and kv_dtype is None
+            and self.sp_size == 1 and backend != "xla")
         self.logits_sharding = last_axis_sharding(model.mesh, 2, vax)
         # An expert-SHARDED model feeds row-sharded token batches to
         # the EP FFN (the a2a dispatch on the ep backends, the

@@ -165,7 +165,9 @@ scheduler code serves TP=1 and TP=8 with bitwise-identical streams
 AND per-chip tok/s, and lm_head_shards: a dense model's LM head is
 split over the vocabulary on the TP axis (models/dense.py), the
 logits carry of DecodeSlots with it, so a chip reads a quarter of the
-head at TP=4 (tests/test_vocab_parallel_head.py).
+head at TP=4 (tests/test_vocab_parallel_head.py); and kv_append_kernel,
+1 where a decode step appends to the K/V pages as one Pallas launch a
+layer (kernels/paged_kv.append_page_rows).
 
 Disaggregation (models/disagg.py — DistServe, 2401.09670): chunked
 prefill BOUNDS the prefill stall on live streams; `DisaggScheduler`
@@ -2180,6 +2182,11 @@ class ContinuousScheduler:
                   "chips the LM head's vocabulary columns are split "
                   "over (1 = every chip reads the whole head)").set(
             engine.lm_head_shards)
+        reg.gauge("kv_append_kernel",
+                  "1 where a decode step appends to the K/V pages as "
+                  "one kv_append launch a layer, 0 where it scatters "
+                  "(int8 pool, sequence-parallel pool, xla)").set(
+            engine.kv_append_kernel)
         # sequence-parallel topology (long-context serving): the sp
         # mesh size the paged pool's page-id space shards over —
         # per-chip KV reads and attention FLOPs drop to ~1/sp_size and
