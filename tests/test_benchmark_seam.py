@@ -396,3 +396,66 @@ def test_ring_adapter_counts_what_the_metric_files_divide(served_rings):
             assert args.get(k, "tokens_emitted") in after, (name, k)
         for k in args.get("numerators", []) + args.get("denominators", []):
             assert k in after, (name, k)
+
+
+# ----------------------------------------------------------------------
+# the falcon_h1 family's adapter (benchmark/systems/falcon_h1_server.py)
+# on a tiny configuration of that family: what the `.reply` metric
+# files (benchmark/metrics/*.reply.json) take from stats() by name
+# ----------------------------------------------------------------------
+
+_STATE_KEYS = ("ssm_state_updates", "cache_bytes{kind=pages}",
+               "cache_bytes{kind=state}", "cache_uniform_bytes",
+               "kv_page_copy_bytes")
+
+
+@pytest.fixture(scope="module")
+def served_state():
+    from benchmark.systems.falcon_h1_server import Served, request
+    from triton_dist_tpu import finalize_distributed
+    cache_dir = jax.config.jax_compilation_cache_dir
+    with open(os.path.join(_REPO, "benchmark", "testdata",
+                           "tiny-falcon-h1.json")) as f:
+        cfg = json.load(f)
+    s = Served(cfg, 2**31 + 47, jax.devices()[:1], trace=True)
+    try:
+        before = s.stats()
+        msgs = list(request(s.host, s.port, list(range(3, 22)), 8, 300.0))
+        assert msgs[-1].get("done") and not msgs[-1].get("error"), msgs[-1]
+        yield s, before, s.stats()
+    finally:
+        s.stop()
+        assert not s.errors, s.errors
+        finalize_distributed()
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+
+
+@pytest.mark.parametrize("key", _STATS_KEYS + _STATE_KEYS)
+def test_state_adapter_stats_carry_the_key_a_reader_takes(served_state,
+                                                           key):
+    _, _, after = served_state
+    assert key in after, key
+
+
+def test_state_adapter_counts_what_the_metric_files_divide(served_state):
+    """`cache.state_bytes_pct.reply` divides the state gauge by state
+    plus pages at the close; `ssm_state_updates` counts (live slot,
+    layer) updates, chunk steps a tick; every series a `.reply` metric
+    file names is in stats()."""
+    s, before, after = served_state
+    assert s.pool_pages() > 0 and (s.batch, s.chunk) == (4, 4)
+    d = lambda k: after[k] - before.get(k, 0)  # noqa: E731
+    # one live slot, 2 layers, 4 steps a tick: the 8 tokens took 2 ticks
+    # or more (the first token comes from the admission)
+    n = d("ssm_state_updates")
+    assert n > 0 and n % (1 * 2 * 4) == 0
+    assert "cache_bytes{kind=window}" not in after
+    for name in os.listdir(os.path.join(_REPO, "benchmark", "metrics")):
+        if not name.endswith(".reply.json"):
+            continue
+        with open(os.path.join(_REPO, "benchmark", "metrics", name)) as f:
+            args = json.load(f).get("args", {})
+        for k in ("numerator", "denominator"):
+            assert args.get(k, "tokens_emitted") in after, (name, k)
+        for k in args.get("numerators", []) + args.get("denominators", []):
+            assert k in after, (name, k)

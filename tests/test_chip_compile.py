@@ -6,7 +6,8 @@ cannot — a slice not aligned to the tiling, a kernel over the VMEM
 limit, a shape Mosaic refuses — at no chip time. Shapes are the ones
 `chip_smoke.py` drives (B=8, Hq=16, Hkv=8, d=128, max_seq 512, page 16,
 FFN 6144, bf16). The `phi4_` cases are Phi-4-mini-flash's new kernels and
-walks at its published widths (models/phi4flash.py).
+walks at its published widths (models/phi4flash.py), the `fh_` cases
+Falcon-H1's SSD kernels at its own (kernels/ssd.py).
 
 The topology is described inside a module-scoped fixture and nowhere at
 import: the TPU library admits one process at a time, xdist workers all
@@ -276,6 +277,22 @@ def _afmoe_ragged(pairs):
 _AF_PROMPT = [((AF_P, AF_HQ, D), BF16), ((AF_P, AF_HKV, D), BF16),
               ((AF_P, AF_HKV, D), BF16)]
 
+# --- Falcon-H1-34B at its published widths, the shapes of the cell
+# falcon-h1-34b-pp18.longreply-saturated: 64 slots; 32 SSM heads of 128
+# channels in 2 groups, a state of 256; a 1,024-token admission in
+# chunks of 128
+FH_B, FH_H, FH_P, FH_G, FH_N, FH_S = 64, 32, 128, 2, 256, 1024
+
+
+def _ssd_scan(x, dt, bm, cm, a, d, s0, n):
+    from triton_dist_tpu.kernels.ssd import ssd_scan
+    return ssd_scan(x, dt, bm, cm, a, d, s0, n)
+
+
+def _ssd_step(x, dt, bm, cm, a, d, s, keep):
+    from triton_dist_tpu.kernels.ssd import ssd_step
+    return ssd_step(x, dt, bm, cm, a, d, s, keep)
+
 # name -> (function, [(shape, dtype), ...]); () is a traced scalar
 CASES = {
     # the window layers' walk of decode: every slot's ring of 2,048
@@ -353,6 +370,20 @@ CASES = {
                     ((P4_B, P4_N), F32), ((P4_B, P4_N), F32),
                     ((P4_N, P4_E), F32), ((P4_E,), F32),
                     ((P4_B, P4_N, P4_E), F32), ((P4_B,), jnp.bool_)]),
+    # Falcon-H1's SSD kernels: the admission's chunked scan over a
+    # 1,024-token prompt, and the decode's one-token update of 64 slots'
+    # [32, 256, 128] float32 states, aliased in place
+    "fh_ssd_scan_s1024": (
+        _ssd_scan, [((FH_S, FH_H, FH_P), F32), ((FH_S, FH_H), F32),
+                    ((FH_S, FH_G, FH_N), F32), ((FH_S, FH_G, FH_N), F32),
+                    ((FH_H,), F32), ((FH_H,), F32),
+                    ((FH_H, FH_N, FH_P), F32), ((), I32)]),
+    "fh_ssd_step_b64": (
+        _ssd_step, [((FH_B, FH_H, FH_P), F32), ((FH_B, FH_H), F32),
+                    ((FH_B, FH_G, FH_N), F32), ((FH_B, FH_G, FH_N), F32),
+                    ((FH_H,), F32), ((FH_H,), F32),
+                    ((FH_B, FH_H, FH_N, FH_P), F32),
+                    ((FH_B,), jnp.bool_)]),
     # window attention of a prefill: 256 query rows over the 768 keys
     # they can see, the window mask in the kernel
     "phi4_window_prefill_q256": (

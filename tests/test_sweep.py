@@ -133,7 +133,7 @@ def test_registry_declares_schedule_spaces():
     uniform-key spaces; fp-order-changing knobs stay out by contract
     (flash block_t / ep_fused block_i are never tunable axes)."""
     reg = kernel_registry()
-    assert len(reg) == 31
+    assert len(reg) == 33
     tuned = {n for n, s in reg.items() if s.tunables}
     assert {"flash_decode", "flash_decode_paged",
             "flash_decode_paged_partial", "grouped_gemm", "ag_gemm",
@@ -409,7 +409,7 @@ def test_cli_dry_run_enumerates_every_kernel(monkeypatch, tmp_path,
     out = capsys.readouterr().out
     lines = [ln for ln in out.splitlines() if ln and not
              ln.startswith(" ")]
-    assert len(lines) == len(kernel_registry()) == 31
+    assert len(lines) == len(kernel_registry()) == 33
     paged = [ln for ln in lines if ln.startswith("flash_decode_paged ")]
     assert paged and "surviving= 3" in paged[0]
     assert "prune {\"block_w\": 8}" in out

@@ -452,6 +452,35 @@ def _b_gdn(mesh):
     return (lambda *a: gdn_fwd(*a), (q, k, v, g, b))
 
 
+def _b_ssd_step(mesh):
+    import jax.numpy as jnp
+    import numpy as np
+    from triton_dist_tpu.kernels.ssd import ssd_step
+    rng = _np_rng(22)
+    B, H, P, G, N = 2, 8, 128, 2, 128
+    x = _f32(rng, B, H, P)
+    dt = jnp.asarray(rng.rand(B, H) * 0.1, jnp.float32)
+    bm, cm = _f32(rng, B, G, N), _f32(rng, B, G, N)
+    a = -jnp.arange(1, H + 1, dtype=jnp.float32)
+    d = jnp.ones((H,), jnp.float32)
+    keep = jnp.asarray(np.array([True, False]))
+    return (lambda s_: ssd_step(x, dt, bm, cm, a, d, s_, keep),
+            (_f32(rng, B, H, N, P),))
+
+
+def _b_ssd_scan(mesh):
+    import jax.numpy as jnp
+    from triton_dist_tpu.kernels.ssd import ssd_scan
+    rng = _np_rng(23)
+    S, H, P, G, N = 256, 8, 128, 2, 128
+    dt = jnp.asarray(rng.rand(S, H) * 0.1, jnp.float32)
+    a = -jnp.arange(1, H + 1, dtype=jnp.float32)
+    d = jnp.ones((H,), jnp.float32)
+    return (lambda x_, b_, c_: ssd_scan(x_, dt, b_, c_, a, d,
+                                        jnp.zeros((H, N, P), jnp.float32)),
+            (_f32(rng, S, H, P), _f32(rng, S, G, N), _f32(rng, S, G, N)))
+
+
 def _b_flash_attention(mesh):
     from triton_dist_tpu.kernels.flash_attn_train import flash_attention
     rng = _np_rng(19)
@@ -606,5 +635,8 @@ def kernel_registry() -> dict:
                    ablation_phases=("exps", "solve", "out", "state")),
         KernelSpec("flash_attention", "kernels.flash_attn_train",
                    "compute", _b_flash_attention),
+        KernelSpec("ssd_step", "kernels.ssd", "compute", _b_ssd_step,
+                   inplace=((4, 1),)),
+        KernelSpec("ssd_scan", "kernels.ssd", "compute", _b_ssd_scan),
     ]
     return {s.name: s for s in specs}

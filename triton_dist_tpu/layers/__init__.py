@@ -16,6 +16,7 @@ from triton_dist_tpu.layers.ep_moe import EP_MoE  # noqa: F401
 from triton_dist_tpu.layers.mla_attn import MLA_Attn  # noqa: F401
 from triton_dist_tpu.layers.sparse_attn import SA_Attn  # noqa: F401
 from triton_dist_tpu.layers.gated_attn import GatedAttn  # noqa: F401
+from triton_dist_tpu.layers.mamba2 import Mamba2  # noqa: F401
 from triton_dist_tpu.layers.sp_attn import (  # noqa: F401
     SPAttn,
     UlyssesAttn,

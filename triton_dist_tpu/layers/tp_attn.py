@@ -13,6 +13,7 @@ reduces+scatters back to sequence sharding.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Optional
@@ -61,6 +62,10 @@ class TP_Attn:
     w_qkv: [D, (Hq + 2*Hkv) * hd] — n per-rank blocks [q_r | k_r | v_r].
     w_o:   [Hq * hd, D] — row-parallel.
     q_norm/k_norm: per-head-dim RMSNorm weights (Qwen3 QK-norm).
+    scope: a name for the paged decode walk's events (`jax.named_scope`
+    around the kernel, inside the shard_map), for a family whose trace
+    must tell its walk apart from kernels of the same shape; None
+    leaves the walk named by its place (`shard_map`).
     """
 
     w_qkv: jax.Array
@@ -72,11 +77,13 @@ class TP_Attn:
     n_heads: int = dataclasses.field(metadata=dict(static=True))
     n_kv_heads: int = dataclasses.field(metadata=dict(static=True))
     head_dim: int = dataclasses.field(metadata=dict(static=True))
+    scope: Optional[str] = dataclasses.field(default=None,
+                                             metadata=dict(static=True))
 
     @staticmethod
     def init(w_q, w_k, w_v, w_o, *, mesh: Mesh, axis: str = "tp",
              n_heads: int, n_kv_heads: int, head_dim: int,
-             q_norm=None, k_norm=None):
+             q_norm=None, k_norm=None, scope: Optional[str] = None):
         n = mesh.shape[axis]
         packed = shard_cols_packed([w_q, w_k, w_v], n)
         packed = jax.device_put(packed, NamedSharding(mesh, P(None, axis)))
@@ -86,7 +93,8 @@ class TP_Attn:
                        q_norm=None if q_norm is None else jnp.asarray(q_norm),
                        k_norm=None if k_norm is None else jnp.asarray(k_norm),
                        mesh=mesh, axis=axis, n_heads=n_heads,
-                       n_kv_heads=n_kv_heads, head_dim=head_dim)
+                       n_kv_heads=n_kv_heads, head_dim=head_dim,
+                       scope=scope)
 
     # per-rank sizes
     @property
@@ -743,10 +751,12 @@ class TP_Attn:
             lens = pos + 1
             qd = jnp.bfloat16 if quant else ck.dtype
             if impl == "flash":
-                o = flash_decode_paged(q.astype(qd), ck, cv, tbl,
-                                       jnp.max(lens), scale=scale,
-                                       kv_lens=lens, k_scale=sk,
-                                       v_scale=sv)
+                with (jax.named_scope(self.scope) if self.scope
+                      else contextlib.nullcontext()):
+                    o = flash_decode_paged(q.astype(qd), ck, cv, tbl,
+                                           jnp.max(lens), scale=scale,
+                                           kv_lens=lens, k_scale=sk,
+                                           v_scale=sv)
             else:
                 kd = dequantize_kv_int8(ck, sk) if quant else ck
                 vd = dequantize_kv_int8(cv, sv) if quant else cv

@@ -5,6 +5,9 @@ Qwen3-MoE -> Qwen3MoE)."""
 
 from triton_dist_tpu.models.afmoe import (Afmoe, AfmoeConfig,  # noqa: F401
                                           tiny_afmoe)
+from triton_dist_tpu.models.falcon_h1 import (FalconH1,  # noqa: F401
+                                              FalconH1Config,
+                                              tiny_falcon_h1)
 from triton_dist_tpu.models.config import (ModelConfig, SAConfig,  # noqa: F401
                                            qwen3_30b_a3b, qwen3_32b,
                                            tiny_qwen3, tiny_qwen3_moe)

@@ -298,7 +298,7 @@ class Afmoe:
         return HybridSlotCache.create_hybrid(
             batch, max_seq, heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
             window=cfg.sliding_window, window_layers=attn.count("swa"),
-            state_layers=0, d_inner=0, d_state=0, d_conv=1,
+            state_layers=0,
             attn_layers=cfg.num_layers, page=page, num_pages=num_pages,
             mesh=self.mesh, axis=self.axis, dtype=dtype or cfg.jax_dtype,
             paged_layers=attn.count("full"), fused=True)

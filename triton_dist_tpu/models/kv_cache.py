@@ -465,7 +465,8 @@ class IndexedSlotCache(PagedSlotCache):
 @dataclasses.dataclass
 class HybridSlotCache(PagedSlotCache):
     """One slot cache for a model whose layers keep up to THREE kinds of
-    per-slot state (models/phi4flash.py, models/afmoe.py):
+    per-slot state (models/phi4flash.py, models/afmoe.py,
+    models/falcon_h1.py):
 
     (a) pages behind the shared table, inherited from PagedSlotCache:
         `paged_layers` pools, `pages_k[i]` the i-th paged layer's.
@@ -475,7 +476,8 @@ class HybridSlotCache(PagedSlotCache):
         (`fused`: K and V in ONE plane [NP, 2 heads, page, d], a
         page's first `heads` rows the keys, its last the values, so
         the decode walk fetches both with one copy a page, as
-        IndexedSlotCache; `pages_v` is then empty). Allocator, table
+        IndexedSlotCache; `pages_v` is then empty); Falcon-H1 has one,
+        K and V in two planes, for each of its layers. Allocator, table
         install, retire-to-trash: all the paged path's own.
     (b) `win_k` / `win_v`: one RING of `window` positions per slot and
         window-attention layer, [B, heads, window, d]. Position t lives
@@ -489,8 +491,12 @@ class HybridSlotCache(PagedSlotCache):
         cached attention (the choice between a ring and pages freed
         behind the window: the ring needs no allocator traffic per 16
         tokens and no window start in the paged walk).
-    (c) `conv` [B, d_conv - 1, E] and `ssm` [B, d_state, E] float32
-        planes per state-space layer: fixed size, not pageable; none
+    (c) `conv` and `ssm` float32 planes per state-space layer, of the
+        shapes a slot's state has in the model (`conv_shape`,
+        `state_shape`): Phi-4-mini-flash's Mamba-1 holds
+        [d_conv - 1, E] and [d_state, E], Falcon-H1's Mamba-2
+        [d_conv - 1, channels] and [heads, d_state, head_dim] in every
+        layer, beside that layer's pages. Fixed size, not pageable; none
         (`state_layers` 0) in a model of attention layers only.
 
     `live` [B] marks the slots that hold an admitted request: a decode
@@ -511,11 +517,13 @@ class HybridSlotCache(PagedSlotCache):
     @staticmethod
     def create_hybrid(batch: int, max_seq: int, *, heads: int,
                       head_dim: int, window: int, window_layers: int,
-                      state_layers: int, d_inner: int, d_state: int,
-                      d_conv: int, attn_layers: int, page: int,
+                      state_layers: int, attn_layers: int, page: int,
                       num_pages: int, mesh: Mesh, axis: str = "tp",
                       dtype=jnp.bfloat16, paged_layers: int = 1,
-                      fused: bool = False) -> "HybridSlotCache":
+                      fused: bool = False, conv_shape: tuple = (),
+                      state_shape: tuple = ()) -> "HybridSlotCache":
+        """`conv_shape` / `state_shape`: what ONE slot holds in each of
+        the `state_layers` layers (section (c))."""
         base = PagedSlotCache.create(
             paged_layers, batch, max_seq, heads * (2 if fused else 1),
             head_dim, page=page, num_pages=num_pages, mesh=mesh,
@@ -532,9 +540,9 @@ class HybridSlotCache(PagedSlotCache):
             table=base.table, trash=base.trash,
             win_k=planes(window_layers, ring, dtype),
             win_v=planes(window_layers, ring, dtype),
-            conv=planes(state_layers, (batch, d_conv - 1, d_inner),
+            conv=planes(state_layers, (batch,) + tuple(conv_shape),
                         jnp.float32),
-            ssm=planes(state_layers, (batch, d_state, d_inner),
+            ssm=planes(state_layers, (batch,) + tuple(state_shape),
                        jnp.float32),
             live=jax.device_put(jnp.zeros((batch,), bool), rep),
             attn_layers=attn_layers)
@@ -549,7 +557,8 @@ class HybridSlotCache(PagedSlotCache):
         it has overwritten them.)"""
         zero = lambda t: tuple(  # noqa: E731
             jax.lax.dynamic_update_slice(
-                a, jnp.zeros((1,) + a.shape[1:], a.dtype), (slot, 0, 0))
+                a, jnp.zeros((1,) + a.shape[1:], a.dtype),
+                (slot,) + (0,) * (a.ndim - 1))
             for a in t)
         return dataclasses.replace(
             self, conv=zero(self.conv), ssm=zero(self.ssm),
@@ -557,16 +566,17 @@ class HybridSlotCache(PagedSlotCache):
 
     def slot_bytes(self) -> dict:
         """Bytes ONE slot holds of each kind: a mapped page of (a) (K
-        and V, every head, every paged layer), its rings (b), its
-        planes (c, left out where the model has none); and what a page
-        would cost in a uniform cache, where each of the `attn_layers`
-        attention layers keeps its own."""
+        and V, every head, every paged layer), its rings (b) and its
+        planes (c), each left out where the model has none; and what a
+        page would cost in a uniform cache, where each of the
+        `attn_layers` attention layers keeps its own."""
         nbytes = lambda t: sum(a[0].nbytes for a in t)  # noqa: E731
         page = nbytes(self.pages_k) + nbytes(self.pages_v)
         out = {"page": page,
-               "window": nbytes(self.win_k) + nbytes(self.win_v),
                "uniform_page": page // len(self.pages_k)
                * self.attn_layers}
+        if self.win_k:
+            out["window"] = nbytes(self.win_k) + nbytes(self.win_v)
         if self.conv:
             out["state"] = nbytes(self.conv) + nbytes(self.ssm)
         return out

@@ -386,8 +386,9 @@ class Phi4Flash:
             batch, max_seq, heads=cfg.num_kv_heads // 2,
             head_dim=2 * cfg.head_dim, window=cfg.sliding_window,
             window_layers=kinds.count("swa"),
-            state_layers=kinds.count("mamba"), d_inner=cfg.d_inner,
-            d_state=cfg.d_state, d_conv=cfg.d_conv,
+            state_layers=kinds.count("mamba"),
+            conv_shape=(cfg.d_conv - 1, cfg.d_inner),
+            state_shape=(cfg.d_state, cfg.d_inner),
             attn_layers=sum(k in ("swa", "full", "cross") for k in kinds),
             page=page,
             num_pages=num_pages, mesh=self.mesh, axis=self.axis,

@@ -1582,6 +1582,15 @@ class PagedDecodeSlots(DecodeSlots):
                 "cache_uniform_bytes",
                 "bytes a uniform cache (every attention layer its own "
                 "full-length pool) would hold for the same live slots")
+        # a model whose live slots advance a recurrent state in every
+        # layer of `state_layers` each decode step counts the updates
+        self._state_layers = int(getattr(self.engine.model.config,
+                                         "state_layers", 0))
+        if self._state_layers:
+            self._c_state_updates = freg.counter(
+                "ssm_state_updates",
+                "(slot, layer) state updates the decode steps made for "
+                "live slots")
 
     def _make_cache(self):
         return self.engine.make_paged_slot_cache(
@@ -1927,6 +1936,10 @@ class PagedDecodeSlots(DecodeSlots):
                                          self.pos, self.active,
                                          chunk=chunk, keys=self.keys,
                                          mask=self._mask_chunk())
+        if self._state_layers:
+            # the step's live mask is the cache's: admitted, not retired
+            self._c_state_updates.inc(
+                len(self.occupied) * chunk * self._state_layers)
         return toks
 
     def _run_verify(self, tokens, q_lens):
